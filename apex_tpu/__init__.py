@@ -29,11 +29,6 @@ test axis (reference: tests/L1/common/run_test.sh).
 
 __version__ = "0.1.0"
 
-# Feature-gated aliases for older jax installs (no-op on current jax);
-# must land before any submodule references jax.shard_map.
-from apex_tpu.utils import jax_compat as _jax_compat  # noqa: E402
-_jax_compat.install()
-
 from apex_tpu import amp  # noqa: F401
 from apex_tpu import ops  # noqa: F401
 from apex_tpu import optimizers  # noqa: F401

@@ -8,13 +8,13 @@ severity-tagged function over one of two view types —
   nothing executes, donated buffers are not consumed) and exposes what
   every jaxpr rule needs: the closed jaxpr (walkable via
   ``analysis.walker``), flat in/out avals with pytree-path labels,
-  per-input donation flags (read off the pjit equation's
+  per-input donation flags (read off the jit equation's
   ``donated_invars``), the ``parallel.Plan`` the program was compiled
   with (so a rule can reason about the selected lowering), and the
   scheduler-lineage metadata the serve engine declares. A trace that
-  *fails* is itself evidence (``trace_error`` — e.g. jax 0.4.37's
-  ``NameError: unbound axis name`` when a named-axis collective can't
-  bind under the program's lowering) and rules may match on it.
+  *fails* is itself evidence (``trace_error`` — e.g. ``NameError:
+  unbound axis name`` when a named-axis collective can't bind under
+  the program's lowering) and rules may match on it.
 - :class:`SourceView`: a parsed Python source file for host-side
   hazard rules (AST + raw lines + inline-suppression table).
 
@@ -123,7 +123,7 @@ class ProgramView:
     """One compiled-step program as the jaxpr rules see it.
 
     ``fn`` should be the *jitted* callable (donation info comes from
-    its pjit equation); a plain callable still traces but reports no
+    its jit equation); a plain callable still traces but reports no
     donation. ``lineages``/``warmup_lineages`` carry the scheduler
     dataflow a donated program participates in (the serve engine
     declares these — see ``ContinuousBatchingEngine.program_lineages``)
@@ -155,7 +155,7 @@ class ProgramView:
         self._cache["closed_jaxpr"] = cj
         donated = None
         eqns = cj.jaxpr.eqns
-        if len(eqns) == 1 and eqns[0].primitive.name == "pjit":
+        if len(eqns) == 1 and eqns[0].primitive.name == "jit":
             donated = tuple(eqns[0].params.get("donated_invars") or ())
             if len(donated) != len(cj.in_avals):
                 donated = None

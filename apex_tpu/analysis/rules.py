@@ -9,8 +9,8 @@ maps each to its round):
   hunted in HLO, now checked at the aval level for every program.
 - ``layout-recompile-hazard`` (error): a donated jitted program is
   reachable from more input-layout lineages than its ``warmup()``
-  covers — the r14 mid-run ~1.2 s recompile stall (jax 0.4.37 keys
-  donated-program jit caches on concrete input LAYOUTS), as a rule.
+  covers — the r14 mid-run ~1.2 s recompile stall (donated-program
+  jit caches key on concrete input LAYOUTS), as a rule.
 - ``host-sync-in-hot-loop`` (error in production paths, warning in
   measurement tools): a blocking fetch / implicit device->host
   conversion inside a timed loop — the class span forensics kept
@@ -20,8 +20,9 @@ maps each to its round):
   control-flow gap (ROADMAP; strict xfail in tests/test_numerics.py),
   via the same ``prof.coverage`` audit that pinned it in r09.
 - ``collective-misuse`` (error): a named-axis collective bound under a
-  Plan lowering that can't carry it — the jax 0.4.37 pjit trap
-  ``parallel/plan.py`` dodges by falling back to shard_map.
+  Plan lowering that can't carry it — jax binds a named axis only
+  under shard_map, the lowering ``parallel/plan.py`` gives a Plan
+  with in_specs/out_specs.
 - ``dead-output`` (warning): a program output its registered caller
   never reads — computed, shipped, dropped.
 - ``bare-json-line`` (error, tools only): a measurement tool printing
@@ -47,7 +48,7 @@ maps each to its round):
   named; an unattributed drop is indistinguishable from a LOST one,
   which is exactly what the zero-drop contract flags).
 - ``page-gather-hazard`` (error): a page-map operand of the paged KV
-  gather rebuilt or fetched inside a timed loop — the r14/0.4.37
+  gather rebuilt or fetched inside a timed loop — the r14
   layout-recompile landmine applied to the r20 paged arena's new
   gather operand. The page table must be a loop-invariant HOST
   ``np.int32`` buffer mutated in place: ``jnp.asarray``/``jnp.array``/
@@ -213,10 +214,9 @@ def collective_misuse(view: ProgramView) -> list:
     Two detection paths: (a) the trace itself failed with jax's
     ``unbound axis name`` — a psum/all_gather reached jit/pjit with no
     shard_map to bind its axis (the exact runtime failure, caught
-    before any device sees it); (b) the trace succeeded under a
-    shard_map fallback but the Plan carries in/out_shardings, so on a
-    jax whose jit accepts shardings the SAME Plan takes the pjit path
-    and the collectives stop binding (the 0.4.37 trap in reverse)."""
+    before any device sees it); (b) the body binds collectives under
+    a shard_map of its own but the Plan carries in/out_shardings, so
+    the Plan takes the pjit path, where named axes do not bind."""
     err = view.trace_error
     low = view.lowering_name()
     if err is not None:
@@ -264,9 +264,8 @@ def collective_misuse(view: ProgramView) -> list:
             rule="collective-misuse", severity="error",
             target=view.name, location=f"plan axes {axes}",
             message=f"body binds named-axis collectives over {axes} "
-                    f"but the Plan also carries in/out_shardings: on "
-                    f"a jax whose jit accepts shardings this Plan "
-                    f"prefers the pjit lowering, where these "
+                    f"but the Plan also carries in/out_shardings: this "
+                    f"Plan takes the pjit lowering, where these "
                     f"collectives cannot bind — drop the shardings or "
                     f"the named collectives",
             details={"axes": axes, "lowering": low}))

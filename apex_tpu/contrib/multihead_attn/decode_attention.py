@@ -19,17 +19,14 @@ per-step question — one query per slot against the slot's lanes of the
 Dispatch mirrors the flash crossover: ``impl='auto'`` routes to the
 kernel only on TPU (``ops.dispatch``), only for supported shapes
 (lanes-aligned head_dim), and only past a minimum arena length —
-resolution ``APEX_DECODE_MIN_L`` env > measured ``_decode_crossover
-.json`` > :data:`DEFAULT_DECODE_MIN_L`. The default is conservative and
-chip-unproven (decode is memory-bound; the kernel's win is avoiding
-score-temporary traffic, which only matters once L is large) — refine
-it on chip the same way ``kernel_bench --write-crossover`` refined the
-flash number.
+resolution ``APEX_DECODE_MIN_L`` env > :data:`DEFAULT_DECODE_MIN_L`.
+The default is conservative and was never swept on a chip (decode is
+memory-bound; the kernel's win is avoiding score-temporary traffic,
+which only matters once L is large).
 """
 
 from __future__ import annotations
 
-import json
 import os
 from typing import Optional
 
@@ -44,29 +41,18 @@ __all__ = ["slot_decode_attention", "reference_slot_decode_attention",
 
 _IMPLS = ("auto", "reference", "pallas")
 
-# Smallest arena max_len 'auto' sends to the Pallas kernel. Chip-window
-# backlog: sweep on hardware and write _decode_crossover.json; until
-# then this stays past the CPU-smoke shapes and below the long-context
-# pools where score-temporary HBM traffic dominates the step.
+# Smallest arena max_len 'auto' sends to the Pallas kernel: past the
+# CPU-smoke shapes and below the long-context pools where
+# score-temporary HBM traffic dominates the step. Not yet swept on a
+# chip; the sweep that would justify another number is a later PR.
 DEFAULT_DECODE_MIN_L = 1024
 
 
-def crossover_path() -> str:
-    return os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "_decode_crossover.json")
-
-
 def decode_min_l() -> int:
-    """APEX_DECODE_MIN_L env > measured _decode_crossover.json >
-    DEFAULT_DECODE_MIN_L (read at trace time, same as flash_min_s)."""
+    """APEX_DECODE_MIN_L env > DEFAULT_DECODE_MIN_L (read at trace
+    time, same as flash_min_s)."""
     env = os.environ.get("APEX_DECODE_MIN_L")
-    if env:
-        return int(env)
-    try:
-        with open(crossover_path()) as f:
-            return int(json.load(f)["decode_min_l"])
-    except Exception:
-        return DEFAULT_DECODE_MIN_L
+    return int(env) if env else DEFAULT_DECODE_MIN_L
 
 
 def gather_pages(pool: jax.Array, page_table: jax.Array) -> jax.Array:

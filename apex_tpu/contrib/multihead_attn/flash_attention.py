@@ -770,36 +770,23 @@ def _bwd_impl() -> str:
 # (apex/contrib/examples/multihead_attn/perf_test_multihead_attn.py is
 # its own crossover evidence); on TPU the shoe is on the other foot:
 # XLA's composed attention beat this kernel 12x at S=1024 while the
-# kernel wins 1.84x at S=4096 and is the ONLY path at S=16384
-# (KBENCH_r04_flash.txt). impl='auto' in the modules routes below-
-# crossover sequence lengths to reference_attention. 4096 is the
-# conservative default — the smallest S where the kernel's win is
-# on-chip-proven; tools/kernel_bench.py --only flash_crossover
-# --write-crossover refines it into _crossover.json (an autotune
-# record, same spirit as the measured BN-welford demotion).
+# kernel wins 1.84x at S=4096 and is the ONLY path at S=16384 (r04,
+# docs/PERF.md "Attention crossover"). impl='auto' in the modules routes
+# below-crossover sequence lengths to reference_attention. 4096 is the
+# conservative default — the smallest S where the kernel's win was
+# measured; tools/kernel_bench.py --only flash_crossover prints the
+# sweep that would justify another number.
 DEFAULT_FLASH_MIN_S = 4096
-
-
-def crossover_path() -> str:
-    import os
-    return os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "_crossover.json")
 
 
 def flash_min_s() -> int:
     """Smallest max(Sq, Sk) the 'auto' dispatch sends to the Pallas
-    kernel. Resolution: APEX_FLASH_MIN_S env > measured _crossover.json
-    > DEFAULT_FLASH_MIN_S. Read at trace time (cheap: once per compile)."""
-    import json
+    kernel: APEX_FLASH_MIN_S env > DEFAULT_FLASH_MIN_S — a function of
+    the environment and of committed code, never of a file git does
+    not track. Read at trace time (cheap: once per compile)."""
     import os
     env = os.environ.get("APEX_FLASH_MIN_S")
-    if env:
-        return int(env)
-    try:
-        with open(crossover_path()) as f:
-            return int(json.load(f)["flash_min_s"])
-    except Exception:
-        return DEFAULT_FLASH_MIN_S
+    return int(env) if env else DEFAULT_FLASH_MIN_S
 
 
 def _flash_core_bwd(causal, scale, block_q, block_k, bwd_block_q,
@@ -853,7 +840,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     defaults up to MAX_BLOCK); ``bwd_block_q``/``bwd_block_k`` tile the
     backward kernels independently (their VMEM working set is ~3x the
     forward's — bwd 512x512 measured a 9x VMEM-spill cliff on v5e,
-    KBENCH_r04_flash_blocks; sweep with ``tools/kernel_bench.py --only
+    docs/PERF.md r04 block sweep; sweep with ``tools/kernel_bench.py --only
     flash_blocks``). ``bwd_block_k`` defaults to ``block_k``;
     ``bwd_block_q`` defaults to ``block_q`` capped at the largest of
     {256, 192, 128} that divides the padded length (for block_q > 256).
@@ -887,7 +874,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # Adaptive default: wide blocks keep the MXU matmuls fat and cut the
     # grid-step count up to 16x vs a fixed 128 — at S=16k the fixed size
     # meant 262k sequential grid steps and the kernel ran
-    # grid-overhead-bound (~1.5% MFU, PERF_r03.md). The pick is
+    # grid-overhead-bound (~1.5% MFU, docs/PERF.md r03). The pick is
     # divisor-aware (largest of 512/384/256/128 dividing the 128-rounded
     # length) so mid-length sequences don't pay pad blowup; note a wider
     # block changes the online-softmax accumulation ORDER for
@@ -903,7 +890,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     kpad = (-sk) % block_k
     # Backward blocks default to the forward's CAPPED at q<=256 (k can
     # stay wide): the bwd kernels hold ~3x the forward's VMEM working
-    # set, and the r4 on-chip sweep (KBENCH_r04_flash_blocks) measured
+    # set, and the r4 on-chip sweep (docs/PERF.md r04 block sweep) measured
     # bwd 512x512 at 162.8 ms vs 18.4 ms for 256x512 at S=4096 — a VMEM
     # spill cliff. 256x512 was the sweep's best; the cap costs <7% vs
     # any other measured combo and avoids the 9x cliff. Overrides must

@@ -97,7 +97,7 @@ class _AttnBase:
     # composed jnp attention; 'auto' -> measured crossover dispatch:
     # flash at max(Sq, Sk) >= flash_min_s, composed below it (XLA's
     # composed attention beats the kernel at short S on TPU —
-    # KBENCH_r04_flash.txt; same honesty as the BN-welford demotion)
+    # docs/PERF.md r04; same honesty as the BN-welford demotion)
     impl: str = "fast"
     # reference positions 7-8 (self_multihead_attn.py:29): separate
     # q/k/v parameter tensors instead of the packed in_proj, and a
@@ -105,7 +105,7 @@ class _AttnBase:
     separate_qkv_params: bool = False
     mask_additive: bool = False
     # crossover override for impl='auto'; None = flash_attention.
-    # flash_min_s() (env > measured _crossover.json > 4096 default)
+    # flash_min_s() (env > 4096 default)
     flash_min_s: Optional[int] = None
     causal: bool = False
     # Sequence parallelism: when seq_axis is set, the attention core runs

@@ -67,7 +67,6 @@ from jax.sharding import PartitionSpec as P
 
 from apex_tpu.ops import flat as _flat
 from apex_tpu.ops import reference as R
-from apex_tpu.utils import jax_compat as _compat
 
 __all__ = ["DistributedFusedAdam", "DistributedFusedLAMB"]
 
@@ -171,7 +170,7 @@ class _DistributedBase:
         if self.gradient_predivide:
             world = self.num_shards
             if self.replica_axis_name is not None:
-                world = world * _compat.axis_size(self.replica_axis_name)
+                world = world * lax.axis_size(self.replica_axis_name)
             flat = flat / world
         shard = lax.psum_scatter(flat, self.axis_name,
                                  scatter_dimension=0, tiled=True)
@@ -326,7 +325,7 @@ class DistributedFusedLAMB(_DistributedBase):
         over any replica axis, so no second psum). Segments are
         (num_shards*ALIGN)-aligned, so the shard-local partials take the
         shared aligned fast path — an element-level segment_sum would be
-        a serialized TPU scatter (PERF_r03.md)."""
+        a serialized TPU scatter (docs/PERF.md r03)."""
         part = R.segment_sumsq_aligned(x, ids, num_seg + 1)
         return jnp.sqrt(lax.psum(part, self.axis_name))[:num_seg]
 

@@ -17,7 +17,7 @@ Loss/grad semantics match ``softmax_cross_entropy_loss`` exactly
 
 This is scan + MXU matmuls, not a Pallas kernel: each chunk step is one
 ``[N, D] @ [D, C]`` matmul XLA fuses the online-softmax update into —
-the measured round-3 lesson (PERF_r03.md: XLA beats hand kernels for
+the measured round-3 lesson (docs/PERF.md r03: XLA beats hand kernels for
 everything it can fuse; the win here is the algorithmic memory bound,
 which no per-op fusion can deliver).
 """

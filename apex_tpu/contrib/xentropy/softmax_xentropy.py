@@ -31,7 +31,7 @@ import jax.numpy as jnp
 
 
 def _use_pallas_xent(logits) -> bool:
-    # Measured on v5e (PERF_r03.md): XLA's fused logsumexp+recompute path
+    # Measured on v5e (docs/PERF.md r03): XLA's fused logsumexp+recompute path
     # runs the fwd+bwd ~1.2x faster than the blocked Pallas kernels at
     # both 32k and 256k vocab (the lse-recompute custom_vjp already gives
     # the memory saving; the kernel adds boundary cost, not fusion).

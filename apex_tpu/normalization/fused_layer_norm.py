@@ -72,7 +72,7 @@ def _keepdims_shape(x_shape, normalized_shape):
 
 
 def _use_pallas_ln(x, normalized_shape) -> bool:
-    # Measured on v5e (PERF_r03.md): XLA's fused LN matches the Pallas
+    # Measured on v5e (docs/PERF.md r03): XLA's fused LN matches the Pallas
     # kernels at F in {8192, 32768} (0.96-0.98x) and wins 7x at
     # F=1024 x 8192 rows, so "auto" takes the XLA path; the kernels stay
     # parity-tested behind an explicit backend="pallas".

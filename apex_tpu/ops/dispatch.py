@@ -72,7 +72,7 @@ def resolve_crossover(reference_fn, pallas_fn, size: int, min_size: int):
     """:func:`resolve` with a measured crossover gate: route to the
     Pallas kernel only past ``min_size`` (flash_attention's
     ``S >= flash_min_s`` rule generalized — below the crossover XLA's
-    composed program is the faster one even on TPU, KBENCH_r04_flash).
+    composed program is the faster one even on TPU, docs/PERF.md r04).
     ``size`` is whatever dimension the kernel's win scales with."""
     if pallas_fn is not None and use_pallas() and size >= min_size:
         return pallas_fn

@@ -172,7 +172,7 @@ def unflatten(flat: jax.Array, table: SegmentTable,
 
     ``dtype`` converts on the FLAT buffer before slicing: one fused convert
     instead of one per leaf — per-leaf converts each pay XLA per-op
-    overhead (~9 ms total for RN50's 161 params on a v5e, PERF_r03.md).
+    overhead (~9 ms total for RN50's 161 params on a v5e, docs/PERF.md r03).
 
     Differentiating through ``unflatten(master, table, half)`` is the fast
     way to get flat master grads, so the transpose is pinned via

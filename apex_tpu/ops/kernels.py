@@ -102,7 +102,7 @@ def novograd_step(g, p, m, v_norms, segment_ids, *,
 
 def lamb_step(g, p, m, v, segment_ids, num_segments, *,
               aligned_segments: bool = False, **kw):
-    # Measured on v5e (PERF_r03.md): XLA fuses the whole two-phase LAMB
+    # Measured on v5e (docs/PERF.md r03): XLA fuses the whole two-phase LAMB
     # into ~2 sweeps (4.3 ms for 25.6M params) while the Pallas composition
     # pays per-kernel boundaries and skinny per-row norm outputs (7.5-21
     # ms). "auto" therefore takes the aligned XLA path; the Pallas kernel

@@ -102,7 +102,7 @@ def segment_sum_dense(vals: jax.Array, ids: jax.Array,
 
     ``jax.ops.segment_sum`` lowers to an XLA scatter-add, which the TPU
     executes one update at a time (~10 ms for 200k rows — measured as the
-    dominant cost of a whole LAMB step, PERF_r03.md). For the few-hundred
+    dominant cost of a whole LAMB step, docs/PERF.md r03). For the few-hundred
     segment counts of an optimizer table, a dense (n, num_segments)
     masked reduce is exact per-segment fp32 tree summation (no
     long-running-cumsum cancellation), fully vectorized, and XLA fuses

@@ -10,7 +10,7 @@ Public surface (reference: apex/parallel/__init__.py:10-21):
 - ``Plan`` / ``compile_step_with_plan`` — the sharding-plan layer: specs
   live in a Plan object, ONE compile entry point for every distributed
   step (pjit when global-view shardings are given, shard_map for
-  per-device bodies — the required path on this box's jax 0.4.37)
+  per-device bodies with named-axis collectives)
 - ``launch.initialize`` / ``launch.multiproc`` — multi-host / local spawn
 """
 

@@ -130,12 +130,7 @@ class DistributedDataParallel:
             # getattr guard (ADVICE r4): a leaf whose type carries no vma
             # info falls back to classic semantics (assume varying -> do
             # the psum) instead of raising inside a check_vma region.
-            # jax.typeof itself is absent on jax 0.4.37 (ROADMAP
-            # "Environment drift") — same fallback.
-            try:
-                vma = getattr(jax.typeof(g), "vma", None)
-            except AttributeError:
-                vma = None
+            vma = getattr(jax.typeof(g), "vma", None)
             already_summed = tracking and vma is not None \
                 and self.axis_name not in vma
             if self.allreduce_always_fp32:

@@ -2,12 +2,15 @@
 
 The reference spawns one process per GPU with ``--rank i`` args
 (multiproc.py:12-35) because NCCL is process-per-device. The JAX runtime is
-process-per-HOST: a single process drives all local chips, and multi-host
-jobs call ``jax.distributed.initialize`` once per host — so the launcher's
-job here is (a) a thin initialize wrapper, and (b) a local CPU-simulation
-spawner for testing multi-process code paths without hardware (something
-the reference never had; its distributed tests require real GPUs,
-SURVEY §4).
+process-per-HOST: on one host the supported shape is ONE process driving
+all local chips through a mesh (``make_mesh`` + ``Plan``). A chip belongs
+to one process at a time, so N local children that each initialize the
+TPU would each try to claim every chip, and all but one fail or hang.
+Multi-host jobs call ``jax.distributed.initialize`` once per host — so the
+launcher's job here is (a) a thin initialize wrapper, and (b) a local
+CPU-simulation spawner for testing multi-process code paths without
+hardware (something the reference never had; its distributed tests
+require real GPUs, SURVEY §4). (b) never starts a child on the chip.
 """
 
 from __future__ import annotations
@@ -48,22 +51,31 @@ def multiproc(script: str, world_size: int, *script_args: str,
     """Spawn ``world_size`` local CPU processes running ``script`` — the
     reference launcher's shape (multiproc.py:12-35: one process per device,
     non-rank-0 stdout to files), retargeted at CPU-simulated multi-process
-    testing. Each child gets WORLD_SIZE/RANK env vars and a single-CPU JAX
-    platform. Returns the first non-zero child exit status (signal deaths
-    included via their negative returncode), 0 if all succeeded."""
-    procs = []
-    for rank in range(world_size):
-        env = dict(os.environ,
-                   WORLD_SIZE=str(world_size), RANK=str(rank),
-                   JAX_PLATFORMS="cpu")
-        argv = [sys.executable, script, *script_args]
-        if rank == 0:
-            p = subprocess.Popen(argv, env=env)
-        else:
-            out = open(os.path.join(log_dir, f"rank{rank}.log"), "w")
-            p = subprocess.Popen(argv, env=env, stdout=out, stderr=out)
-        procs.append(p)
-    codes = [p.wait() for p in procs]
+    testing. Each child gets WORLD_SIZE/RANK env vars and is PINNED to the
+    CPU platform (``JAX_PLATFORMS=cpu``, whatever the caller's
+    environment says): N children sharing this host's device visibility
+    would each claim the same local chips. Returns the first non-zero
+    child exit status (signal deaths included via their negative
+    returncode), 0 if all succeeded."""
+    procs, logs = [], []
+    try:
+        for rank in range(world_size):
+            env = dict(os.environ,
+                       WORLD_SIZE=str(world_size), RANK=str(rank),
+                       JAX_PLATFORMS="cpu")
+            argv = [sys.executable, script, *script_args]
+            if rank == 0:
+                p = subprocess.Popen(argv, env=env)
+            else:
+                out = open(os.path.join(log_dir, f"rank{rank}.log"), "w")
+                logs.append(out)
+                p = subprocess.Popen(argv, env=env, stdout=out,
+                                     stderr=out)
+            procs.append(p)
+        codes = [p.wait() for p in procs]
+    finally:
+        for out in logs:
+            out.close()
     return next((rc for rc in codes if rc != 0), 0)
 
 

@@ -75,8 +75,7 @@ def gpipe(layer_fn: Callable, local_layers, x: jax.Array, *,
     s = num_stages
     m = num_microbatches
     b = x.shape[0]
-    from apex_tpu.utils.jax_compat import axis_size as _axis_size
-    axis = _axis_size(axis_name)
+    axis = lax.axis_size(axis_name)
     if axis != s:
         raise ValueError(
             f"num_stages={s} != size of mesh axis {axis_name!r} ({axis}); "
@@ -117,9 +116,8 @@ def gpipe(layer_fn: Callable, local_layers, x: jax.Array, *,
     # pipe axis); mark the zero-init carries varying up front so
     # shard_map's static replication checking (check_vma) accepts the
     # scan — the final psum restores a provably-replicated output
-    from apex_tpu.utils.jax_compat import pcast_varying as _pcast
-    h0 = _pcast(jnp.zeros_like(micro[0]), axis_name)
-    out0 = _pcast(jnp.zeros_like(micro), axis_name)
+    h0 = lax.pcast(jnp.zeros_like(micro[0]), (axis_name,), to="varying")
+    out0 = lax.pcast(jnp.zeros_like(micro), (axis_name,), to="varying")
     (_, out_buf), _ = lax.scan(tick, (h0, out0), jnp.arange(m + s - 1))
     # broadcast the last rank's collected outputs to every rank
     out = lax.psum(jnp.where(rank == last, out_buf, 0.0), axis_name)

@@ -19,10 +19,9 @@ Two lowerings, chosen by which spec family the Plan carries:
   against ``plan.mesh``) or full ``Sharding`` objects.
 - ``in_specs``/``out_specs`` (per-device body with explicit named-axis
   collectives — ``psum``/``psum_scatter``/``all_gather``) ->
-  **shard_map**. This is the required lowering on this container's
-  jax 0.4.37, where named-axis collectives cannot bind under plain
-  pjit (ROADMAP "Environment drift"): a Plan carrying BOTH families
-  lowers via pjit where that works and falls back to shard_map here.
+  **shard_map**, the only lowering under which jax binds a named axis
+  (plain jit raises ``unbound axis name``). A Plan carrying BOTH
+  families lowers via pjit.
 - neither -> plain ``jax.jit`` (a single-device Plan is still a Plan:
   the call site keeps one compile path everywhere).
 
@@ -35,15 +34,10 @@ compiled.
 from __future__ import annotations
 
 import dataclasses
-import inspect
 from typing import Any, Callable, Optional
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, Sharding
-
-from apex_tpu.utils import jax_compat as _compat
-
-_compat.install()  # jax.shard_map (check_vma=) on old jaxlibs
 
 __all__ = ["Plan", "PlanCompilationError", "compile_step_with_plan",
            "place_with_specs"]
@@ -55,17 +49,6 @@ class PlanCompilationError(ValueError):
     def __init__(self, msg: str, hint: str = ""):
         super().__init__(f"{msg}\n  hint: {hint}" if hint else msg)
         self.hint = hint
-
-
-def _jit_supports_shardings() -> bool:
-    """Whether this jax's ``jit`` accepts in/out_shardings (the pjit
-    path). Feature-probed once — some older jaxlibs only expose the
-    experimental pjit entry point."""
-    try:
-        params = inspect.signature(jax.jit).parameters
-    except (TypeError, ValueError):
-        return False
-    return "in_shardings" in params and "out_shardings" in params
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,11 +87,7 @@ class Plan:
         """Which path :func:`compile_step_with_plan` will take:
         ``"pjit"`` / ``"shard_map"`` / ``"jit"``."""
         if self.in_shardings is not None or self.out_shardings is not None:
-            if _jit_supports_shardings():
-                return "pjit"
-            if self.in_specs is not None or self.out_specs is not None:
-                return "shard_map"   # this box's fallback
-            return "pjit"            # will raise with the upgrade hint
+            return "pjit"
         if self.in_specs is not None or self.out_specs is not None:
             return "shard_map"
         return "jit"
@@ -134,13 +113,14 @@ def _as_shardings(tree, mesh: Optional[Mesh]):
 
 
 def place_with_specs(tree: Any, mesh: Mesh, spec_tree: Any) -> Any:
-    """``device_put`` a pytree according to a matching pytree of
-    PartitionSpecs (e.g. a ZeRO optimizer's ``state_pspec()``), so the
-    first plan-compiled call starts from the declared placement instead
-    of an implicit reshard."""
+    """``device_put`` a pytree according to a pytree of PartitionSpecs
+    (a prefix tree, like a Plan's ``in_specs``: one spec covers a whole
+    subtree — e.g. a ZeRO optimizer's ``state_pspec()``, or ``P()`` for
+    a replicated state), so the first plan-compiled call starts from
+    the declared placement instead of an implicit reshard."""
     return jax.tree_util.tree_map(
-        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
-        tree, spec_tree)
+        lambda s, sub: jax.device_put(sub, NamedSharding(mesh, s)),
+        spec_tree, tree, is_leaf=lambda s: isinstance(s, P))
 
 
 def _note_plan(plan: Plan, lowering: str, body_name: str) -> None:
@@ -178,11 +158,6 @@ def compile_step_with_plan(body: Callable, plan: Plan, *,
                 "out_shardings for the pjit path",
                 "pass both, or use in_specs/out_specs for a per-device "
                 "(shard_map) body")
-        if not _jit_supports_shardings():
-            raise PlanCompilationError(
-                "this jax's jit does not accept in/out_shardings",
-                "upgrade jax, or give the Plan in_specs/out_specs so it "
-                "can fall back to shard_map")
         try:
             compiled = jax.jit(
                 body,

@@ -88,7 +88,7 @@ def _folded_upcast() -> bool:
     identical for fp32 inputs; for bf16 the x^2 rounds to bf16 before
     accumulation (relative 2^-8 per element — same tolerance class as
     the MXU-moments rewrite, pinned by the parity test). UNMEASURED on
-    chip: stays opt-in until a window A/B decides it (PERF_r06.md has
+    chip: stays opt-in until a window A/B decides it (docs/PERF.md r06 has
     the arm commands)."""
     import os
     return os.environ.get("APEX_BN_FOLDED_UPCAST") == "1"
@@ -145,7 +145,7 @@ def _use_pallas_bn(x, channel_axis) -> bool:
     from apex_tpu.ops import dispatch
     if dispatch.get_backend() != "pallas":
         # "auto" lets XLA fuse the BN reductions. Measured head-to-head on
-        # a v5e chip (PERF_r03.md): RN50's 53 BNs cost ~16 ms/step this way
+        # a v5e chip (docs/PERF.md r03): RN50's 53 BNs cost ~16 ms/step this way
         # vs ~150 ms through the Pallas welford kernels — the kernel
         # boundary forces the activation through HBM per call and pays
         # per-grid-step overhead 53x, while XLA folds the reductions into
@@ -270,7 +270,7 @@ def _bn_train_bwd_out(eps, axis_name, groups, fuse_relu, channel_axis, res,
     # (welford.cu:387). The Pallas path streams x/dy in their storage
     # dtype and recomputes xhat in-kernel — materializing fp32 xhat/masked
     # dy around a kernel boundary was the dominant cost of the whole RN50
-    # step (~150 ms/step at batch 256; see PERF_r03.md).
+    # step (~150 ms/step at batch 256; see docs/PERF.md r03).
     if use_pallas:
         from apex_tpu.ops.pallas import welford as P
         c = x.shape[ca]

@@ -37,6 +37,8 @@ from typing import Callable, Optional
 
 import jax
 
+from apex_tpu.prof.peaks import ChipPeak, PEAKS, chip_peak  # noqa: F401
+
 __all__ = ["annotate", "mark", "trace", "analyze", "CostReport", "init",
            "OpStats", "top_ops", "format_top_ops", "RooflineSummary",
            "roofline", "gaps", "Gap", "GapReport", "TimelineEvent",
@@ -150,12 +152,6 @@ class CostReport:
         return "\n".join(lines)
 
 
-# v5e-class defaults; override per generation.
-_TPU_PEAK = {"tpu": (197e12, 819e9)}  # (bf16 flops/s, HBM B/s) per chip
-# 197e12 = v5e bf16 (matches tools/_perf_common.V5E_BF16_PEAK — 394 is
-# the int8 rate and was silently halving every default-peak MFU here)
-
-
 def analyze(fn: Callable, *example_args,
             peak_flops_per_s: Optional[float] = None,
             hbm_bw_bytes_per_s: Optional[float] = None,
@@ -169,11 +165,11 @@ def analyze(fn: Callable, *example_args,
     if isinstance(ca, (list, tuple)):
         ca = ca[0] if ca else {}
     ca = ca or {}
-    if peak_flops_per_s is None or hbm_bw_bytes_per_s is None:
-        peak = _TPU_PEAK.get(jax.default_backend())
-        if peak:
-            peak_flops_per_s = peak_flops_per_s or peak[0]
-            hbm_bw_bytes_per_s = hbm_bw_bytes_per_s or peak[1]
+    if (peak_flops_per_s is None or hbm_bw_bytes_per_s is None) \
+            and jax.default_backend() == "tpu":
+        peak = chip_peak()
+        peak_flops_per_s = peak_flops_per_s or peak.bf16_flops_per_s
+        hbm_bw_bytes_per_s = hbm_bw_bytes_per_s or peak.hbm_bytes_per_s
     return CostReport(
         flops=float(ca.get("flops", 0.0)),
         bytes_accessed=float(ca.get("bytes accessed", 0.0)),
@@ -210,9 +206,10 @@ class OpStats:
         return self.bytes_per_s * self.self_time_us * 1e-6
 
     def efficiency(self, peak_flops_per_s: Optional[float] = None) -> float:
-        """Achieved / peak FLOP rate (MFU of this op's busy time)."""
+        """Achieved / peak FLOP rate (MFU of this op's busy time);
+        the peak defaults to the attached chip's (``prof.peaks``)."""
         if peak_flops_per_s is None:
-            peak_flops_per_s = _TPU_PEAK.get("tpu")[0]
+            peak_flops_per_s = chip_peak().bf16_flops_per_s
         return self.flops_per_s / peak_flops_per_s
 
 
@@ -336,7 +333,7 @@ def _top_ops_from_events(xplane_paths: list[str]) -> list[OpStats]:
 class RooflineSummary:
     """Whole-capture roofline verdict from a :func:`trace` directory —
     the analysis that pinned the r4 RN50 step at ~96% of the v5e HBM
-    roofline (PERF_r04.md), as a library call."""
+    roofline (docs/PERF.md r04), as a library call."""
     busy_us: float             # device busy (non-IDLE) self time
     idle_us: float
     flops: float               # total attributed FLOPs over the capture
@@ -365,7 +362,8 @@ class RooflineSummary:
 def roofline(trace_dir: Optional[str] = None, *,
              stats: Optional[list[OpStats]] = None,
              peak_flops_per_s: Optional[float] = None,
-             peak_bytes_per_s: Optional[float] = None) -> RooflineSummary:
+             peak_bytes_per_s: Optional[float] = None,
+             device_kind: Optional[str] = None) -> RooflineSummary:
     """Aggregate a :func:`top_ops` capture into one roofline verdict.
 
     Answers "is this program bandwidth- or compute-bound, and how close
@@ -376,10 +374,10 @@ def roofline(trace_dir: Optional[str] = None, *,
     Pass ``stats`` (an un-truncated :func:`top_ops` result) to reuse an
     already-parsed capture — xplane parsing is the expensive step.
 
-    Peaks default to v5e (197 TF bf16, 819 GB/s) because captures are
-    usually analyzed off-host where ``jax.default_backend()`` says
-    nothing about the chip that produced them; pass explicit peaks for
-    other hardware.
+    Peaks come from the ``prof.peaks`` table for ``device_kind``
+    (default: the attached device). Captures are usually analyzed away
+    from the chip that produced them: name that chip's kind there, or
+    pass explicit peaks — an unknown kind raises.
 
     Raises ``ValueError`` on captures without device rate counters
     (host/CPU fallback rows) — a 0 TF/s, 0 GB/s "verdict" would be
@@ -388,9 +386,12 @@ def roofline(trace_dir: Optional[str] = None, *,
         if trace_dir is None:
             raise ValueError("pass trace_dir or stats")
         stats = top_ops(trace_dir)
-    peak = _TPU_PEAK["tpu"]
-    peak_f = peak[0] if peak_flops_per_s is None else peak_flops_per_s
-    peak_b = peak[1] if peak_bytes_per_s is None else peak_bytes_per_s
+    if peak_flops_per_s is None or peak_bytes_per_s is None:
+        peak = chip_peak(device_kind)
+        if peak_flops_per_s is None:
+            peak_flops_per_s = peak.bf16_flops_per_s
+        if peak_bytes_per_s is None:
+            peak_bytes_per_s = peak.hbm_bytes_per_s
     idle = sum(s.self_time_us for s in stats if s.op_type == "IDLE")
     busy_rows = [s for s in stats if s.op_type != "IDLE"]
     busy = sum(s.self_time_us for s in busy_rows)
@@ -408,7 +409,8 @@ def roofline(trace_dir: Optional[str] = None, *,
         busy_us=busy, idle_us=idle, flops=flops, bytes_accessed=byts,
         achieved_flops_per_s=flops / busy_s,
         achieved_bytes_per_s=byts / busy_s,
-        peak_flops_per_s=peak_f, peak_bytes_per_s=peak_b,
+        peak_flops_per_s=peak_flops_per_s,
+        peak_bytes_per_s=peak_bytes_per_s,
         hbm_bound_pct=100.0 * hbm / max(busy, 1e-9))
 
 

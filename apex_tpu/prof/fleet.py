@@ -49,9 +49,8 @@ the latency histogram. Measured on the CPU bench loop: within run noise
 
 Offline provability: the gathers ride a ``pmap`` psum over every
 device once ``jax.distributed`` is initialized; on runtimes whose
-backend refuses multiprocess computations (this container's jax
-0.4.37 CPU client — the same drift that fails the suite's pmap-psum
-multiproc test, ROADMAP "Environment drift"), they feature-probe and
+backend refuses multiprocess computations (a CPU client with no
+cross-process collectives), they feature-probe and
 degrade to the jax.distributed coordination-service key-value store —
 a real cross-process exchange with identical record output, so the
 whole layer is provable with
@@ -82,9 +81,8 @@ _GATHER_CACHE: dict = {}
 # traced collective under the `apex_fleet_probe` scope) or "kv" (the
 # jax.distributed coordination-service key-value store — the degrade
 # path for backends whose runtime refuses multiprocess computations,
-# e.g. this container's jax 0.4.37 CPU client, where even the suite's
-# own pmap-psum multiproc test fails with "Multiprocess computations
-# aren't implemented on the CPU backend"). Same records either way; the
+# e.g. a CPU client that raises "Multiprocess computations aren't
+# implemented on the CPU backend"). Same records either way; the
 # traced named scope only exists on the psum path.
 _TRANSPORT: dict = {"mode": None}
 _KV_GEN = {"n": 0}

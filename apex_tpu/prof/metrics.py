@@ -21,9 +21,8 @@ Overhead discipline (the <2% budget):
   jax arrays and held by REFERENCE; the host fetch happens once per
   :meth:`~MetricsLogger.flush`, never per step — no extra host syncs
   on the step path.
-- compile tracking rides ``jax.monitoring`` listeners (feature-probed
-  via :func:`apex_tpu.utils.jax_compat.monitoring_available`), which
-  fire only when XLA actually traces/compiles.
+- compile tracking rides ``jax.monitoring`` listeners, which fire only
+  when XLA actually traces/compiles.
 - memory watermarks (``device.memory_stats()``) and the collective-bytes
   tally (:mod:`apex_tpu.parallel.collectives`) are sampled at flush
   boundaries only.
@@ -352,11 +351,7 @@ class CompileTracker:
 
     @classmethod
     def install(cls) -> "CompileTracker | None":
-        """Register a fresh tracker (deactivating any previous one).
-        Returns None when this jax has no monitoring listener API."""
-        from apex_tpu.utils import jax_compat
-        if not jax_compat.monitoring_available():
-            return None
+        """Register a fresh tracker (deactivating any previous one)."""
         import jax.monitoring as _m
         with cls._lock:
             if cls._installed is not None:

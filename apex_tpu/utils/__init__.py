@@ -5,7 +5,7 @@ from apex_tpu.utils.checkpoint import (  # noqa: F401
     AsyncCheckpoint, save_checkpoint, load_checkpoint, verify_checkpoint,
 )
 from apex_tpu.utils.host_init import (  # noqa: F401
-    host_init, ship, setup_host_backend, extend_platforms_with_cpu,
-    check_no_silent_fallback,
+    host_init, ship, setup_host_backend, require_accelerator,
+    enable_compile_cache,
 )
 from apex_tpu.utils import xla_flags  # noqa: F401

@@ -11,6 +11,7 @@ apex/multi_tensor_apply/multi_tensor_apply.py:20-22).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -23,8 +24,19 @@ _CSRC = os.path.join(os.path.dirname(
 _SRCS = [os.path.join(_CSRC, "flat_runtime.cpp"),
          os.path.join(_CSRC, "image_pipeline.cpp")]
 _BUILD_DIR = os.path.join(_CSRC, "_build")
-_LIB_NAME = "libapex_tpu_runtime.so"
-_LIB_PATH = os.path.join(_BUILD_DIR, _LIB_NAME)
+
+
+def _lib_name() -> str:
+    """The library's name carries the content hash of the sources it
+    was built from, so a ``.so`` that is loaded provably matches
+    ``csrc/*.cpp`` — a stale build left in an (ignored) ``_build/``, or
+    in the per-user temp dir by another checkout, has another name and
+    is simply never found."""
+    h = hashlib.sha256()
+    for src in _SRCS:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return f"libapex_tpu_runtime-{h.hexdigest()[:16]}.so"
 
 
 def _tmp_build_dir() -> str:
@@ -48,7 +60,7 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> Optional[str]:
+def _build(name: str) -> Optional[str]:
     # Build next to the source when the install is writable; otherwise
     # (read-only site-packages) fall back to a per-user 0700 temp dir.
     for build_dir in (_BUILD_DIR, _tmp_build_dir()):
@@ -58,11 +70,15 @@ def _build() -> Optional[str]:
             continue
         if build_dir != _BUILD_DIR and not _dir_is_safe(build_dir):
             continue  # pre-existing dir owned by someone else
-        lib = os.path.join(build_dir, _LIB_NAME)
+        lib = os.path.join(build_dir, name)
+        # build under a private name, then rename: another process never
+        # opens a half-written library
+        tmp = f"{lib}.{os.getpid()}.tmp"
         cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-               *_SRCS, "-o", lib]
+               *_SRCS, "-o", tmp]
         try:
             subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+            os.replace(tmp, lib)
             return lib
         except (subprocess.SubprocessError, FileNotFoundError, OSError):
             continue
@@ -77,45 +93,17 @@ def load() -> Optional[ctypes.CDLL]:
             return _lib
         _tried = True
         tmp_dir = _tmp_build_dir()
-        candidates = [_LIB_PATH]
+        name = _lib_name()
+        candidates = [os.path.join(_BUILD_DIR, name)]
         if _dir_is_safe(tmp_dir):
-            candidates.append(os.path.join(tmp_dir, _LIB_NAME))
-
-        def _fresh(p):
-            # a cached .so predating any source is stale (missing symbols)
-            try:
-                built = os.path.getmtime(p)
-                return all(built >= os.path.getmtime(s) for s in _SRCS)
-            except OSError:
-                return False
-
-        path = next((p for p in candidates if _fresh(p)),
-                    None) or _build()
+            candidates.append(os.path.join(tmp_dir, name))
+        path = next((p for p in candidates if os.path.exists(p)),
+                    None) or _build(name)
         if path is None:
             return None
-
-        def _open(p):
-            try:
-                lib = ctypes.CDLL(p)
-            except OSError:
-                return None
-            lib.apex_tpu_native_abi_version.restype = ctypes.c_int
-            # ABI 3 added the PPM decode tier (apex_tpu_ppm_dims /
-            # apex_tpu_decode_ppm_augment_u8); a cached .so from an older
-            # source tree can pass the mtime heuristic (shared per-user
-            # temp dir across checkouts) — reject and rebuild instead of
-            # AttributeError-ing later
-            if lib.apex_tpu_native_abi_version() != 3:
-                return None
-            if not hasattr(lib, "apex_tpu_decode_ppm_augment_u8"):
-                return None
-            return lib
-
-        lib = _open(path)
-        if lib is None:
-            path = _build()
-            lib = _open(path) if path else None
-        if lib is None:
+        try:
+            lib = ctypes.CDLL(path)
+        except OSError:
             return None
         lib.apex_tpu_fnv1a64.restype = ctypes.c_uint64
         _lib = lib

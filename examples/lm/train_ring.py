@@ -74,8 +74,8 @@ def main():
     from apex_tpu.parallel import make_mesh
     from apex_tpu.utils import load_checkpoint, save_checkpoint
 
-    # host-side init + one replicated placement (the bench.py move) +
-    # loud failure if a pinned remote platform silently fell back to cpu
+    # the strict device gate, then host-side init + one replicated
+    # placement (the bench.py move)
     from apex_tpu.utils import setup_host_backend, host_init, ship
     setup_host_backend()
 

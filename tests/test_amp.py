@@ -253,7 +253,7 @@ class TestO2:
     def test_params_cast_coalesced_single_convert(self):
         """Cast coalescing (r06): under jit the O2 param cast must be
         ONE flat-buffer convert, not one per leaf (the per-leaf shape
-        cost ~9 ms/step at RN50's 161 params, PERF_r03.md) — and the
+        cost ~9 ms/step at RN50's 161 params, docs/PERF.md r03) — and the
         values must be bit-identical to the per-leaf cast."""
         params = {"dense": {"kernel": jnp.arange(12.0).reshape(3, 4),
                             "bias": jnp.ones((4,))},

@@ -237,8 +237,8 @@ class TestCollectiveMisuse:
         assert fs[0].details["lowering"] == "jit"
 
     def test_fires_under_pjit_plan(self):
-        """The 0.4.37 trap parallel/plan.py dodges: named-axis
-        collectives cannot bind under the pjit lowering."""
+        """Named-axis collectives cannot bind under the pjit lowering
+        (jax binds them only under shard_map)."""
         from jax.sharding import PartitionSpec as P
 
         from apex_tpu.parallel import Plan, compile_step_with_plan

@@ -1,0 +1,210 @@
+"""Compile the main path's Pallas kernels for a DESCRIBED v5e.
+
+The TPU's compiler is installed beside the CPU backend and compiles for
+a chip that is described, not attached (``on-chip-measurement`` guide,
+section 2): what it refuses here — a misaligned slice, too much VMEM, a
+program that does not fit HBM — it would refuse on the chip, and costs
+no chip time to find. Nothing runs, so these are not chip runs and say
+nothing about results or speed.
+
+The kernels read ``jax.default_backend()`` to choose interpret mode and
+the dispatch side; the fixture steers that here, in the test.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+FLAT = 128 * 1024 * 1024            # >= the dense LM's 138.5M-param masters
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e device, or skip."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:            # no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def for_chip(monkeypatch):
+    """Take the on-chip branches (interpret off, Pallas dispatch) and
+    keep the persistent compile cache out of it: an entry compiled for
+    a described chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from apex_tpu.ops import dispatch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dispatch._default_platform.cache_clear()
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+    dispatch._default_platform.cache_clear()
+
+
+def _flash(bwd, **kw):
+    from apex_tpu.contrib.multihead_attn import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, **kw)
+    if not bwd:
+        return fwd
+    return jax.grad(lambda q, k, v: jnp.sum(
+        fwd(q, k, v).astype(F32) ** 2), argnums=(0, 1, 2))
+
+
+def _qkv(b, h, s, d):
+    return [((b, h, s, d), BF16)] * 3
+
+
+def _dense_decode(q, k, v, n):
+    from apex_tpu.ops.pallas.decode_attn import decode_attention
+    return decode_attention(q, k, v, n)
+
+
+def _paged_decode(q, k, v, n, table):
+    from apex_tpu.ops.pallas.decode_attn import paged_decode_attention
+    return paged_decode_attention(q, k, v, n, page_table=table)
+
+
+def _dense_args(s, h, ln, hd):
+    return [((s, h, hd), BF16), ((s, h, ln, hd), BF16),
+            ((s, h, ln, hd), BF16), ((s,), I32)]
+
+
+def _paged_args(s, h, page, pages, hd):
+    pool = ((s * pages + 1, h, page, hd), BF16)
+    return [((s, h, hd), BF16), pool, pool, ((s,), I32),
+            ((s, pages), I32)]
+
+
+def _forced_pallas(fn):
+    """LayerNorm and xentropy take their kernels only under an explicit
+    ``backend("pallas")`` ("auto" keeps XLA's fusion, which measured
+    faster on v5e in r03 — docs/PERF.md)."""
+    from apex_tpu.ops import dispatch
+
+    def traced(*args):
+        with dispatch.backend("pallas"):
+            return fn(*args)
+    return traced
+
+
+def _layer_norm(f):
+    from apex_tpu.normalization import fused_layer_norm_affine
+    return _forced_pallas(jax.value_and_grad(lambda x, w, b: jnp.sum(
+        fused_layer_norm_affine(x, (f,), w, b) ** 2), argnums=(0, 1, 2)))
+
+
+def _ln_args(rows, f):
+    return [((rows, f), F32), ((f,), F32), ((f,), F32)]
+
+
+def _xentropy():
+    from apex_tpu.contrib.xentropy import softmax_cross_entropy_loss
+    return _forced_pallas(lambda logits, labels: jax.value_and_grad(
+        lambda l: jnp.sum(softmax_cross_entropy_loss(
+            l, labels, padding_idx=None, half_to_float=True)))(logits))
+
+
+def _multi_tensor(name, **kw):
+    from apex_tpu.ops.pallas import multi_tensor
+    return functools.partial(getattr(multi_tensor, name), **kw)
+
+
+_ADAM = dict(lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-8, step=1)
+_LAMB = dict(lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-6, step=1,
+             weight_decay=0.01, global_grad_norm=1.0)
+_SEGS = 8                           # lamb's per-parameter trust ratios
+
+# (id, builder of the traced function, [(shape, dtype), ...])
+KERNELS = [
+    ("dense_decode-32x8x8192x128", lambda: _dense_decode,
+     _dense_args(32, 8, 8192, 128)),
+    ("dense_decode-32x8x2048x128", lambda: _dense_decode,
+     _dense_args(32, 8, 2048, 128)),
+    ("paged_decode-page32x64", lambda: _paged_decode,
+     _paged_args(32, 8, 32, 64, 128)),
+    ("paged_decode-page16x128", lambda: _paged_decode,
+     _paged_args(32, 8, 16, 128, 128)),
+    ("flash_fwd-B8H8S4096D128", lambda: _flash(False),
+     _qkv(8, 8, 4096, 128)),
+    ("flash_fwd_bwd-B8H8S4096D128", lambda: _flash(True),
+     _qkv(8, 8, 4096, 128)),
+    ("flash_fwd-B1H8S16384D128", lambda: _flash(False),
+     _qkv(1, 8, 16384, 128)),
+    ("flash_fwd_bwd-B1H8S16384D128", lambda: _flash(True),
+     _qkv(1, 8, 16384, 128)),
+    ("flash_fwd-B8H16S4096D64", lambda: _flash(False),
+     _qkv(8, 16, 4096, 64)),
+    ("flash_fwd_bwd-B8H16S4096D64", lambda: _flash(True),
+     _qkv(8, 16, 4096, 64)),
+    ("layer_norm_fwd_bwd-F1024", lambda: _layer_norm(1024),
+     _ln_args(8 * 4096, 1024)),
+    ("layer_norm_fwd_bwd-F4096", lambda: _layer_norm(4096),
+     _ln_args(4096, 4096)),
+    ("layer_norm_fwd_bwd-F16384-wide", lambda: _layer_norm(16384),
+     _ln_args(520, 16384)),
+    ("xentropy_fwd_bwd-V32768", _xentropy,
+     [((4096, 32768), BF16), ((4096,), I32)]),
+    ("xentropy_fwd_bwd-V50304", _xentropy,
+     [((4096, 50304), BF16), ((4096,), I32)]),
+    ("scale-128M", lambda: _multi_tensor("scale", scale_factor=0.5),
+     [((FLAT,), F32)]),
+    ("l2norm-128M", lambda: _multi_tensor("l2norm"), [((FLAT,), F32)]),
+    ("adam_step-128M", lambda: _multi_tensor("adam_step", **_ADAM),
+     [((FLAT,), F32)] * 4),
+    ("lamb_step-128M", lambda: (lambda g, p, m, v, seg: _multi_tensor(
+        "lamb_step", **_LAMB)(g, p, m, v, seg, _SEGS)),
+     [((FLAT,), F32)] * 4 + [((FLAT,), I32)]),
+]
+
+
+def _compile(fn, args, sharding):
+    specs = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+             for shape, dtype in args]
+    return jax.jit(fn).lower(*specs).compile()
+
+
+@pytest.mark.parametrize("make_fn,args",
+                         [pytest.param(m, a, id=i) for i, m, a in KERNELS])
+def test_kernel_compiles_for_v5e(chip, for_chip, make_fn, args):
+    compiled = _compile(make_fn(), args, chip)
+    assert "tpu_custom_call" in compiled.as_text()   # not interpreted
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + mem.output_size_in_bytes < 16e9            # fits one v5e's HBM
+
+
+def test_engine_paged_decode_program_compiles_for_v5e(chip, for_chip):
+    """The serving engine's paged decode step, as ``lint_programs()``
+    describes it, at the dense LM's widths (depth and arena cut so the
+    host copy stays small)."""
+    from apex_tpu.models import TransformerLM
+    from apex_tpu.serve import ContinuousBatchingEngine
+    lm = TransformerLM(vocab_size=32768, max_seq_len=2048, embed_dim=1024,
+                       num_heads=8, num_layers=2)
+    params = jax.tree.map(lambda t: t.astype(BF16) if t.dtype == F32 else t,
+                          lm.init(jax.random.key(0)))
+    engine = ContinuousBatchingEngine(
+        lm, params, slots=8, max_len=2048, prefill_chunk=32, fused=True,
+        paged=True, page_size=32, prefix_share=True)
+    decode, = [p for p in engine.lint_programs()
+               if p["name"].endswith(".decode")]
+    specs = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        np.shape(x), x.dtype, sharding=chip), decode["args"])
+    text = decode["fn"].lower(*specs).compile().as_text()
+    # one paged decode-attention kernel per layer
+    assert text.count("tpu_custom_call") >= lm.num_layers

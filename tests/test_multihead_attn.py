@@ -382,7 +382,7 @@ class TestEncdecMultiheadAttn:
 def test_default_bwd_blocks_odd_and_long_lengths():
     """Default backward-block selection: long sequences cap bwd_block_q
     at a {256,192,128} divisor of the padded length (the bwd-512 VMEM
-    cliff, KBENCH_r04_flash_blocks); odd mid-lengths like S=300 (padded
+    cliff, docs/PERF.md r04 block sweep); odd mid-lengths like S=300 (padded
     304, no such divisor) keep the forward block instead of collapsing
     to a sliver tile. Values AND grads must match the reference at both
     kinds of length."""
@@ -471,26 +471,37 @@ class TestAutoCrossoverDispatch:
                                    np.asarray(outs["default"]),
                                    rtol=RTOL, atol=ATOL)
 
-    def test_threshold_resolution_env_beats_file_beats_default(
-            self, monkeypatch, tmp_path):
+    def test_threshold_resolution_env_beats_default(self, monkeypatch):
+        """The kernel choice is a function of the environment and of
+        committed code — no untracked file beside the module moves
+        it."""
         import importlib
+        import os
         # the package __init__ re-exports the flash_attention FUNCTION
         # under the submodule's name; import_module gets the module
         FA = importlib.import_module(
             "apex_tpu.contrib.multihead_attn.flash_attention")
-        # default: no env, no record
+        DA = importlib.import_module(
+            "apex_tpu.contrib.multihead_attn.decode_attention")
         monkeypatch.delenv("APEX_FLASH_MIN_S", raising=False)
-        monkeypatch.setattr(FA, "crossover_path",
-                            lambda: str(tmp_path / "absent.json"))
-        assert FA.flash_min_s() == FA.DEFAULT_FLASH_MIN_S
-        # measured record beats the default
-        rec = tmp_path / "_crossover.json"
-        rec.write_text('{"flash_min_s": 2048}\n')
-        monkeypatch.setattr(FA, "crossover_path", lambda: str(rec))
-        assert FA.flash_min_s() == 2048
-        # env beats the record
+        monkeypatch.delenv("APEX_DECODE_MIN_L", raising=False)
+        here = os.path.dirname(FA.__file__)
+        written = [os.path.join(here, n) for n in
+                   ("_crossover.json", "_decode_crossover.json")]
+        try:
+            for path, body in zip(written, ('{"flash_min_s": 2048}',
+                                            '{"decode_min_l": 64}')):
+                with open(path, "w") as f:
+                    f.write(body)
+            assert FA.flash_min_s() == FA.DEFAULT_FLASH_MIN_S
+            assert DA.decode_min_l() == DA.DEFAULT_DECODE_MIN_L
+        finally:
+            for path in written:
+                os.remove(path)
         monkeypatch.setenv("APEX_FLASH_MIN_S", "1024")
+        monkeypatch.setenv("APEX_DECODE_MIN_L", "256")
         assert FA.flash_min_s() == 1024
+        assert DA.decode_min_l() == 256
 
     def test_crossover_threshold_rule(self):
         import sys as _sys

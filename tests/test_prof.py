@@ -310,7 +310,10 @@ def test_roofline_summary(tmp_path):
            bytes_per_s=700e9, bound_by="HBM"),
         mk(op="IDLE", op_type="IDLE", self_time_us=20_000.0),
     ]
-    r = prof.roofline(stats=stats)
+    with pytest.raises(ValueError, match="no published peak"):
+        prof.roofline(stats=stats)   # cpu: no peak is assumed
+    r = prof.roofline(stats=stats, device_kind="TPU v5 lite")
+    assert r.peak_flops_per_s == 197e12 and r.peak_bytes_per_s == 819e9
     assert r.busy_us == 100_000.0 and r.idle_us == 20_000.0
     # time-weighted rates over busy time
     exp_f = (60e12 * 0.06 + 1e12 * 0.04) / 0.1
@@ -320,8 +323,8 @@ def test_roofline_summary(tmp_path):
     assert r.mfu == r.achieved_flops_per_s / r.peak_flops_per_s
     assert r.bandwidth_util == r.achieved_bytes_per_s / r.peak_bytes_per_s
     # explicit peak override honored (and 0.0 is not treated as unset)
-    assert prof.roofline(stats=stats,
-                         peak_flops_per_s=1e12).peak_flops_per_s == 1e12
+    assert prof.roofline(stats=stats, peak_flops_per_s=1e12,
+                         peak_bytes_per_s=1e9).peak_flops_per_s == 1e12
 
     # a real CPU capture carries no device counters -> ValueError
     @jax.jit
@@ -334,7 +337,7 @@ def test_roofline_summary(tmp_path):
     with prof.trace(logdir):
         f(a, a).block_until_ready()
     with pytest.raises(ValueError, match="counters"):
-        prof.roofline(logdir)
+        prof.roofline(logdir, device_kind="TPU v5 lite")
 
 
 class TestScopesUnderJit:

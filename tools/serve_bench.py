@@ -65,7 +65,7 @@ import json
 import sys
 import time
 import os
-# repo root importable from any launcher env (watcher has no PYTHONPATH)
+# repo root importable without PYTHONPATH
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 _feed = lambda: None  # rebound by arm_watchdog in main()
@@ -245,9 +245,8 @@ def main():
                                 poisson_requests, summarize_serving)
     from apex_tpu.utils import setup_host_backend
 
-    setup_host_backend()
-    on_tpu = jax.default_backend() == "tpu"
-    if not on_tpu:  # CPU smoke config: shrink the MODEL, keep the load
+    on_tpu = setup_host_backend() == "tpu"
+    if not on_tpu:  # explicit CPU request: shrink the MODEL, keep the load
         args.layers, args.dim, args.heads, args.vocab = 2, 128, 4, 512
         args.max_len = min(args.max_len, 64)
         args.prefill_chunk = min(args.prefill_chunk, 8)

@@ -53,6 +53,10 @@ def main():
     ap.add_argument("--max-unattributed-pct", type=float, default=10.0,
                     help="--strict threshold: max %% of dead time the "
                          "classifier may leave unattributed (default 10)")
+    ap.add_argument("--device-kind", default=None,
+                    help="device_kind of the chip that made the capture "
+                         "(prof.peaks table key, e.g. 'TPU v5 lite'); "
+                         "default: the attached device")
     args = ap.parse_args()
 
     from apex_tpu import prof
@@ -61,7 +65,7 @@ def main():
         sys.stderr.write("no Device rows; showing Host rows\n")
     print(prof.format_top_ops(stats[:args.top]))
     try:
-        r = prof.roofline(stats=stats)
+        r = prof.roofline(stats=stats, device_kind=args.device_kind)
         print(f"\nroofline: busy {r.busy_us / 1e3:.1f} ms "
               f"(idle {r.idle_us / 1e3:.1f}), "
               f"{r.achieved_bytes_per_s / 1e9:.0f} GB/s "
