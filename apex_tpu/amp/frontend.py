@@ -134,22 +134,28 @@ class AmpHandle:
         return tuple(s.init() for s in self.scalers)
 
     # -- per-step ops -----------------------------------------------------
+    # each under prof.SCOPES' "amp_scale" (metadata only)
+    @jax.named_scope("amp_scale")
     def scale_loss(self, loss, amp_state, loss_id: int = 0):
         return self.scalers[loss_id].scale_loss(loss, amp_state[loss_id])
 
+    @jax.named_scope("amp_scale")
     def unscale(self, flat_grads, amp_state, loss_id: int = 0):
         return self.scalers[loss_id].unscale(flat_grads, amp_state[loss_id])
 
+    @jax.named_scope("amp_scale")
     def unscale_with_stashed(self, flat_grads, stashed, amp_state,
                              loss_id: int = 0):
         return self.scalers[loss_id].unscale_with_stashed(
             flat_grads, stashed, amp_state[loss_id])
 
+    @jax.named_scope("amp_scale")
     def update(self, amp_state, found_inf, loss_id: int = 0):
         new = self.scalers[loss_id].update(amp_state[loss_id], found_inf)
         return tuple(new if i == loss_id else s
                      for i, s in enumerate(amp_state))
 
+    @jax.named_scope("amp_scale")
     def update_with_census(self, amp_state, found_inf, grads, census=None,
                            loss_id: int = 0, table=None):
         """:meth:`update` plus overflow provenance (r09 numerics — see

@@ -97,15 +97,21 @@ def iter_eqns(jaxpr) -> Iterator[EqnView]:
     if hasattr(jaxpr, "jaxpr"):
         jaxpr = jaxpr.jaxpr
 
-    def walk(j, cf_label: Optional[str],
-             axes: frozenset) -> Iterator[EqnView]:
+    def walk(j, cf_label: Optional[str], axes: frozenset,
+             around: str = "main") -> Iterator[EqnView]:
         for eqn in j.eqns:
             subs = sub_jaxprs(eqn)
             is_cf = eqn.primitive.name in CF_PRIMS
-            scope = cf_label if cf_label else scope_of(eqn)
+            # a body's name stacks start anew (pjit, linear_call,
+            # pallas_call): an equation with no scope of its own is in
+            # the module that holds the equation around it
+            module = scope_of(eqn)
+            if module == "main":
+                module = around
+            scope = cf_label if cf_label else module
             children = ()
             if subs and is_cf:
-                outer = cf_label or scope_of(eqn)
+                outer = cf_label or module
                 children = tuple(
                     f"{eqn.primitive.name}:{label}@{outer}"
                     for label, _ in subs)
@@ -120,6 +126,6 @@ def iter_eqns(jaxpr) -> Iterator[EqnView]:
             for (label, sub), child in zip(
                     subs, children or [None] * len(subs)):
                 yield from walk(sub, child if is_cf else cf_label,
-                                new_axes)
+                                new_axes, module)
 
     yield from walk(jaxpr, None, frozenset())

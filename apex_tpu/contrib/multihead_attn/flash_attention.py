@@ -298,6 +298,7 @@ def _flash_fwd(q, k, v, bias, kvb, offs, *, causal, scale, block_q, block_k,
             pltpu.VMEM((block_q, LANES), jnp.float32),
         ],
         interpret=_interpret(),
+        name="apex_flash_fwd",
     )(*args)
     return o, lse[:, :, 0]
 
@@ -525,6 +526,7 @@ def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
         out_shape=dq_out_shape,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
+        name="apex_flash_bwd_dq",
     )(*args)
     if dbias_in_dq:
         dq, dbias = dq_res
@@ -547,6 +549,7 @@ def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
             out_shape=_sds((1, sq, sk), jnp.float32, vma=vma),
             scratch_shapes=[pltpu.VMEM((block_q, block_k), jnp.float32)],
             interpret=_interpret(),
+            name="apex_flash_bwd_dbias",
         )(*args).astype(bias.dtype)
     if has_bias and not emit_dbias:
         dbias = jnp.zeros_like(bias)
@@ -575,6 +578,7 @@ def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=_interpret(),
+        name="apex_flash_bwd_dkv",
     )(*args)
     return dq, dk, dv, dbias
 

@@ -154,6 +154,7 @@ class _DistributedBase:
                                  (idx * self.shard_size,),
                                  (self.shard_size,))
 
+    @jax.named_scope("collective")      # prof.SCOPES: metadata only
     def _reduce_scatter(self, grads, scale):
         """grads: pytree (local, unsummed) or flat [N] buffer. Returns the
         summed-and-averaged local grad shard [N/n] in fp32 (the
@@ -179,6 +180,7 @@ class _DistributedBase:
             shard = lax.psum(shard, self.replica_axis_name)
         return shard
 
+    @jax.named_scope("collective")
     def _all_gather_params(self, master_shard):
         gathered = lax.all_gather(
             master_shard.astype(self.gather_dtype), self.axis_name,
@@ -202,8 +204,9 @@ class _DistributedBase:
         the local [N/n] shards, ``grads`` the device-local grads (pytree or
         flat [N]). Returns (new_state, params_tree in model dtype)."""
         g_shard = self._reduce_scatter(grads, jnp.asarray(scale, jnp.float32))
-        new_master, new_slots = self._update_shard(state, g_shard)
-        new_state = self._finish(state, new_master, new_slots, found_inf)
+        with jax.named_scope("optimizer"):
+            new_master, new_slots = self._update_shard(state, g_shard)
+            new_state = self._finish(state, new_master, new_slots, found_inf)
         return new_state, self._all_gather_params(new_state.master)
 
     def _update_shard(self, state, g_shard):

@@ -177,7 +177,10 @@ class TransformerLM:
                 f"sequence length {total} exceeds max_seq_len="
                 f"{self.max_seq_len}; raise max_seq_len at construction")
         pos = pos0 + jnp.arange(t)
-        x = params["tok_emb"][tokens] + params["pos_emb"][pos]
+        # module boundaries are prof.SCOPES named scopes: metadata only
+        # (HLO op names, so a device trace splits by module and direction)
+        with jax.named_scope("embed"):
+            x = params["tok_emb"][tokens] + params["pos_emb"][pos]
         mha = self._mha()
 
         moe_balance = jnp.asarray(0.0, jnp.float32)
@@ -193,22 +196,24 @@ class TransformerLM:
                 else jax.random.fold_in(dropout_key, i)
 
             def layer_body(x, lp, *, _moe=is_moe, _key=layer_key):
-                h = self._ln(x, lp["ln1"])
-                # MHA modules are time-major [T, B, E]
-                attn_out, _ = mha.apply(lp["attn"], h.swapaxes(0, 1),
-                                        is_training=is_training,
-                                        dropout_key=_key)
-                x = x + attn_out.swapaxes(0, 1)
-                h = self._ln(x, lp["ln2"])
-                if _moe:
-                    y, aux = self._moe().apply(
-                        lp["moe"], h.reshape(-1, self.embed_dim))
-                    return (x + y.reshape(h.shape),
-                            aux["load_balance_loss"],
-                            aux["dropped_fraction"])
-                h = jax.nn.gelu(h @ lp["mlp"]["w1"] + lp["mlp"]["b1"])
-                return x + (h @ lp["mlp"]["w2"] + lp["mlp"]["b2"]), \
-                    zero, zero
+                with jax.named_scope("attention"):
+                    h = self._ln(x, lp["ln1"])
+                    # MHA modules are time-major [T, B, E]
+                    attn_out, _ = mha.apply(lp["attn"], h.swapaxes(0, 1),
+                                            is_training=is_training,
+                                            dropout_key=_key)
+                    x = x + attn_out.swapaxes(0, 1)
+                with jax.named_scope("mlp"):
+                    h = self._ln(x, lp["ln2"])
+                    if _moe:
+                        y, aux = self._moe().apply(
+                            lp["moe"], h.reshape(-1, self.embed_dim))
+                        return (x + y.reshape(h.shape),
+                                aux["load_balance_loss"],
+                                aux["dropped_fraction"])
+                    h = jax.nn.gelu(h @ lp["mlp"]["w1"] + lp["mlp"]["b1"])
+                    return x + (h @ lp["mlp"]["w2"] + lp["mlp"]["b2"]), \
+                        zero, zero
 
             if self.remat:
                 # trade FLOPs for HBM: drop each block's internal
@@ -225,11 +230,12 @@ class TransformerLM:
                 moe_dropped = moe_dropped + drop
                 n_moe += 1
 
-        x = self._ln(x, params["ln_f"])
-        if return_hidden:
-            out = x
-        else:
-            out = (x @ params["tok_emb"].T).astype(jnp.float32)
+        with jax.named_scope("head_loss"):
+            x = self._ln(x, params["ln_f"])
+            if return_hidden:
+                out = x
+            else:
+                out = (x @ params["tok_emb"].T).astype(jnp.float32)
         if return_aux:
             return out, {
                 "moe_load_balance_loss": moe_balance,
@@ -241,15 +247,16 @@ class TransformerLM:
         """Per-token losses from apply()'s output — full logits through
         the fused xentropy op, or (head_chunk > 0) final hidden states
         through the chunked fused head+xentropy."""
-        if self.head_chunk > 0:
-            from apex_tpu.contrib.xentropy import linear_cross_entropy
-            return linear_cross_entropy(
-                out.reshape(-1, self.embed_dim), params["tok_emb"],
-                targets_flat, chunk=self.head_chunk)
-        from apex_tpu.contrib.xentropy import SoftmaxCrossEntropyLoss
-        return SoftmaxCrossEntropyLoss.apply(
-            out.reshape(-1, self.vocab_size), targets_flat,
-            padding_idx=None)  # no padding token in this LM
+        from apex_tpu.contrib.xentropy import (SoftmaxCrossEntropyLoss,
+                                               linear_cross_entropy)
+        with jax.named_scope("head_loss"):
+            if self.head_chunk > 0:
+                return linear_cross_entropy(
+                    out.reshape(-1, self.embed_dim), params["tok_emb"],
+                    targets_flat, chunk=self.head_chunk)
+            return SoftmaxCrossEntropyLoss.apply(
+                out.reshape(-1, self.vocab_size), targets_flat,
+                padding_idx=None)  # no padding token in this LM
 
     def loss(self, params: dict, tokens: jax.Array, *,
              is_training: bool = True,

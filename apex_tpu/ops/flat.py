@@ -19,6 +19,7 @@ zero by every op in this library, so sums/norms over segments stay exact.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any
 
@@ -194,17 +195,21 @@ def unflatten(flat: jax.Array, table: SegmentTable,
         buf = flatten(ct, table=table, dtype=common)[0]
         return buf.astype(in_dtype)
 
-    if _linear_call_diffable():
-        return jax.custom_derivatives.linear_call(_fwd, _transpose, None,
-                                                  flat)
+    # with a dtype this is the O2 cast of the master and, transposed, the
+    # flat fp32 gradient: prof.SCOPES' "amp_cast" (metadata only)
+    with jax.named_scope("amp_cast") if dtype is not None \
+            else contextlib.nullcontext():
+        if _linear_call_diffable():
+            return jax.custom_derivatives.linear_call(_fwd, _transpose,
+                                                      None, flat)
 
-    @jax.custom_vjp
-    def _unflat(f):
-        return _fwd(None, f)
+        @jax.custom_vjp
+        def _unflat(f):
+            return _fwd(None, f)
 
-    _unflat.defvjp(lambda f: (_fwd(None, f), None),
-                   lambda _res, ct: (_transpose(None, ct),))
-    return _unflat(flat)
+        _unflat.defvjp(lambda f: (_fwd(None, f), None),
+                       lambda _res, ct: (_transpose(None, ct),))
+        return _unflat(flat)
 
 
 def zeros_like_flat(table: SegmentTable, dtype=jnp.float32) -> jax.Array:

@@ -115,6 +115,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_dim * h, 1, hd), q.dtype),
         interpret=_interpret(),
+        name="apex_decode_dense",
     )(lens, q2, k2, v2)
     return out.reshape(s_dim, h, hd)
 
@@ -230,5 +231,6 @@ def paged_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_dim * h, 1, hd), q.dtype),
         interpret=_interpret(),
+        name="apex_decode_paged",
     )(lens, pt, q2, k, v)
     return out.reshape(s_dim, h, hd)

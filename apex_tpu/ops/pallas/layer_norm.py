@@ -96,6 +96,7 @@ def _ln_fwd_single(x2d: jax.Array, weight, bias, eps: float):
                    jax.ShapeDtypeStruct((np_, LANES), jnp.float32, vma=vma),
                    jax.ShapeDtypeStruct((np_, LANES), jnp.float32, vma=vma)],
         interpret=_interpret(),
+        name="apex_ln_fwd",
     )(*args)
     return y[:n], mean[:n, 0], inv[:n, 0]
 
@@ -177,6 +178,7 @@ def _ln_bwd_single(dy2d, x2d, weight, mean, invvar):
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=_interpret(),
+        name="apex_ln_bwd",
     )(*args)
     if affine:
         dx, gw, gb = outs
@@ -255,6 +257,7 @@ def _ln_fwd_wide(x2d: jax.Array, weight, bias, eps: float):
         out_shape=[jax.ShapeDtypeStruct((np_, LANES), jnp.float32,
                                         vma=vma)] * 3,
         interpret=_interpret(),
+        name="apex_ln_wide_moments",
     )(xx)
     dmean = s[:, 0] / f                      # true (unpadded) width
     mean = shift[:, 0] + dmean
@@ -281,6 +284,7 @@ def _ln_fwd_wide(x2d: jax.Array, weight, bias, eps: float):
         out_specs=pl.BlockSpec((rows, FBLK), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((np_, fp_), x2d.dtype, vma=vma),
         interpret=_interpret(),
+        name="apex_ln_wide_apply",
     )(*args)
     return y[:n, :f], mean[:n], inv[:n]
 
@@ -381,6 +385,7 @@ def _ln_bwd_wide(dy2d, x2d, weight, mean, invvar):
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=_interpret(),
+        name="apex_ln_wide_bwd_reduce",
     )(*args)
     if affine:
         # separate pass with rows innermost (see _wide_gwgb_kernel)
@@ -398,6 +403,7 @@ def _ln_bwd_wide(dy2d, x2d, weight, mean, invvar):
                        jax.ShapeDtypeStruct((1, fp_), jnp.float32,
                                             vma=vma)],
             interpret=_interpret(),
+            name="apex_ln_wide_bwd_gwgb",
         )(dd, xx, mean_l, inv_l)
         gw = gw_part[0, :f]
         gb = gb_part[0, :f]
@@ -415,6 +421,7 @@ def _ln_bwd_wide(dy2d, x2d, weight, mean, invvar):
         out_specs=pl.BlockSpec((rows, FBLK), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((np_, fp_), x2d.dtype, vma=vma),
         interpret=_interpret(),
+        name="apex_ln_wide_bwd_dx",
     )(*args2)
     if affine:
         return dx[:n, :f], gw, gb

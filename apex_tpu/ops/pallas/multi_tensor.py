@@ -120,6 +120,7 @@ def scale(x: jax.Array, scale_factor) -> tuple[jax.Array, jax.Array]:
         out_shape=[jax.ShapeDtypeStruct(x2.shape, x.dtype),
                    jax.ShapeDtypeStruct((1, 1), jnp.int32)],
         interpret=interpret_mode(),
+        name="apex_mt_scale",
     )(_scalars(scale_factor), x2)
     return out.reshape(x.shape), inf[0, 0] > 0
 
@@ -158,6 +159,7 @@ def axpby(a, x: jax.Array, b, y: jax.Array,
         out_shape=[jax.ShapeDtypeStruct(x2.shape, jnp.result_type(x)),
                    jax.ShapeDtypeStruct((1, 1), jnp.int32)],
         interpret=interpret_mode(),
+        name="apex_mt_axpby",
     )(_scalars(a, b), x2, y2)
     return out.reshape(x.shape), inf[0, 0] > 0
 
@@ -190,6 +192,7 @@ def l2norm(x: jax.Array) -> jax.Array:
         out_specs=_flag_spec(),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         interpret=interpret_mode(),
+        name="apex_mt_l2norm",
     )(x2)
     return jnp.sqrt(acc[0, 0])
 
@@ -212,6 +215,7 @@ def rowsumsq(x: jax.Array) -> jax.Array:
         out_specs=_col_spec(),
         out_shape=jax.ShapeDtypeStruct((nrows, 1), jnp.float32),
         interpret=interpret_mode(),
+        name="apex_mt_rowsumsq",
     )(x2)
     return out[:, 0]
 
@@ -233,6 +237,7 @@ def rowmaxabs(x: jax.Array) -> jax.Array:
         out_specs=_col_spec(),
         out_shape=jax.ShapeDtypeStruct((nrows, 1), jnp.float32),
         interpret=interpret_mode(),
+        name="apex_mt_rowmaxabs",
     )(x2)
     return out[:, 0]
 
@@ -314,6 +319,7 @@ def adam_step(g, p, m, v, *, lr, beta1, beta2, eps, step, mode=0,
                    jax.ShapeDtypeStruct(m2.shape, m.dtype),
                    jax.ShapeDtypeStruct(v2.shape, v.dtype)],
         interpret=interpret_mode(),
+        name="apex_mt_adam",
     )(_scalars(lr, beta1, beta2, eps, bc1, bc2, weight_decay,
                1.0 - beta1, 1.0 - beta2), g2, p2, m2, v2)
     return po.reshape(p.shape), mo.reshape(m.shape), vo.reshape(v.shape)
@@ -347,6 +353,7 @@ def adagrad_step(g, p, h, *, lr, eps, mode=0, weight_decay=0.0):
         out_shape=[jax.ShapeDtypeStruct(p2.shape, p.dtype),
                    jax.ShapeDtypeStruct(h2.shape, h.dtype)],
         interpret=interpret_mode(),
+        name="apex_mt_adagrad",
     )(_scalars(lr, eps, weight_decay), g2, p2, h2)
     return po.reshape(p.shape), ho.reshape(h.shape)
 
@@ -386,6 +393,7 @@ def sgd_step(g, p, mom, *, wd, momentum, dampening, lr, nesterov=False,
         out_shape=[jax.ShapeDtypeStruct(p2.shape, p.dtype),
                    jax.ShapeDtypeStruct(m2.shape, mom.dtype)],
         interpret=interpret_mode(),
+        name="apex_mt_sgd",
     )(_scalars(wd, lr, scale, first), g2, p2, m2)
     return po.reshape(p.shape), mo.reshape(mom.shape)
 
@@ -444,6 +452,7 @@ def novograd_step(g, p, m, v_norms, segment_ids, *, lr, beta1, beta2, eps,
         out_shape=[jax.ShapeDtypeStruct(p2.shape, p.dtype),
                    jax.ShapeDtypeStruct(m2.shape, m.dtype)],
         interpret=interpret_mode(),
+        name="apex_mt_novograd",
     )(_scalars(lr, beta1, weight_decay, bc1, 1.0 - beta1), g2, p2, m2,
       denom)
     return po.reshape(p.shape), mo.reshape(m.shape), v_new
@@ -515,6 +524,7 @@ def lamb_step(g, p, m, v, segment_ids, num_segments, *, lr, beta1, beta2,
                    jax.ShapeDtypeStruct((nrows, 1), jnp.float32),
                    jax.ShapeDtypeStruct((nrows, 1), jnp.float32)],
         interpret=interpret_mode(),
+        name="apex_mt_lamb_stage1",
     )(_scalars(beta1, beta2, eps, bc1, bc2, weight_decay, clip,
                1.0 - beta1, 1.0 - beta2),
       g2, p2, m2, v2)
@@ -540,5 +550,6 @@ def lamb_step(g, p, m, v, segment_ids, num_segments, *, lr, beta1, beta2,
         out_specs=_row_spec(),
         out_shape=jax.ShapeDtypeStruct(p2.shape, p.dtype),
         interpret=interpret_mode(),
+        name="apex_mt_lamb_stage2",
     )(row_ratio, p2, u2)
     return po.reshape(p.shape), mo.reshape(m.shape), vo.reshape(v.shape)

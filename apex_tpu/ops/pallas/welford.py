@@ -87,6 +87,7 @@ def bn_moments(x2d: jax.Array) -> tuple[jax.Array, jax.Array]:
         out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32, vma=vma),
                    jax.ShapeDtypeStruct((1, c), jnp.float32, vma=vma)],
         interpret=interpret_mode(),
+        name="apex_bn_moments",
     )(xx)
     return s[0], sq[0]
 
@@ -145,6 +146,7 @@ def bn_backward_fused_reduce(dy2d, x2d, mean, invvar, out2d=None):
         out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32, vma=vma),
                    jax.ShapeDtypeStruct((1, c), jnp.float32, vma=vma)],
         interpret=interpret_mode(),
+        name="apex_bn_bwd_fused_reduce",
     )(*ops)
     return sdy[0], sdx[0]
 
@@ -199,6 +201,7 @@ def bn_backward_dx(dy2d, x2d, mean, invvar, winv, mean_dy, mean_dy_xhat,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret_mode(),
+        name="apex_bn_bwd_dx",
     )(*ops)
     dx = res[0][:n]
     dz = res[1][:n] if emit_dz else None
@@ -247,5 +250,6 @@ def bn_backward_reduce(dy2d, xhat2d):
         out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32, vma=vma),
                    jax.ShapeDtypeStruct((1, c), jnp.float32, vma=vma)],
         interpret=interpret_mode(),
+        name="apex_bn_bwd_reduce",
     )(dd, xx)
     return sdy[0], sdx[0]

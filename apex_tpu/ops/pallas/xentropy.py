@@ -124,6 +124,7 @@ def xent_fwd(logits: jax.Array, labels: jax.Array, smoothing: float):
                                         vma=vma)] * 2,
         scratch_shapes=[pltpu.VMEM((rows, LANES), jnp.float32)] * 4,
         interpret=_interpret(),
+        name="apex_xent_fwd",
     )(xx, lbl)
     return loss[:n, 0], lse[:n, 0]
 
@@ -167,5 +168,6 @@ def xent_bwd(logits, labels, lse, g, smoothing: float):
         out_specs=pl.BlockSpec((rows, VBLK), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((np_, vp_), logits.dtype, vma=vma),
         interpret=_interpret(),
+        name="apex_xent_bwd",
     )(xx, lbl, lse_l, g_l)
     return dx[:n, :v]

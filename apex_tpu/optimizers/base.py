@@ -138,6 +138,7 @@ class FusedOptimizer:
             tuple(sorted((k, repr(v)) for k, v in hp.items() if k != "lr"))
             for hp in self.param_groups)
 
+    @jax.named_scope("optimizer")       # prof.SCOPES: metadata only
     def _step_impl(self, state: OptimizerState, flat_grads: list[jax.Array],
                    lrs: list[jax.Array], found_inf, scale, hp_key=None):
         # Fold AMP grad-unscaling into the update for every optimizer (the

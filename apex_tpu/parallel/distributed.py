@@ -114,6 +114,7 @@ class DistributedDataParallel:
     gradient_average_split_factor: Optional[float] = None
     prof: bool = False
 
+    @jax.named_scope("collective")      # prof.SCOPES: metadata only
     def average_gradients(self, grads: Any) -> Any:
         """psum-average a grads pytree. Call inside shard_map/pmap."""
         world = _group_size(self.axis_name, self.axis_index_groups)

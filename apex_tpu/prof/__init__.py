@@ -39,7 +39,8 @@ import jax
 
 from apex_tpu.prof.peaks import ChipPeak, PEAKS, chip_peak  # noqa: F401
 
-__all__ = ["annotate", "mark", "trace", "analyze", "CostReport", "init",
+__all__ = ["annotate", "mark", "SCOPES", "trace", "analyze", "CostReport",
+           "init",
            "OpStats", "top_ops", "format_top_ops", "RooflineSummary",
            "roofline", "gaps", "Gap", "GapReport", "TimelineEvent",
            "attribute_gaps", "format_gaps",
@@ -100,6 +101,19 @@ def mark(name: str):
     reference distributed.py:359-360, sync_batchnorm.py:69)."""
     with jax.named_scope(name):
         yield
+
+
+# The one scope vocabulary: the outermost ``jax.named_scope`` of every op a
+# training step issues, opened where the work is issued (models/, ops/flat.py,
+# amp/, optimizers/base.py, parallel/, contrib/optimizers/). Each entry is a
+# regex for one whole component of an op's name path; a device trace names
+# every HLO instruction by that path (``jit(step)/transpose(jvp(mlp))/...``),
+# so the benchmark's ``trace_scope`` reader buckets device time by these
+# names and by direction. Applied with ``jax.named_scope`` / :func:`mark` /
+# :func:`annotate`; a new model adds its scopes here.
+SCOPES = ("embed", "attention", "mlp", "head_loss",     # models/transformer
+          "stem", r"stage\d+_block\d+", "head",          # models/resnet
+          "amp_cast", "amp_scale", "optimizer", "collective")
 
 
 @contextlib.contextmanager

@@ -680,10 +680,11 @@ def _run_zero_arm(*, mode, backend, batch, iters, image, stem,
         # buffer, the half cast fused into unflatten
         p_half = F.unflatten(flat_params, table, dtype=half)
         logits, new_st = model.apply(p_half, bn_state, x, training=True)
-        logits = logits.astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits)
         from apex_tpu.contrib.xentropy import select_label_logits
-        loss = -jnp.mean(select_label_logits(logp, y))
+        with jax.named_scope("head"):   # the model's own scope: prof.SCOPES
+            logits = logits.astype(jnp.float32)
+            logp = jax.nn.log_softmax(logits)
+            loss = -jnp.mean(select_label_logits(logp, y))
         return handle.scale_loss(loss, amp_state), (loss, new_st)
 
     if mode == "zero":
@@ -869,10 +870,11 @@ def build_train_step(model, params, handle, *, lr=1e-3):
         # (_process_optimizer.py:321) with the copy fused into autodiff.
         p_half = F.unflatten(master, table, dtype=half)
         logits, new_st = model.apply(p_half, bn_state, x, training=True)
-        logits = logits.astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits)
         from apex_tpu.contrib.xentropy import select_label_logits
-        loss = -jnp.mean(select_label_logits(logp, y))
+        with jax.named_scope("head"):   # the model's own scope: prof.SCOPES
+            logits = logits.astype(jnp.float32)
+            logp = jax.nn.log_softmax(logits)
+            loss = -jnp.mean(select_label_logits(logp, y))
         return handle.scale_loss(loss, amp_state), (loss, new_st)
 
     def train_step(opt_state, bn_state, amp_state, x, y, census=None):

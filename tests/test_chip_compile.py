@@ -13,6 +13,7 @@ the dispatch side; the fixture steers that here, in the test.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -129,46 +130,66 @@ _LAMB = dict(lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-6, step=1,
              weight_decay=0.01, global_grad_norm=1.0)
 _SEGS = 8                           # lamb's per-parameter trust ratios
 
-# (id, builder of the traced function, [(shape, dtype), ...])
+# (id, builder of the traced function, [(shape, dtype), ...],
+#  the kernels' names: the HLO instructions a device trace will show)
 KERNELS = [
     ("dense_decode-32x8x8192x128", lambda: _dense_decode,
-     _dense_args(32, 8, 8192, 128)),
+     _dense_args(32, 8, 8192, 128),
+     ("apex_decode_dense",)),
     ("dense_decode-32x8x2048x128", lambda: _dense_decode,
-     _dense_args(32, 8, 2048, 128)),
+     _dense_args(32, 8, 2048, 128),
+     ("apex_decode_dense",)),
     ("paged_decode-page32x64", lambda: _paged_decode,
-     _paged_args(32, 8, 32, 64, 128)),
+     _paged_args(32, 8, 32, 64, 128),
+     ("apex_decode_paged",)),
     ("paged_decode-page16x128", lambda: _paged_decode,
-     _paged_args(32, 8, 16, 128, 128)),
+     _paged_args(32, 8, 16, 128, 128),
+     ("apex_decode_paged",)),
     ("flash_fwd-B8H8S4096D128", lambda: _flash(False),
-     _qkv(8, 8, 4096, 128)),
+     _qkv(8, 8, 4096, 128),
+     ("apex_flash_fwd",)),
     ("flash_fwd_bwd-B8H8S4096D128", lambda: _flash(True),
-     _qkv(8, 8, 4096, 128)),
+     _qkv(8, 8, 4096, 128),
+     ("apex_flash_fwd", "apex_flash_bwd_dq", "apex_flash_bwd_dkv")),
     ("flash_fwd-B1H8S16384D128", lambda: _flash(False),
-     _qkv(1, 8, 16384, 128)),
+     _qkv(1, 8, 16384, 128),
+     ("apex_flash_fwd",)),
     ("flash_fwd_bwd-B1H8S16384D128", lambda: _flash(True),
-     _qkv(1, 8, 16384, 128)),
+     _qkv(1, 8, 16384, 128),
+     ("apex_flash_fwd", "apex_flash_bwd_dq", "apex_flash_bwd_dkv")),
     ("flash_fwd-B8H16S4096D64", lambda: _flash(False),
-     _qkv(8, 16, 4096, 64)),
+     _qkv(8, 16, 4096, 64),
+     ("apex_flash_fwd",)),
     ("flash_fwd_bwd-B8H16S4096D64", lambda: _flash(True),
-     _qkv(8, 16, 4096, 64)),
+     _qkv(8, 16, 4096, 64),
+     ("apex_flash_fwd", "apex_flash_bwd_dq", "apex_flash_bwd_dkv")),
     ("layer_norm_fwd_bwd-F1024", lambda: _layer_norm(1024),
-     _ln_args(8 * 4096, 1024)),
+     _ln_args(8 * 4096, 1024),
+     ("apex_ln_fwd", "apex_ln_bwd")),
     ("layer_norm_fwd_bwd-F4096", lambda: _layer_norm(4096),
-     _ln_args(4096, 4096)),
+     _ln_args(4096, 4096),
+     ("apex_ln_fwd", "apex_ln_bwd")),
     ("layer_norm_fwd_bwd-F16384-wide", lambda: _layer_norm(16384),
-     _ln_args(520, 16384)),
+     _ln_args(520, 16384),
+     ("apex_ln_wide_moments", "apex_ln_wide_apply", "apex_ln_wide_bwd_reduce", "apex_ln_wide_bwd_gwgb", "apex_ln_wide_bwd_dx")),
     ("xentropy_fwd_bwd-V32768", _xentropy,
-     [((4096, 32768), BF16), ((4096,), I32)]),
+     [((4096, 32768), BF16), ((4096,), I32)],
+     ("apex_xent_fwd", "apex_xent_bwd")),
     ("xentropy_fwd_bwd-V50304", _xentropy,
-     [((4096, 50304), BF16), ((4096,), I32)]),
+     [((4096, 50304), BF16), ((4096,), I32)],
+     ("apex_xent_fwd", "apex_xent_bwd")),
     ("scale-128M", lambda: _multi_tensor("scale", scale_factor=0.5),
-     [((FLAT,), F32)]),
-    ("l2norm-128M", lambda: _multi_tensor("l2norm"), [((FLAT,), F32)]),
+     [((FLAT,), F32)],
+     ("apex_mt_scale",)),
+    ("l2norm-128M", lambda: _multi_tensor("l2norm"), [((FLAT,), F32)],
+     ("apex_mt_l2norm",)),
     ("adam_step-128M", lambda: _multi_tensor("adam_step", **_ADAM),
-     [((FLAT,), F32)] * 4),
+     [((FLAT,), F32)] * 4,
+     ("apex_mt_adam",)),
     ("lamb_step-128M", lambda: (lambda g, p, m, v, seg: _multi_tensor(
         "lamb_step", **_LAMB)(g, p, m, v, seg, _SEGS)),
-     [((FLAT,), F32)] * 4 + [((FLAT,), I32)]),
+     [((FLAT,), F32)] * 4 + [((FLAT,), I32)],
+     ("apex_mt_lamb_stage1", "apex_mt_lamb_stage2")),
 ]
 
 
@@ -178,11 +199,18 @@ def _compile(fn, args, sharding):
     return jax.jit(fn).lower(*specs).compile()
 
 
-@pytest.mark.parametrize("make_fn,args",
-                         [pytest.param(m, a, id=i) for i, m, a in KERNELS])
-def test_kernel_compiles_for_v5e(chip, for_chip, make_fn, args):
+@pytest.mark.parametrize("make_fn,args,names",
+                         [pytest.param(m, a, n, id=i)
+                          for i, m, a, n in KERNELS])
+def test_kernel_compiles_for_v5e(chip, for_chip, make_fn, args, names):
     compiled = _compile(make_fn(), args, chip)
-    assert "tpu_custom_call" in compiled.as_text()   # not interpreted
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text                 # not interpreted
+    # each kernel's instruction is named after its pallas_call's name=:
+    # %apex_mt_adam.1 under jit or a named scope; under a bare grad the
+    # transformation wraps it (%transpose_jvp_apex_flash_bwd_dq__.1)
+    assert [n for n in names
+            if not re.search(rf"%(\w+_)?{n}_*\.\d+ = ", text)] == []
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
         + mem.output_size_in_bytes < 16e9            # fits one v5e's HBM

@@ -258,3 +258,122 @@ def test_fuzz_random_segments_all_ops(seed):
     for got, want in zip(P.lamb_step(g, p, m, v, ids, nseg, **kw),
                          R.lamb_step(g, p, m, v, ids, nseg, **kw)):
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+# -- every pallas_call carries a stable name (PR 24) ------------------------
+# A device trace names a kernel's HLO instruction after the call's
+# ``name=``; unnamed, it takes the name of whatever encloses it and the next
+# refactor changes it. One case per call site: tracing the wrapper (abstract
+# shapes, nothing runs) shows a pallas_call of the documented name.
+
+def _pallas_names(jaxpr) -> list:
+    from apex_tpu.analysis import walker
+    return [v.eqn.params["name"] for v in walker.iter_eqns(jaxpr)
+            if v.eqn.primitive.name == "pallas_call"]
+
+
+def _f32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+
+def _i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
+def _flash_grad(with_bias):
+    from apex_tpu.contrib.multihead_attn import flash_attention
+
+    def loss(q, k, v, bias):
+        return flash_attention(q, k, v, bias if with_bias else None,
+                               causal=True).sum()
+    return jax.grad(loss, argnums=(0, 1, 2, 3))
+
+
+def _site(fn, *args, **kw):
+    return lambda: jax.make_jaxpr(lambda *a: fn(*a, **kw))(*args)
+
+
+def _kernel_sites() -> dict:
+    """``{documented name: thunk tracing the wrapper that holds the site}``."""
+    from apex_tpu.ops.pallas import (decode_attn as D, layer_norm as L,
+                                     welford as W, xentropy as X)
+    n = 128 * 16
+    buf, rows = _f32(n), _i32(n // 128)
+    hp = dict(lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-8, step=1)
+    lamb = _site(P.lamb_step, buf, buf, buf, buf, rows, global_grad_norm=1.0,
+                 num_segments=2, **hp)
+    x, wide = _f32(16, 256), _f32(16, L.F_SINGLE_MAX + 1024)
+    row, wrow, col = _f32(16), _f32(wide.shape[1]), _f32(256)
+    qkv = _f32(2, 256, 128)
+    return {
+        "apex_mt_scale": _site(P.scale, buf, scale_factor=2.0),
+        "apex_mt_axpby": _site(lambda x, y: P.axpby(1.0, x, 2.0, y), buf, buf),
+        "apex_mt_l2norm": _site(P.l2norm, buf),
+        "apex_mt_rowsumsq": _site(P.rowsumsq, buf),
+        "apex_mt_rowmaxabs": _site(P.rowmaxabs, buf),
+        "apex_mt_adam": _site(P.adam_step, buf, buf, buf, buf, **hp),
+        "apex_mt_adagrad": _site(P.adagrad_step, buf, buf, buf, lr=1e-3,
+                                 eps=1e-8),
+        "apex_mt_sgd": _site(P.sgd_step, buf, buf, buf, wd=0.0, momentum=0.9,
+                             dampening=0.0, lr=1e-3),
+        "apex_mt_novograd": _site(P.novograd_step, buf, buf, buf, _f32(2),
+                                  rows, **hp),
+        "apex_mt_lamb_stage1": lamb,
+        "apex_mt_lamb_stage2": lamb,
+        "apex_ln_fwd": _site(L.ln_fwd, x, col, col, eps=1e-5),
+        "apex_ln_bwd": _site(L.ln_bwd, x, x, col, row, row),
+        "apex_ln_wide_moments": _site(L.ln_fwd, wide, wrow, wrow, eps=1e-5),
+        "apex_ln_wide_apply": _site(L.ln_fwd, wide, wrow, wrow, eps=1e-5),
+        "apex_ln_wide_bwd_reduce": _site(L.ln_bwd, wide, wide, wrow, row, row),
+        "apex_ln_wide_bwd_gwgb": _site(L.ln_bwd, wide, wide, wrow, row, row),
+        "apex_ln_wide_bwd_dx": _site(L.ln_bwd, wide, wide, wrow, row, row),
+        "apex_bn_moments": _site(W.bn_moments, x),
+        "apex_bn_bwd_fused_reduce": _site(W.bn_backward_fused_reduce, x, x,
+                                          col, col),
+        "apex_bn_bwd_dx": _site(W.bn_backward_dx, x, x, col, col, col, col,
+                                col),
+        "apex_bn_bwd_reduce": _site(W.bn_backward_reduce, x, x),
+        "apex_xent_fwd": _site(X.xent_fwd, _f32(16, 1024), _i32(16),
+                               smoothing=0.0),
+        "apex_xent_bwd": _site(X.xent_bwd, _f32(16, 1024), _i32(16), row, row,
+                               smoothing=0.0),
+        "apex_decode_dense": _site(D.decode_attention, _f32(2, 4, 128),
+                                   _f32(2, 4, 64, 128), _f32(2, 4, 64, 128),
+                                   _i32(2)),
+        "apex_decode_paged": _site(
+            lambda q, k, v, n, pt: D.paged_decode_attention(
+                q, k, v, n, page_table=pt),
+            _f32(2, 4, 128), _f32(9, 4, 16, 128), _f32(9, 4, 16, 128),
+            _i32(2), _i32(2, 4)),
+        "apex_flash_fwd": _site(_flash_grad(False), qkv, qkv, qkv, qkv),
+        "apex_flash_bwd_dq": _site(_flash_grad(False), qkv, qkv, qkv, qkv),
+        "apex_flash_bwd_dkv": _site(_flash_grad(False), qkv, qkv, qkv, qkv),
+        "apex_flash_bwd_dbias": _site(_flash_grad(True), qkv, qkv, qkv,
+                                      _f32(1, 256, 256)),     # head-shared
+    }
+
+
+KERNEL_SITES = _kernel_sites()
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_SITES))
+def test_pallas_call_site_carries_its_documented_name(name):
+    assert name in _pallas_names(KERNEL_SITES[name]())
+
+
+def test_no_pallas_call_is_unnamed_and_no_two_sites_share_a_name():
+    """The source of truth is the source: every ``pl.pallas_call(`` of the
+    package is followed by its own ``name="apex_..."``."""
+    import pathlib
+    import re
+
+    import apex_tpu
+    found = []
+    for path in pathlib.Path(apex_tpu.__file__).parent.rglob("*.py"):
+        calls = path.read_text().split("pl.pallas_call(")[1:]
+        for body in calls:
+            m = re.search(r'\bname="(apex_\w+)"', body.split(")(")[0])
+            assert m, f"a pallas_call of {path} has no name="
+            found.append(m.group(1))
+    assert sorted(found) == sorted(KERNEL_SITES)
+    assert len(set(found)) == len(found)

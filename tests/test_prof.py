@@ -427,3 +427,132 @@ class TestUnattributedFooter:
         assert rep.unattributed_us == 0.0
         assert "unattributed: 0.00 ms (0.0% of dead time)" in \
             prof.format_gaps(rep)
+
+
+# -- the scope vocabulary on both training steps (PR 24) --------------------
+# A device trace names every instruction by its op_name path; the
+# benchmark's trace_scope reader buckets device time by the first component
+# of that path that is in prof.SCOPES, and by direction. So every op that
+# does real work has to sit under a scope, forward and backward.
+
+def _tool(directory: str, module: str):
+    """A module of the repo's root or of ``tools/`` (no packages)."""
+    import importlib
+    import os
+    import sys
+    path = os.path.join(os.path.dirname(__file__), "..", directory)
+    if path not in sys.path:
+        sys.path.insert(0, path)
+    return importlib.import_module(module)
+
+
+def _scope_of(op_name: str):
+    """The scope the benchmark's reader puts a path to: what the program
+    promises is what ``trace_scope`` finds."""
+    from benchmarks.readers import trace_scope
+    return trace_scope.scope_of(op_name)
+
+
+def _op_names(step, *args) -> list:
+    import re
+    text = jax.jit(step).lower(*args).compile().as_text()
+    return re.findall(r'op_name="([^"]+)"', text)
+
+
+@pytest.fixture(scope="module")
+def lm_op_names():
+    """The tiny twin of the benchmark's LM step: ``tools/lm_bench.
+    build_train_step`` on one device, flash attention, chunked head."""
+    lm_bench = _tool("tools", "lm_bench")
+    from apex_tpu.models import TransformerLM
+    from apex_tpu.parallel import make_mesh
+    lm = TransformerLM(vocab_size=256, max_seq_len=64, embed_dim=128,
+                       num_heads=1, num_layers=2, attn_impl="fast",
+                       head_chunk=128)
+    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
+    _, state, step, _ = lm_bench.build_train_step(
+        lm, lm.init(jax.random.key(0)), mesh, half=jnp.bfloat16)
+    return _op_names(step, state, jnp.zeros((2, 65), jnp.int32))
+
+
+@pytest.fixture(scope="module")
+def rn_op_names():
+    """The tiny twin of the benchmark's ResNet step: ``bench.
+    build_train_step``, O2 with dynamic loss scaling, FusedLAMB."""
+    from apex_tpu import amp
+    from apex_tpu.models import ResNet
+    build_train_step = _tool("", "bench").build_train_step
+    model = ResNet(block_sizes=(1, 1), bottleneck=True, num_classes=10,
+                   width=8, stem="space_to_depth")
+    params, bn = model.init(jax.random.key(0))
+    _, handle = amp.initialize(opt_level="O2", loss_scale="dynamic",
+                               verbosity=0)
+    opt, _, step = build_train_step(model, params, handle)
+    return _op_names(step, opt.init_state(), bn, handle.init_state(),
+                     jnp.zeros((2, 32, 32, 3), jnp.bfloat16),
+                     jnp.zeros((2,), jnp.int32))
+
+
+@pytest.fixture
+def op_names(request, which):
+    return request.getfixturevalue(
+        {"lm": "lm_op_names", "rn50": "rn_op_names"}[which])
+
+
+def _heavy(op_name: str) -> bool:
+    """A matmul, a convolution, or anything inside a named Pallas kernel
+    (interpreted on the CPU, so the kernel is the ops under its name)."""
+    parts = op_name.split("/")
+    return parts[-1] in ("dot_general", "conv_general_dilated") \
+        or any(p.startswith("apex_") for p in parts)
+
+
+@pytest.mark.parametrize("which", ["lm", "rn50"])
+def test_every_matmul_conv_and_kernel_sits_under_a_vocabulary_scope(
+        which, op_names):
+    heavy = [n for n in op_names if _heavy(n)]
+    assert len(heavy) > 20
+    assert [n for n in heavy if _scope_of(n) is None] == []
+
+
+@pytest.mark.parametrize("which, scope", [
+    ("lm", "embed"), ("lm", "attention"), ("lm", "mlp"), ("lm", "head_loss"),
+    ("lm", "amp_cast"),
+    ("rn50", "stem"), ("rn50", "stage0_block0"), ("rn50", "stage1_block0"),
+    ("rn50", "head"), ("rn50", "amp_cast")])
+def test_scope_names_both_directions(which, scope, op_names):
+    mine = [n for n in op_names if _scope_of(n) == scope]
+    assert any(f"/jvp({scope})/" in n for n in mine)             # forward
+    assert any(f"/transpose(jvp({scope}))/" in n for n in mine)  # backward
+
+
+@pytest.mark.parametrize("which, scope", [
+    ("lm", "optimizer"), ("rn50", "optimizer"), ("rn50", "amp_scale")])
+def test_step_phases_outside_autodiff_are_scoped(which, scope, op_names):
+    mine = [n for n in op_names if _scope_of(n) == scope]
+    assert mine and not any("transpose(" in n for n in mine)
+
+
+def test_collective_scope_on_ddp_and_zero_steps():
+    """DDP's gradient average and ZeRO's gather / scatter land in
+    ``collective`` (read by no cell yet: ``cgpt_train_ddp4`` will)."""
+    import re
+    lm_bench = _tool("tools", "lm_bench")
+    from apex_tpu.models import TransformerLM
+    from apex_tpu.parallel import compile_step_with_plan, make_mesh
+    lm = TransformerLM(vocab_size=256, max_seq_len=32, embed_dim=128,
+                       num_heads=1, num_layers=1)
+    mesh = make_mesh({"data": 2}, devices=jax.devices()[:2])
+    toks = jnp.zeros((4, 33), jnp.int32)
+    for zero, prims in ((False, {"psum"}),
+                        (True, {"all_gather", "reduce_scatter"})):
+        _, state, step, plan = lm_bench.build_train_step(
+            lm, lm.init(jax.random.key(0)), mesh, half=jnp.bfloat16,
+            zero=zero)
+        state, toks_p = lm_bench.place_for_plan(state, toks, plan)
+        text = compile_step_with_plan(step, plan).lower(
+            state, toks_p).as_text(debug_info=True)
+        paths = re.findall(r'loc\("([^"]+)"', text)
+        got = {p.rsplit("/", 1)[-1] for p in paths
+               if _scope_of(p) == "collective"}
+        assert prims <= got, (zero, got)

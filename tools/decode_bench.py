@@ -335,12 +335,22 @@ def main():
         jax.block_until_ready(g(params, prompt))
     _note(f"compiled+first calls in {time.perf_counter() - t0:.0f}s")
 
+    # On the CPU smoke config a generate() takes ~3 ms and the variants
+    # differ by six decode steps of ~0.05 ms: one stall of a loaded host
+    # times the short variant as the slower one and the step clamps to
+    # 0. A stall only ever adds time, so there each variant keeps the
+    # best of many rounds; the chip's timings need one.
+    rounds = 1 if on_tpu else 50
+
     def timed(g):
-        t0 = time.perf_counter()
-        for _ in range(args.iters):
-            out = g(params, prompt)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / args.iters, out
+        best = float("inf")
+        for _ in range(rounds):
+            t0 = time.perf_counter()
+            for _ in range(args.iters):
+                out = g(params, prompt)
+            jax.block_until_ready(out)
+            best = min(best, (time.perf_counter() - t0) / args.iters)
+        return best, out
 
     dt_short, _ = timed(gens[n_short])
     dt_long, out = timed(gens[args.new])

@@ -73,9 +73,10 @@ def build_train_step(lm, params, mesh, *, half, zero=False, lr=1e-4):
             # transiently (compressed all_gather at gather_dtype); the
             # flat grad psum_scatters back to the 1/n shard inside
             # shard_step
-            gathered = lax.all_gather(
-                state.master.astype(opt.gather_dtype), "data",
-                tiled=True)
+            with jax.named_scope("collective"):     # prof.SCOPES
+                gathered = lax.all_gather(
+                    state.master.astype(opt.gather_dtype), "data",
+                    tiled=True)
             loss, fg = jax.value_and_grad(
                 lambda g: lm.loss(F.unflatten(g, table, dtype=half),
                                   toks))(gathered)
