@@ -188,12 +188,10 @@ class _DistributedBase:
         return _flat.unflatten(gathered.astype(self.model_dtype), self.table)
 
     def _finish(self, state, new_master, new_slots, found_inf):
+        # master and slots already hold the old values where found_inf is
+        # set (_update_shard's skip); only the counter is selected here
         new_step = state.step + 1
         if found_inf is not None:
-            keep = lambda old, new: jnp.where(found_inf, old, new)
-            new_master = keep(state.master, new_master)
-            new_slots = {k: keep(state.slots[k], v)
-                         for k, v in new_slots.items()}
             new_step = jnp.where(found_inf, state.step, new_step)
         return ShardedState(master=new_master, slots=new_slots,
                             step=new_step)
@@ -205,11 +203,15 @@ class _DistributedBase:
         flat [N]). Returns (new_state, params_tree in model dtype)."""
         g_shard = self._reduce_scatter(grads, jnp.asarray(scale, jnp.float32))
         with jax.named_scope("optimizer"):
-            new_master, new_slots = self._update_shard(state, g_shard)
+            new_master, new_slots = self._update_shard(state, g_shard,
+                                                       found_inf)
             new_state = self._finish(state, new_master, new_slots, found_inf)
         return new_state, self._all_gather_params(new_state.master)
 
-    def _update_shard(self, state, g_shard):
+    def _update_shard(self, state, g_shard, skip):
+        """(new master, new slots) of the local shard, updated in place;
+        where the traced ``skip`` is set (None: never), the old ones
+        bit-for-bit."""
         raise NotImplementedError
 
     # -- checkpoint --------------------------------------------------------
@@ -286,7 +288,7 @@ class DistributedFusedAdam(_DistributedBase):
                          betas=tuple(betas), eps=eps,
                          adam_w_mode=bool(adam_w_mode), **kw)
 
-    def _update_shard(self, state, g_shard):
+    def _update_shard(self, state, g_shard, skip):
         hp = self.hp
         b1, b2 = hp["betas"]
         p, m, v = R.adam_step(
@@ -294,7 +296,7 @@ class DistributedFusedAdam(_DistributedBase):
             lr=jnp.asarray(hp["lr"], jnp.float32), beta1=b1, beta2=b2,
             eps=hp["eps"], step=state.step + 1,
             mode=R.MODE_DECOUPLED if hp["adam_w_mode"] else R.MODE_L2,
-            weight_decay=hp["weight_decay"])
+            weight_decay=hp["weight_decay"], skip=skip)
         return p, {"m": m, "v": v}
 
 
@@ -332,7 +334,7 @@ class DistributedFusedLAMB(_DistributedBase):
         part = R.segment_sumsq_aligned(x, ids, num_seg + 1)
         return jnp.sqrt(lax.psum(part, self.axis_name))[:num_seg]
 
-    def _update_shard(self, state, g_shard):
+    def _update_shard(self, state, g_shard, skip):
         hp = self.hp
         b1, b2 = hp["betas"]
         num_seg = self.table.num_segments
@@ -379,5 +381,8 @@ class DistributedFusedLAMB(_DistributedBase):
             ratio = jnp.full((num_seg,), lr, jnp.float32)
         # pad ratio for the out-of-range id used by padding elements
         ratio = jnp.concatenate([ratio, jnp.zeros((1,), jnp.float32)])
-        new_p = p - ratio[jnp.minimum(ids, num_seg)] * update
-        return new_p.astype(state.master.dtype), {"m": m, "v": v}
+        new_p = (p - ratio[jnp.minimum(ids, num_seg)] * update).astype(
+            state.master.dtype)
+        return R.keep_old(skip, state.master, new_p), {
+            "m": R.keep_old(skip, state.slots["m"], m),
+            "v": R.keep_old(skip, state.slots["v"], v)}

@@ -27,6 +27,7 @@ NORM_LINF = R.NORM_LINF
 NORM_L2 = R.NORM_L2
 
 all_finite = R.all_finite
+keep_old = R.keep_old
 norm_out_blend = R.norm_out_blend
 
 

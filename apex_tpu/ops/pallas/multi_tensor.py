@@ -19,6 +19,22 @@ Conventions:
 - the ragged final row-block is handled by Pallas write-masking; reduction
   kernels additionally mask out-of-range rows so garbage lanes never reach a
   scalar accumulator;
+- the optimizer-step kernels update their state IN PLACE, as the reference
+  kernels do (csrc/multi_tensor_adam.cu writes p, m, v where it read them):
+  every state output aliases its input (``input_output_aliases``). Grid
+  step ``i`` reads row-block ``i`` and writes row-block ``i``, so the
+  pipeline's prefetch of block ``i+1`` never sees a written block. The
+  contract for callers: DONATE the state or pay one copy — under a jit
+  that donates p, m, v the program holds no copy of them; where the caller
+  keeps its arrays XLA copies each in front of the kernel and they stay
+  intact (what every call cost before the alias: three 5 ms copies a step
+  at 409M parameters on a v5e, PERF.md PR 25);
+- the overflow skip is inside the step kernels: ``skip`` (None, or a traced
+  scalar — AMP's ``found_inf``) rides as one more SMEM scalar and, when set,
+  every state output is the value read, bit for bit. With ``skip=None``
+  nothing is passed and nothing is read. A select over whole buffers AFTER
+  the kernel would keep the old state alive beside the new and bring the
+  copies back;
 - per-tensor (segment) semantics ride on the 128-alignment invariant: every
   flat row belongs to exactly one segment, so per-tensor reductions are a
   Pallas per-row pass plus a tiny XLA segment-sum over rows (the moral
@@ -81,6 +97,26 @@ def _flag_spec() -> pl.BlockSpec:
 
 def _grid(nrows: int) -> tuple[int]:
     return (pl.cdiv(nrows, BLOCK_ROWS),)
+
+
+def _step_scalars(*vals, skip):
+    """A step kernel's SMEM operand, its BlockSpec, and where the overflow
+    ``skip`` flag rides in it: last, or nowhere (None) when the caller
+    passed none — then nothing is packed and nothing is read."""
+    packed = _scalars(*vals, *(() if skip is None else (skip,)))
+    return (packed, _smem_spec(packed.shape[1]),
+            None if skip is None else len(vals))
+
+
+def _skip_flag(s_ref, at):
+    return None if at is None else s_ref[0, at] != 0.0
+
+
+def _store(o_ref, old_ref, new, skip):
+    """Write the new state over the state read — or, when ``skip`` is set,
+    the very value read, so an overflowing step leaves it bit-for-bit."""
+    new = new.astype(o_ref.dtype)
+    o_ref[...] = new if skip is None else jnp.where(skip, old_ref[...], new)
 
 
 def _valid(shape, block_idx: jax.Array, nrows: int) -> jax.Array:
@@ -272,7 +308,7 @@ def maxnorm_per_segment(x: jax.Array, segment_ids: jax.Array,
 # Optimizer steps
 # ---------------------------------------------------------------------------
 
-def _adam_kernel(mode, s_ref, g_ref, p_ref, m_ref, v_ref,
+def _adam_kernel(mode, skip_at, s_ref, g_ref, p_ref, m_ref, v_ref,
                  po_ref, mo_ref, vo_ref):
     # (1-beta) arrives precomputed in float64 and rounded once to fp32 —
     # computing it in-kernel from the fp32 beta rounds differently
@@ -280,6 +316,7 @@ def _adam_kernel(mode, s_ref, g_ref, p_ref, m_ref, v_ref,
     # source of >1-ulp divergence from the jnp reference path.
     lr, b1, b2, eps, bc1, bc2, wd, omb1, omb2 = (
         s_ref[0, k] for k in range(9))
+    skip = _skip_flag(s_ref, skip_at)
     gf = g_ref[...].astype(jnp.float32)
     pf = p_ref[...].astype(jnp.float32)
     mf = m_ref[...].astype(jnp.float32)
@@ -291,17 +328,17 @@ def _adam_kernel(mode, s_ref, g_ref, p_ref, m_ref, v_ref,
     update = (mf / bc1) / (jnp.sqrt(vf / bc2) + eps)
     if mode == 1:  # AdamW decoupled decay
         update = update + wd * pf
-    po_ref[...] = (pf - lr * update).astype(po_ref.dtype)
-    mo_ref[...] = mf.astype(mo_ref.dtype)
-    vo_ref[...] = vf.astype(vo_ref.dtype)
+    _store(po_ref, p_ref, pf - lr * update, skip)
+    _store(mo_ref, m_ref, mf, skip)
+    _store(vo_ref, v_ref, vf, skip)
 
 
 def adam_step(g, p, m, v, *, lr, beta1, beta2, eps, step, mode=0,
-              bias_correction=True, weight_decay=0.0):
+              bias_correction=True, weight_decay=0.0, skip=None):
     """Fused Adam/AdamW over the flat buffer (reference:
     multi_tensor_adam.cu:23-171). Bias corrections are precomputed scalars
     outside the kernel, exactly as the reference does host-side
-    (multi_tensor_adam.cu:144-149)."""
+    (multi_tensor_adam.cu:144-149). p, m and v are updated in place."""
     stepf = _f32(step)
     if bias_correction:
         bc1 = 1.0 - jnp.power(_f32(beta1), stepf)
@@ -310,23 +347,28 @@ def adam_step(g, p, m, v, *, lr, beta1, beta2, eps, step, mode=0,
         bc1 = bc2 = _f32(1.0)
     g2, p2, m2, v2 = _rows(g), _rows(p), _rows(m), _rows(v)
     nrows = p2.shape[0]
+    scalars, s_spec, skip_at = _step_scalars(
+        lr, beta1, beta2, eps, bc1, bc2, weight_decay, 1.0 - beta1,
+        1.0 - beta2, skip=skip)
     po, mo, vo = pl.pallas_call(
-        functools.partial(_adam_kernel, mode),
+        functools.partial(_adam_kernel, mode, skip_at),
         grid=_grid(nrows),
-        in_specs=[_smem_spec(9)] + [_row_spec()] * 4,
+        in_specs=[s_spec] + [_row_spec()] * 4,
         out_specs=[_row_spec()] * 3,
         out_shape=[jax.ShapeDtypeStruct(p2.shape, p.dtype),
                    jax.ShapeDtypeStruct(m2.shape, m.dtype),
                    jax.ShapeDtypeStruct(v2.shape, v.dtype)],
+        input_output_aliases={2: 0, 3: 1, 4: 2},
         interpret=interpret_mode(),
         name="apex_mt_adam",
-    )(_scalars(lr, beta1, beta2, eps, bc1, bc2, weight_decay,
-               1.0 - beta1, 1.0 - beta2), g2, p2, m2, v2)
+    )(scalars, g2, p2, m2, v2)
     return po.reshape(p.shape), mo.reshape(m.shape), vo.reshape(v.shape)
 
 
-def _adagrad_kernel(mode, s_ref, g_ref, p_ref, h_ref, po_ref, ho_ref):
+def _adagrad_kernel(mode, skip_at, s_ref, g_ref, p_ref, h_ref,
+                    po_ref, ho_ref):
     lr, eps, wd = s_ref[0, 0], s_ref[0, 1], s_ref[0, 2]
+    skip = _skip_flag(s_ref, skip_at)
     gf = g_ref[...].astype(jnp.float32)
     pf = p_ref[...].astype(jnp.float32)
     hf = h_ref[...].astype(jnp.float32)
@@ -337,30 +379,35 @@ def _adagrad_kernel(mode, s_ref, g_ref, p_ref, h_ref, po_ref, ho_ref):
     else:
         hf = hf + gf * gf
         pf = pf - lr * (gf / (jnp.sqrt(hf) + eps) + wd * pf)
-    po_ref[...] = pf.astype(po_ref.dtype)
-    ho_ref[...] = hf.astype(ho_ref.dtype)
+    _store(po_ref, p_ref, pf, skip)
+    _store(ho_ref, h_ref, hf, skip)
 
 
-def adagrad_step(g, p, h, *, lr, eps, mode=0, weight_decay=0.0):
-    """Fused Adagrad (reference: multi_tensor_adagrad.cu:24-85)."""
+def adagrad_step(g, p, h, *, lr, eps, mode=0, weight_decay=0.0, skip=None):
+    """Fused Adagrad (reference: multi_tensor_adagrad.cu:24-85), p and h
+    updated in place."""
     g2, p2, h2 = _rows(g), _rows(p), _rows(h)
     nrows = p2.shape[0]
+    scalars, s_spec, skip_at = _step_scalars(lr, eps, weight_decay,
+                                             skip=skip)
     po, ho = pl.pallas_call(
-        functools.partial(_adagrad_kernel, mode),
+        functools.partial(_adagrad_kernel, mode, skip_at),
         grid=_grid(nrows),
-        in_specs=[_smem_spec(3)] + [_row_spec()] * 3,
+        in_specs=[s_spec] + [_row_spec()] * 3,
         out_specs=[_row_spec()] * 2,
         out_shape=[jax.ShapeDtypeStruct(p2.shape, p.dtype),
                    jax.ShapeDtypeStruct(h2.shape, h.dtype)],
+        input_output_aliases={2: 0, 3: 1},
         interpret=interpret_mode(),
         name="apex_mt_adagrad",
-    )(_scalars(lr, eps, weight_decay), g2, p2, h2)
+    )(scalars, g2, p2, h2)
     return po.reshape(p.shape), ho.reshape(h.shape)
 
 
-def _sgd_kernel(momentum, dampening, nesterov, wd_after_momentum,
+def _sgd_kernel(momentum, dampening, nesterov, wd_after_momentum, skip_at,
                 s_ref, g_ref, p_ref, m_ref, po_ref, mo_ref):
     wd, lr, scl, first_run = (s_ref[0, k] for k in range(4))
+    skip = _skip_flag(s_ref, skip_at)
     gf = g_ref[...].astype(jnp.float32) * scl
     pf = p_ref[...].astype(jnp.float32)
     mf = m_ref[...].astype(jnp.float32)
@@ -372,36 +419,41 @@ def _sgd_kernel(momentum, dampening, nesterov, wd_after_momentum,
         gf = gf + momentum * mf if nesterov else mf
     if wd_after_momentum:
         gf = gf + wd * pf
-    po_ref[...] = (pf - lr * gf).astype(po_ref.dtype)
-    mo_ref[...] = mf.astype(mo_ref.dtype)
+    _store(po_ref, p_ref, pf - lr * gf, skip)
+    _store(mo_ref, m_ref, mf, skip)
 
 
 def sgd_step(g, p, mom, *, wd, momentum, dampening, lr, nesterov=False,
-             first_run=False, wd_after_momentum=False, scale=1.0):
+             first_run=False, wd_after_momentum=False, scale=1.0,
+             skip=None):
     """Fused SGD with momentum/nesterov and folded grad unscale (reference:
     multi_tensor_sgd_kernel.cu:29-140; ``first_run`` initializes momentum to
-    the incoming grad, :113-117). ``first_run`` may be traced."""
+    the incoming grad, :113-117). ``first_run`` may be traced. p and the
+    momentum buffer are updated in place."""
     g2, p2, m2 = _rows(g), _rows(p), _rows(mom)
     nrows = p2.shape[0]
     first = jnp.asarray(first_run, jnp.float32)
+    scalars, s_spec, skip_at = _step_scalars(wd, lr, scale, first, skip=skip)
     po, mo = pl.pallas_call(
         functools.partial(_sgd_kernel, float(momentum), float(dampening),
-                          bool(nesterov), bool(wd_after_momentum)),
+                          bool(nesterov), bool(wd_after_momentum), skip_at),
         grid=_grid(nrows),
-        in_specs=[_smem_spec(4)] + [_row_spec()] * 3,
+        in_specs=[s_spec] + [_row_spec()] * 3,
         out_specs=[_row_spec()] * 2,
         out_shape=[jax.ShapeDtypeStruct(p2.shape, p.dtype),
                    jax.ShapeDtypeStruct(m2.shape, mom.dtype)],
+        input_output_aliases={2: 0, 3: 1},
         interpret=interpret_mode(),
         name="apex_mt_sgd",
-    )(_scalars(wd, lr, scale, first), g2, p2, m2)
+    )(scalars, g2, p2, m2)
     return po.reshape(p.shape), mo.reshape(mom.shape)
 
 
-def _novograd_kernel(mode, grad_averaging, s_ref, g_ref, p_ref, m_ref,
-                     d_ref, po_ref, mo_ref):
+def _novograd_kernel(mode, grad_averaging, skip_at, s_ref, g_ref, p_ref,
+                     m_ref, d_ref, po_ref, mo_ref):
     # omb1 = 1-beta1 precomputed host-side in float64 (see _adam_kernel)
     lr, b1, wd, bc1, omb1 = (s_ref[0, k] for k in range(5))
+    skip = _skip_flag(s_ref, skip_at)
     gf = g_ref[...].astype(jnp.float32)
     pf = p_ref[...].astype(jnp.float32)
     mf = m_ref[...].astype(jnp.float32)
@@ -414,16 +466,18 @@ def _novograd_kernel(mode, grad_averaging, s_ref, g_ref, p_ref, m_ref,
     else:
         mf = b1 * mf + beta3 * gf
         pf = pf - lr * ((mf / bc1) / denom + wd * pf)
-    po_ref[...] = pf.astype(po_ref.dtype)
-    mo_ref[...] = mf.astype(mo_ref.dtype)
+    _store(po_ref, p_ref, pf, skip)
+    _store(mo_ref, m_ref, mf, skip)
 
 
 def novograd_step(g, p, m, v_norms, segment_ids, *, lr, beta1, beta2, eps,
                   step, bias_correction=True, weight_decay=0.0,
-                  grad_averaging=True, mode=0, norm_type=2):
+                  grad_averaging=True, mode=0, norm_type=2, skip=None):
     """Fused NovoGrad (reference: multi_tensor_novograd.cu:31-186): the
     per-tensor second-moment *norm* blend runs as a Pallas row pass +
-    segment reduce; the elementwise update reads the per-row denominator."""
+    segment reduce; the elementwise update reads the per-row denominator.
+    p and m are updated in place; the per-tensor norms are a few scalars
+    and take ``skip`` as a plain select."""
     num_segments = v_norms.shape[0]
     row_ids = row_segment_ids(segment_ids)
     if norm_type == 0:
@@ -444,25 +498,32 @@ def novograd_step(g, p, m, v_norms, segment_ids, *, lr, beta1, beta2, eps,
 
     g2, p2, m2 = _rows(g), _rows(p), _rows(m)
     nrows = p2.shape[0]
+    scalars, s_spec, skip_at = _step_scalars(lr, beta1, weight_decay, bc1,
+                                             1.0 - beta1, skip=skip)
     po, mo = pl.pallas_call(
-        functools.partial(_novograd_kernel, mode, bool(grad_averaging)),
+        functools.partial(_novograd_kernel, mode, bool(grad_averaging),
+                          skip_at),
         grid=_grid(nrows),
-        in_specs=[_smem_spec(5)] + [_row_spec()] * 3 + [_col_spec()],
+        in_specs=[s_spec] + [_row_spec()] * 3 + [_col_spec()],
         out_specs=[_row_spec()] * 2,
         out_shape=[jax.ShapeDtypeStruct(p2.shape, p.dtype),
                    jax.ShapeDtypeStruct(m2.shape, m.dtype)],
+        input_output_aliases={2: 0, 3: 1},
         interpret=interpret_mode(),
         name="apex_mt_novograd",
-    )(_scalars(lr, beta1, weight_decay, bc1, 1.0 - beta1), g2, p2, m2,
-      denom)
-    return po.reshape(p.shape), mo.reshape(m.shape), v_new
+    )(scalars, g2, p2, m2, denom)
+    from apex_tpu.ops.reference import keep_old
+    return (po.reshape(p.shape), mo.reshape(m.shape),
+            keep_old(skip, v_norms, v_new))
 
 
-def _lamb_phase1_kernel(mode, grad_averaging, s_ref, g_ref, p_ref, m_ref,
-                        v_ref, uo_ref, mo_ref, vo_ref, prow_ref, urow_ref):
+def _lamb_phase1_kernel(mode, grad_averaging, skip_at, s_ref, g_ref, p_ref,
+                        m_ref, v_ref, uo_ref, mo_ref, vo_ref, prow_ref,
+                        urow_ref):
     # omb1/omb2 precomputed host-side in float64 (see _adam_kernel)
     b1, b2, eps, bc1, bc2, wd, clip, omb1, omb2 = (
         s_ref[0, k] for k in range(9))
+    skip = _skip_flag(s_ref, skip_at)
     gf = g_ref[...].astype(jnp.float32) / clip
     pf = p_ref[...].astype(jnp.float32)
     mf = m_ref[...].astype(jnp.float32)
@@ -476,8 +537,8 @@ def _lamb_phase1_kernel(mode, grad_averaging, s_ref, g_ref, p_ref, m_ref,
     if mode == 1:
         update = update + wd * pf
     uo_ref[...] = update
-    mo_ref[...] = mf.astype(mo_ref.dtype)
-    vo_ref[...] = vf.astype(vo_ref.dtype)
+    _store(mo_ref, m_ref, mf, skip)
+    _store(vo_ref, v_ref, vf, skip)
     # per-row sumsq of p and u ride along (p and u are already in VMEM) so
     # the per-tensor norms cost no extra sweep over HBM — the reference
     # pays two more multi_tensor_l2norm launches here
@@ -486,19 +547,23 @@ def _lamb_phase1_kernel(mode, grad_averaging, s_ref, g_ref, p_ref, m_ref,
     urow_ref[...] = jnp.sum(update * update, axis=1, keepdims=True)
 
 
-def _lamb_phase2_kernel(r_ref, p_ref, u_ref, po_ref):
+def _lamb_phase2_kernel(*refs):
+    *s_ref, r_ref, p_ref, u_ref, po_ref = refs  # s_ref: the skip flag, if any
     pf = p_ref[...].astype(jnp.float32)
-    po_ref[...] = (pf - r_ref[...] * u_ref[...]).astype(po_ref.dtype)
+    _store(po_ref, p_ref, pf - r_ref[...] * u_ref[...],
+           _skip_flag(s_ref[0], 0) if s_ref else None)
 
 
 def lamb_step(g, p, m, v, segment_ids, num_segments, *, lr, beta1, beta2,
               eps, step, bias_correction=True, weight_decay=0.0,
               grad_averaging=True, mode=0, global_grad_norm,
-              max_grad_norm=0.0, use_nvlamb=False):
+              max_grad_norm=0.0, use_nvlamb=False, skip=None):
     """Two-phase LAMB (reference: multi_tensor_lamb.cu:40-413): phase 1
     writes the Adam-style update term (the reference overwrites the grad
-    buffer, :332-391); per-tensor param/update norms are row passes +
-    segment sums (:370,394); phase 2 applies the trust ratio (:234-329)."""
+    buffer, :332-391; here it is a temporary of its own, since the caller's
+    gradient need be neither fp32 nor dead) and m, v in place; per-tensor
+    param/update norms are row passes + segment sums (:370,394); phase 2
+    applies the trust ratio to p in place (:234-329)."""
     stepf = _f32(step)
     if bias_correction:
         bc1 = 1.0 - jnp.power(_f32(beta1), stepf)
@@ -513,21 +578,24 @@ def lamb_step(g, p, m, v, segment_ids, num_segments, *, lr, beta1, beta2,
 
     g2, p2, m2, v2 = _rows(g), _rows(p), _rows(m), _rows(v)
     nrows = p2.shape[0]
+    scalars, s_spec, skip_at = _step_scalars(
+        beta1, beta2, eps, bc1, bc2, weight_decay, clip, 1.0 - beta1,
+        1.0 - beta2, skip=skip)
     u2, mo, vo, prow, urow = pl.pallas_call(
-        functools.partial(_lamb_phase1_kernel, mode, bool(grad_averaging)),
+        functools.partial(_lamb_phase1_kernel, mode, bool(grad_averaging),
+                          skip_at),
         grid=_grid(nrows),
-        in_specs=[_smem_spec(9)] + [_row_spec()] * 4,
+        in_specs=[s_spec] + [_row_spec()] * 4,
         out_specs=[_row_spec()] * 3 + [_col_spec()] * 2,
         out_shape=[jax.ShapeDtypeStruct(p2.shape, jnp.float32),
                    jax.ShapeDtypeStruct(m2.shape, m.dtype),
                    jax.ShapeDtypeStruct(v2.shape, v.dtype),
                    jax.ShapeDtypeStruct((nrows, 1), jnp.float32),
                    jax.ShapeDtypeStruct((nrows, 1), jnp.float32)],
+        input_output_aliases={3: 1, 4: 2},
         interpret=interpret_mode(),
         name="apex_mt_lamb_stage1",
-    )(_scalars(beta1, beta2, eps, bc1, bc2, weight_decay, clip,
-               1.0 - beta1, 1.0 - beta2),
-      g2, p2, m2, v2)
+    )(scalars, g2, p2, m2, v2)
 
     row_ids = row_segment_ids(segment_ids)
     from apex_tpu.ops.reference import segment_sum_dense
@@ -543,13 +611,16 @@ def lamb_step(g, p, m, v, segment_ids, num_segments, *, lr, beta1, beta2,
         ratio = jnp.full((num_segments,), lrf, jnp.float32)
     row_ratio = ratio[row_ids][:, None]
 
+    flag = () if skip is None else (_scalars(skip),)
     po = pl.pallas_call(
         _lamb_phase2_kernel,
         grid=_grid(nrows),
-        in_specs=[_col_spec(), _row_spec(), _row_spec()],
+        in_specs=[_smem_spec(1)] * len(flag)
+        + [_col_spec(), _row_spec(), _row_spec()],
         out_specs=_row_spec(),
         out_shape=jax.ShapeDtypeStruct(p2.shape, p.dtype),
+        input_output_aliases={len(flag) + 1: 0},
         interpret=interpret_mode(),
         name="apex_mt_lamb_stage2",
-    )(row_ratio, p2, u2)
+    )(*flag, row_ratio, p2, u2)
     return po.reshape(p.shape), mo.reshape(m.shape), vo.reshape(v.shape)

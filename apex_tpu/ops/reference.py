@@ -182,10 +182,18 @@ def norm_out_blend(old_norms: jax.Array, new_norms: jax.Array,
 # Optimizer steps (flat-buffer, functional)
 # ---------------------------------------------------------------------------
 
+def keep_old(skip, old: jax.Array, new: jax.Array) -> jax.Array:
+    """The overflow skip of every step below: ``new`` — or, where the traced
+    ``skip`` is set, ``old`` bit-for-bit. With ``skip=None`` nothing is
+    selected. The select fuses into the update, so a donated ``old`` is
+    still overwritten in place."""
+    return new if skip is None else jnp.where(skip, old, new)
+
+
 def adam_step(g: jax.Array, p: jax.Array, m: jax.Array, v: jax.Array, *,
               lr, beta1: float, beta2: float, eps: float, step,
               mode: int = MODE_L2, bias_correction: bool = True,
-              weight_decay: float = 0.0,
+              weight_decay: float = 0.0, skip=None,
               ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Fused Adam/AdamW step (reference: multi_tensor_adam.cu:23-171).
 
@@ -210,12 +218,15 @@ def adam_step(g: jax.Array, p: jax.Array, m: jax.Array, v: jax.Array, *,
         vf = beta2 * vf + (1.0 - beta2) * gf * gf
         update = (mf / bc1) / (jnp.sqrt(vf / bc2) + eps) + weight_decay * pf
     pf = pf - lr * update
-    return pf.astype(p.dtype), mf.astype(m.dtype), vf.astype(v.dtype)
+    return (keep_old(skip, p, pf.astype(p.dtype)),
+            keep_old(skip, m, mf.astype(m.dtype)),
+            keep_old(skip, v, vf.astype(v.dtype)))
 
 
 def adagrad_step(g: jax.Array, p: jax.Array, h: jax.Array, *,
                  lr, eps: float, mode: int = MODE_L2,
-                 weight_decay: float = 0.0) -> tuple[jax.Array, jax.Array]:
+                 weight_decay: float = 0.0, skip=None,
+                 ) -> tuple[jax.Array, jax.Array]:
     """Fused Adagrad step (reference: multi_tensor_adagrad.cu:24-85).
     Returns (p, h)."""
     gf, pf, hf = _f32(g), _f32(p), _f32(h)
@@ -226,14 +237,15 @@ def adagrad_step(g: jax.Array, p: jax.Array, h: jax.Array, *,
     else:
         hf = hf + gf * gf
         pf = pf - lr * (gf / (jnp.sqrt(hf) + eps) + weight_decay * pf)
-    return pf.astype(p.dtype), hf.astype(h.dtype)
+    return (keep_old(skip, p, pf.astype(p.dtype)),
+            keep_old(skip, h, hf.astype(h.dtype)))
 
 
 def sgd_step(g: jax.Array, p: jax.Array, mom: jax.Array, *,
              wd: float, momentum: float, dampening: float, lr,
              nesterov: bool = False, first_run: bool = False,
              wd_after_momentum: bool = False, scale: float = 1.0,
-             ) -> tuple[jax.Array, jax.Array]:
+             skip=None) -> tuple[jax.Array, jax.Array]:
     """Fused SGD step (reference: multi_tensor_sgd_kernel.cu:29-140).
 
     ``scale`` folds AMP's grad unscale into the step (grads are multiplied by
@@ -255,7 +267,8 @@ def sgd_step(g: jax.Array, p: jax.Array, mom: jax.Array, *,
     if wd != 0.0 and wd_after_momentum:
         gf = gf + wd * pf
     pf = pf - lr * gf
-    return pf.astype(p.dtype), mf.astype(mom.dtype)
+    return (keep_old(skip, p, pf.astype(p.dtype)),
+            keep_old(skip, mom, mf.astype(mom.dtype)))
 
 
 def _broadcast_per_segment(vals: jax.Array, segment_ids: jax.Array,
@@ -285,7 +298,7 @@ def novograd_step(g: jax.Array, p: jax.Array, m: jax.Array,
                   bias_correction: bool = True, weight_decay: float = 0.0,
                   grad_averaging: bool = True, mode: int = MODE_L2,
                   norm_type: int = NORM_L2, aligned: bool = False,
-                  ) -> tuple[jax.Array, jax.Array, jax.Array]:
+                  skip=None) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Fused NovoGrad step (reference: multi_tensor_novograd.cu:31-186).
 
     ``v_norms`` is the per-tensor second-moment vector storing *norms* (not
@@ -323,7 +336,9 @@ def novograd_step(g: jax.Array, p: jax.Array, m: jax.Array,
         mf = beta1 * mf + beta3 * gf
         update = (mf / bc1) / denom + weight_decay * pf
         pf = pf - lr * update
-    return pf.astype(p.dtype), mf.astype(m.dtype), v_new
+    return (keep_old(skip, p, pf.astype(p.dtype)),
+            keep_old(skip, m, mf.astype(m.dtype)),
+            keep_old(skip, v_norms, v_new))
 
 
 def lamb_step(g: jax.Array, p: jax.Array, m: jax.Array, v: jax.Array,
@@ -332,7 +347,7 @@ def lamb_step(g: jax.Array, p: jax.Array, m: jax.Array, v: jax.Array,
               bias_correction: bool = True, weight_decay: float = 0.0,
               grad_averaging: bool = True, mode: int = MODE_L2,
               global_grad_norm, max_grad_norm: float = 0.0,
-              use_nvlamb: bool = False, aligned: bool = False,
+              use_nvlamb: bool = False, aligned: bool = False, skip=None,
               ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Fused two-phase LAMB step (reference: multi_tensor_lamb.cu:40-413).
 
@@ -381,4 +396,6 @@ def lamb_step(g: jax.Array, p: jax.Array, m: jax.Array, v: jax.Array,
         ratio = jnp.full((num_segments,), lr, MATH_DTYPE)
     pf = pf - _broadcast_per_segment(ratio, segment_ids, p.size,
                                      aligned) * update
-    return pf.astype(p.dtype), mf.astype(m.dtype), vf.astype(v.dtype)
+    return (keep_old(skip, p, pf.astype(p.dtype)),
+            keep_old(skip, m, mf.astype(m.dtype)),
+            keep_old(skip, v, vf.astype(v.dtype)))

@@ -12,8 +12,11 @@ same API shape is a thin stateful wrapper:
   with AMP integration as explicit arguments: ``scale`` folds grad
   unscaling into the kernel (the FusedSGD ``scale`` arg,
   multi_tensor_sgd_kernel.cu:86), ``found_inf`` selects old-vs-new state
-  branchlessly (replacing amp.handle's "patch step into a no-op once"
-  trick, apex/amp/handle.py:128-154);
+  branchlessly inside the step kernel (replacing amp.handle's "patch step
+  into a no-op once" trick, apex/amp/handle.py:128-154);
+- the step kernels update master and slots IN PLACE: a caller that donates
+  its state (``step`` does; a jitted ``apply_update`` should) pays no copy,
+  one that keeps the old state gets XLA's protective copy of it;
 - hyperparameters that schedules mutate (lr) are traced scalars, so
   ``set_lr`` never retriggers compilation;
 - ``state_dict``/``load_state_dict`` round-trip everything, including the
@@ -121,7 +124,10 @@ class FusedOptimizer:
                           step=jnp.asarray(0, jnp.int32))
 
     def _update_group(self, gidx: int, grad: jax.Array, gs: GroupState,
-                      hp: dict, lr, extras: dict) -> GroupState:
+                      hp: dict, lr, extras: dict, skip) -> GroupState:
+        """One group's update. ``skip`` (None, or the traced ``found_inf``)
+        goes to the ``ops.kernels`` step as ``skip=``: master and slots come
+        back bit-for-bit when it is set."""
         raise NotImplementedError
 
     def _pre_update(self, flat_grads: list[jax.Array], scale) -> dict:
@@ -151,17 +157,14 @@ class FusedOptimizer:
         for i, (gs, g) in enumerate(zip(state, flat_grads)):
             hp = self.param_groups[i]
             new_gs = self._update_group(i, g, dataclasses.replace(
-                gs, step=gs.step + 1), hp, lrs[i], extras)
+                gs, step=gs.step + 1), hp, lrs[i], extras, found_inf)
             if found_inf is not None:
-                # Branchless step-skip: on overflow keep the old state and
-                # do not advance the step counter.
-                keep = lambda old, new: jnp.where(found_inf, old, new)
-                new_gs = GroupState(
-                    master=keep(gs.master, new_gs.master),
-                    slots={k: keep(gs.slots[k], v)
-                           for k, v in new_gs.slots.items()},
-                    step=jnp.where(found_inf, gs.step, new_gs.step),
-                )
+                # Branchless step-skip: on overflow the step kernel wrote
+                # back the state it read (a select out here, over whole
+                # buffers, would keep the old state alive beside the new
+                # and cost a copy of each); the counter does not advance.
+                new_gs = dataclasses.replace(new_gs, step=jnp.where(
+                    found_inf, gs.step, new_gs.step))
             new_states.append(new_gs)
         return tuple(new_states)
 

@@ -18,9 +18,10 @@ class FusedAdagrad(FusedOptimizer):
         super().__init__(params, defaults, set_grad_none=set_grad_none,
                          **kw)
 
-    def _update_group(self, gidx, grad, gs: GroupState, hp, lr, extras):
+    def _update_group(self, gidx, grad, gs: GroupState, hp, lr, extras,
+                      skip):
         p, h = R.adagrad_step(
             grad, gs.master, gs.slots["sum"], lr=lr, eps=hp["eps"],
             mode=R.MODE_DECOUPLED if self.adagrad_w_mode else R.MODE_L2,
-            weight_decay=hp["weight_decay"])
+            weight_decay=hp["weight_decay"], skip=skip)
         return dataclasses.replace(gs, master=p, slots={"sum": h})

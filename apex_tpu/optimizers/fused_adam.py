@@ -29,13 +29,14 @@ class FusedAdam(FusedOptimizer):
         super().__init__(params, defaults, set_grad_none=set_grad_none,
                          **kw)
 
-    def _update_group(self, gidx, grad, gs: GroupState, hp, lr, extras):
+    def _update_group(self, gidx, grad, gs: GroupState, hp, lr, extras,
+                      skip):
         beta1, beta2 = hp["betas"]
         p, m, v = R.adam_step(
             grad, gs.master, gs.slots["exp_avg"], gs.slots["exp_avg_sq"],
             lr=lr, beta1=beta1, beta2=beta2, eps=hp["eps"], step=gs.step,
             mode=R.MODE_DECOUPLED if self.adam_w_mode else R.MODE_L2,
             bias_correction=bool(hp["bias_correction"]),
-            weight_decay=hp["weight_decay"])
+            weight_decay=hp["weight_decay"], skip=skip)
         return dataclasses.replace(
             gs, master=p, slots={"exp_avg": m, "exp_avg_sq": v})

@@ -45,7 +45,8 @@ class FusedLAMB(FusedOptimizer):
                  for g in flat_grads)
         return {"global_grad_norm": jnp.sqrt(sq)}
 
-    def _update_group(self, gidx, grad, gs: GroupState, hp, lr, extras):
+    def _update_group(self, gidx, grad, gs: GroupState, hp, lr, extras,
+                      skip):
         beta1, beta2 = hp["betas"]
         table = self._tables[gidx]
         p, m, v = R.lamb_step(
@@ -59,6 +60,6 @@ class FusedLAMB(FusedOptimizer):
             mode=R.MODE_DECOUPLED if self.adam_w_mode else R.MODE_L2,
             global_grad_norm=extras["global_grad_norm"],
             max_grad_norm=hp["max_grad_norm"],
-            use_nvlamb=self.use_nvlamb)
+            use_nvlamb=self.use_nvlamb, skip=skip)
         return dataclasses.replace(
             gs, master=p, slots={"exp_avg": m, "exp_avg_sq": v})

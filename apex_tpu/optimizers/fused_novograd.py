@@ -50,7 +50,8 @@ class FusedNovoGrad(FusedOptimizer):
             jnp.float32)
         return gs
 
-    def _update_group(self, gidx, grad, gs: GroupState, hp, lr, extras):
+    def _update_group(self, gidx, grad, gs: GroupState, hp, lr, extras,
+                      skip):
         beta1, beta2 = hp["betas"]
         table = self._tables[gidx]
         seg = table.segment_ids()
@@ -73,6 +74,10 @@ class FusedNovoGrad(FusedOptimizer):
             bias_correction=bool(hp["bias_correction"]),
             weight_decay=hp["weight_decay"],
             grad_averaging=bool(hp["grad_averaging"]),
-            mode=self.moment_mode, norm_type=hp["norm_type"])
+            mode=self.moment_mode, norm_type=hp["norm_type"], skip=skip)
+        if not self.init_zero:
+            # a skipped first step must leave the NaN marker, not the
+            # overflowing gradient's norms seeded above
+            v = R.keep_old(skip, gs.slots["exp_avg_sq"], v)
         return dataclasses.replace(
             gs, master=p, slots={"exp_avg": m, "exp_avg_sq": v})

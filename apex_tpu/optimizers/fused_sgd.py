@@ -37,7 +37,8 @@ class FusedSGD(FusedOptimizer):
         self.wd_after_momentum = wd_after_momentum
         super().__init__(params, defaults, **kw)
 
-    def _update_group(self, gidx, grad, gs: GroupState, hp, lr, extras):
+    def _update_group(self, gidx, grad, gs: GroupState, hp, lr, extras,
+                      skip):
         # first_run initializes momentum to the incoming grad
         # (multi_tensor_sgd_kernel.cu:113-117); step was already incremented.
         first_run = gs.step == 1
@@ -47,6 +48,7 @@ class FusedSGD(FusedOptimizer):
             grad, gs.master, gs.slots["momentum_buffer"],
             wd=hp["weight_decay"], momentum=hp["momentum"],
             dampening=hp["dampening"], lr=lr, nesterov=hp["nesterov"],
-            first_run=first_run, wd_after_momentum=self.wd_after_momentum)
+            first_run=first_run, wd_after_momentum=self.wd_after_momentum,
+            skip=skip)
         return dataclasses.replace(gs, master=p,
                                    slots={"momentum_buffer": mom})

@@ -216,6 +216,57 @@ def test_kernel_compiles_for_v5e(chip, for_chip, make_fn, args, names):
         + mem.output_size_in_bytes < 16e9            # fits one v5e's HBM
 
 
+def _step(name, skip, **kw):
+    """``multi_tensor.<name>`` over (g, p, m, v[, segment ids]) and, with
+    ``skip``, a traced overflow flag as the last argument."""
+    fn = _multi_tensor(name, **kw)
+
+    def traced(g, p, m, v, *rest):
+        if skip:
+            *rest, flag = rest
+            return fn(g, p, m, v, *rest, skip=flag)
+        return fn(g, p, m, v, *rest)
+    return traced
+
+
+# (id, step, arguments after (g, p, m, v), kernels, state-sized temporaries
+#  allowed). LAMB's are its update term and the two per-row norm columns:
+#  the chip's (8, 128) tiling pads an f32[rows, 1] to a whole buffer.
+IN_PLACE = [
+    ("adam_step-128M", "adam_step", _ADAM, [],
+     ("apex_mt_adam",), 1),
+    ("lamb_step-128M", "lamb_step", dict(_LAMB, num_segments=_SEGS),
+     [((FLAT,), I32)],
+     ("apex_mt_lamb_stage1", "apex_mt_lamb_stage2"), 3),
+]
+
+
+@pytest.mark.parametrize("skip", [False, True], ids=["donated",
+                                                     "donated-skip"])
+@pytest.mark.parametrize("name,kw,more,names,temporaries",
+                         [pytest.param(*c[1:], id=c[0]) for c in IN_PLACE])
+def test_step_kernel_updates_donated_state_in_place(
+        chip, for_chip, name, kw, more, names, temporaries, skip):
+    """The in-place contract on the chip's compiler: with p, m and v
+    donated, the program holds no copy of a state-shaped buffer (the
+    kernel's outputs alias its inputs, so XLA has nothing to protect) and
+    its temporaries stay under what the step itself needs, with or
+    without the overflow flag."""
+    args = [((FLAT,), F32)] * 4 + more + ([((), jnp.bool_)] if skip else [])
+    specs = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+             for shape, dtype in args]
+    compiled = jax.jit(_step(name, skip, **kw),
+                       donate_argnums=(1, 2, 3)).lower(*specs).compile()
+    text = compiled.as_text()
+    assert [n for n in names
+            if not re.search(rf"%(\w+_)?{n}_*\.\d+ = ", text)] == []
+    state = rf"f32\[({FLAT}|{FLAT // 128},128)\]"
+    assert re.findall(rf"= {state}(\{{[^}}]*\}})? copy\(", text) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 3 * 4 * FLAT      # p, m, v: in place
+    assert mem.temp_size_in_bytes < temporaries * 4 * FLAT * 1.01
+
+
 def test_engine_paged_decode_program_compiles_for_v5e(chip, for_chip):
     """The serving engine's paged decode step, as ``lint_programs()``
     describes it, at the dense LM's widths (depth and arena cut so the

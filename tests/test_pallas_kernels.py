@@ -10,6 +10,8 @@ on TPU. Includes the reference suite's inf/nan injection at buffer
 boundaries to verify the overflow flag.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -167,6 +169,139 @@ def test_lamb_step_matches_reference(use_nvlamb, weight_decay):
     for got, want in zip(P.lamb_step(g, p, m, v, ids, nseg, **kw),
                          R.lamb_step(g, p, m, v, ids, nseg, **kw)):
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+# --- the in-place contract and the overflow skip of the step kernels (PR 25)
+
+_ROWS = 1500            # three row blocks of 512, the last one ragged
+
+
+def _step_cases():
+    """name -> (step(backend module, g, *state, skip=), state builder):
+    the five optimizer steps with the arguments the parity tests above
+    use, at a size whose last block is ragged."""
+    rs = np.random.RandomState(25)
+    n = 128 * _ROWS
+    ids = jnp.repeat(jnp.arange(4, dtype=jnp.int32), n // 4)
+    pos = lambda: jnp.abs(_buf(rs, n, jnp.float32)) * 0.01
+    adam = dict(lr=1e-2, beta1=0.9, beta2=0.999, eps=1e-8, step=3,
+                weight_decay=0.01)
+    return {
+        "adam": (lambda B, g, p, m, v, **kw: B.adam_step(
+            g, p, m, v, **adam, **kw),
+            lambda: (_buf(rs, n, jnp.float32), pos(), pos())),
+        "adagrad": (lambda B, g, p, h, **kw: B.adagrad_step(
+            g, p, h, lr=1e-2, eps=1e-10, weight_decay=0.1, **kw),
+            lambda: (_buf(rs, n, jnp.float32), pos())),
+        "sgd": (lambda B, g, p, mom, **kw: B.sgd_step(
+            g, p, mom, wd=1e-4, momentum=0.9, dampening=0.0, lr=0.1,
+            nesterov=True, scale=0.5, **kw),
+            lambda: (_buf(rs, n, jnp.float32), _buf(rs, n, jnp.float32))),
+        "novograd": (lambda B, g, p, m, vn, **kw: B.novograd_step(
+            g, p, m, vn, ids, lr=1e-2, beta1=0.95, beta2=0.98, eps=1e-8,
+            step=2, weight_decay=0.01, **kw),
+            lambda: (_buf(rs, n, jnp.float32), pos(),
+                     jnp.abs(jnp.asarray(rs.randn(4), jnp.float32)))),
+        "lamb": (lambda B, g, p, m, v, **kw: B.lamb_step(
+            g, p, m, v, ids, 4, **{**adam, "eps": 1e-6},
+            global_grad_norm=3.0, max_grad_norm=1.0, **kw),
+            lambda: (_buf(rs, n, jnp.float32), pos(), pos())),
+    }
+
+
+STEP_CASES = _step_cases()
+
+
+def _bits(arrays):
+    return [np.asarray(a).view(np.uint32) for a in arrays]
+
+
+@pytest.mark.parametrize("backend", ["pallas", "reference"])
+@pytest.mark.parametrize("name", sorted(STEP_CASES))
+def test_step_kernel_skips_in_kernel_and_updates_in_place(name, backend):
+    """One signature on both backends: ``skip`` set returns the state that
+    came in, bit for bit, whatever the gradient holds; ``skip`` clear is
+    the result without the argument; a caller that keeps its state finds
+    it intact; a caller that donates it gets the same result."""
+    B = P if backend == "pallas" else R
+    step, make_state = STEP_CASES[name]
+    rs = np.random.RandomState(26)
+    g = _buf(rs, 128 * _ROWS, jnp.float32)
+    state = make_state()
+    before = _bits(state)
+    plain = jax.jit(lambda g, *st: step(B, g, *st))
+    skipping = jax.jit(lambda g, s, *st: step(B, g, *st, skip=s))
+    donating = jax.jit(lambda g, s, st: step(B, g, *st, skip=s),
+                       donate_argnums=(2,))
+
+    want = skipping(g, jnp.asarray(False), *state)
+    assert any((w != b).any() for w, b in zip(_bits(want), before))
+    # the same arithmetic as without the argument (to the last bit on the
+    # chip; the CPU's compiler contracts a multiply-add differently once a
+    # select follows it, an ulp in a few elements)
+    for got, ref in zip(want, plain(g, *state)):
+        np.testing.assert_allclose(got, ref, rtol=1e-6, atol=1e-7)
+    want = _bits(want)
+    # an overflowing gradient, in the first and in the ragged last block
+    bad = g.at[7].set(jnp.inf).at[-3].set(jnp.nan)
+    for flag in (jnp.asarray(True), jnp.asarray(1.0, jnp.float32)):
+        for got, ref in zip(_bits(skipping(bad, flag, *state)), before):
+            np.testing.assert_array_equal(got, ref)
+    for now, ref in zip(_bits(state), before):       # not donated: intact
+        np.testing.assert_array_equal(now, ref)
+    for got, ref in zip(_bits(donating(g, jnp.asarray(False),
+                                       jax.tree.map(jnp.copy, state))), want):
+        np.testing.assert_array_equal(got, ref)
+    for got, ref in zip(_bits(donating(bad, jnp.asarray(True),
+                                       jax.tree.map(jnp.copy, state))),
+                        before):
+        np.testing.assert_array_equal(got, ref)
+
+
+def _fused(name, params):
+    from apex_tpu import optimizers as O
+    return {"adam": lambda: O.FusedAdam(params, lr=1e-2, weight_decay=0.01),
+            "lamb": lambda: O.FusedLAMB(params, lr=1e-2),
+            "sgd": lambda: O.FusedSGD(params, lr=0.1, momentum=0.9),
+            "adagrad": lambda: O.FusedAdagrad(params, lr=1e-2),
+            "novograd": lambda: O.FusedNovoGrad(params, lr=1e-2)}[name]()
+
+
+@pytest.mark.parametrize("backend", ["pallas", "reference"])
+@pytest.mark.parametrize("name", ["adam", "lamb", "sgd", "adagrad",
+                                  "novograd"])
+def test_apply_update_found_inf_keeps_state_and_step(name, backend):
+    """Through the optimizers: ``found_inf`` set leaves master, slots and
+    the step counter as they were (NovoGrad's not-yet-seeded norms stay
+    NaN, so the overflowing first gradient never seeds them);
+    ``found_inf`` clear is the update without the argument."""
+    rs = np.random.RandomState(27)
+    params = {"w": jnp.asarray(rs.randn(64, 32), jnp.float32),
+              "b": jnp.asarray(rs.randn(32), jnp.float32)}
+    with dispatch.backend(backend):
+        opt = _fused(name, params)
+        fg = opt.flatten_grads(jax.tree.map(lambda x: x * 0.1, params))
+        for state in (opt.init_state(),                          # step 0
+                      opt.apply_update(opt.init_state(), fg)):   # step 1
+            start = int(state[0].step)
+            want = opt.apply_update(state, fg)
+            assert int(want[0].step) == start + 1
+            ran = opt.apply_update(state, fg, found_inf=jnp.asarray(False))
+            bad = [fg[0].at[5].set(jnp.inf)]
+            skipped = opt.apply_update(state, bad,
+                                       found_inf=jnp.asarray(True))
+            assert int(ran[0].step) == start + 1
+            assert int(skipped[0].step) == start
+            assert ran[0].slots.keys() == skipped[0].slots.keys() \
+                == state[0].slots.keys()
+            close = functools.partial(np.testing.assert_allclose,
+                                      rtol=1e-6, atol=1e-7)
+            for got, ref, same in ((ran, want, close),
+                                   (skipped, state,
+                                    np.testing.assert_array_equal)):
+                same(got[0].master, ref[0].master)
+                for k in ref[0].slots:
+                    same(got[0].slots[k], ref[0].slots[k])
 
 
 def test_dispatch_backend_context_switches_paths():
