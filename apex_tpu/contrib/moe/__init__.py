@@ -17,8 +17,15 @@ Every rank computes the (cheap, replicated) routing; each rank runs ONLY
 its local experts' FFNs; one ``psum`` over the expert axis combines the
 per-token outputs (each token's value is produced by exactly one rank).
 Composes with a data axis outside (tokens sharded on batch).
+
+``ExpertLayer`` (``expert_layer.py``) is the many-expert layer of today's
+models as one expert-parallel rank computes it: routing over every expert,
+dropless sorted dispatch to the experts held here (``experts_held``),
+grouped matmuls, a gated shared expert, pairs past the one static bound
+counted.
 """
 
+from apex_tpu.contrib.moe.expert_layer import ExpertLayer  # noqa: F401
 from apex_tpu.contrib.moe.moe import MoEMLP  # noqa: F401
 
-__all__ = ["MoEMLP"]
+__all__ = ["ExpertLayer", "MoEMLP"]

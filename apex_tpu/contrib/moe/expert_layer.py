@@ -1,0 +1,192 @@
+"""A chip's share of a many-expert layer: routing over every expert,
+dropless dispatch to the experts held here, grouped matmuls, a gated
+shared expert.
+
+Where :class:`~apex_tpu.contrib.moe.MoEMLP` is the Switch layer (a queue
+of fixed capacity an expert, overflow dropped, a ``[K*N, E]`` one-hot),
+this is the layer of today's many-expert models (hundreds of small SwiGLU
+experts, ten a token, one shared expert every token passes through), as
+one rank of an expert-parallel deployment computes it:
+
+- the router keeps its full width: ``softmax`` over all ``num_experts``
+  in float32, the ``top_k`` largest, their weights divided by their sum;
+- the layer is told ``experts_held = (lo, hi)``, the range of experts
+  whose weights it has. The (token, expert) pairs that fall in the range
+  are sorted by expert into one buffer of ``dispatch_bound`` rows, each
+  expert's group starting on a multiple of ``tile`` rows, so that every
+  tile of the buffer belongs to one expert and the experts' three matmuls
+  are batched matmuls over tiles, each tile with its expert's weights;
+- there is no capacity an expert and no drop: an expert takes whatever
+  share of the buffer its tokens need. ``dispatch_bound`` is the one
+  static size. Pairs that fall past it are left out **and counted**
+  (``aux["overflow_pairs"]``; 0 in a sound run), never silently lost; the
+  default bound (0) is the worst case and cannot overflow;
+- what the experts held elsewhere would add is left out: on one chip the
+  layer runs without its exchange, and nothing stands in for other chips.
+  The parts all shares give, with the shared expert counted once, add up
+  to the uncut layer (``tests/test_expert_layer.py``);
+- a share's router learns from its load-balancing term alone: the
+  gradient of a token's weights needs all ``top_k`` of its experts'
+  outputs, which come back through the exchange. The held experts' part
+  alone would reward the router only for choosing the experts that are
+  here, and it runs away to them, so a share holds the weights constant
+  in the backward (``lax.stop_gradient``); the layer that holds every
+  expert differentiates them as written.
+
+``aux`` also carries the router's load-balancing term (Switch form over
+all experts, ``E * sum_e f_e P_e`` with ``f_e`` the share of tokens that
+chose expert ``e`` and ``P_e`` its mean probability), the pairs that fell
+on held experts (``held_pairs``: what the bound is sized from) and the
+load of the fullest held expert over the mean (``load_max_over_mean``).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import ClassVar
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+__all__ = ["ExpertLayer"]
+
+_F32 = jnp.float32
+
+
+def _swiglu(x, w_gate, w_up, w_down, eq_in, eq_out):
+    g = jnp.einsum(eq_in, x, w_gate, preferred_element_type=_F32)
+    u = jnp.einsum(eq_in, x, w_up, preferred_element_type=_F32)
+    return jnp.einsum(eq_out, (jax.nn.silu(g) * u).astype(x.dtype), w_down,
+                      preferred_element_type=_F32)
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertLayer:
+    hidden: int
+    ffn: int                    # a routed expert's width
+    num_experts: int            # the router's width: all experts, anywhere
+    top_k: int
+    experts_held: tuple = ()    # (lo, hi) of the experts here; () = all
+    shared_ffn: int = 0         # the shared expert's width; 0 = none
+    dispatch_bound: int = 0     # rows of the dispatch buffer; 0 = worst case
+
+    tile: ClassVar[int] = 128   # rows a tile: one expert's weights each
+
+    def __post_init__(self):
+        lo, hi = self.held
+        if not 0 <= lo < hi <= self.num_experts:
+            raise ValueError(f"experts_held {self.experts_held} is no range "
+                             f"of the {self.num_experts} experts")
+        if not 1 <= self.top_k <= self.num_experts:
+            raise ValueError(f"top_k must be in [1, {self.num_experts}]")
+        if self.dispatch_bound % self.tile:
+            raise ValueError(f"dispatch_bound ({self.dispatch_bound}) must "
+                             f"be a multiple of tile ({self.tile})")
+
+    @property
+    def held(self) -> tuple:
+        return tuple(self.experts_held) or (0, self.num_experts)
+
+    def init(self, key, scale: float = 0.02) -> dict:
+        ks = jax.random.split(key, 8)
+        d, f, n = self.hidden, self.ffn, self.held[1] - self.held[0]
+
+        def w(k, *shape):
+            return jax.random.normal(k, shape) * scale
+        p = {"router": w(ks[0], d, self.num_experts),
+             "w_gate": w(ks[1], n, d, f), "w_up": w(ks[2], n, d, f),
+             "w_down": w(ks[3], n, f, d)}
+        if self.shared_ffn:
+            s = self.shared_ffn
+            p["shared"] = {"w_gate": w(ks[4], d, s), "w_up": w(ks[5], d, s),
+                           "w_down": w(ks[6], s, d), "gate": w(ks[7], d, 1)}
+        return p
+
+    def bound(self, n_tokens: int) -> int:
+        """Rows of the dispatch buffer: ``dispatch_bound``, or every pair
+        on a held expert with each group's last tile all but empty."""
+        if self.dispatch_bound:
+            return self.dispatch_bound
+        lo, hi = self.held
+        pairs = n_tokens * min(self.top_k, hi - lo)
+        return -(-(pairs + (hi - lo) * (self.tile - 1)) // self.tile) \
+            * self.tile
+
+    def route(self, params: dict, x):
+        """``(weights [N, K] float32, experts [N, K], probs [N, E])``."""
+        logits = jnp.dot(x, params["router"], preferred_element_type=_F32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        w, idx = lax.top_k(probs, self.top_k)
+        return w / jnp.sum(w, axis=-1, keepdims=True), idx, probs
+
+    def routed(self, params: dict, x):
+        """The held experts' part of the layer for ``x [N, hidden]``:
+        ``(y [N, hidden] float32, aux)``."""
+        n, d = x.shape
+        k, tm, e_all = self.top_k, self.tile, self.num_experts
+        lo, hi = self.held
+        held = hi - lo
+        rows = self.bound(n)
+        with jax.named_scope("moe_route"):              # prof.SCOPES
+            w, idx, probs = self.route(params, x)
+            if held < e_all:    # a share: no reward from held experts only
+                w = lax.stop_gradient(w)
+            # pairs an expert, all experts: a fused compare-and-sum, no
+            # [pairs, E] one-hot in memory
+            counts = jnp.sum(idx[:, :, None] == jnp.arange(e_all), (0, 1))
+            balance = e_all * jnp.sum(
+                lax.stop_gradient(counts.astype(_F32) / n)
+                * jnp.mean(probs, axis=0))
+            # sort the pairs by held expert; pairs on absent experts last
+            local = jnp.where((idx >= lo) & (idx < hi), idx - lo, held)
+            order = jnp.argsort(local.reshape(-1), stable=True)
+            n_e = counts[lo:hi]
+            first = jnp.cumsum(n_e) - n_e       # in the sorted pairs
+            tiles_e = -(-n_e // tm)             # whole tiles an expert
+            end_tile = jnp.cumsum(tiles_e)
+            # a tile belongs to one expert, so what a row needs of its
+            # expert is looked up a tile (rows // tm of them) and spread
+            tile = jnp.arange(rows // tm)
+            tile_e = jnp.minimum(
+                jnp.searchsorted(end_tile, tile, side="right",
+                                 method="compare_all"), held - 1)
+            off = ((tile - (end_tile - tiles_e)[tile_e]) * tm)[:, None] \
+                + jnp.arange(tm)                # [tiles, tm]: row in group
+            live = (off < n_e[tile_e][:, None]).reshape(rows)
+            pair = order[jnp.clip(first[tile_e][:, None] + off, 0,
+                                  n * k - 1).reshape(rows)]
+            tok = pair // k
+            xb = jnp.where(live[:, None], x[tok], 0)
+            wb = jnp.where(live, w.reshape(-1)[pair], 0.0)
+            overflow = jnp.sum(jnp.clip(
+                (end_tile - tiles_e) * tm + n_e - rows, 0, n_e))
+            load = jnp.max(n_e) / jnp.maximum(jnp.mean(n_e.astype(_F32)),
+                                              1e-9)
+        with jax.named_scope("moe_experts"):
+            yb = _swiglu(xb.reshape(rows // tm, tm, d),
+                         params["w_gate"][tile_e], params["w_up"][tile_e],
+                         params["w_down"][tile_e],
+                         "tmd,tdf->tmf", "tmf,tfd->tmd").reshape(rows, d)
+        with jax.named_scope("moe_route"):
+            y = jnp.zeros((n, d), _F32).at[tok].add(yb * wb[:, None])
+        return y, {"load_balance_loss": balance,
+                   "overflow_pairs": overflow.astype(jnp.int32),
+                   "held_pairs": jnp.sum(n_e).astype(jnp.int32),
+                   "load_max_over_mean": load}
+
+    def shared(self, params: dict, x):
+        """The gated shared expert, which every chip computes alike."""
+        sp = params["shared"]
+        with jax.named_scope("moe_experts"):
+            gate = jax.nn.sigmoid(jnp.dot(x, sp["gate"],
+                                          preferred_element_type=_F32))
+            return gate * _swiglu(x, sp["w_gate"], sp["w_up"], sp["w_down"],
+                                  "nd,df->nf", "nf,fd->nd")
+
+    def apply(self, params: dict, x):
+        """``x [N, hidden]`` -> ``(y [N, hidden] in x's type, aux)``."""
+        y, aux = self.routed(params, x)
+        if self.shared_ffn:
+            y = y + self.shared(params, x)
+        return y.astype(x.dtype), aux

@@ -6,6 +6,7 @@ from apex_tpu.models.resnet import (  # noqa: F401
     ResNet, resnet18, resnet34, resnet50, resnet101, resnet152,
 )
 from apex_tpu.models.transformer import TransformerLM  # noqa: F401
+from apex_tpu.models.hybrid_lm import HybridLM  # noqa: F401
 from apex_tpu.models.vit import (  # noqa: F401
     ViT, vit_tiny, vit_small, vit_b16, vit_l16,
 )

@@ -1,0 +1,306 @@
+"""A hybrid linear-attention / softmax-attention mixture-of-experts
+decoder: the block of today's Gated DeltaNet hybrids, with the layer
+pattern as data.
+
+    x  = embed[tokens]
+    x += mixer_i(norm0(x))        mixer_i by ``layer_types[i]``:
+                                  "linear" (Gated DeltaNet) or "full"
+                                  (gated grouped-query softmax attention)
+    x += experts(norm0(x))        a chip's share of a many-expert layer
+    loss = xent(norm0(x) @ head^T) + aux_coef * sum of the routers'
+           load-balancing terms
+
+``norm0(x; w) = x * rsqrt(mean(x^2) + eps) * (1 + w)`` is the zero-centred
+RMSNorm (weight zero at init); no matrix has a bias; the head is not tied
+to the embedding. Where :class:`~apex_tpu.models.TransformerLM` is one
+GPT-2 block repeated, this model's layers differ, so it is a class of its
+own and shares with the dense LM what lies under it: the flash-attention
+kernels, the chunked head (``linear_cross_entropy``), recomputation
+(``jax.checkpoint`` a block) and the step builder
+(``tools/lm_bench.build_train_step``).
+
+The **Gated DeltaNet mixer** (``linear_k_heads`` key heads and
+``linear_v_heads`` value heads of ``linear_k_dim`` / ``linear_v_dim``):
+``[q, k, v, z] = h W_qkvz``, ``[b, a] = h W_ba``; ``q, k, v`` go through
+a causal depthwise convolution (``conv_kernel`` taps, no bias) and SiLU;
+``beta = sigmoid(b)``, ``g = -exp(A_log) softplus(a + dt_bias)`` in
+float32; ``q`` and ``k`` are L2-normalised a head (``q`` scaled by
+``dk^-0.5``) and repeated to the value heads; the recurrence is
+``ops.gated_delta_rule``; its output is RMS-normalised a head, gated by
+``silu(z)`` and projected out.
+
+The **gated attention mixer** (``num_heads`` query heads over
+``num_kv_heads`` key/value heads of ``head_dim``): ``W_q`` gives each
+head its query and an output gate; ``q`` and ``k`` are ``norm0``-ed a
+head; rotary positions turn the first ``rotary_dim`` of a head
+(half-split pairing); causal attention runs through ``flash_attention``
+with K and V **broadcast to the query heads in front of the kernel**
+(the kernels, which three other programs share, stay as they are; the
+broadcast's transpose sums a group's dK and dV); the result times
+``sigmoid(gate)`` is projected out.
+
+Scopes (``prof.SCOPES``): ``embed``, ``linear_attention``,
+``delta_rule``, ``attention``, ``moe_route``, ``moe_experts``,
+``head_loss``; siblings, never nested.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import itertools
+
+import jax
+import jax.numpy as jnp
+
+from apex_tpu.contrib.moe.expert_layer import ExpertLayer
+from apex_tpu.ops.gated_delta_rule import gated_delta_rule
+
+__all__ = ["HybridLM"]
+
+_F32 = jnp.float32
+MIXERS = ("linear", "full")
+
+
+def _norm0(x, w, eps):
+    """Zero-centred RMSNorm over the last axis, in float32."""
+    xf = x.astype(_F32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+    return (y * (1.0 + w.astype(_F32))).astype(x.dtype)
+
+
+def _rotary(x, theta: float, rot: int):
+    """Rotary positions on the first ``rot`` of the last axis of
+    ``x [B, T, H, D]``, half-split pairing."""
+    half = rot // 2
+    freq = theta ** (-jnp.arange(half, dtype=_F32) / half)
+    ang = jnp.arange(x.shape[1], dtype=_F32)[:, None] * freq    # [T, half]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    x1, x2 = x[..., :half].astype(_F32), x[..., half:rot].astype(_F32)
+    turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return jnp.concatenate([turned.astype(x.dtype), x[..., rot:]], -1)
+
+
+def _causal_conv(x, w):
+    """Depthwise causal convolution of ``x [B, T, C]`` with taps
+    ``w [K, C]`` (the last tap on the current token), as shifted products."""
+    taps = w.shape[0]
+    xp = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    t = x.shape[1]
+    return sum(xp[:, j:j + t] * w[j] for j in range(taps))
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridLM:
+    vocab_size: int
+    hidden: int
+    layer_types: tuple          # a mixer kind a layer: "linear" | "full"
+    # gated softmax attention
+    num_heads: int = 16
+    num_kv_heads: int = 2
+    head_dim: int = 256
+    rotary_dim: int = 64
+    rope_theta: float = 1e7
+    # Gated DeltaNet
+    linear_k_heads: int = 16
+    linear_v_heads: int = 32
+    linear_k_dim: int = 128
+    linear_v_dim: int = 128
+    conv_kernel: int = 4
+    delta_chunk: int = 64
+    # the expert layer (contrib.moe.ExpertLayer)
+    num_experts: int = 512
+    top_k: int = 10
+    expert_ffn: int = 512
+    shared_ffn: int = 512
+    experts_held: tuple = ()
+    dispatch_bound: int = 0
+    aux_coef: float = 0.001
+    rms_eps: float = 1e-6
+    attn_impl: str = "fast"     # "fast": the flash kernels; "default": jnp
+    head_chunk: int = 0         # vocabulary columns a step of the head
+    remat: bool = False         # recompute each block in the backward
+
+    def __post_init__(self):
+        bad = set(self.layer_types) - set(MIXERS)
+        if bad or not self.layer_types:
+            raise ValueError(f"layer_types: a tuple of {MIXERS}, got "
+                             f"{self.layer_types}")
+        if self.num_heads % self.num_kv_heads \
+                or self.linear_v_heads % self.linear_k_heads:
+            raise ValueError("query heads must be a multiple of key/value "
+                             "heads, value heads of key heads")
+        if self.head_chunk and self.vocab_size % self.head_chunk:
+            raise ValueError(f"head_chunk ({self.head_chunk}) must divide "
+                             f"vocab_size ({self.vocab_size})")
+
+    def _experts(self) -> ExpertLayer:
+        return ExpertLayer(
+            hidden=self.hidden, ffn=self.expert_ffn,
+            num_experts=self.num_experts, top_k=self.top_k,
+            experts_held=self.experts_held, shared_ffn=self.shared_ffn,
+            dispatch_bound=self.dispatch_bound)
+
+    # -- parameters ----------------------------------------------------------
+    def init(self, key, scale: float = 0.02) -> dict:
+        d, v = self.hidden, self.vocab_size
+        kd = self.linear_k_heads * self.linear_k_dim
+        vd = self.linear_v_heads * self.linear_v_dim
+        keys = iter(jax.random.split(key, 3 + 8 * len(self.layer_types)))
+
+        def w(*shape):
+            return jax.random.normal(next(keys), shape) * scale
+        p = {"embed": w(v, d), "head": w(v, d), "norm_f": jnp.zeros((d,))}
+        for i, kind in enumerate(self.layer_types):
+            lp = {"norm1": jnp.zeros((d,)), "norm2": jnp.zeros((d,)),
+                  "moe": self._experts().init(next(keys), scale)}
+            if kind == "linear":
+                lp["linear"] = {
+                    "w_qkvz": w(d, 2 * kd + 2 * vd),
+                    "w_ba": w(d, 2 * self.linear_v_heads),
+                    "conv": w(self.conv_kernel, 2 * kd + vd),
+                    "A_log": jnp.zeros((self.linear_v_heads,)),
+                    "dt_bias": jnp.ones((self.linear_v_heads,)),
+                    "norm": jnp.ones((self.linear_v_dim,)),
+                    "w_out": w(vd, d)}
+            else:
+                h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
+                lp["attn"] = {
+                    "w_q": w(d, h * 2 * hd), "w_k": w(d, kv * hd),
+                    "w_v": w(d, kv * hd), "q_norm": jnp.zeros((hd,)),
+                    "k_norm": jnp.zeros((hd,)), "w_o": w(h * hd, d)}
+            p[f"layer_{i}"] = lp
+        return p
+
+    # -- the mixers ----------------------------------------------------------
+    def _linear_mixer(self, lp, x):
+        b, t, _ = x.shape
+        hk, hv = self.linear_k_heads, self.linear_v_heads
+        dk, dv = self.linear_k_dim, self.linear_v_dim
+        kd, vd = hk * dk, hv * dv
+        with jax.named_scope("linear_attention"):
+            h = _norm0(x, lp["norm1"], self.rms_eps)
+            p = lp["linear"]
+            qkvz = h @ p["w_qkvz"]
+            ba = (h @ p["w_ba"]).astype(_F32)
+            z = qkvz[..., 2 * kd + vd:]
+            qkv = jax.nn.silu(_causal_conv(qkvz[..., :2 * kd + vd],
+                                           p["conv"]))
+            beta = jax.nn.sigmoid(ba[..., :hv])
+            g = -jnp.exp(p["A_log"].astype(_F32)) * jax.nn.softplus(
+                ba[..., hv:] + p["dt_bias"].astype(_F32))
+
+            def unit(a):        # L2-normalise a head, in float32
+                af = a.astype(_F32)
+                return af * jax.lax.rsqrt(jnp.sum(af * af, -1, keepdims=True)
+                                          + 1e-6)
+            q = unit(qkv[..., :kd].reshape(b, t, hk, dk)) * dk ** -0.5
+            k = unit(qkv[..., kd:2 * kd].reshape(b, t, hk, dk))
+            # [B, T, H, D] -> [B, Hv, T, D], key heads repeated to value heads
+            q, k = (jnp.repeat(a.astype(x.dtype).transpose(0, 2, 1, 3),
+                               hv // hk, axis=1) for a in (q, k))
+            v = qkv[..., 2 * kd:].reshape(b, t, hv, dv).transpose(0, 2, 1, 3)
+            g, beta = g.transpose(0, 2, 1), beta.transpose(0, 2, 1)
+        with jax.named_scope("delta_rule"):
+            o = gated_delta_rule(q, k, v, g, beta, chunk=self.delta_chunk)
+        with jax.named_scope("linear_attention"):
+            of = o.transpose(0, 2, 1, 3).astype(_F32)       # [B, T, Hv, dv]
+            of = of * jax.lax.rsqrt(jnp.mean(of * of, -1, keepdims=True)
+                                    + self.rms_eps) * p["norm"].astype(_F32)
+            of = of * jax.nn.silu(z.reshape(b, t, hv, dv).astype(_F32))
+            return x + of.reshape(b, t, vd).astype(x.dtype) @ p["w_out"]
+
+    def _full_mixer(self, lp, x):
+        from apex_tpu.contrib.multihead_attn.flash_attention import (
+            flash_attention, reference_attention)
+        b, t, _ = x.shape
+        h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        with jax.named_scope("attention"):
+            p = lp["attn"]
+            hid = _norm0(x, lp["norm1"], self.rms_eps)
+            qg = (hid @ p["w_q"]).reshape(b, t, h, 2 * hd)
+            q, gate = qg[..., :hd], qg[..., hd:]
+            k = (hid @ p["w_k"]).reshape(b, t, kv, hd)
+            v = (hid @ p["w_v"]).reshape(b, t, kv, hd)
+            q = _rotary(_norm0(q, p["q_norm"], self.rms_eps),
+                        self.rope_theta, self.rotary_dim)
+            k = _rotary(_norm0(k, p["k_norm"], self.rms_eps),
+                        self.rope_theta, self.rotary_dim)
+            # each key/value head serves h // kv query heads: broadcast in
+            # front of the kernel (its transpose sums the group's dK, dV)
+            q = q.transpose(0, 2, 1, 3)
+            k, v = (jnp.repeat(a.transpose(0, 2, 1, 3), h // kv, axis=1)
+                    for a in (k, v))
+            attend = flash_attention if self.attn_impl == "fast" \
+                else reference_attention
+            a = attend(q, k, v, causal=True, scale=hd ** -0.5)
+            a = a.transpose(0, 2, 1, 3) * jax.nn.sigmoid(
+                gate.astype(_F32)).astype(x.dtype)
+            return x + a.reshape(b, t, h * hd) @ p["w_o"]
+
+    def _block(self, kind: str, lp, x):
+        x = (self._linear_mixer if kind == "linear"
+             else self._full_mixer)(lp, x)
+        b, t, d = x.shape
+        with jax.named_scope("moe_route"):
+            h = _norm0(x, lp["norm2"], self.rms_eps)
+        y, aux = self._experts().apply(lp["moe"], h.reshape(b * t, d))
+        with jax.named_scope("moe_route"):
+            return x + y.reshape(b, t, d), aux
+
+    # -- forward, loss -------------------------------------------------------
+    def hidden_states(self, params: dict, tokens):
+        """``tokens [B, T]`` -> (the final norm's output ``[B, T, hidden]``,
+        counters): the routers' summed load-balancing term, the pairs past
+        the dispatch bound (all layers), the fullest layer's pairs on held
+        experts and the worst layer's held-expert load over the mean."""
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens]
+        # a run of layers of one kind is one scanned body over the run's
+        # stacked parameters: three Gated DeltaNet layers compile once
+        auxes, first = [], 0
+        for kind, run in itertools.groupby(self.layer_types):
+            n = len(list(run))
+
+            def block(x, lp, _kind=kind):
+                return self._block(_kind, lp, x)
+            if self.remat:
+                block = jax.checkpoint(block)
+            layers = [params[f"layer_{i}"] for i in range(first, first + n)]
+            x, aux = jax.lax.scan(
+                block, x, jax.tree.map(lambda *a: jnp.stack(a), *layers))
+            auxes.append(aux)
+            first += n
+        aux = jax.tree.map(lambda *a: jnp.concatenate(a), *auxes)
+        with jax.named_scope("head_loss"):
+            x = _norm0(x, params["norm_f"], self.rms_eps)
+        return x, {"load_balance_loss": jnp.sum(aux["load_balance_loss"]),
+                   "moe_overflow_pairs": jnp.sum(aux["overflow_pairs"]),
+                   "moe_held_pairs_max": jnp.max(aux["held_pairs"]),
+                   "expert_load_max_over_mean": jnp.max(
+                       aux["load_max_over_mean"])}
+
+    def apply(self, params: dict, tokens):
+        """Logits ``[B, T, vocab]`` in float32."""
+        x, _ = self.hidden_states(params, tokens)
+        with jax.named_scope("head_loss"):
+            return jnp.einsum("btd,vd->btv", x, params["head"],
+                              preferred_element_type=_F32)
+
+    def loss_with_counters(self, params: dict, tokens):
+        """Mean next-token cross-entropy of ``tokens [B, T + 1]`` plus
+        ``aux_coef`` times the load-balancing terms, and the step's
+        counters (``moe_overflow_pairs``, ``moe_held_pairs_max``,
+        ``expert_load_max_over_mean``)."""
+        from apex_tpu.contrib.xentropy import linear_cross_entropy
+        x, c = self.hidden_states(params, tokens[:, :-1])
+        with jax.named_scope("head_loss"):
+            losses = linear_cross_entropy(
+                x.reshape(-1, self.hidden), params["head"],
+                tokens[:, 1:].reshape(-1),
+                chunk=self.head_chunk or self.vocab_size)
+            loss = jnp.mean(losses) + self.aux_coef * c.pop(
+                "load_balance_loss")
+        return loss, c
+
+    def loss(self, params: dict, tokens):
+        return self.loss_with_counters(params, tokens)[0]

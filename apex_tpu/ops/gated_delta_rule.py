@@ -1,0 +1,179 @@
+"""The gated delta rule (Gated DeltaNet's linear-attention recurrence).
+
+A head keeps a state ``S`` of ``dk x dv`` in float32, from zero, and for
+each token ``t`` in order, with a log-decay ``g_t <= 0`` and a write
+strength ``beta_t`` in (0, 1):
+
+    S   = exp(g_t) S
+    d_t = beta_t (v_t - S^T k_t)
+    S   = S + k_t d_t^T
+    o_t = S^T q_t
+
+:func:`gated_delta_rule_recurrent` is that recurrence token by token, in
+float32: the op's reference twin (``dispatch.backend("reference")``), and
+what the chunked form is tested against.
+
+:func:`gated_delta_rule_chunked` is the form a training step runs: the
+sequence in chunks of ``chunk`` tokens, everything inside a chunk as
+matrix products (the WY form: with ``G`` the running sum of ``g`` inside
+the chunk and ``A[t, s] = beta_t exp(G_t - G_s) k_t.k_s`` for ``s < t``,
+``T = (I + A)^-1``, ``U = T (beta V)``, ``W = T (beta exp(G) K)``, the
+chunk's writes are ``D = U - W S0``), and only the ``dk x dv`` state
+carried from chunk to chunk by a ``lax.scan``, in float32. It is plain
+``jax.numpy`` and differentiated by JAX: the backward keeps one state a
+*chunk* (the scan's carry), never one a token. There is no Pallas kernel
+yet; the name ``apex_gdn_*`` is kept for one.
+
+Shapes: ``q, k [B, H, L, dk]``, ``v [B, H, L, dv]``, ``g, beta [B, H,
+L]``; the result is ``[B, H, L, dv]`` in ``v``'s type. ``q`` and ``k``
+come normalised and ``q`` scaled, as the caller's model has them. Any
+``L``: the chunked form pads to a whole chunk with tokens that write
+nothing (``beta`` 0, ``g`` 0).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from apex_tpu.ops import dispatch
+
+__all__ = ["gated_delta_rule", "gated_delta_rule_chunked",
+           "gated_delta_rule_recurrent"]
+
+_HI = jax.lax.Precision.HIGHEST
+_F32 = jnp.float32
+
+
+def gated_delta_rule_recurrent(q, k, v, g, beta):
+    """The recurrence as written, one token at a time, in float32."""
+    out_dtype = v.dtype
+    q, k, v, g, beta = (x.astype(_F32) for x in (q, k, v, g, beta))
+    b, h, _, dk = q.shape
+
+    def step(s, x):
+        q_t, k_t, v_t, g_t, b_t = x
+        s = s * jnp.exp(g_t)[..., None, None]
+        d = b_t[..., None] * (v_t - jnp.einsum(
+            "bhkv,bhk->bhv", s, k_t, precision=_HI))
+        s = s + k_t[..., :, None] * d[..., None, :]
+        return s, jnp.einsum("bhkv,bhk->bhv", s, q_t, precision=_HI)
+
+    xs = tuple(jnp.moveaxis(x, 2, 0) for x in (q, k, v, g, beta))
+    _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, v.shape[-1]), _F32), xs)
+    return jnp.moveaxis(o, 0, 2).astype(out_dtype)
+
+
+def _mm_hi(x, y):
+    return jnp.matmul(x, y, precision=_HI)
+
+
+def _inv_blocks(a):
+    """``(I + a)^-1`` by matrix products alone: blocks of 16 by the finite
+    series ``(I - a)(I + a^2)(I + a^4)(I + a^8)`` (``a^16 = 0``), then
+    halves merged, ``[[P, 0], [C, Q]]^-1 = [[P', 0], [-Q' C P', Q']]``."""
+    n = a.shape[-1]
+    if n <= 16:
+        eye = jnp.eye(n, dtype=a.dtype)
+        out, p, k = eye - a, a, 2
+        while k < n:
+            p = _mm_hi(p, p)
+            out = _mm_hi(out, eye + p)
+            k *= 2
+        return out
+    half = n // 2
+    p = _inv_blocks(a[..., :half, :half])
+    q = _inv_blocks(a[..., half:, half:])
+    low = -_mm_hi(_mm_hi(q, a[..., half:, :half]), p)
+    return jnp.concatenate([
+        jnp.concatenate([p, jnp.zeros_like(low)], -1),
+        jnp.concatenate([low, q], -1)], -2)
+
+
+@jax.custom_vjp
+def _inv_unit_lower(a):
+    """``T = (I + a)^-1`` for strictly lower-triangular ``a [..., n, n]``
+    in float32. Its backward is ``da = -T^T dT T^T`` from ``T`` alone:
+    nothing of the inversion's inside is kept (blocks of 16 x 16 pad to
+    eight times their size in the chip's tiled memory)."""
+    return _inv_blocks(a)
+
+
+def _inv_unit_lower_fwd(a):
+    t = _inv_blocks(a)
+    return t, t
+
+
+def _inv_unit_lower_bwd(t, dt):
+    tt = jnp.swapaxes(t, -1, -2)
+    return (-_mm_hi(_mm_hi(tt, dt), tt),)
+
+
+_inv_unit_lower.defvjp(_inv_unit_lower_fwd, _inv_unit_lower_bwd)
+
+
+def gated_delta_rule_chunked(q, k, v, g, beta, *, chunk: int = 64):
+    """The same result from chunks of ``chunk`` tokens (16, 32, 64 or
+    128). Products take their operands in ``v``'s type and accumulate in
+    float32; the inverse ``T``, the decays and the state are float32."""
+    if chunk not in (16, 32, 64, 128):
+        raise ValueError(f"chunk must be 16, 32, 64 or 128, got {chunk}")
+    dt = v.dtype
+    b, h, length, dk = q.shape
+    pad = (-length) % chunk
+    if pad:
+        q, k, v = (jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                   for x in (q, k, v))
+        g, beta = (jnp.pad(x, ((0, 0), (0, 0), (0, pad))) for x in (g, beta))
+    n = (length + pad) // chunk
+
+    def chunks(x):
+        return x.reshape(b, h, n, chunk, *x.shape[3:])
+
+    def mm(eq, x, y):
+        return jnp.einsum(eq, x.astype(dt), y.astype(dt),
+                          preferred_element_type=_F32)
+    q, k, v = chunks(q), chunks(k), chunks(v)
+    beta = chunks(beta.astype(_F32))[..., None]
+    gsum = jnp.cumsum(chunks(g.astype(_F32)), axis=-1)      # G, per chunk
+    idx = jnp.arange(chunk)
+    lower = idx[:, None] >= idx[None, :]
+    # exp(G_t - G_s) for s <= t, 0 above the diagonal (masked before the
+    # exp: the differences above it are positive and overflow)
+    decay = jnp.exp(jnp.where(lower, gsum[..., :, None] - gsum[..., None, :],
+                              -jnp.inf))
+    kk = mm("bhnck,bhnsk->bhncs", k, k)
+    t_inv = _inv_unit_lower(jnp.where(idx[:, None] > idx[None, :],
+                                      beta * kk * decay, 0.0))
+    u = jnp.matmul(t_inv, beta * v.astype(_F32), precision=_HI)
+    w = jnp.matmul(t_inv, beta * jnp.exp(gsum)[..., None] * k.astype(_F32),
+                   precision=_HI)
+    qk = mm("bhnck,bhnsk->bhncs", q, k) * decay
+    q_in = q.astype(_F32) * jnp.exp(gsum)[..., None]
+    last = gsum[..., -1:]
+    k_out = k.astype(_F32) * jnp.exp(last - gsum)[..., None]
+
+    # the body is recomputed in the backward: the scan keeps its carry, one
+    # state a chunk, and its inputs (in the products' type), nothing else
+    @jax.checkpoint
+    def step(s, x):
+        w_i, u_i, q_i, k_i, qk_i, decay_i = x
+        d = u_i - mm("bhck,bhkv->bhcv", w_i, s)
+        o = mm("bhck,bhkv->bhcv", q_i, s) + mm("bhcs,bhsv->bhcv", qk_i, d)
+        s = s * decay_i[..., None] + mm("bhck,bhcv->bhkv", k_i, d)
+        return s, o.astype(dt)
+
+    xs = tuple(jnp.moveaxis(x, 2, 0) for x in (
+        w.astype(dt), u.astype(dt), q_in.astype(dt), k_out.astype(dt),
+        qk.astype(dt), jnp.exp(last)))
+    _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, v.shape[-1]), _F32), xs)
+    o = jnp.moveaxis(o, 0, 2).reshape(b, h, n * chunk, v.shape[-1])
+    return o[:, :, :length]
+
+
+def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64):
+    """The op as the models call it: the chunked form, or under
+    ``dispatch.backend("reference")`` the recurrence."""
+    if dispatch.get_backend() == "reference":
+        return gated_delta_rule_recurrent(q, k, v, g, beta)
+    return gated_delta_rule_chunked(q, k, v, g, beta, chunk=chunk)

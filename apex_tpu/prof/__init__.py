@@ -110,10 +110,14 @@ def mark(name: str):
 # every HLO instruction by that path (``jit(step)/transpose(jvp(mlp))/...``),
 # so the benchmark's ``trace_scope`` reader buckets device time by these
 # names and by direction. Applied with ``jax.named_scope`` / :func:`mark` /
-# :func:`annotate`; a new model adds its scopes here.
+# :func:`annotate`; a new model appends its scopes here and lists them,
+# with where it opens them, in ``benchmarks/scopes/<family>.json`` (a test
+# holds the two sets equal).
 SCOPES = ("embed", "attention", "mlp", "head_loss",     # models/transformer
           "stem", r"stage\d+_block\d+", "head",          # models/resnet
-          "amp_cast", "amp_scale", "optimizer", "collective")
+          "amp_cast", "amp_scale", "optimizer", "collective",
+          "linear_attention", "delta_rule",             # models/hybrid_lm
+          "moe_route", "moe_experts")                   # contrib/moe
 
 
 @contextlib.contextmanager

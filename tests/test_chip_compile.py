@@ -163,6 +163,14 @@ KERNELS = [
     ("flash_fwd_bwd-B8H16S4096D64", lambda: _flash(True),
      _qkv(8, 16, 4096, 64),
      ("apex_flash_fwd", "apex_flash_bwd_dq", "apex_flash_bwd_dkv")),
+    # the hybrid LM's full-attention layer: 16 heads of 256 (K and V
+    # broadcast from 2 in front of the kernel), 2 rows of 8192
+    ("flash_fwd-B2H16S8192D256", lambda: _flash(False),
+     _qkv(2, 16, 8192, 256),
+     ("apex_flash_fwd",)),
+    ("flash_fwd_bwd-B2H16S8192D256", lambda: _flash(True),
+     _qkv(2, 16, 8192, 256),
+     ("apex_flash_fwd", "apex_flash_bwd_dq", "apex_flash_bwd_dkv")),
     ("layer_norm_fwd_bwd-F1024", lambda: _layer_norm(1024),
      _ln_args(8 * 4096, 1024),
      ("apex_ln_fwd", "apex_ln_bwd")),
@@ -214,6 +222,56 @@ def test_kernel_compiles_for_v5e(chip, for_chip, make_fn, args, names):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
         + mem.output_size_in_bytes < 16e9            # fits one v5e's HBM
+
+
+def _delta_rule():
+    from apex_tpu.ops.gated_delta_rule import gated_delta_rule
+    return jax.grad(lambda q, k, v, g, b: jnp.sum(gated_delta_rule(
+        q, k, v, g, b, chunk=64).astype(F32)), argnums=(0, 1, 2, 3, 4))
+
+
+def _expert_layer():
+    from apex_tpu.contrib.moe import ExpertLayer
+    layer = ExpertLayer(hidden=2048, ffn=512, num_experts=512, top_k=10,
+                        experts_held=(0, 16), shared_ffn=512,
+                        dispatch_bound=12288)
+    return lambda p, x: jax.grad(lambda p, x: jnp.sum(
+        layer.apply(p, x)[0].astype(F32)), argnums=(0, 1))(p, x)
+
+
+def _expert_args():
+    d, f, held = 2048, 512, 16
+    return [{"router": ((d, 512), BF16), "w_gate": ((held, d, f), BF16),
+             "w_up": ((held, d, f), BF16), "w_down": ((held, f, d), BF16),
+             "shared": {"w_gate": ((d, f), BF16), "w_up": ((d, f), BF16),
+                        "w_down": ((f, d), BF16), "gate": ((d, 1), BF16)}},
+            ((2 * 8192, d), BF16)]
+
+
+# (id, builder, arguments (trees of (shape, dtype)), temporaries allowed in
+#  GB): the hybrid LM's two new ops, which are jax.numpy and no kernel, at
+#  the published widths and the benchmark cell's 2 x 8192 tokens
+NEW_OPS = [
+    # the chunked scan and its backward: one 128 x 128 state a chunk a
+    # head (0.54 GB), never one a token (34 GB)
+    ("gated_delta_rule_fwd_bwd-B2H32S8192D128", _delta_rule,
+     [((2, 32, 8192, 128), BF16)] * 3 + [((2, 32, 8192), F32)] * 2, 4.0),
+    # 512-way routing, the sort, grouped matmuls over 16 held experts
+    ("expert_layer_fwd_bwd-N16384E512held16", _expert_layer,
+     _expert_args(), 1.5),
+]
+
+
+@pytest.mark.parametrize("make_fn,args,temporaries",
+                         [pytest.param(m, a, t, id=i)
+                          for i, m, a, t in NEW_OPS])
+def test_jnp_op_compiles_and_fits_for_v5e(chip, for_chip, make_fn, args,
+                                          temporaries):
+    is_spec = lambda x: isinstance(x, tuple) and isinstance(x[0], tuple)
+    specs = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a[0], a[1], sharding=chip), args, is_leaf=is_spec)
+    compiled = jax.jit(make_fn()).lower(*specs).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < temporaries * 1e9
 
 
 def _step(name, skip, **kw):

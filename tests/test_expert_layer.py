@@ -1,0 +1,175 @@
+"""The chip's share of a many-expert layer: against a dense computation of
+the same mathematics, the shares adding up to the uncut layer, no pair on
+a held expert dropped under a skewed router, pairs past the bound
+counted."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from apex_tpu.contrib.moe import ExpertLayer
+
+D, F, E, K = 32, 16, 32, 4
+
+
+class SmallTiles(ExpertLayer):
+    tile = 8        # the layer's is 128: the chip's; no constructor knob
+
+
+def _layer(held=(), **kw):
+    return SmallTiles(hidden=D, ffn=F, num_experts=E, top_k=K,
+                      experts_held=held, shared_ffn=F, **kw)
+
+
+def _params(key=0, scale=0.3):
+    return _layer().init(jax.random.key(key), scale)
+
+
+def _share(params, lo, hi):
+    """The leaves a chip holding experts ``[lo, hi)`` has."""
+    return {**params, **{k: params[k][lo:hi]
+                         for k in ("w_gate", "w_up", "w_down")}}
+
+
+def _dense(params, x, lo=0, hi=E):
+    """Every expert of ``[lo, hi)`` over every token, weighted by the
+    token's renormalised top-k weight for it (a constant in a share's
+    backward)."""
+    probs = jax.nn.softmax(x @ params["router"], -1)
+    w, idx = jax.lax.top_k(probs, K)
+    w = w / w.sum(-1, keepdims=True)
+    if hi - lo < E:
+        w = jax.lax.stop_gradient(w)
+    y = jnp.zeros_like(x)
+    for e in range(lo, hi):
+        w_e = jnp.sum(jnp.where(idx == e, w, 0.0), -1, keepdims=True)
+        h = jax.nn.silu(x @ params["w_gate"][e]) * (x @ params["w_up"][e])
+        y = y + w_e * (h @ params["w_down"][e])
+    return y
+
+
+def _x(n=64, key=1):
+    return jax.random.normal(jax.random.key(key), (n, D))
+
+
+def test_the_uncut_layer_is_the_dense_mixture_plus_the_shared_expert():
+    params, x = _params(), _x()
+    layer = _layer()
+    y, aux = layer.apply(params, x)
+    want = _dense(params, x) + layer.shared(params, x)
+    np.testing.assert_allclose(y, want, atol=1e-5)
+    assert int(aux["overflow_pairs"]) == 0
+
+
+@pytest.mark.parametrize("chips", [4, 32])
+def test_the_shares_add_up(chips):
+    """The parts all shares give, the shared expert counted once, are the
+    uncut layer; and a share computes its own experts' part, nothing
+    that stands in for the others."""
+    params, x = _params(2), _x(48, 3)
+    uncut, _ = _layer().apply(params, x)
+    per = E // chips
+    total = 0.0
+    for c in range(chips):
+        lo, hi = c * per, (c + 1) * per
+        layer = _layer((lo, hi))
+        part, aux = layer.routed(_share(params, lo, hi), x)
+        np.testing.assert_allclose(part, _dense(params, x, lo, hi),
+                                   atol=1e-5)
+        assert int(aux["overflow_pairs"]) == 0
+        total = total + part
+    total = total + _layer().shared(params, x)
+    np.testing.assert_allclose(total, uncut, atol=1e-5)
+
+
+@pytest.mark.parametrize("lo,hi", [(8, 16), (0, E)])
+def test_gradients_are_the_dense_mixtures(lo, hi):
+    params, x = _params(4), _x(40, 5)
+    layer = _layer((lo, hi))
+    mine = _share(params, lo, hi)
+
+    def ours(p, x):
+        return jnp.sum(jnp.sin(layer.routed(p, x)[0]))
+
+    def dense(p, x):
+        full = {**params, **{k: params[k].at[lo:hi].set(p[k])
+                             for k in ("w_gate", "w_up", "w_down")},
+                "router": p["router"]}
+        return jnp.sum(jnp.sin(_dense(full, x, lo, hi)))
+    got = jax.grad(ours, argnums=(0, 1))(mine, x)
+    want = jax.grad(dense, argnums=(0, 1))(mine, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+
+
+def test_a_shares_router_learns_from_the_balancing_term_alone():
+    """The held experts' outputs reward the router only where every
+    expert is held: a share's weights are constants in the backward (or
+    the router runs away to the experts that are here), and its
+    load-balancing term still reaches the router."""
+    params, x = _params(12), _x(56, 13)
+
+    def through_outputs(layer, p):
+        return jax.grad(lambda p: jnp.sum(jnp.sin(layer.routed(p, x)[0])))(p)
+    share = _layer((8, 16))
+    mine = _share(params, 8, 16)
+    assert float(jnp.abs(through_outputs(share, mine)["router"]).max()) == 0
+    assert float(jnp.abs(through_outputs(share, mine)["w_down"]).max()) > 0
+    assert float(jnp.abs(through_outputs(_layer(), params)["router"])
+                 .max()) > 0
+    balance = jax.grad(lambda p: share.routed(p, x)[1]["load_balance_loss"])(
+        mine)
+    assert float(jnp.abs(balance["router"]).max()) > 0
+
+
+def test_no_pair_on_a_held_expert_is_dropped_under_a_skewed_router():
+    """Nearly every token sends a pair to each of experts 8..10: there
+    is no capacity an expert to drop them, and the default bound holds
+    the worst case."""
+    params, x = _params(6), _x(96, 7)
+    params = {**params, "router": params["router"] * 0.05}
+    # the skew: a constant column in x that the router weighs heavily
+    x = x.at[:, 0].set(4.0)
+    params["router"] = params["router"].at[0, 8:11].set(3.0)
+    lo, hi = 8, 16
+    layer = _layer((lo, hi))
+    part, aux = layer.routed(_share(params, lo, hi), x)
+    assert float(aux["load_max_over_mean"]) > 2.0        # skewed indeed
+    assert int(aux["overflow_pairs"]) == 0
+    np.testing.assert_allclose(part, _dense(params, x, lo, hi), atol=1e-5)
+
+
+def test_pairs_past_the_bound_are_counted_not_lost_in_silence():
+    params, x = _params(8), _x(96, 9)
+    lo, hi = 0, 16
+    sound, aux0 = _layer((lo, hi)).routed(_share(params, lo, hi), x)
+    _, idx, _ = _layer().route(params, x)
+    held_pairs = int(jnp.sum((idx >= lo) & (idx < hi)))
+    small = _layer((lo, hi), dispatch_bound=64)     # 8 tiles of 8 rows
+    part, aux = small.routed(_share(params, lo, hi), x)
+    assert int(aux0["overflow_pairs"]) == 0
+    assert held_pairs > 64
+    # every pair is either in the buffer or counted
+    assert int(aux["overflow_pairs"]) >= held_pairs - 64
+    assert int(aux["overflow_pairs"]) < held_pairs
+    assert float(jnp.abs(part - sound).max()) > 0       # and it shows
+
+
+def test_the_load_balancing_term_is_the_switch_form():
+    params, x = _params(10), _x(80, 11)
+    _, aux = _layer().routed(params, x)
+    probs = jax.nn.softmax(x @ params["router"], -1)
+    _, idx = jax.lax.top_k(probs, K)
+    f = jnp.zeros((E,)).at[idx.reshape(-1)].add(1.0) / x.shape[0]
+    want = E * jnp.sum(f * probs.mean(0))
+    np.testing.assert_allclose(aux["load_balance_loss"], want, rtol=1e-5)
+
+
+@pytest.mark.parametrize("kw", [
+    dict(experts_held=(8, 4)), dict(experts_held=(0, E + 1)),
+    dict(top_k=0), dict(dispatch_bound=100)])
+def test_what_the_layer_refuses(kw):
+    base = dict(hidden=D, ffn=F, num_experts=E, top_k=K)
+    with pytest.raises(ValueError):
+        SmallTiles(**{**base, **kw})

@@ -46,7 +46,8 @@ def build_train_step(lm, params, mesh, *, half, zero=False, lr=1e-4):
     ``host_init()``: the optimizers flatten real arrays.
 
     Returns ``(opt, state, step, plan)``; ``step(state, toks) ->
-    (state, loss)`` is the body ``compile_step_with_plan(body, plan)``
+    (state, loss)`` (``(state, (loss, counters))`` for a model that has
+    ``loss_with_counters``) is the body ``compile_step_with_plan(body, plan)``
     lowers (a 1-device plan is plain jit — the single-chip program),
     and :func:`place_for_plan` puts ``(state, toks)`` where it wants
     them."""
@@ -61,6 +62,15 @@ def build_train_step(lm, params, mesh, *, half, zero=False, lr=1e-4):
     from apex_tpu.parallel import DistributedDataParallel, Plan
 
     n_dev = mesh.size
+    # a model with counters of its own (HybridLM: pairs past the dispatch
+    # bound, expert load) hands them out beside the loss: the step then
+    # returns (state, (loss, counters)). On one chip only: across chips
+    # each counter would need its own reduction (a sum, a maximum)
+    counted = getattr(lm, "loss_with_counters", None)
+    if counted is not None and (zero or n_dev > 1):
+        raise NotImplementedError(
+            f"{type(lm).__name__} hands counters out of its step, which "
+            "only the one-chip FusedAdam step carries")
     if zero:
         opt = DistributedFusedAdam(
             params, lr=lr, axis_name="data", num_shards=n_dev,
@@ -91,14 +101,17 @@ def build_train_step(lm, params, mesh, *, half, zero=False, lr=1e-4):
             if n_dev > 1 else None
 
         def step(state, toks):
-            loss, fg = jax.value_and_grad(
-                lambda m: lm.loss(F.unflatten(m, table, dtype=half),
-                                  toks))(state[0].master)
+            out, fg = jax.value_and_grad(
+                lambda m: (counted or lm.loss)(
+                    F.unflatten(m, table, dtype=half), toks),
+                has_aux=counted is not None)(state[0].master)
+            loss, counters = out if counted else (out, None)
             if ddp is not None:
                 # the whole gradient is ONE psum of ONE buffer
                 fg = ddp.average_gradients(fg)
                 loss = lax.pmean(loss, "data")
-            return opt.apply_update(state, [fg]), loss
+            return opt.apply_update(state, [fg]), \
+                (loss, counters) if counted else loss
 
     if zero or n_dev > 1:
         plan = Plan(mesh=mesh, in_specs=(state_spec, P("data")),
