@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-from typing import Any
+from typing import Any, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -210,6 +210,86 @@ def unflatten(flat: jax.Array, table: SegmentTable,
         _unflat.defvjp(lambda f: (_fwd(None, f), None),
                        lambda _res, ct: (_transpose(None, ct),))
         return _unflat(flat)
+
+
+def split_table(table: SegmentTable,
+                counts: Sequence[int]) -> tuple[SegmentTable, ...]:
+    """Cut ``table`` at leaf boundaries into contiguous sub-tables of
+    ``counts`` leaves each, in the flat's order (``sum(counts)`` is the
+    table's leaf count). A sub-table's offsets are relative to its own
+    slice of the flat buffer (see :func:`split`), and it unflattens to the
+    list of its leaves. One count gives back ``table`` itself."""
+    if sum(counts) != table.num_segments or min(counts, default=0) < 1:
+        raise ValueError(
+            f"counts {tuple(counts)} do not partition a table of "
+            f"{table.num_segments} leaves")
+    if len(counts) == 1:
+        return (table,)
+    subs, lo = [], 0
+    for n in counts:
+        hi, start = lo + n, table.offsets[lo]
+        subs.append(SegmentTable(
+            treedef=jax.tree_util.tree_structure([0] * n),
+            shapes=table.shapes[lo:hi], sizes=table.sizes[lo:hi],
+            offsets=tuple(o - start for o in table.offsets[lo:hi]),
+            padded_sizes=table.padded_sizes[lo:hi],
+            total=sum(table.padded_sizes[lo:hi]), align=table.align))
+        lo = hi
+    return tuple(subs)
+
+
+def split(flat: jax.Array,
+          tables: Sequence[SegmentTable]) -> tuple[jax.Array, ...]:
+    """The slices of ``flat`` that :func:`split_table`'s sub-tables
+    describe, in order; one table is the buffer itself."""
+    if len(tables) == 1:
+        return (flat,)
+    bounds = np.cumsum([0] + [t.total for t in tables])
+    return tuple(jax.lax.slice(flat, (int(lo),), (int(hi),))
+                 for lo, hi in zip(bounds, bounds[1:]))
+
+
+# The TPU compiler joins large buffers by updating the result in place,
+# one fusion an operand, and walks each operand in windows of whole
+# 1024-element tiles whose count must divide the operand's: 20,506 tiles
+# (a layer's biases, norms and mlp.w1) go 2 at a time, at 135 GB/s on a
+# v5e, where 16,384 go 512 at a time at 650 GB/s. Cut at a multiple of
+# this many elements, the head of every operand finds wide windows and
+# only a tail of a few tiles goes narrowly (PERF.md section 6, PR 28:
+# 19 buckets of 1.6 GB joined in 5.0 ms, not 11.7).
+_JOIN_CUT = 512 * 1024
+
+
+def join(bufs: Sequence[jax.Array], divisor=None) -> jax.Array:
+    """The buffers of :func:`split` (or their gradients) as one flat buffer
+    again, each divided by ``divisor`` on the way (a sum's average rides
+    the pass that joins, and costs none of its own); one buffer is itself,
+    divided."""
+    def scaled(x):
+        return x if divisor is None else x / divisor
+    if len(bufs) == 1:
+        return scaled(bufs[0])
+    parts = []
+    for buf in bufs:
+        head = buf.shape[0] // _JOIN_CUT * _JOIN_CUT
+        # sliced first, divided after: two slices of one array side by
+        # side the compiler would put back together
+        parts += [scaled(buf[:head]), scaled(buf[head:])] \
+            if 0 < head < buf.shape[0] else [scaled(buf)]
+    return jnp.concatenate(parts)
+
+
+def unflatten_split(bufs: Sequence[jax.Array],
+                    tables: Sequence[SegmentTable], treedef: Any,
+                    dtype: jnp.dtype | None = None) -> Any:
+    """The whole tree from the slices of :func:`split`, each through its
+    own :func:`unflatten`: differentiated with respect to ``bufs`` it
+    gives one flat gradient a slice (one concat + one convert each),
+    ready when that slice's leaves are and not when the last leaf is."""
+    return jax.tree_util.tree_unflatten(treedef, [
+        leaf for buf, table in zip(bufs, tables)
+        for leaf in jax.tree_util.tree_leaves(
+            unflatten(buf, table, dtype=dtype))])
 
 
 def zeros_like_flat(table: SegmentTable, dtype=jnp.float32) -> jax.Array:

@@ -4,10 +4,15 @@ The reference DDP (apex/parallel/distributed.py:129-639) is ~600 lines of
 bucketing machinery: per-param grad hooks, arrival-order bucket discovery,
 rank-0 bucket-structure broadcast, flatten -> NCCL allreduce -> unflatten on
 side CUDA streams, with knobs for fp32 allreduce and gradient predivision.
-Under XLA none of that machinery is needed — collectives issued inside a
-jitted step are scheduled asynchronously and overlapped with compute by the
-compiler (latency-hiding scheduling), which is exactly what the hand-rolled
-streams/buckets approximate. What must be preserved is the *semantics*:
+Under XLA the hooks and streams are the compiler's: a collective issued
+inside a jitted step is placed by its scheduler, which can only move what
+the program's data flow lets it. So the one thing kept of the machinery is
+the **bucket**: a step that wants its gradient reduced under the backward
+cuts the flat gradient where :meth:`DistributedDataParallel.buckets` says
+and reduces a tuple, one ``psum`` a bucket, each depending on its own
+leaves only (``tools/lm_bench.build_train_step`` does; on the TPU
+``parallel/plan.py`` tells the compiler to run them asynchronously). What
+must be preserved besides is the *semantics*:
 
 - gradients averaged over the replica axis (allreduce ∘ /world);
 - ``gradient_predivide_factor`` f: grads are divided by f before the
@@ -43,7 +48,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -82,11 +87,20 @@ class DistributedDataParallel:
     """Gradient-averaging policy over a mesh axis (reference:
     apex.parallel.DistributedDataParallel, distributed.py:129).
 
-    Parameters mirror the reference knobs that affect numerics; the
-    scheduling knobs (message_size, delay_allreduce, allreduce_trigger_params,
-    num_allreduce_streams, retain_allreduce_buffers — distributed.py:140-152)
-    have no TPU equivalent because XLA owns scheduling; they are accepted
-    and ignored for drop-in compatibility.
+    Parameters mirror the reference knobs that affect numerics, and the
+    two scheduling knobs that say how the gradient is cut (:meth:`buckets`):
+
+    message_size : the least number of elements in a bucket (reference
+        distributed.py:140-142; its default, 10,000,000, kept).
+    delay_allreduce : one bucket of everything, reduced after the whole
+        backward (reference distributed.py:143-146).
+
+    The other scheduling knobs (shared_param, allreduce_trigger_params,
+    num_allreduce_streams, allreduce_communicators,
+    retain_allreduce_buffers, gradient_average_split_factor, prof —
+    distributed.py:147-175) say when and on which stream a bucket goes
+    out, which here the compiler decides: accepted and ignored for drop-in
+    compatibility.
 
     gradient_average : divide by world size (reference
         ``gradient_average=True``, distributed.py:462-466).
@@ -101,11 +115,12 @@ class DistributedDataParallel:
     allreduce_always_fp32: bool = False
     gradient_predivide_factor: float = 1.0
     axis_index_groups: Optional[tuple[tuple[int, ...], ...]] = None
-    # accepted-and-ignored scheduling knobs (XLA owns scheduling) — the
-    # COMPLETE reference kwarg list (distributed.py:162-175) so keyword
-    # migrations are drop-in:
+    # how the flat gradient is cut into buckets (see ``buckets``)
     message_size: int = 10_000_000
     delay_allreduce: bool = False
+    # accepted-and-ignored scheduling knobs (XLA owns scheduling) — with
+    # the two above the COMPLETE reference kwarg list (distributed.py:
+    # 162-175) so keyword migrations are drop-in:
     shared_param: Optional[Any] = None
     allreduce_trigger_params: Optional[Any] = None
     num_allreduce_streams: int = 1
@@ -113,6 +128,36 @@ class DistributedDataParallel:
     retain_allreduce_buffers: bool = False
     gradient_average_split_factor: Optional[float] = None
     prof: bool = False
+
+    def buckets(self, sizes: Sequence[int]) -> tuple[int, ...]:
+        """How a flat gradient whose leaves hold ``sizes`` elements, in the
+        flat's order, is cut for the reduction: the number of leaves in
+        each bucket. Walking the leaves in that order, a bucket closes once
+        it holds ``message_size`` elements (reference distributed.py:
+        140-142, "minimum number of elements in a communication bucket");
+        what is left at the end is the last bucket. ``delay_allreduce`` is
+        one bucket of everything.
+
+        A step that differentiates with respect to the buckets
+        (``ops.flat.split_table`` / ``split`` / ``unflatten_split``) and
+        hands the tuple to :meth:`average_gradients` gets one ``psum`` a
+        bucket, each depending on its own leaves only: XLA can start it
+        where their backward ends and run it under the backward of the
+        layers before them. Nothing here knows when a gradient arrives: a
+        bucket is ready when the last of its leaves is. Where the step
+        wants one flat gradient again, ``gradient_average=False`` and
+        ``ops.flat.join(sums, divisor=world)`` divide in the pass that
+        joins, not in one of their own."""
+        if self.delay_allreduce:
+            return (len(sizes),)
+        counts, held = [0], 0
+        for size in sizes:
+            if held >= self.message_size:
+                counts.append(0)
+                held = 0
+            counts[-1] += 1
+            held += size
+        return tuple(counts)
 
     @jax.named_scope("collective")      # prof.SCOPES: metadata only
     def average_gradients(self, grads: Any) -> Any:

@@ -25,15 +25,20 @@ FLAT = 128 * 1024 * 1024            # >= the dense LM's 138.5M-param masters
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One described v5e device, or skip."""
+def topo():
+    """A described v5e 2x2 (four chips, none attached), or skip."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:            # no TPU compiler in this install
         pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One described v5e device."""
     return jax.sharding.SingleDeviceSharding(topo.devices[0])
 
 
@@ -345,3 +350,92 @@ def test_engine_paged_decode_program_compiles_for_v5e(chip, for_chip):
     text = decode["fn"].lower(*specs).compile().as_text()
     # one paged decode-attention kernel per layer
     assert text.count("tpu_custom_call") >= lm.num_layers
+
+
+# -- the dense-LM train step: DDP's gradient buckets on the 2x2 -------------
+
+def _lm_step(devices, layers=2):
+    """``tools/lm_bench.build_train_step`` at the benchmark configuration's
+    widths (Cerebras-GPT-1.3B's; ``layers`` of them, 4 rows of 2048+1
+    tokens a chip) compiled for the described ``devices``: (compiled
+    step, the optimizer's table)."""
+    import sys
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from apex_tpu.models import TransformerLM
+    from apex_tpu.parallel import compile_step_with_plan, make_mesh
+    tools = os.path.join(os.path.dirname(__file__), "..", "tools")
+    if tools not in sys.path:
+        sys.path.insert(0, tools)
+    import lm_bench
+    lm = TransformerLM(vocab_size=50257, max_seq_len=2048, embed_dim=2048,
+                       num_heads=16, num_layers=layers, ffn_mult=4,
+                       attn_impl="fast", head_chunk=1733)
+    mesh = make_mesh({"data": len(devices)}, devices=list(devices))
+    # the optimizer flattens real arrays: zeros, on the host
+    with jax.default_device(jax.devices("cpu")[0]):
+        params = jax.tree.map(lambda s: jnp.zeros(s.shape, F32),
+                              jax.eval_shape(lm.init, jax.random.key(0)))
+        opt, state, step, plan = lm_bench.build_train_step(
+            lm, params, mesh, half=BF16)
+    many = len(devices) > 1
+    rep = NamedSharding(mesh, P()) if many \
+        else jax.sharding.SingleDeviceSharding(devices[0])
+    rows = NamedSharding(mesh, P("data")) if many else rep
+    state = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=rep), state)
+    toks = jax.ShapeDtypeStruct((4 * len(devices), 2049), I32, sharding=rows)
+    compiled = compile_step_with_plan(step, plan).lower(state,
+                                                        toks).compile()
+    return compiled, opt._tables[0]
+
+
+def _entry(text):
+    """(the scheduled ENTRY computation's lines, every computation's body
+    by name) of a compiled module's text."""
+    bodies = {m.group(2): m.group(3) for m in re.finditer(
+        r"^(ENTRY )?%(\S+) \(.*?\{\n(.*?)^\}", text, re.S | re.M)}
+    name = re.search(r"^ENTRY %(\S+) ", text, re.M).group(1)
+    return bodies[name].splitlines(), bodies
+
+
+def test_ddp_step_reduces_its_buckets_under_the_backward(topo, for_chip):
+    """The four-chip step holds one all-reduce a bucket (the combiner has
+    not tied them back into one), most of them asynchronous, and backward
+    matmul fusions run between the first one's start and the last one's
+    done: the schedule hides them, which the compiler's defaults do not
+    (``parallel/plan.py`` ``_TPU_SHARD_MAP_OPTIONS``)."""
+    from apex_tpu.parallel import DistributedDataParallel
+    compiled, table = _lm_step(topo.devices)
+    k = len(DistributedDataParallel().buckets(table.padded_sizes))
+    assert k >= 6                                 # three a layer, the rest
+    lines, bodies = _entry(compiled.as_text())
+    starts = [i for i, l in enumerate(lines)
+              if re.match(r"\s+%async-collective-start\S* = ", l)]
+    dones = [i for i, l in enumerate(lines)
+             if re.match(r"\s+%async-collective-done\S* = ", l)]
+    sync = [i for i, l in enumerate(lines) if " all-reduce(" in l]
+    assert len(starts) == len(dones) >= k // 2
+    assert len(starts) + len(sync) == k + 1       # the buckets, the loss
+    for i in starts:                              # each reduces a bucket
+        called = re.search(r"calls=%([^,\s]+)", lines[i]).group(1)
+        assert " all-reduce(" in bodies[called]
+
+    def backward_matmul(line):
+        called = re.search(r"calls=%([^,\s]+)", line)
+        return called is not None and "transpose(jvp(" in line \
+            and " convolution(" in bodies.get(called.group(1), "")
+    hidden_under = [l for l in lines[starts[0]:dones[-1]]
+                    if backward_matmul(l)]
+    assert len(hidden_under) >= 1
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+def test_one_chip_step_has_no_all_reduce(topo, for_chip):
+    """One chip is one bucket and no mesh axis: no collective at all, and
+    the kernels the cell counts on."""
+    compiled, _ = _lm_step(topo.devices[:1])
+    text = compiled.as_text()
+    assert "all-reduce" not in text and "async-collective" not in text
+    assert len(re.findall(r"%apex_mt_adam\S* = ", text)) == 1
+    assert "apex_flash_fwd" in text

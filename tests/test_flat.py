@@ -206,3 +206,114 @@ def test_fuzz_random_trees_roundtrip_and_grad(seed):
                                      dtype=jnp.float32)[0])
     np.testing.assert_allclose(np.asarray(g_flat), expect,
                                rtol=1e-6, atol=1e-6)
+
+
+# -- a table cut into contiguous sub-tables (DDP's gradient buckets) -------
+
+def _five_leaves():
+    rng = np.random.default_rng(7)
+    shapes = {"a": (3, 50), "b": (7,), "c": {"d": (129,), "e": ()},
+              "f": (2, 3, 11)}
+    return jax.tree.map(
+        lambda s: jnp.asarray(rng.normal(size=s), jnp.float32), shapes,
+        is_leaf=lambda s: isinstance(s, tuple))
+
+
+def _every_split(n):
+    """Every way to cut ``n`` leaves into contiguous runs."""
+    for cuts in range(2 ** (n - 1)):
+        counts, run = [], 1
+        for i in range(n - 1):
+            if cuts >> i & 1:
+                counts.append(run)
+                run = 0
+            run += 1
+        yield tuple(counts + [run])
+
+
+@pytest.mark.parametrize("counts", list(_every_split(5)),
+                         ids=lambda c: "-".join(map(str, c)))
+@pytest.mark.parametrize("dtype", [None, jnp.bfloat16],
+                         ids=["f32", "cast-bf16"])
+def test_split_table_reproduces_the_whole(counts, dtype):
+    """Offsets, sizes and alignment of every split: the slices unflatten to
+    the whole's tree, and their transposes, joined, are the whole's
+    flat gradient (the transpose of ``unflatten`` is ``flatten``)."""
+    tree = _five_leaves()
+    buf, table = flat.flatten(tree, align=128)
+    subs = flat.split_table(table, counts)
+    assert len(subs) == len(counts)
+    assert sum(s.total for s in subs) == table.total
+    lo = 0
+    for sub, n in zip(subs, counts):
+        assert sub.num_segments == n and sub.align == table.align
+        assert sub.sizes == table.sizes[lo:lo + n]
+        assert sub.shapes == table.shapes[lo:lo + n]
+        assert sub.padded_sizes == table.padded_sizes[lo:lo + n]
+        assert sub.total % 128 == 0
+        if len(counts) > 1:
+            assert sub.offsets[0] == 0
+            assert sub.offsets == tuple(
+                o - table.offsets[lo] for o in table.offsets[lo:lo + n])
+        lo += n
+    bufs = flat.split(buf, subs)
+    assert [b.shape[0] for b in bufs] == [s.total for s in subs]
+    np.testing.assert_array_equal(np.concatenate(bufs), np.asarray(buf))
+
+    def loss(tree):
+        return sum(jnp.sum(jnp.sin(leaf.astype(jnp.float32)) * (i + 1))
+                   for i, leaf in enumerate(jax.tree.leaves(tree)))
+    whole, g_whole = jax.value_and_grad(
+        lambda b: loss(flat.unflatten(b, table, dtype=dtype)))(buf)
+    parts, g_parts = jax.value_and_grad(
+        lambda bs: loss(flat.unflatten_split(bs, subs, table.treedef,
+                                             dtype=dtype)))(bufs)
+    out = flat.unflatten_split(bufs, subs, table.treedef, dtype=dtype)
+    assert jax.tree.structure(out) == jax.tree.structure(tree)
+    jax.tree.map(np.testing.assert_array_equal, out,
+                 flat.unflatten(buf, table, dtype=dtype))
+    assert float(whole) == float(parts)
+    assert all(g.dtype == jnp.float32 for g in g_parts)
+    np.testing.assert_array_equal(np.asarray(flat.join(g_parts)),
+                                  np.asarray(g_whole))
+    np.testing.assert_array_equal(np.asarray(flat.join(bufs)),
+                                  np.asarray(buf))
+
+
+def test_one_bucket_is_the_table_and_the_buffer_themselves():
+    buf, table = flat.flatten(_five_leaves())
+    (sub,) = flat.split_table(table, (table.num_segments,))
+    assert sub is table
+    assert flat.split(buf, (sub,))[0] is buf
+
+
+@pytest.mark.parametrize("counts", [(2, 2), (5, 1), (0, 5), (3, -1, 3), ()])
+def test_split_table_refuses_counts_that_do_not_partition(counts):
+    _, table = flat.flatten(_five_leaves())
+    with pytest.raises(ValueError, match="partition"):
+        flat.split_table(table, counts)
+
+
+@pytest.mark.parametrize("sizes", [(5,), (3, 4), (1024, 2 * 1024 + 384, 128),
+                                   (4096, 4096)], ids=str)
+@pytest.mark.parametrize("divisor", [None, 4, 3.0])
+def test_join_is_concatenate_whatever_the_cut(sizes, divisor, monkeypatch):
+    """``join`` cuts each buffer at a multiple of ``_JOIN_CUT`` (for the
+    TPU compiler's windows); cut or not, it is the buffers in order, each
+    element divided once."""
+    monkeypatch.setattr(flat, "_JOIN_CUT", 1024)
+    rng = np.random.default_rng(3)
+    bufs = [jnp.asarray(rng.normal(size=n), jnp.float32) for n in sizes]
+    want = np.concatenate(bufs) if divisor is None \
+        else np.concatenate([np.asarray(b / divisor) for b in bufs])
+    got = jax.jit(lambda bs: flat.join(bs, divisor))(bufs)
+    # a compiled x / 3 may be x * (1 / 3): an ulp; a power of two is exact
+    np.testing.assert_allclose(np.asarray(got), want,
+                               rtol=2e-7 if divisor == 3.0 else 0)
+    text = str(jax.make_jaxpr(lambda bs: flat.join(bs, divisor))(bufs))
+    pieces = sum(2 if n > 1024 and n % 1024 else 1 for n in sizes)
+    if len(sizes) > 1:
+        assert f"concatenate[dimension=0]" in text
+        assert text.count(" slice[") == 2 * (pieces - len(sizes))
+    else:
+        assert "concatenate" not in text

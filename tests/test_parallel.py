@@ -9,6 +9,7 @@ Covers the reference's tests/distributed suite without hardware:
 - group sub-syncing (reference: test_groups.py on 4 GPUs).
 """
 
+import re
 from functools import partial
 
 import jax
@@ -646,3 +647,133 @@ class TestReferenceSignatureParity:
             SyncBatchNorm(16, 1e-5, 0.1, True, True, "data")
         with pytest.raises(TypeError, match="keyword-only"):
             convert_syncbn_model(object(), "data")
+
+
+# -- DDP's gradient buckets: the policy, and the step that uses it ---------
+
+@pytest.mark.parametrize("sizes,message_size,expect", [
+    # a bucket closes once it HOLDS message_size elements
+    ((4, 4, 4, 4), 8, (2, 2)),
+    ((4, 4, 4, 4), 7, (2, 2)),
+    ((4, 4, 4, 4), 9, (3, 1)),
+    ((16, 1, 1, 16), 8, (1, 3)),            # small leaves ride with the next
+    ((1, 1, 1), 100, (3,)),                 # never full: one bucket
+    ((5,), 1, (1,)),
+    ((3, 3, 3), 1, (1, 1, 1)),
+], ids=str)
+def test_ddp_buckets_close_at_message_size(sizes, message_size, expect):
+    ddp = DistributedDataParallel(message_size=message_size)
+    assert ddp.buckets(sizes) == expect
+    assert sum(ddp.buckets(sizes)) == len(sizes)
+    # delay_allreduce: everything waits for the end, in one bucket
+    late = DistributedDataParallel(message_size=message_size,
+                                   delay_allreduce=True)
+    assert late.buckets(sizes) == (len(sizes),)
+
+
+def _lm_bench():
+    import importlib
+    import os
+    import sys
+    tools = os.path.join(os.path.dirname(__file__), "..", "tools")
+    if tools not in sys.path:
+        sys.path.insert(0, tools)
+    return importlib.import_module("lm_bench")
+
+
+def _tiny_lm():
+    from apex_tpu.models import TransformerLM
+    return TransformerLM(vocab_size=256, max_seq_len=32, embed_dim=64,
+                         num_heads=2, num_layers=2, head_chunk=128)
+
+
+@pytest.fixture(scope="module")
+def ddp_steps():
+    """One step from the same state and batch on four virtual devices:
+    ``build_train_step``'s DDP arm with buckets of 20,000 elements (the
+    policy's own default holds this toy in one), and the step it replaced,
+    written out: the flat master differentiated whole, ONE psum of ONE
+    buffer. Plus what ``record_collective`` saw while the first traced."""
+    import apex_tpu.parallel as par
+    from apex_tpu.ops import flat as F
+    from apex_tpu.parallel import collectives as C
+    from apex_tpu.parallel import Plan, compile_step_with_plan
+    lm_bench = _lm_bench()
+    lm, half = _tiny_lm(), jnp.bfloat16
+    params = lm.init(jax.random.key(0))
+    mesh = make_mesh({"data": 4}, devices=jax.devices()[:4])
+    toks = jax.random.randint(jax.random.key(1), (8, 33), 0, 256)
+    real = par.DistributedDataParallel
+    par.DistributedDataParallel = partial(real, message_size=20_000)
+    try:
+        opt, state, step, plan = lm_bench.build_train_step(
+            lm, params, mesh, half=half)
+    finally:
+        par.DistributedDataParallel = real
+    table = opt._tables[0]
+    state, toks = lm_bench.place_for_plan(state, toks, plan)
+    keep = jax.tree.map(jnp.copy, state)
+    C.reset_collective_bytes()
+    got_state, got_loss = compile_step_with_plan(step, plan)(state, toks)
+    tally = C.collective_bytes()
+    ddp = real(axis_name="data")
+
+    def one_psum(state, toks):
+        loss, fg = jax.value_and_grad(lambda m: lm.loss(
+            F.unflatten(m, table, dtype=half), toks))(state[0].master)
+        return opt.apply_update(state, [ddp.average_gradients(fg)]), \
+            jax.lax.pmean(loss, "data")
+    ref_state, ref_loss = compile_step_with_plan(
+        one_psum, Plan(mesh=mesh, in_specs=plan.in_specs,
+                       out_specs=plan.out_specs))(keep, toks)
+    return dict(table=table, tally=tally, got=(got_state[0], got_loss),
+                ref=(ref_state[0], ref_loss))
+
+
+def test_bucketed_ddp_step_is_the_one_psum_step(ddp_steps):
+    """Loss, the flat gradient (read back through ``exp_avg``, as the
+    benchmark reads it) and the master: each bucket is the same float32
+    sum over the same four devices, so to rounding at the worst."""
+    (got, got_loss), (ref, ref_loss) = ddp_steps["got"], ddp_steps["ref"]
+    assert float(got_loss) == float(ref_loss)
+    g, r = np.asarray(got.slots["exp_avg"]), np.asarray(ref.slots["exp_avg"])
+    assert np.abs(r).max() > 0
+    np.testing.assert_allclose(g, r, rtol=1e-6, atol=1e-9)
+    np.testing.assert_allclose(np.asarray(got.slots["exp_avg_sq"]),
+                               np.asarray(ref.slots["exp_avg_sq"]),
+                               rtol=1e-6, atol=1e-12)
+    np.testing.assert_allclose(np.asarray(got.master),
+                               np.asarray(ref.master), rtol=0, atol=1e-7)
+    assert int(got.step) == int(ref.step) == 1
+
+
+def test_bucketed_ddp_step_issues_one_psum_a_bucket(ddp_steps):
+    """K psums of float32 buckets whose bytes sum to the flat gradient's,
+    and the loss's scalar."""
+    table = ddp_steps["table"]
+    k = len(DistributedDataParallel(message_size=20_000).buckets(
+        table.padded_sizes))
+    assert k > 3
+    psum = ddp_steps["tally"]["ops"]["psum[data]"]
+    assert psum["calls"] == k
+    assert psum["bytes"] == 4 * table.total
+
+
+def test_one_device_step_is_one_unflatten_and_no_psum():
+    """One chip is one bucket: the buffer itself through ONE ``unflatten``
+    (one cast of the whole master), no slice of it and no collective."""
+    lm_bench = _lm_bench()
+    lm = _tiny_lm()
+    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
+    _, state, step, plan = lm_bench.build_train_step(
+        lm, lm.init(jax.random.key(0)), mesh, half=jnp.bfloat16)
+    assert plan.lowering() == "jit"
+    text = str(jax.make_jaxpr(step)(state, jnp.zeros((2, 33), jnp.int32)))
+    assert "psum" not in text and "all_reduce" not in text
+    n = state[0].master.shape[0]
+    # the whole master cast once, forward; its transpose is one concat of
+    # the leaves' gradients and one convert back
+    assert len(re.findall(rf"bf16\[{n}\] = convert_element_type", text)) == 1
+    assert len(re.findall(rf"bf16\[{n}\] = concatenate", text)) == 1
+    assert len(re.findall(rf"f32\[{n}\] = convert_element_type", text)) == 1
+    assert not re.search(r"f32\[\d+\] = slice\[", text)    # no bucket cut

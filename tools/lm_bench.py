@@ -41,8 +41,10 @@ def build_train_step(lm, params, mesh, *, half, zero=False, lr=1e-4):
     compiles: FusedAdam over flat fp32 masters (the O2 master-weight
     pattern: differentiate wrt the FLAT master, ``unflatten``'s dtype
     arg fuses the ``half`` cast and its transpose returns ONE flat fp32
-    grad), replicated + DDP over a >1-device ``mesh``, or with
-    ``zero`` the DistributedFusedAdam 1/n shards. Call under
+    grad), replicated + DDP over a >1-device ``mesh`` (the flat master
+    then in DDP's buckets, each bucket's flat grad reduced where its
+    backward ends, the sums joined into the optimizer's one buffer), or
+    with ``zero`` the DistributedFusedAdam 1/n shards. Call under
     ``host_init()``: the optimizers flatten real arrays.
 
     Returns ``(opt, state, step, plan)``; ``step(state, toks) ->
@@ -97,19 +99,37 @@ def build_train_step(lm, params, mesh, *, half, zero=False, lr=1e-4):
         opt = FusedAdam(params, lr=lr)
         table = opt._tables[0]
         state_spec = P()
-        ddp = DistributedDataParallel(axis_name="data") \
+        # DDP sums; the division by the world rides the join below
+        ddp = DistributedDataParallel(axis_name="data",
+                                      gradient_average=False) \
             if n_dev > 1 else None
+        # the flat master in buckets, runs of leaves (the DDP policy's;
+        # one chip: one bucket, the buffer itself). Differentiated with
+        # respect to the buckets, each bucket's flat gradient is whole
+        # where the backward of ITS leaves ends, so its psum runs under
+        # the backward of the layers before them
+        buckets = F.split_table(
+            table, ddp.buckets(table.padded_sizes) if ddp is not None
+            else (table.num_segments,))
 
         def step(state, toks):
-            out, fg = jax.value_and_grad(
-                lambda m: (counted or lm.loss)(
-                    F.unflatten(m, table, dtype=half), toks),
-                has_aux=counted is not None)(state[0].master)
+            out, fgs = jax.value_and_grad(
+                lambda ms: (counted or lm.loss)(
+                    F.unflatten_split(ms, buckets, table.treedef,
+                                      dtype=half), toks),
+                has_aux=counted is not None)(
+                    F.split(state[0].master, buckets))
             loss, counters = out if counted else (out, None)
             if ddp is not None:
-                # the whole gradient is ONE psum of ONE buffer
-                fg = ddp.average_gradients(fg)
+                # one psum a bucket; the pass that joins the sums into
+                # the optimizer's one buffer makes them the average, and
+                # is DDP's cost like them (prof.SCOPES)
+                fgs = ddp.average_gradients(fgs)
+                with jax.named_scope("collective"):
+                    fg = F.join(fgs, divisor=n_dev)
                 loss = lax.pmean(loss, "data")
+            else:
+                fg = F.join(fgs)
             return opt.apply_update(state, [fg]), \
                 (loss, counters) if counted else loss
 
