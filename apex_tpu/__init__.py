@@ -42,5 +42,6 @@ from apex_tpu import prof  # noqa: F401
 from apex_tpu import data  # noqa: F401
 from apex_tpu import utils  # noqa: F401
 from apex_tpu import models  # noqa: F401
+from apex_tpu import train_step  # noqa: F401
 # contrib is intentionally NOT imported eagerly (reference apex/__init__.py
 # leaves contrib opt-in); import apex_tpu.contrib.<pkg> directly.

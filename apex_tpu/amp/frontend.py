@@ -58,8 +58,8 @@ def cast_model_params(params, dtype, keep_fp32_predicate=None,
 
     Cast coalescing (r06): leaves headed for ``dtype`` that share one
     source dtype are packed into ONE flat buffer, converted once, and
-    sliced back out — the r03 (docs/PERF.md) one-convert pattern bench.py already
-    uses for its master buffer, applied to the O2 wrapped-apply path the
+    sliced back out — the r03 (docs/PERF.md) one-convert pattern
+    ``apex_tpu.train_step`` uses for the master buffer, applied to the O2 wrapped-apply path the
     examples run. Under jit the step carries 1 param convert instead of
     one per leaf (161 for RN50, ~9 ms/step of per-op overhead on a
     v5e). Values are bit-identical to the per-leaf cast; opt out with

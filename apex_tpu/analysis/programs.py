@@ -1,17 +1,17 @@
 """The canonical program registry ``tools/apex_lint.py`` audits.
 
-One builder per program the repo actually ships: the bench.py train
-step (tiny-ResNet O2 flat-master shape — the same builder
-``tools/precision_audit.py`` delegates to), the lm_bench fori-loop
-step (plan-compiled; DDP shard_map body when >1 device is visible),
+One builder per program the repo actually ships: the two benchmark
+training steps at tiny sizes, built through ``apex_tpu.train_step`` as
+the cells build theirs (tiny-ResNet O2 + FusedLAMB — the same builder
+``tools/precision_audit.py`` delegates to — and the dense LM,
+plan-compiled; DDP shard_map body when >1 device is visible),
 the serve engine's prefill/commit/decode trio (fused, serialized
 AND paged — r20,
 described by the engine itself via
 ``ContinuousBatchingEngine.lint_programs``), and tiny replicas of
 both examples' train steps (mirroring their donation contract and AMP
 opt levels — the examples build their steps inside ``main()``, so the
-replicas restate the step shape the way ``precision_audit`` always
-has for bench.py).
+replicas restate the step shape).
 
 Everything here only *builds and traces* — ``jax.jit`` is lazy and
 ``make_jaxpr`` is abstract, so registering the full canonical set
@@ -37,7 +37,9 @@ CANONICAL = ("bench_o2", "lm", "serve_fused", "serve_serial",
 
 
 def _bench_step(opt_level: str, batch: int, image: int, half_dtype):
-    """The bench.py train_step shape: tiny-ResNet, flat fp32 master,
+    """The ResNet benchmark step at tiny sizes, through the package's
+    builder (``apex_tpu.train_step.build_step``) as ``bench.py`` makes it:
+    tiny-ResNet, FusedLAMB over the flat fp32 master, the softmax head,
     dynamic scaler — O2 casts the master via unflatten's fused convert,
     O1 wraps the apply in autocast, O0 stays fp32."""
     import jax
@@ -45,9 +47,10 @@ def _bench_step(opt_level: str, batch: int, image: int, half_dtype):
     import numpy as np
 
     from apex_tpu import amp
+    from apex_tpu.contrib.xentropy import select_label_logits
     from apex_tpu.models import ResNet
-    from apex_tpu.optimizers import FusedSGD
-    from apex_tpu.ops import flat as F
+    from apex_tpu.optimizers import FusedLAMB
+    from apex_tpu.train_step import build_step
 
     model = ResNet(block_sizes=(1, 1), bottleneck=True, num_classes=10,
                    width=8)
@@ -56,36 +59,33 @@ def _bench_step(opt_level: str, batch: int, image: int, half_dtype):
                                half_dtype=half_dtype)
     amp_state = handle.init_state()
     half = handle.policy.cast_model_dtype
-    opt = FusedSGD(params, lr=0.1)
-    table = opt._tables[0]
+    opt = FusedLAMB(params, lr=1e-3)
     opt_state = opt.init_state()
     apply_fn = (amp.autocast(model.apply, handle.policy.compute_dtype)
                 if handle.policy.autocast else model.apply)
 
     rs = np.random.RandomState(0)
     # the batch rides in the model compute dtype under O2/O3, exactly as
-    # bench.py feeds it (model convs follow x.dtype); fp32 under O0/O1
+    # the benchmark feeds it (model convs follow x.dtype); fp32 under
+    # O0/O1
     x = jnp.asarray(rs.randn(batch, image, image, 3),
                     half if half is not None else jnp.float32)
     y = jnp.asarray(rs.randint(0, 10, batch), jnp.int32)
 
-    def train_step(opt_state, bn_state, amp_state, x, y):
-        def loss_fn(master):
-            p = F.unflatten(master, table,
-                            dtype=half if half is not None else None)
-            logits, new_st = apply_fn(p, bn_state, x, training=True)
+    def loss_fn(p, bn_state, x, y):
+        logits, new_st = apply_fn(p, bn_state, x, training=True)
+        with jax.named_scope("head"):
             logits = logits.astype(jnp.float32)
             logp = jax.nn.log_softmax(logits)
-            loss = -jnp.mean(jnp.take_along_axis(
-                logp, y[:, None], axis=-1))
-            return handle.scale_loss(loss, amp_state), (loss, new_st)
+            loss = -jnp.mean(select_label_logits(logp, y))
+        return loss, new_st
 
-        fg, (loss, new_bn) = jax.grad(loss_fn, has_aux=True)(
-            opt_state[0].master)
-        fg, found_inf = handle.unscale(fg, amp_state)
-        new_opt = opt.apply_update(opt_state, [fg], found_inf=found_inf)
-        new_amp = handle.update(amp_state, found_inf)
-        return new_opt, new_bn, new_amp, loss
+    body = build_step(opt, loss_fn, half=half, handle=handle)
+
+    def train_step(opt_state, bn_state, amp_state, x, y):
+        opt_state, amp_state, loss, bn_state = body(
+            opt_state, amp_state, bn_state, x, y)
+        return opt_state, bn_state, amp_state, loss
 
     return train_step, (opt_state, bn_state, amp_state, x, y)
 
@@ -95,7 +95,7 @@ def bench_step_program(opt_level: str = "O2", batch: int = 8,
                        half_dtype: str = "bfloat16") -> ProgramView:
     import jax
     step, ex = _bench_step(opt_level, batch, image, half_dtype)
-    # bench.py donates the flat opt/bn/amp state (r06)
+    # the benchmark's driver donates the flat opt/bn/amp state
     jstep = jax.jit(step, donate_argnums=(0, 1, 2))
     return ProgramView(
         name=f"bench.train_step@{opt_level}", fn=jstep,
@@ -150,62 +150,46 @@ def rnn_step_program(opt_level: str = "O1", batch: int = 2,
         consumed_outputs=frozenset({"0", "1"}))
 
 
-def lm_step_program(iters: int = 2) -> ProgramView:
-    """The lm_bench CPU-smoke fori-loop step, plan-compiled the way
-    tools/lm_bench.py compiles it: plain-jit plan on one device, DDP
-    (shard_map + psum over 'data') when more devices are visible."""
+def lm_step_program() -> ProgramView:
+    """The dense-LM benchmark step at tiny sizes, through the package's
+    builder (``apex_tpu.train_step``) as ``tools/lm_bench.py`` makes it
+    and plan-compiled the way the drivers compile it: plain-jit plan on
+    one device, DDP's buckets (shard_map + a psum a bucket over 'data')
+    when more devices are visible."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
     from jax.sharding import PartitionSpec as P
 
+    from apex_tpu import train_step as T
     from apex_tpu.models import TransformerLM
     from apex_tpu.optimizers import FusedAdam
-    from apex_tpu.ops import flat as F
-    from apex_tpu.parallel import (DistributedDataParallel, Plan,
+    from apex_tpu.parallel import (DistributedDataParallel,
                                    compile_step_with_plan, make_mesh)
 
     seq, batch, layers, dim, heads, vocab = 128, 2, 2, 128, 4, 512
     lm = TransformerLM(vocab_size=vocab, max_seq_len=seq,
                        embed_dim=dim, num_heads=heads,
                        num_layers=layers, head_chunk=vocab)
-    half = jnp.bfloat16
     n_dev = len(jax.devices())
     if batch % n_dev:
         batch += -batch % n_dev
-    params = lm.init(jax.random.key(0))
-    opt = FusedAdam(params, lr=1e-4)
-    table = opt._tables[0]
+    opt = FusedAdam(lm.init(jax.random.key(0)), lr=1e-4)
     state = opt.init_state()
     toks = jax.random.randint(jax.random.key(1), (batch, seq), 0, vocab)
-    ddp = DistributedDataParallel(axis_name="data") if n_dev > 1 else None
+    body = T.build_step(
+        opt, lm.loss, half=jnp.bfloat16,
+        ddp=DistributedDataParallel(axis_name="data") if n_dev > 1
+        else None)
 
     def step(state, toks):
-        loss, fg = jax.value_and_grad(
-            lambda m: lm.loss(F.unflatten(m, table, dtype=half),
-                              toks))(state[0].master)
-        if ddp is not None:
-            fg = ddp.average_gradients(fg)
-            loss = lax.pmean(loss, "data")
-        return opt.apply_update(state, [fg]), loss
+        state, _, loss, _ = body(state, None, toks)
+        return state, loss
 
-    def run_n_body(state, toks):
-        def body(i, carry):
-            st, _ = carry
-            return step(st, toks)
-        return jax.lax.fori_loop(
-            0, iters, body, (state, jnp.asarray(0.0, jnp.float32)))
-
-    mesh = make_mesh({"data": n_dev})
-    if n_dev > 1:
-        plan = Plan(mesh=mesh, in_specs=(P(), P("data")),
-                    out_specs=(P(), P()), donate_argnums=(0,),
-                    check_vma=False)
-    else:
-        plan = Plan(mesh=mesh, donate_argnums=(0,))
-    run_n = compile_step_with_plan(run_n_body, plan)
+    plan = T.step_plan(make_mesh({"data": n_dev}),
+                       P() if n_dev > 1 else None)
     return ProgramView(
-        name=f"lm_bench.run_n@{plan.lowering()}x{n_dev}", fn=run_n,
+        name=f"lm.train_step@{plan.lowering()}x{n_dev}",
+        fn=compile_step_with_plan(step, plan),
         example_args=(state, toks), plan=plan, expect_half=True,
         consumed_outputs=frozenset({"0", "1"}))
 

@@ -27,7 +27,7 @@ Sharding contract (multi-host data parallelism):
 Formats: binary PPM (P6) rides the native decode tier; ``.npy`` (uint8
 HWC arrays) decodes host-side via numpy — the escape hatch for tests
 and toolchain-less installs. :func:`write_image_folder` generates a
-synthetic dataset directory (tests, ``bench.py --data synth``).
+synthetic dataset directory (tests).
 """
 
 from __future__ import annotations

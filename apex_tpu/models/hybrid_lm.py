@@ -17,7 +17,7 @@ GPT-2 block repeated, this model's layers differ, so it is a class of its
 own and shares with the dense LM what lies under it: the flash-attention
 kernels, the chunked head (``linear_cross_entropy``), recomputation
 (``jax.checkpoint`` a block) and the step builder
-(``tools/lm_bench.build_train_step``).
+(``apex_tpu.train_step.build_step``).
 
 The **Gated DeltaNet mixer** (``linear_k_heads`` key heads and
 ``linear_v_heads`` value heads of ``linear_k_dim`` / ``linear_v_dim``):

@@ -276,7 +276,7 @@ def analytic_flops(model: "ResNet", image: int) -> float:
     """Analytic forward FLOPs/img (2*K*K*Cin*Cout*Hout*Wout per conv + fc,
     2 flops per MAC). Training approx = 3x (bwd-wrt-input and
     bwd-wrt-weights each cost ~1 fwd). Used as the honest MFU numerator by
-    bench.py and tools/perf_probe.py (validated within 2% of XLA's cost
+    tools/perf_probe.py (validated within 2% of XLA's cost
     analysis for RN50@224)."""
     def up(n, s):  # SAME-padding output size: ceil(n / s)
         return -(-n // s)

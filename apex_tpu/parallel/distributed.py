@@ -10,7 +10,7 @@ the program's data flow lets it. So the one thing kept of the machinery is
 the **bucket**: a step that wants its gradient reduced under the backward
 cuts the flat gradient where :meth:`DistributedDataParallel.buckets` says
 and reduces a tuple, one ``psum`` a bucket, each depending on its own
-leaves only (``tools/lm_bench.build_train_step`` does; on the TPU
+leaves only (``apex_tpu.train_step.build_step`` does; on the TPU
 ``parallel/plan.py`` tells the compiler to run them asynchronously). What
 must be preserved besides is the *semantics*:
 
@@ -233,7 +233,7 @@ class DistributedDataParallel:
                      check_vma: "bool | None" = False) -> Callable:
         """Compile a DDP train-step body through the sharding Plan layer
         (:func:`apex_tpu.parallel.plan.compile_step_with_plan`) — the
-        one compile path shared with the multichip bench and lm_bench,
+        one compile path shared with ``apex_tpu.train_step``'s plans,
         replacing the per-call-site ``jit(shard_map(...))`` stanzas.
 
         ``body`` is a per-device function (call ``average_gradients`` /

@@ -58,8 +58,7 @@ def _sum_pair(a, b, axes):
     emitter handles a pair of fused reductions better than one variadic
     reduce. Same measured-demotion story as welford. The variadic shape
     stays available under APEX_BN_VARIADIC_REDUCE=1 for future re-A/B;
-    any other value (including "0", which window A/B arms use to force
-    split over a bench.py defaults-driven export) selects split."""
+    any other value (including "0") selects split."""
     import os
     if os.environ.get("APEX_BN_VARIADIC_REDUCE") == "1":
         zero = jnp.asarray(0.0, jnp.float32)

@@ -87,7 +87,7 @@ def _eqn_class(eqn) -> Optional[str]:
 def _eqn_flops(eqn) -> float:
     """Estimated FLOPs for the MXU primitives (2 flops/MAC); 0 for
     everything else. Loop bodies are counted ONCE — trip counts are not
-    modeled, matching XLA's HloCostAnalysis convention (bench.py)."""
+    modeled, matching XLA's HloCostAnalysis convention."""
     try:
         out = eqn.outvars[0].aval.shape
         if eqn.primitive.name == "dot_general":

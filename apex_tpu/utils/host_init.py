@@ -1,7 +1,7 @@
 """Process start-up for every entry point: which device, which cache.
 
 ``setup_host_backend()`` is the one preamble ``chip_smoke.py``,
-``bench.py``, ``tools/*_bench.py`` and the examples call before any
+``benchmarks/run.py``, ``tools/*_bench.py`` and the examples call before any
 other jax operation. It is strict about the device: the program runs on
 the TPU, or on the CPU because the caller asked for the CPU
 (``JAX_PLATFORMS=cpu``, or ``parallel.pin_cpu_devices``) — never on the

@@ -11,12 +11,10 @@ VMEM limit trades prefetch depth against fusion size.
 
 Discipline (same as BENCH_DEFAULTS.json): every knob is **off unless
 armed via env**, so a plain run measures the measured-default config and
-an armed run is an A/B arm (bench.py counts any of these env vars as a
-config override — the arm's number can never seed or satisfy the plain
-replay cache). Flags ride ``LIBTPU_INIT_ARGS`` (read by libtpu when the
+an armed run is an A/B arm. Flags ride ``LIBTPU_INIT_ARGS`` (read by libtpu when the
 TPU client initializes; inert on CPU-only runs), so ``apply()`` must run
-before the first backend-touching jax call — bench.py and the examples
-call it at startup.
+before the first backend-touching jax call — ``setup_host_backend()``
+calls it at startup.
 
 Env surface:
 
@@ -108,8 +106,8 @@ def apply(env: Optional[MutableMapping[str, str]] = None) -> list[str]:
     flags already present are not duplicated). Returns the flag strings
     that ended up applied — empty for a plain (unarmed) run.
 
-    Must run before the first backend-touching jax call; bench.py and
-    the examples call it right after import."""
+    Must run before the first backend-touching jax call;
+    ``setup_host_backend()`` calls it."""
     env = os.environ if env is None else env
     flags = armed_flags(env)
     if not flags:

@@ -1,1274 +1,45 @@
-"""Headline benchmark: ResNet-50 O2 + FusedLAMB training throughput.
-
-Reproduces the reference's metric definition — img/s = world_size * batch /
-batch_time (reference: examples/imagenet/main_amp.py:390-398) — on the
-flagship config from BASELINE.md (RN50, O2 mixed precision, FusedLAMB).
-
-Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline", ...}.
-``vs_baseline`` is value / 800 img/s — the reference publishes no numbers
-(BASELINE.md), so 800 stands in for Apex-CUDA RN50 AMP per-V100 throughput
-(NVIDIA's commonly reported DGX-1V per-GPU figure for this config).
-``mfu`` is model-flops-utilization from ANALYTIC RN50 FLOPs (24.54
-GFLOP/img fwd+bwd at 224px, counting one MAC as 2 flops — validated
-against XLA's cost analysis, which reports 25.06; ``step_tflops`` still
-records XLA's number) against the chip's bf16 peak.
-
-Timing: N steps run inside ONE ``lax.fori_loop`` dispatch, warmed up with
-a full first call, so per-call dispatch can neither pipeline nor pollute
-the measurement (VERDICT r2 Weak #7).
-
-Device: the bench runs on the chip, or on the CPU because the caller asked
-for it (``JAX_PLATFORMS=cpu`` or ``BENCH_CPU_DEVICES=N`` — the tiny smoke
-config the tests use). With nothing pinned and no chip it exits non-zero
-(``utils.setup_host_backend``): there is no probe, no fall-back to the CPU
-and no replayed line. It starts no child process.
-
-Env knobs: BENCH_BATCH (default 384 on TPU — the best of the three
-on-chip-measured sizes, see BENCH_r04_batch*.json — 8 on CPU), BENCH_ITERS
-(default 100 on TPU, 2 on CPU), BENCH_IMAGE (default 224 on TPU, 32 on
-CPU), BENCH_NUMERICS=1 /
---numerics (r09: carry the per-parameter overflow-provenance census
-through the fori loop, sample an underflow census, audit precision
-coverage — summaries in the JSON line, full records in the telemetry
-sidecar when armed), BENCH_SLO / --slo RULES (r13: in-run SLO monitor
-over the bench's own intervals — prof/slo.py rule syntax, e.g.
-``step_p95_ms<=900,skip_rate<=0.25``; violations emit schema-5
-``alert`` records into the sidecar and a ``slo`` summary in the JSON
-line; a telemetered run also records phase spans — model_build /
-lower_compile / warmup / timed_fori / numerics_census / fleet_probe —
-as schema-5 ``span`` records), BENCH_LIVE / --live [ENDPOINT] (r18:
-stream the telemetry records through a non-blocking
-``prof.live.LiveEmitter`` — ``tcp:HOST:PORT``/``unix:/path.sock``
-targets an external LiveCollector, a bare ``--live`` hosts an
-in-process one so even a single-process bench gets a Prometheus
-/metrics scrape; needs telemetry). A repo-root
-BENCH_DEFAULTS.json ({"stem": ..., "batch": ...}) supplies measured-best
-defaults; env vars override.
+"""The ResNet-50 O2 + FusedLAMB training step the benchmark's driver and
+``chip_smoke.py`` build: the choice of optimizer and loss around the
+package's step builder (``apex_tpu.train_step``).
+``benchmarks/drivers/train_rn50.py`` imports :func:`build_train_step` from
+here (ROADMAP D1b moves the choice into the driver); measure with
+``python3 benchmarks/run.py --workload rn50_train_b384``.
 """
 
 from __future__ import annotations
 
-import json
-import os
-import sys
-import time
-import traceback
-from functools import partial
-
-BASELINE_IMG_S = 800.0  # stand-in for Apex-CUDA V100 RN50 AMP (see above)
-
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                "tools"))
-
-
-def _stamp(line: dict) -> dict:
-    """run_meta/format stamping (r16, tools/_perf_common.stamp_result)
-    on every emission path — guarded so a bookkeeping failure can never
-    cost the one JSON line (this includes the crash emitter)."""
-    try:
-        from _perf_common import stamp_result
-        return stamp_result(line, "bench")
-    except Exception:
-        return line
-
-
-def _traj(line: dict) -> None:
-    """The r16 trajectory hook (APEX_TRAJECTORY env; no-op otherwise)."""
-    try:
-        from _perf_common import append_trajectory
-        append_trajectory(line, tool="bench")
-    except Exception:
-        pass
-
-# updated by main() once the backend is known, so the crash handler labels
-# the JSON line with the config that actually ran
-_metric_name = "resnet50_O2_fusedlamb_train_throughput"
-
-
-# Runtime telemetry (r07): --telemetry [PATH] or BENCH_TELEMETRY=<path|1>
-# arms a prof.MetricsLogger sidecar (TELEM_*.jsonl next to the BENCH_*
-# artifacts) + stall watchdog. Populated by _arm_telemetry(); the
-# __main__ crash handler closes it so even a dying run leaves its
-# record. All logging happens OUTSIDE the timed region (measured
-# overhead on the CPU bench loop: <1%).
-_TELEM: dict = {}
-
-
-def _telemetry_path() -> "str | None":
-    """Resolve the sidecar path from --telemetry [PATH] argv or the
-    BENCH_TELEMETRY env var ('1'/'true' = auto-named next to bench.py).
-    None = telemetry off (the default)."""
-    val = None
-    argv = sys.argv[1:]
-    if "--telemetry" in argv:
-        i = argv.index("--telemetry")
-        val = argv[i + 1] if i + 1 < len(argv) and \
-            not argv[i + 1].startswith("-") else "1"
-    elif os.environ.get("BENCH_TELEMETRY"):
-        val = os.environ["BENCH_TELEMETRY"]
-    if not val or val == "0":
-        return None
-    if val in ("1", "true", "True"):
-        from apex_tpu.prof.metrics import default_sidecar_path
-        return default_sidecar_path(
-            "bench", os.path.dirname(os.path.abspath(__file__)))
-    return val
-
-
-def _slo_rules() -> "str | None":
-    """--slo RULES argv or BENCH_SLO env (r13): arm an in-run SLO
-    monitor (prof/slo.py syntax over rolling windows — e.g.
-    ``step_p95_ms<=900,skip_rate<=0.25``); violations emit schema-5
-    ``alert`` records through the telemetry sidecar and a ``slo``
-    summary in the JSON line. Needs telemetry."""
-    argv = sys.argv[1:]
-    if "--slo" in argv:
-        i = argv.index("--slo")
-        if i + 1 < len(argv) and not argv[i + 1].startswith("-"):
-            return argv[i + 1]
-        raise ValueError("--slo needs a rule spec "
-                         "(e.g. step_p95_ms<=900)")
-    return os.environ.get("BENCH_SLO") or None
-
-
-def _live_endpoint() -> "str | None":
-    """--live [ENDPOINT] argv or BENCH_LIVE env (r18): stream the
-    bench's telemetry records through a non-blocking
-    ``prof.live.LiveEmitter``. An explicit ``tcp:HOST:PORT`` /
-    ``unix:/path.sock`` targets an external collector; ``1`` (or a
-    bare ``--live``) starts an in-process LiveCollector so even a
-    single-process bench gets a live /metrics scrape. Needs
-    telemetry (the emitter rides the MetricsLogger tee)."""
-    argv = sys.argv[1:]
-    if "--live" in argv:
-        i = argv.index("--live")
-        return argv[i + 1] if i + 1 < len(argv) and \
-            not argv[i + 1].startswith("-") else "1"
-    return os.environ.get("BENCH_LIVE") or None
-
-
-def _arm_telemetry(backend: str, meta: dict) -> None:
-    """Create the sidecar logger + watchdog once the backend is known
-    (the header must record what actually ran). Never lets a telemetry
-    failure cost the bench its one JSON line. r13: also arms the phase
-    span tracer (model_build / lower_compile / warmup / timed windows
-    / census / fleet_probe spans, logged at close) and — under
-    --slo/BENCH_SLO — the in-run SLO monitor."""
-    path = _telemetry_path()
-    if path is None:
-        return
-    try:
-        from apex_tpu import prof
-        logger = prof.MetricsLogger(path, run=_metric_name,
-                                    meta=dict(meta, backend=backend))
-        tracer = prof.SpanTracer()
-        # the watchdog's job here is the attributable stall RECORD
-        # (min interval generous: compile+warmup is minutes), naming
-        # the open phase span when it fires
-        wd = prof.Watchdog(logger, min_interval_s=600.0,
-                           label="bench", tracer=tracer).start()
-        _TELEM.update(path=path, logger=logger, wd=wd, tracer=tracer)
-        rules = _slo_rules()
-        if rules:
-            # min_samples=1: the fori bench observes per-interval
-            # aggregates, not per-step samples — one bad interval is
-            # already a violation worth alerting on
-            _TELEM["slo"] = prof.SLOMonitor(rules, logger=logger,
-                                            min_samples=1)
-            _note("SLO rules armed: " + ", ".join(
-                r.name for r in _TELEM["slo"].rules))
-        endpoint = _live_endpoint()
-        if endpoint:
-            # r18: stream the sidecar's records live. "1" = host an
-            # in-process collector (the /metrics scrape for a
-            # single-process bench); else target an external one.
-            if endpoint in ("1", "true"):
-                _TELEM["live_col"] = prof.LiveCollector(
-                    logger=logger).start()
-                endpoint = _TELEM["live_col"].endpoint
-                _note(f"live collector: {endpoint}; scrape "
-                      f"{_TELEM['live_col'].metrics_url}")
-            _TELEM["live"] = prof.LiveEmitter(
-                endpoint, run=_metric_name).attach(logger)
-            _note(f"live stream armed: {endpoint}")
-        _note(f"telemetry sidecar: {path}")
-    except Exception as e:
-        _note(f"telemetry arm failed: {type(e).__name__}: {e}")
-
-
-def _telem_event(name: str, **fields) -> None:
-    lg = _TELEM.get("logger")
-    if lg is not None:
-        try:
-            lg.event(name, **fields)
-        except Exception:
-            pass
-
-
-def _phase_begin(name: str, **attrs) -> "int | None":
-    """Open a phase span when the tracer is armed (r13); None = off."""
-    tr = _TELEM.get("tracer")
-    return tr.begin(name, **attrs) if tr is not None else None
-
-
-def _phase_end(sid: "int | None", **attrs) -> None:
-    tr = _TELEM.get("tracer")
-    if tr is not None and sid is not None:
-        tr.end(sid, **attrs)
-
-
-def _slo_observe(metric: str, value) -> None:
-    """Feed the in-run SLO monitor (no-op when --slo is not armed);
-    never lets a monitor bug cost the bench its JSON line."""
-    mon = _TELEM.get("slo")
-    if mon is not None:
-        try:
-            mon.observe(metric, value)
-        except Exception as e:
-            _note(f"slo observe failed: {type(e).__name__}: {e}")
-
-
-def _close_telemetry() -> None:
-    """The ONE close funnel (main path + data/zero arms): flush the
-    phase spans, stop the watchdog, close the sidecar."""
-    lg = _TELEM.get("logger")
-    if lg is None:
-        return
-    tr = _TELEM.get("tracer")
-    if tr is not None:
-        try:
-            lg.log_spans(tr)
-        except Exception:
-            pass
-    em = _TELEM.get("live")
-    if em is not None:
-        try:
-            em.close()                 # bye + live_drop accounting
-        except Exception:
-            pass
-    col = _TELEM.get("live_col")
-    if col is not None:
-        try:
-            col.close()                # LIVE table -> this sidecar
-        except Exception:
-            pass
-    wd = _TELEM.get("wd")
-    if wd is not None:
-        wd.stop()
-    lg.close()
-
-
-def _note(msg: str) -> None:
-    wd = _TELEM.get("wd")
-    if wd is not None:
-        wd.heartbeat()
-    sys.stderr.write(f"bench[{time.strftime('%H:%M:%S')}]: {msg}\n")
-    sys.stderr.flush()
-
-
-# --------------------------------------------------------------------------
-# --data arm: real on-disk input path (ISSUE r08). The plain bench times
-# the compiled step with a FIXED device batch; this arm feeds it from the
-# sharded folder loader -> native decode/crop/flip -> background device
-# prefetch, measures steady-state per-call throughput WITH input-wait
-# accounting, and first emits a host-pipeline-only microbench
-# (DATABENCH_*.json: loader img/s at the flagship batch/crop, no device
-# in the loop). BENCH_DATA=<dir|synth> or `--data <dir|synth>` arms it;
-# `synth` generates a deterministic throwaway dataset so the arm is
-# provable offline. BENCH_DATA_THROTTLE_MS=<ms> artificially throttles
-# the host iterator — the input-starved attribution proof.
-
-
-def _data_arg() -> "str | None":
-    """--data [DIR|synth] argv or BENCH_DATA env; None = plain bench."""
-    argv = sys.argv[1:]
-    if "--data" in argv:
-        i = argv.index("--data")
-        if i + 1 < len(argv) and not argv[i + 1].startswith("-"):
-            return argv[i + 1]
-        return "synth"
-    return os.environ.get("BENCH_DATA") or None
-
-
-def _zero_arg() -> "str | None":
-    """r11 ZeRO arm selector: ``--zero [ddp]`` argv or BENCH_ZERO env.
-
-    Returns None (plain bench), ``"zero"`` (DistributedFusedLAMB: fp32
-    master + m + v sharded 1/n per device, psum_scatter grads ->
-    sharded update -> bf16 all_gather) or ``"ddp"`` (the replicated
-    baseline over the SAME mesh: DDP psum of the flat grad + replicated
-    FusedLAMB). Both compile through the sharding Plan layer; the pair
-    is the telemetry A/B whose ``params+opt_state bytes/device`` delta
-    proves the ZeRO HBM saving."""
-    argv = sys.argv[1:]
-    val = None
-    if "--zero" in argv:
-        i = argv.index("--zero")
-        val = argv[i + 1] if i + 1 < len(argv) and \
-            not argv[i + 1].startswith("-") else "1"
-    elif os.environ.get("BENCH_ZERO"):
-        val = os.environ["BENCH_ZERO"]
-    if not val or val == "0":
-        return None
-    if val in ("1", "true", "True", "zero"):
-        return "zero"
-    if val == "ddp":
-        return "ddp"
-    raise ValueError(f"--zero/BENCH_ZERO must be 1|zero|ddp, got {val!r}")
-
-
-def _fleet_arg() -> bool:
-    """--fleet-probe argv or BENCH_FLEET env (r10): after the timed
-    region, run one FleetProbe gather (traced all_gather of the
-    per-process step-duration EMA under the `apex_fleet_probe` scope)
-    so the sidecar carries a `fleet_skew` record. Degenerate but valid
-    single-process; under a multi-process launch every process's
-    sidecar names the fleet's slowest member."""
-    if "--fleet-probe" in sys.argv[1:]:
-        return True
-    return os.environ.get("BENCH_FLEET", "") not in ("", "0")
-
-
-def _numerics_arg() -> bool:
-    """--numerics argv or BENCH_NUMERICS env (r09): arm the numerics
-    layer — per-parameter overflow provenance carried through the fori
-    loop, a sampled underflow census, and the precision-coverage audit
-    of the step. Summaries land in the JSON line; full records go to
-    the telemetry sidecar when one is armed."""
-    if "--numerics" in sys.argv[1:]:
-        return True
-    return os.environ.get("BENCH_NUMERICS", "") not in ("", "0")
-
-
-def _snapshot_arg() -> "str | None":
-    """--snapshot [DIR] argv or BENCH_SNAPSHOT env (r17): arm the
-    async ``runtime.SnapshotWriter`` on the measured arm — one
-    generation submitted after warmup (its device→host fetch + write
-    overlap the timed region: the async contract under measurement)
-    and one after the timed region (the resumable end state). The
-    sidecar carries the schema-6 ``snapshot`` records; snapshot-on vs
-    snapshot-off step medians must stay within noise (docs/PERF.md)."""
-    argv = sys.argv[1:]
-    if "--snapshot" in argv:
-        i = argv.index("--snapshot")
-        if i + 1 < len(argv) and not argv[i + 1].startswith("-"):
-            return argv[i + 1]
-        return "BENCH_SNAPSHOTS"
-    val = os.environ.get("BENCH_SNAPSHOT")
-    if not val or val == "0":
-        return None
-    return val if val not in ("1", "true", "True") else "BENCH_SNAPSHOTS"
-
-
-def _materialize_dataset(spec: str, crop: int) -> str:
-    """Resolve the dataset root: an existing dir passes through; 'synth'
-    generates a deterministic mini image-folder (images crop+8 px so
-    random crops exercise real offsets)."""
-    if spec != "synth":
-        if not os.path.isdir(spec):
-            raise ValueError(f"--data {spec}: not a directory")
-        return spec
-    import tempfile
-    from apex_tpu.data import write_image_folder
-    root = os.path.join(tempfile.gettempdir(),
-                        f"apex_databench_c{crop}_{os.getuid()}")
-    marker = os.path.join(root, ".complete")
-    if not os.path.exists(marker):
-        per_class = int(os.environ.get("BENCH_DATA_PER_CLASS", 48))
-        write_image_folder(root, classes=8, per_class=per_class,
-                           size=(crop + 8, crop + 8), seed=0)
-        with open(marker, "w") as f:
-            f.write("ok\n")
-    return root
-
-
-def _host_pipeline_microbench(root: str, out_path: str) -> "dict | None":
-    """Loader-only throughput (file read + native decode/crop/flip on
-    the worker pool; NO device in the loop) at the flagship batch/crop —
-    the number that says whether the host side can feed the chip.
-    Writes one JSON line to ``out_path``; never raises."""
-    try:
-        from apex_tpu.data import ImageFolder, ShardedImageFolderLoader
-        from apex_tpu.utils import native
-        batch = int(os.environ.get("BENCH_DATABENCH_BATCH", 384))
-        crop = int(os.environ.get("BENCH_DATABENCH_CROP", 224))
-        workers = int(os.environ.get("BENCH_DATA_WORKERS", 2))
-        ds = ImageFolder(root)
-        batch = min(batch, len(ds))
-        loader = ShardedImageFolderLoader(ds, batch_size=batch,
-                                          crop=(crop, crop), seed=0,
-                                          workers=workers)
-        want = int(os.environ.get("BENCH_DATABENCH_BATCHES", 8))
-
-        def cycle():  # mini datasets re-epoch (fresh crops each pass)
-            while True:
-                for b in loader:
-                    yield b
-
-        # warm one batch (page cache + pool spin-up), then time a pass
-        it = cycle()
-        next(it)
-        n_batches = imgs = 0
-        t0 = time.perf_counter()
-        for x, y in it:
-            n_batches += 1
-            imgs += x.shape[0]
-            if n_batches >= want:
-                break
-        dt = time.perf_counter() - t0
-        if dt <= 0:
-            raise ValueError("degenerate microbench timing")
-        line = {"metric": "host_pipeline_decode_augment_throughput",
-                "value": round(imgs / dt, 2), "unit": "img/s",
-                "batch": batch, "crop": crop, "workers": workers,
-                "batches": n_batches, "dataset": root,
-                "samples": len(ds),
-                "native": bool(native.available()),
-                "batch_ms": round(dt / n_batches * 1e3, 2)}
-        with open(out_path, "w") as f:
-            json.dump(line, f)
-            f.write("\n")
-        _note(f"DATABENCH {out_path}: {line['value']} img/s "
-              f"(b{batch}/c{crop})")
-        return line
-    except Exception as e:
-        _note(f"host-pipeline microbench failed: "
-              f"{type(e).__name__}: {e}")
-        return None
-
-
-def _run_data_arm(*, data_spec, backend, batch, iters, image, stem,
-                  train_step, opt_state, bn_state, amp_state, handle,
-                  num_classes, applied_flags, half) -> None:
-    """The --data measurement: DATABENCH host microbench, then the SAME
-    compiled step timed per-call twice — fed by the real loader ->
-    prefetcher (with input-wait accounting) and fed a fixed synthetic
-    device batch — so the line itself carries the overlap proof
-    (``value`` vs ``synthetic_percall_img_s``). Emits THE one JSON line
-    and returns; the fori path never runs under --data (a fori over one
-    fixed batch cannot exercise an input pipeline)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from apex_tpu.data import (DevicePrefetcher, ImageFolder,
-                               ShardedImageFolderLoader,
-                               normalize_imagenet)
-
-    global _metric_name
-    _metric_name += "_data"
-
-    # host-pipeline-only microbench first: it must exist even if the
-    # train timing below dies (the committed DATABENCH artifact)
-    db_root = _materialize_dataset(
-        data_spec, int(os.environ.get("BENCH_DATABENCH_CROP", 224)))
-    db_out = os.environ.get(
-        "BENCH_DATABENCH_OUT",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                     "DATABENCH_host_pipeline.json"))
-    databench = _host_pipeline_microbench(db_root, db_out)
-    _telem_event("databench_done")
-
-    root = _materialize_dataset(data_spec, image)
-    ds = ImageFolder(root)
-    workers = int(os.environ.get("BENCH_DATA_WORKERS", 2))
-    loader = ShardedImageFolderLoader(ds, batch_size=batch,
-                                      crop=(image, image), seed=0,
-                                      workers=workers)
-    throttle_ms = float(os.environ.get("BENCH_DATA_THROTTLE_MS", 0.0))
-
-    def host_batches(n):
-        it = iter(loader)
-        for _ in range(n):
-            try:
-                b = next(it)
-            except StopIteration:   # next epoch (fresh shuffle/crops)
-                it = iter(loader)
-                b = next(it)
-            if throttle_ms:
-                time.sleep(throttle_ms * 1e-3)  # starvation injection
-            yield b
-
-    # uint8 in, normalization fused into the jitted step (the example's
-    # division of labor) — ONE compile serves warmup + both timed arms
-    @partial(jax.jit, donate_argnums=(0, 1, 2))
-    def data_step(opt_state, bn_state, amp_state, x, y):
-        xn = normalize_imagenet(x, dtype=half or jnp.float32)
-        return train_step(opt_state, bn_state, amp_state, xn, y)
-
-    pf = DevicePrefetcher(host_batches(iters + 1), depth=2,
-                          background=True)
-    itpf = iter(pf)
-    x0, y0 = next(itpf)
-    _note("data arm: compiling + warmup on the first real batch")
-    opt_state, bn_state, amp_state, loss = data_step(
-        opt_state, bn_state, amp_state, x0, y0)
-    float(loss), float(opt_state[0].master[0])
-    pf.pop_input_waits()     # warmup wait is compile time, not input
-    _telem_event("warmup_done")
-    _note(f"data arm: timing {iters} per-call steps at batch {batch}")
-
-    t0 = time.perf_counter()
-    n_done = 0
-    for x, y in itpf:
-        opt_state, bn_state, amp_state, loss = data_step(
-            opt_state, bn_state, amp_state, x, y)
-        n_done += 1
-    float(loss), float(opt_state[0].master[0])
-    dt = time.perf_counter() - t0
-    waits = pf.pop_input_waits()
-    data_img_s = batch * n_done / dt
-    wait_mean = sum(waits) / max(len(waits), 1)
-    waits_sorted = sorted(waits)
-
-    def pct(q):
-        if not waits_sorted:
-            return 0.0
-        return waits_sorted[min(len(waits_sorted) - 1,
-                                round(q * (len(waits_sorted) - 1)))]
-
-    # the synthetic comparison arm: SAME compiled step, fixed uint8
-    # device batch (zero input pipeline) — the overlap denominator
-    rs = np.random.RandomState(1)
-    xs = jnp.asarray(rs.randint(0, 256, (batch, image, image, 3)),
-                     jnp.uint8)
-    ys = jnp.asarray(rs.randint(0, num_classes, batch), jnp.int32)
-    synth_img_s = None
-    try:
-        t0 = time.perf_counter()
-        for _ in range(n_done):
-            opt_state, bn_state, amp_state, loss = data_step(
-                opt_state, bn_state, amp_state, xs, ys)
-        float(loss), float(opt_state[0].master[0])
-        synth_img_s = batch * n_done / (time.perf_counter() - t0)
-    except Exception as e:  # never lose the data number to this
-        _note(f"synthetic comparison failed: {type(e).__name__}: {e}")
-
-    out = {
-        "metric": _metric_name,
-        "value": round(data_img_s, 2),
-        "unit": "img/s",
-        "backend": backend,
-        "vs_baseline": round(data_img_s / BASELINE_IMG_S, 4)
-        if backend == "tpu" else None,
-        "batch": batch, "iters": n_done, "image": image,
-        "data": data_spec if data_spec == "synth" else root,
-        "data_workers": workers,
-        "input_wait_ms": {"mean": round(wait_mean, 3),
-                          "p50": round(pct(0.50), 3),
-                          "p95": round(pct(0.95), 3)},
-        "input_wait_frac": round(
-            wait_mean / max(dt / n_done * 1e3, 1e-9), 4),
-    }
-    if stem != "conv":
-        out["stem"] = stem
-    if applied_flags:
-        out["xla_flags"] = applied_flags
-    if synth_img_s:
-        out["synthetic_percall_img_s"] = round(synth_img_s, 2)
-        out["data_vs_synthetic"] = round(data_img_s / synth_img_s, 4)
-    if throttle_ms:
-        out["throttle_ms"] = throttle_ms
-    if databench:
-        out["databench"] = db_out
-        out["host_pipeline_img_s"] = databench["value"]
-    if _TELEM.get("path"):
-        out["telemetry"] = _TELEM["path"]
-        from apex_tpu.prof.metrics import SCHEMA_VERSION
-        out["telemetry_schema"] = SCHEMA_VERSION
-
-    if _TELEM.get("logger") is not None:
-        lg = _TELEM["logger"]
-        lg.log_step(n_done, steps=n_done, step_ms=dt / n_done * 1e3,
-                    throughput=data_img_s, unit="img/s", loss=loss,
-                    input_wait_ms=round(wait_mean, 3),
-                    loss_scale=amp_state[0].scale, phase="data_percall")
-        if synth_img_s:
-            # no input_wait_ms here: the fixed-batch arm HAS no input
-            # pipeline, and a 0.0 record would dilute the starvation
-            # verdict the report derives over wait-carrying records
-            lg.log_step(n_done, steps=n_done,
-                        step_ms=batch * n_done / synth_img_s / n_done
-                        * 1e3,
-                        throughput=synth_img_s, unit="img/s",
-                        phase="synthetic_percall")
-        lg.log_amp(handle.scalers[0], amp_state[0])
-        lg.log_compiles()
-        lg.log_memory()
-        # r13 SLO feed: the data arm's per-step time and input-bound
-        # share are exactly what an input_wait_share rule watches
-        _slo_observe("step_ms", dt / n_done * 1e3)
-        _slo_observe("input_wait_share", out["input_wait_frac"])
-        if _TELEM.get("slo") is not None:
-            out["slo"] = _TELEM["slo"].summary()
-        _close_telemetry()
-    print(json.dumps(_stamp(out)))
-    _traj(out)
-
-
-def _run_zero_arm(*, mode, backend, batch, iters, image, stem,
-                  applied_flags) -> None:
-    """The --zero measurement (r11): the RN50 O2 train step over a
-    ``data`` mesh of every local device, compiled through
-    ``compile_step_with_plan`` — ``mode="zero"`` shards the fp32
-    (master, m, v) flat buffers 1/n per device (psum_scatter grads ->
-    sharded LAMB -> bf16 all_gather, the weight-update-sharding
-    pipeline), ``mode="ddp"`` is the replicated baseline on the SAME
-    mesh (flat-grad psum + replicated FusedLAMB). Emits THE one JSON
-    line; the telemetry sidecar carries the sharding-derived
-    ``params+opt_state bytes/device`` record the A/B compare reads."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax import lax
-    from jax.sharding import PartitionSpec as P
-
-    from apex_tpu import amp
-    from apex_tpu.contrib.optimizers import DistributedFusedLAMB
-    from apex_tpu.models import ResNet, resnet50
-    from apex_tpu.optimizers import FusedLAMB
-    from apex_tpu.ops import flat as F
-    from apex_tpu.parallel import (DistributedDataParallel, Plan,
-                                   compile_step_with_plan, make_mesh,
-                                   place_with_specs)
-
-    global _metric_name
-    n = len(jax.devices())
-    mesh = make_mesh({"data": n})
-    _metric_name += f"_{mode}{n}dev"
-    on_tpu = backend == "tpu"
-    if batch % n:
-        batch = ((batch + n - 1) // n) * n   # global batch must shard
-
-    sync_bn = "data" if n > 1 else None
-    if on_tpu:
-        model = resnet50(stem=stem, bn_axis_name=sync_bn)
-    else:
-        # width 32 (not the plain smoke's 8): the ZeRO table aligns
-        # segments to n*128, and at width 8 the alignment padding
-        # dominates the flat store — the tracked-bytes A/B would
-        # measure padding, not the sharding. At width 32 waste stays
-        # <25% of the buffer and the (n-1)/n state drop shows through.
-        model = ResNet(block_sizes=(1, 1), bottleneck=True,
-                       num_classes=10, width=32, stem=stem,
-                       bn_axis_name=sync_bn)
-    params, bn_state = model.init(jax.random.key(0))
-    _, handle = amp.initialize(opt_level="O2", verbosity=0)
-    amp_state = handle.init_state()
-    half = handle.policy.cast_model_dtype
-    num_classes = model.num_classes
-
-    rs = np.random.RandomState(0)
-    x = jnp.asarray(rs.randn(batch, image, image, 3), half)
-    y = jnp.asarray(rs.randint(0, num_classes, batch), jnp.int32)
-
-    if mode == "zero":
-        opt = DistributedFusedLAMB(params, lr=1e-3, axis_name="data",
-                                   num_shards=n, model_dtype=half)
-        table = opt.table
-        opt_state = opt.init_state()
-        state_spec = opt.state_pspec()
-    else:
-        opt = FusedLAMB(params, lr=1e-3)
-        table = opt._tables[0]
-        opt_state = opt.init_state()
-        state_spec = P()
-        ddp = DistributedDataParallel(axis_name="data")
-    del params
-
-    def _loss_fn(flat_params, bn_state, amp_state, x, y):
-        # same O2 idiom as the plain bench: differentiate wrt ONE flat
-        # buffer, the half cast fused into unflatten
-        p_half = F.unflatten(flat_params, table, dtype=half)
-        logits, new_st = model.apply(p_half, bn_state, x, training=True)
-        from apex_tpu.contrib.xentropy import select_label_logits
-        with jax.named_scope("head"):   # the model's own scope: prof.SCOPES
-            logits = logits.astype(jnp.float32)
-            logp = jax.nn.log_softmax(logits)
-            loss = -jnp.mean(select_label_logits(logp, y))
-        return handle.scale_loss(loss, amp_state), (loss, new_st)
-
-    if mode == "zero":
-        def step(opt_state, bn_state, amp_state, x, y):
-            # the compressed allgather (gather_dtype=bf16 mirrors the
-            # reference's dwu_e5m2_allgather knob): full params exist
-            # only transiently, grads come back as ONE flat buffer
-            gathered = lax.all_gather(
-                opt_state.master.astype(opt.gather_dtype), "data",
-                tiled=True)
-            fg, (loss, new_bn) = jax.grad(_loss_fn, has_aux=True)(
-                gathered, bn_state, amp_state, x, y)
-            fg, found_inf = handle.unscale(fg.astype(jnp.float32),
-                                           amp_state)
-            # any device's overflow must skip the step on EVERY shard
-            # (and keep the scaler state fleet-consistent)
-            found_inf = jnp.minimum(lax.psum(found_inf, "data"), 1.0)
-            new_opt, _ = opt.shard_step(opt_state, fg,
-                                        found_inf=found_inf > 0)
-            new_amp = handle.update(amp_state, found_inf)
-            return new_opt, new_bn, new_amp, lax.pmean(loss, "data")
-    else:
-        def step(opt_state, bn_state, amp_state, x, y):
-            fg, (loss, new_bn) = jax.grad(_loss_fn, has_aux=True)(
-                opt_state[0].master, bn_state, amp_state, x, y)
-            fg = ddp.average_gradients(fg)   # ONE psum of ONE buffer
-            fg, found_inf = handle.unscale(fg, amp_state)
-            new_opt = opt.apply_update(opt_state, [fg],
-                                       found_inf=found_inf)
-            new_amp = handle.update(amp_state, found_inf)
-            return new_opt, new_bn, new_amp, lax.pmean(loss, "data")
-
-    def train_n(opt_state, bn_state, amp_state, x, y):
-        def body(i, carry):
-            o, b, a, _ = carry
-            return step(o, b, a, x, y)
-        return jax.lax.fori_loop(
-            0, iters, body,
-            (opt_state, bn_state, amp_state,
-             jnp.asarray(0.0, jnp.float32)))
-
-    plan = Plan(mesh=mesh,
-                in_specs=(state_spec, P(), P(), P("data"), P("data")),
-                out_specs=(state_spec, P(), P(), P()),
-                donate_argnums=(0, 1, 2),
-                # all_gather outputs cannot be proven replicated by the
-                # vma checker; pallas kernels may sit inside the body
-                check_vma=False)
-    compiled_n = compile_step_with_plan(train_n, plan)
-
-    if mode == "zero":
-        # start from the DECLARED placement (1/n shard per device) so
-        # warmup doesn't time an initial reshard and donation holds
-        opt_state = place_with_specs(opt_state, mesh, state_spec)
-    x, y = place_with_specs((x, y), mesh, (P("data"), P("data")))
-
-    _note(f"{mode} arm: {n}-device mesh, compiling (plan lowering="
-          f"{plan.lowering()})")
-    opt_state, bn_state, amp_state, loss = compiled_n(
-        opt_state, bn_state, amp_state, x, y)
-    master0 = opt_state.master if mode == "zero" else opt_state[0].master
-    float(loss), float(master0[0])
-    _telem_event("warmup_done")
-
-    # r17: async snapshot arm — generation 0 is the post-warmup state;
-    # the staging copies happen here (async dispatch), the host fetch +
-    # sharded write ride the writer thread UNDER the timed region
-    # below, so the async contract is measured, not assumed. Staging
-    # also decouples the snapshot from the donation of opt/amp state
-    # into the timed dispatch.
-    snap_dir = _snapshot_arg()
-    snap_writer = None
-    if snap_dir:
-        import dataclasses as _dc
-
-        from apex_tpu import runtime as _rt
-
-        def _snap_payload(opt_state, amp_state):
-            opt_sd = (opt.state_dict_arrays(opt_state)
-                      if mode == "zero"
-                      else {"master": opt_state[0].master})
-            return {"opt": opt_sd,
-                    "scaler": {f.name: getattr(amp_state[0], f.name)
-                               for f in _dc.fields(amp_state[0])}}
-        snap_writer = _rt.SnapshotWriter(snap_dir,
-                                         logger=_TELEM.get("logger"))
-        snap_writer.submit(0, 0, _snap_payload(opt_state, amp_state))
-
-    _note(f"{mode} arm: timing {iters} fori_loop iters at global "
-          f"batch {batch}")
-    t0 = time.perf_counter()
-    opt_state, bn_state, amp_state, loss = compiled_n(
-        opt_state, bn_state, amp_state, x, y)
-    master0 = opt_state.master if mode == "zero" else opt_state[0].master
-    float(loss), float(master0[0])
-    dt = time.perf_counter() - t0
-    img_s = batch * iters / dt
-
-    if snap_writer is not None:
-        # generation `iters`: the resumable end state of the timed run
-        snap_writer.submit(iters, iters,
-                           _snap_payload(opt_state, amp_state))
-        snap_writer.close()   # drains both generations
-
-    from apex_tpu.prof.metrics import tracked_bytes_per_device
-    opt_bytes = tracked_bytes_per_device(opt_state)
-    out = {
-        "metric": _metric_name,
-        "value": round(img_s, 2),
-        "unit": "img/s",
-        "backend": backend,
-        "vs_baseline": round(img_s / BASELINE_IMG_S, 4) if on_tpu
-        else None,
-        "batch": batch, "iters": iters, "image": image,
-        "devices": n, "zero": mode,
-        "ms_per_step": round(dt / iters * 1e3, 2),
-        "opt_state_bytes_per_device": opt_bytes,
-        "loss": round(float(loss), 4),
-    }
-    if stem != "conv":
-        out["stem"] = stem
-    if applied_flags:
-        out["xla_flags"] = applied_flags
-    if snap_writer is not None:
-        out["snapshots"] = snap_writer.written
-        out["snapshot_dir"] = snap_dir
-    if _TELEM.get("path"):
-        out["telemetry"] = _TELEM["path"]
-        from apex_tpu.prof.metrics import SCHEMA_VERSION
-        out["telemetry_schema"] = SCHEMA_VERSION
-    if _TELEM.get("logger") is not None:
-        lg = _TELEM["logger"]
-        lg.log_step(iters, steps=iters, step_ms=dt / iters * 1e3,
-                    throughput=img_s, unit="img/s", loss=loss,
-                    loss_scale=amp_state[0].scale, phase=mode)
-        lg.log_amp(handle.scalers[0], amp_state[0])
-        lg.log_compiles()
-        lg.log_memory()
-        # the r11 acceptance record: per-device optimizer-state bytes
-        # derived from the state arrays' REAL shardings
-        lg.log_state_bytes(opt_state=opt_state, label=mode)
-        _slo_observe("step_ms", dt / iters * 1e3)
-        if _TELEM.get("slo") is not None:
-            out["slo"] = _TELEM["slo"].summary()
-        _close_telemetry()
-    print(json.dumps(_stamp(out)))
-    _traj(out)
-
-
-def bench_defaults() -> dict:
-    """BENCH_DEFAULTS.json (repo root): the measured-best config a plain
-    run uses — ``{"stem": ..., "batch": ...}``; {} when absent."""
-    try:
-        with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                               "BENCH_DEFAULTS.json")) as f:
-            return json.load(f)
-    except FileNotFoundError:
-        return {}
-
 
 def build_train_step(model, params, handle, *, lr=1e-3):
-    """The headline step — RN50 O2 + FusedLAMB — as ``main`` times it and
-    ``chip_smoke.py`` drives it. Call under ``host_init()`` (the
+    """FusedLAMB over flat fp32 masters on the softmax cross-entropy of
+    ``model``'s logits, under ``handle`` (AMP O2: the parameters in its
+    half dtype, dynamic loss scale). Call under ``host_init()`` (the
     optimizer flattens real arrays). Returns ``(opt, loss_fn,
-    train_step)``; ``train_step(opt_state, bn_state, amp_state, x, y)
+    train_step)``; ``loss_fn(params, bn_state, x, y) -> (loss,
+    bn_state)`` and ``train_step(opt_state, bn_state, amp_state, x, y)
     -> (opt_state, bn_state, amp_state, loss)``."""
     import jax
     import jax.numpy as jnp
 
-    from apex_tpu.ops import flat as F
+    from apex_tpu.contrib.xentropy import select_label_logits
     from apex_tpu.optimizers import FusedLAMB
+    from apex_tpu.train_step import build_step
 
-    half = handle.policy.cast_model_dtype
     opt = FusedLAMB(params, lr=lr)
-    table = opt._tables[0]
 
-    def loss_fn(master, bn_state, amp_state, x, y):
-        # Differentiate wrt the FLAT fp32 master buffer: the bf16 cast is
-        # one fused convert (unflatten's dtype arg) and the grad comes
-        # back as one flat fp32 buffer — per-leaf casts/flattens cost
-        # ~15 ms/step of XLA per-op overhead at RN50's 161 params
-        # (docs/PERF.md, r03). This is the O2 master-weight pattern
-        # (_process_optimizer.py:321) with the copy fused into autodiff.
-        p_half = F.unflatten(master, table, dtype=half)
-        logits, new_st = model.apply(p_half, bn_state, x, training=True)
-        from apex_tpu.contrib.xentropy import select_label_logits
+    def loss_fn(p_half, bn_state, x, y):
+        logits, new_bn = model.apply(p_half, bn_state, x, training=True)
         with jax.named_scope("head"):   # the model's own scope: prof.SCOPES
             logits = logits.astype(jnp.float32)
             logp = jax.nn.log_softmax(logits)
             loss = -jnp.mean(select_label_logits(logp, y))
-        return handle.scale_loss(loss, amp_state), (loss, new_st)
+        return loss, new_bn
 
-    def train_step(opt_state, bn_state, amp_state, x, y, census=None):
-        fg, (loss, new_bn) = jax.grad(loss_fn, has_aux=True)(
-            opt_state[0].master, bn_state, amp_state, x, y)
-        fg, found_inf = handle.unscale(fg, amp_state)
-        new_opt = opt.apply_update(opt_state, [fg], found_inf=found_inf)
-        if census is not None:
-            # r09 numerics: per-parameter nonfinite census, carried so
-            # the host can name the culprit params of the LAST skipped
-            # step without any per-step sync (prof/numerics.py)
-            new_amp, new_census = handle.update_with_census(
-                amp_state, found_inf, fg, census, table=table)
-            return new_opt, new_bn, new_amp, new_census, loss
-        new_amp = handle.update(amp_state, found_inf)
-        return new_opt, new_bn, new_amp, loss
+    body = build_step(opt, loss_fn, half=handle.policy.cast_model_dtype,
+                      handle=handle)
+
+    def train_step(opt_state, bn_state, amp_state, x, y):
+        opt_state, amp_state, loss, bn_state = body(
+            opt_state, amp_state, bn_state, x, y)
+        return opt_state, bn_state, amp_state, loss
 
     return opt, loss_fn, train_step
-
-
-def main() -> None:
-    cpu_devs = os.environ.get("BENCH_CPU_DEVICES")
-    if cpu_devs:
-        # forced multi-device CPU mesh (the plan/ZeRO smoke and the
-        # offline --zero A/B): the explicit CPU request, pinned before
-        # any backend init
-        from apex_tpu.parallel import pin_cpu_devices
-        pin_cpu_devices(int(cpu_devs))
-    # the strict device gate (the chip, or the CPU that was asked for)
-    # + armed XLA A/B knobs, which must land before backend init; a
-    # plain run applies nothing (utils/xla_flags.py discipline)
-    from apex_tpu.utils import setup_host_backend, xla_flags
-    backend = setup_host_backend()
-    applied_flags = xla_flags.armed_flags()   # labels the A/B arm's line
-    _note(f"backend={backend}")
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from apex_tpu import amp
-    from apex_tpu.models import resnet50, ResNet
-
-    global _metric_name
-    on_tpu = backend == "tpu"
-    if not on_tpu:
-        _metric_name = "tiny_resnet_O2_fusedlamb_train_throughput_cpu_smoke"
-    # default batch 384: the window-1 on-chip A/B measured 2156.7 img/s
-    # at 384 vs 2130.3 at 256 and 2145.9 at 512 (BENCH_r04_batch*.json)
-    # — the HBM-bound step gets ~+1.2% from the larger dispatch grain,
-    # and 384 was the best of the three measured sizes
-    # BENCH_DEFAULTS.json carries the measured-best config so a plain
-    # `python bench.py` runs it; env vars still override.
-    defaults = bench_defaults()
-    if on_tpu and defaults.get("bn_variadic_reduce") and \
-            "APEX_BN_VARIADIC_REDUCE" not in os.environ:
-        # an A/B measured the variadic BN-moments shape faster on
-        # THIS CHIP (split-sums is the shipped default after the r5 A/B
-        # went 2169 vs 1868 img/s the other way); honor the measured
-        # winner for the plain TPU run. The legacy bn_split_sums key is
-        # a no-op now that split-sums IS the default.
-        os.environ["APEX_BN_VARIADIC_REDUCE"] = "1"
-    batch = int(os.environ.get(
-        "BENCH_BATCH", defaults.get("batch", 384) if on_tpu else 8))
-    # 100 timed iterations (was 20): short windows understate steady
-    # state ~3.6% — measured 2240.9 img/s at 100 iters and 2251.7 at
-    # 250 vs 2174.4 at 20 on the same chip/config (the warmup edge and
-    # dispatch ramp amortize out; the reference's own img/s meter also
-    # averages long print windows, main_amp.py:390-398). 100 keeps the
-    # whole bench (2 timing modes + compile + init) well inside a
-    # caller's time limit where 250 starts to crowd it.
-    iters = int(os.environ.get("BENCH_ITERS", 100 if on_tpu else 2))
-    image = int(os.environ.get("BENCH_IMAGE", 224 if on_tpu else 32))
-
-    # BENCH_STEM=space_to_depth opts into the exact stem rewrite
-    # (models/resnet.py) once it has proven faster on-chip. The rewrite
-    # only engages for even spatial sizes (odd sizes silently fall back
-    # to the conv stem) — refuse the mislabeled A/B rather than record it.
-    stem = os.environ.get(
-        "BENCH_STEM", defaults.get("stem", "conv") if on_tpu
-        else "conv")
-    if stem == "space_to_depth" and image % 2:
-        # ValueError (not SystemExit) so the __main__ handler still emits
-        # the one mandatory JSON line, carrying this as its error
-        raise ValueError(
-            f"BENCH_STEM=space_to_depth requires an even BENCH_IMAGE "
-            f"(got {image}): odd sizes run the plain conv stem and the "
-            f"A/B label would lie")
-    # telemetry armed BEFORE model build/lowering so the compile tracker
-    # sees the step's (re)compiles; all per-step cost stays zero (the
-    # timed region below logs nothing)
-    zero_mode = _zero_arg()
-    _arm_telemetry(backend, {"metric": _metric_name, "batch": batch,
-                             "iters": iters, "image": image, "stem": stem,
-                             "numerics": _numerics_arg(),
-                             "fleet": _fleet_arg(),
-                             "zero": zero_mode})
-
-    if zero_mode:
-        # r11 distributed-optimizer arm: self-contained (its own model/
-        # optimizer over a data mesh), never touches the plain path
-        _run_zero_arm(mode=zero_mode, backend=backend, batch=batch,
-                      iters=iters, image=image, stem=stem,
-                      applied_flags=applied_flags)
-        return
-
-    ph = _phase_begin("model_build")
-    if on_tpu:
-        model = resnet50(stem=stem)
-    else:  # CI smoke config
-        model = ResNet(block_sizes=(1, 1), bottleneck=True, num_classes=10,
-                       width=8, stem=stem)
-
-    # Build ALL initial state on the host CPU backend, then ship it in
-    # one bulk device_put (utils.host_init: hundreds of per-leaf init
-    # ops would each be their own compile on the chip)
-    from apex_tpu.utils import host_init, ship
-    with host_init():
-        params, bn_state = model.init(jax.random.key(0))
-
-        _, handle = amp.initialize(opt_level="O2", verbosity=0)
-        amp_state = handle.init_state()
-        half = handle.policy.cast_model_dtype
-
-        opt, _loss_fn, train_step = build_train_step(model, params, handle)
-        table = opt._tables[0]
-        opt_state = opt.init_state()
-        num_classes = model.num_classes
-
-        rs = np.random.RandomState(0)
-        x = jnp.asarray(rs.randn(batch, image, image, 3), half)
-        y = jnp.asarray(rs.randint(0, num_classes, batch), jnp.int32)
-    _note("host-side init done; shipping state to the default device")
-    opt_state, bn_state, amp_state, x, y = ship(
-        (opt_state, bn_state, amp_state, x, y))
-    _note("state on device")
-    _phase_end(ph)
-
-    data_spec = _data_arg()
-    if data_spec:
-        _run_data_arm(data_spec=data_spec, backend=backend, batch=batch,
-                      iters=iters, image=image, stem=stem,
-                      train_step=train_step, opt_state=opt_state,
-                      bn_state=bn_state, amp_state=amp_state,
-                      handle=handle, num_classes=num_classes,
-                      applied_flags=applied_flags, half=half)
-        return
-
-    # r09 numerics arm: carry the overflow-provenance census through the
-    # fori loop (None = off: the carry slot is an empty pytree and the
-    # compiled program is bit-identical to the plain bench)
-    numerics_on = _numerics_arg()
-    num_meta = census0 = None
-    if numerics_on:
-        from apex_tpu.prof import numerics as _NU
-        num_meta = _NU.tree_meta(table)
-        census0 = _NU.empty_census(num_meta.n)
-
-    # N steps inside ONE dispatch: per-call host overhead lands on the
-    # warmup call, and the timed call is pure device time.
-    # Donation updates the ~3x-model-size state in place (reference
-    # analog: Apex mutates params in place).
-    @partial(jax.jit, donate_argnums=(0, 1, 2), static_argnums=(5,))
-    def train_n(opt_state, bn_state, amp_state, x, y, n, census=None):
-        def body(i, carry):
-            o, b, a, c, _ = carry
-            if c is None:
-                o, b, a, l = train_step(o, b, a, x, y)
-                return o, b, a, None, l
-            return train_step(o, b, a, x, y, c)
-        loss0 = jnp.asarray(0.0, jnp.float32)
-        return jax.lax.fori_loop(
-            0, n, body, (opt_state, bn_state, amp_state, census, loss0))
-
-    _note("model/optimizer built; lowering")
-    ph = _phase_begin("lower_compile")
-    compiled = train_n.lower(opt_state, bn_state, amp_state, x, y,
-                             iters, census0).compile()
-    _phase_end(ph)
-    _note("compiled")
-    _telem_event("compiled")
-    step_flops = None
-    try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        # HloCostAnalysis counts a while-loop body ONCE (trip count is not
-        # modeled), so this is already per-step — do not divide by iters.
-        step_flops = float((ca or {}).get("flops", 0.0)) or None
-    except Exception:
-        pass
-
-    ph = _phase_begin("warmup")
-    opt_state, bn_state, amp_state, census, loss = jax.block_until_ready(
-        compiled(opt_state, bn_state, amp_state, x, y, census0))
-    _phase_end(ph)
-    _note(f"warmup call done; timing {iters} fori_loop iters at "
-          f"batch {batch}")
-
-    _telem_event("warmup_done")
-    ph = _phase_begin("timed_fori", steps=iters)
-    t0 = time.perf_counter()
-    opt_state, bn_state, amp_state, census, loss = jax.block_until_ready(
-        compiled(opt_state, bn_state, amp_state, x, y, census))
-    dt = time.perf_counter() - t0
-    _phase_end(ph)
-    _slo_observe("step_ms", dt / iters * 1e3)
-
-    # analytic train FLOPs/img = 3x fwd (models.resnet.analytic_flops) —
-    # within 2% of XLA's cost analysis for RN50@224, so MFU is honest.
-    from apex_tpu.models.resnet import analytic_flops
-    analytic_flops_img = 3.0 * analytic_flops(model, image) if on_tpu \
-        else None
-
-    # r09 numerics post-run pass (outside every timed region): the
-    # precision-coverage audit (abstract trace — free), one sampled
-    # underflow census of the current grads (one extra untimed step),
-    # and — if the timed window actually skipped — the carried census
-    # resolved into culprit paths. Never lets numerics cost the line.
-    numerics_out: dict = {}
-    if numerics_on:
-        ph = _phase_begin("numerics_census")
-        try:
-            from apex_tpu.prof import coverage as _COV
-            from apex_tpu.prof import numerics as _NU
-            cov = _COV.audit_fn(train_step, opt_state, bn_state,
-                                amp_state, x, y)
-            numerics_out["half_op_share"] = round(cov.half_op_share, 4)
-            numerics_out["half_flop_share"] = round(
-                cov.half_flop_share, 4)
-            if cov.cf_fp32_only:
-                numerics_out["cf_fp32_only"] = list(cov.cf_fp32_only)
-
-            @jax.jit
-            def _underflow_probe(opt_state, bn_state, amp_state, x, y):
-                fg, _ = jax.grad(_loss_fn, has_aux=True)(
-                    opt_state[0].master, bn_state, amp_state, x, y)
-                fg, _ = handle.unscale(fg, amp_state)
-                return _NU.underflow_census(fg, table=table)
-
-            ucensus = _underflow_probe(opt_state, bn_state, amp_state,
-                                       x, y)
-            usum = _NU.underflow_summary(num_meta, ucensus)
-            numerics_out["tiny_frac"] = usum["tiny_frac"]
-            numerics_out["ftz_frac"] = usum["ftz_frac"]
-            overflows = int(amp_state[0].overflow_count)
-            numerics_out["overflow_count"] = overflows
-            if overflows and int(census.step) >= 0:
-                numerics_out["culprits"] = _NU.culprit_table(num_meta,
-                                                             census)
-            if _TELEM.get("logger") is not None:
-                lg = _TELEM["logger"]
-                lg.log_coverage(cov, label="bench_train_step")
-                lg.log_numerics(num_meta, ucensus, step=iters)
-                if numerics_out.get("culprits"):
-                    lg.log_overflow(num_meta, census,
-                                    loss_scale=amp_state[0].scale)
-            _note(f"numerics: half_op_share "
-                  f"{numerics_out['half_op_share']}, tiny_frac "
-                  f"{numerics_out['tiny_frac']}, overflows {overflows}")
-        except Exception as e:
-            _note(f"numerics pass failed: {type(e).__name__}: {e}")
-            numerics_out.setdefault("error",
-                                    f"{type(e).__name__}: {e}")
-        _phase_end(ph)
-
-    def result_line(img_s: float) -> dict:
-        """THE result-line builder."""
-        out = {
-            "metric": _metric_name,
-            "value": round(img_s, 2),
-            "unit": "img/s",
-            "backend": backend,
-            # the baseline is a V100 GPU number: a CPU-smoke ratio
-            # against it is meaningless and has been misread as a win
-            # (VERDICT r3 Weak #6) — null unless we actually ran on TPU
-            "vs_baseline": round(img_s / BASELINE_IMG_S, 4)
-            if on_tpu else None,
-        }
-        if stem != "conv":  # label A/B runs of the stem rewrite
-            out["stem"] = stem
-        if applied_flags:   # label XLA-knob A/B arms (self-describing)
-            out["xla_flags"] = applied_flags
-        out["batch"] = batch
-        if on_tpu and analytic_flops_img:
-            from apex_tpu.prof import chip_peak
-            out["mfu"] = round(analytic_flops_img * img_s
-                               / chip_peak().bf16_flops_per_s, 4)
-        if on_tpu and step_flops:
-            out["step_tflops"] = round(step_flops / 1e12, 3)
-        if numerics_out:
-            out["numerics"] = numerics_out
-        if _TELEM.get("path"):
-            out["telemetry"] = _TELEM["path"]
-            from apex_tpu.prof.metrics import SCHEMA_VERSION
-            out["telemetry_schema"] = SCHEMA_VERSION
-        return out
-
-    fori_img_s = batch * iters / dt
-    if _TELEM.get("logger") is not None:
-        lg = _TELEM["logger"]
-        # ONE interval record for the fused fori dispatch (iters steps in
-        # one execute — per-step records don't exist inside the loop);
-        # loss/scale go in as device refs, fetched at this flush only
-        lg.log_step(iters, steps=iters, step_ms=dt / iters * 1e3,
-                    throughput=fori_img_s, unit="img/s", loss=loss,
-                    loss_scale=amp_state[0].scale, phase="fori")
-        lg.log_amp(handle.scalers[0], amp_state[0])
-        lg.log_compiles()
-        lg.log_memory()
-        lg.flush()
-        try:     # r13 SLO feed: the skip-rate budget (one host fetch,
-            # outside the timed region — the counters flush anyway)
-            sc, ov = int(amp_state[0].step_count), \
-                int(amp_state[0].overflow_count)
-            if sc:
-                _slo_observe("skip_rate", ov / sc)
-        except Exception:
-            pass
-        if _fleet_arg():
-            # r10 fleet probe: one gather, OUTSIDE every timed region
-            # (the fori dispatch above logged nothing); never lets the
-            # probe cost the bench its JSON line
-            ph = _phase_begin("fleet_probe")
-            try:
-                from apex_tpu.prof import fleet as _FL
-                _FL.FleetProbe(lg, every=1).observe(
-                    iters, dt / iters * 1e3)
-            except Exception as e:
-                _note(f"fleet probe failed: {type(e).__name__}: {e}")
-            _phase_end(ph)
-
-    # Per-call timing of the SAME step as a second methodology: a jitted
-    # single step dispatched iters times with one fetch at the end — the
-    # async dispatch pipeline the reference example itself measures
-    # (main_amp.py's per-iteration wall clock with async CUDA). The r4
-    # trace showed the fori_loop variant ~5% SLOWER than this (while-loop
-    # carry copies); report whichever is better, carry both in the JSON.
-    percall_img_s = None
-    if on_tpu:
-        ph = _phase_begin("timed_percall", steps=iters)
-        try:
-            jstep = jax.jit(train_step, donate_argnums=(0, 1, 2))
-            cstep = jstep.lower(opt_state, bn_state, amp_state, x,
-                                y).compile()
-            o, b, a, loss = jax.block_until_ready(
-                cstep(opt_state, bn_state, amp_state, x, y))   # warmup
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                o, b, a, loss = cstep(o, b, a, x, y)
-            jax.block_until_ready((o, loss))
-            dt_pc = time.perf_counter() - t0
-            percall_img_s = batch * iters / dt_pc
-            _note(f"percall: {dt_pc / iters * 1e3:.1f} ms/step vs "
-                  f"foriloop {dt / iters * 1e3:.1f}")
-        except Exception as e:   # never lose the fori number to this
-            _note(f"percall timing failed: {type(e).__name__}: {e}")
-        _phase_end(ph)
-
-    out = result_line(max(fori_img_s, percall_img_s or 0.0))
-    if percall_img_s is not None:
-        out["fori_img_s"] = round(fori_img_s, 2)
-        out["percall_img_s"] = round(percall_img_s, 2)
-    if _TELEM.get("logger") is not None:
-        try:
-            if percall_img_s is not None:
-                _TELEM["logger"].log_step(
-                    iters, steps=iters, step_ms=dt_pc / iters * 1e3,
-                    throughput=percall_img_s, unit="img/s",
-                    phase="percall")
-                _slo_observe("step_ms", dt_pc / iters * 1e3)
-            _close_telemetry()
-        except Exception as e:
-            _note(f"telemetry close failed: {type(e).__name__}: {e}")
-    if _TELEM.get("slo") is not None:
-        out["slo"] = _TELEM["slo"].summary()
-    print(json.dumps(_stamp(out)))
-    _traj(out)
-
-
-if __name__ == "__main__":
-    try:
-        main()
-    except Exception as e:  # the error line, then a failing exit code
-        traceback.print_exc()
-        if _TELEM.get("logger") is not None:
-            try:   # a dying run still leaves its telemetry record
-                _TELEM["logger"].event(
-                    "error", error=f"{type(e).__name__}: {e}")
-                _close_telemetry()
-            except Exception:
-                pass
-        print(json.dumps(_stamp({
-            "metric": _metric_name,
-            "value": 0.0, "unit": "img/s", "vs_baseline": 0.0,
-            "error": f"{type(e).__name__}: {e}"})))
-        sys.exit(1)

@@ -41,8 +41,8 @@ _ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(_ROOT, "tools"))   # lm_bench, _perf_common
 sys.path.insert(0, _ROOT)                          # apex_tpu, bench
 
-# The dense LM of tools/lm_bench.py and tools/serve_bench.py at their
-# published widths (docs/PERF.md): d1024, 8 heads x 128, V 32768, S 4096.
+# The dense LM of tools/serve_bench.py at its published widths
+# (docs/PERF.md): d1024, 8 heads x 128, V 32768, S 4096.
 FULL = dict(
     vocab=32768, dim=1024, heads=8, layers=8, seq=4096, batch=8,
     head_chunk=8192, steps=3,
@@ -393,7 +393,7 @@ class Smoke:
             attn_impl="fast", head_chunk=c["head_chunk"], **kw)
 
     def _lm_arm(self, lm, params, devices, *, zero=False):
-        """Build, place and compile the tools/lm_bench.py step over
+        """Build, place and compile the dense-LM step (tools/lm_bench) over
         ``devices`` from host-side ``params``; returns what the checks
         read."""
         import jax
@@ -486,14 +486,17 @@ class Smoke:
         import jax
         import jax.numpy as jnp
         import numpy as np
-        from bench import bench_defaults, build_train_step
+        from bench import build_train_step
 
         from apex_tpu import amp
         from apex_tpu.models import ResNet, resnet50
         from apex_tpu.utils import host_init, ship
 
         r = self.cfg["rn50"]
-        stem = bench_defaults().get("stem", "conv")
+        # the measured-best stem, where the benchmark's configuration
+        # took it from
+        with open(os.path.join(_ROOT, "BENCH_DEFAULTS.json")) as f:
+            stem = json.load(f)["stem"]
         model = (ResNet(block_sizes=(1,), bottleneck=True,
                         num_classes=10, width=8, stem=stem)
                  if self.args.rehearse else resnet50(stem=stem))

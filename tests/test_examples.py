@@ -63,35 +63,6 @@ def test_imagenet_example_real_data(tmp_path):
 
 
 @pytest.mark.slow
-def test_bench_data_arm(tmp_path):
-    """bench.py --data synth: DATABENCH host-pipeline microbench JSON +
-    the BENCH line carrying input-wait accounting and the synthetic
-    comparison arm."""
-    import json
-    db = str(tmp_path / "DATABENCH_test.json")
-    env = dict(os.environ)
-    env.update({
-        "JAX_PLATFORMS": "cpu",
-        "PYTHONPATH": REPO + os.pathsep + env.get("PYTHONPATH", ""),
-        "BENCH_DATABENCH_OUT": db, "BENCH_DATABENCH_BATCH": "32",
-        "BENCH_DATABENCH_CROP": "48", "BENCH_DATABENCH_BATCHES": "2",
-        "BENCH_DATA_PER_CLASS": "8", "BENCH_ITERS": "4",
-    })
-    r = subprocess.run(
-        [sys.executable, "bench.py", "--data", "synth"],
-        capture_output=True, text=True, timeout=600, env=env, cwd=REPO)
-    assert r.returncode == 0, (r.stdout[-1500:], r.stderr[-1500:])
-    line = json.loads(r.stdout.strip().splitlines()[-1])
-    assert line["metric"].endswith("_data")
-    assert line["value"] > 0
-    assert line["input_wait_ms"]["mean"] >= 0
-    assert "synthetic_percall_img_s" in line
-    host = json.loads(open(db).read())
-    assert host["unit"] == "img/s" and host["value"] > 0
-    assert host["crop"] == 48
-
-
-@pytest.mark.slow
 def test_imagenet_example_vit():
     out = _run(["examples/imagenet/main_amp.py", "--arch", "vit_tiny",
                 "--steps-per-epoch", "4", "--batch-size", "8",

@@ -84,8 +84,7 @@ class TestToolsSelfContained:
     --help exercises the module top level including the sys.path
     bootstrap."""
 
-    @pytest.mark.parametrize("tool", ["kernel_bench.py", "lm_bench.py",
-                                      "decode_bench.py",
+    @pytest.mark.parametrize("tool", ["kernel_bench.py", "decode_bench.py",
                                       "perf_probe.py", "../chip_smoke.py",
                                       "trace_top_ops.py", "hlo_audit.py",
                                       "serve_top.py"])
@@ -132,37 +131,6 @@ class TestToolsSelfContained:
         assert r.returncode != 0
         assert "--new must be >= 4" in r.stderr
         assert not r.stdout.strip()          # no JSON line emitted
-
-    @pytest.mark.parametrize("dtype", ["bf16", "f32"])
-    def test_lm_bench_cpu_smoke_both_dtypes(self, dtype, tmp_path):
-        """lm_bench's O2 master-weight pattern (--dtype bf16, the
-        default) and the fp32 escape must both produce a complete JSON
-        line on the CPU smoke config, with the dtype recorded in the
-        metric and the field — pins the r5 plumbing that fixed the
-        fp32-masters-fed-to-the-model bug (and the s4096 OOM)."""
-        import json
-        # BARE_ENV already pins JAX_PLATFORMS=cpu;
-        # no --iters: the CPU smoke path fixes its own iteration count
-        r = subprocess.run(
-            [sys.executable, os.path.join(TOOLS, "lm_bench.py"),
-             "--dtype", dtype],
-            capture_output=True, text=True, timeout=600,
-            cwd=tmp_path, env=BARE_ENV)
-        assert r.returncode == 0, r.stderr[-800:]
-        out = json.loads(r.stdout.strip().splitlines()[-1])
-        want = "bfloat16" if dtype == "bf16" else "float32"
-        assert out["dtype"] == want
-        assert ("_bf16" in out["metric"]) == (dtype == "bf16")
-        assert out["value"] > 0 and out["unit"] == "tokens/s"
-        import math
-        assert math.isfinite(out["loss"])
-        # self-describing rows: head_dim decides flash efficiency on
-        # TPU (the r5 h8/d128 sweep), so every line must record the
-        # head shape in BOTH the fields and the metric key (rows
-        # differing only in --heads must not collide). CPU smoke
-        # config is dim=128, heads=4.
-        assert out["heads"] == 4 and out["head_dim"] == 32
-        assert out["metric"].endswith("_h4d32")
 
 
 class TestHloAudit:

@@ -1,4 +1,4 @@
-"""Shared helpers for the perf tools (perf_probe, lm_bench, bench.py)."""
+"""Shared helpers for the perf tools (perf_probe, decode_bench, serve_bench)."""
 
 from __future__ import annotations
 

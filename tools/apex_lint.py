@@ -3,7 +3,8 @@
 Runs the ``apex_tpu.analysis`` rule registry (docs/ANALYSIS.md) over
 
 - the CANONICAL PROGRAM SET (``apex_tpu/analysis/programs.py``): the
-  bench train step, the lm_bench fori step (plan-compiled; the DDP
+  two benchmark training steps, built through ``apex_tpu.train_step``
+  (the ResNet step; the dense-LM step, plan-compiled: the DDP
   shard_map arm when >1 device is visible — this tool forces a
   2-device CPU mesh for exactly that), the serve engine's
   prefill/commit/decode trio (fused, serialized AND paged — r20),

@@ -12,7 +12,7 @@ routed/completed/shed/redirected counts, routed balance, scale
 events) and the r21 SPEC line (per-replica draft k and accepted-length
 mean when speculative decoding is on). The
 collector is armed by ``serve_bench.py --live``, ``fleet_smoke.py
---live``, or ``bench.py --live``; point this tool at the /metrics
+--live``; point this tool at the /metrics
 port it prints.
 
 Usage:
