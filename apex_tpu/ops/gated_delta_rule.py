@@ -19,10 +19,16 @@ matrix products (the WY form: with ``G`` the running sum of ``g`` inside
 the chunk and ``A[t, s] = beta_t exp(G_t - G_s) k_t.k_s`` for ``s < t``,
 ``T = (I + A)^-1``, ``U = T (beta V)``, ``W = T (beta exp(G) K)``, the
 chunk's writes are ``D = U - W S0``), and only the ``dk x dv`` state
-carried from chunk to chunk by a ``lax.scan``, in float32. It is plain
-``jax.numpy`` and differentiated by JAX: the backward keeps one state a
-*chunk* (the scan's carry), never one a token. There is no Pallas kernel
-yet; the name ``apex_gdn_*`` is kept for one.
+carried from chunk to chunk, in float32. The inverse and the two float32
+products ``U`` and ``W`` are plain ``jax.numpy``, differentiated by JAX
+(the inverse has a backward of its own). The rest, the decayed queries
+``exp(G) q`` and keys ``exp(G_last - G) k``, the masked ``(q k^T) exp(G_t -
+G_s)`` and the loop over chunks, is on the TPU at the kernels' shapes
+(head sizes whole multiples of 128, chunks of 64 or 128) the Pallas pair
+``apex_gdn_fwd`` / ``apex_gdn_bwd`` (``ops/pallas/gated_delta_rule.py``:
+the state and those three never leave VMEM), and anywhere else
+``jax.numpy`` with a ``lax.scan``, the kernels' oracle. Either way the
+backward keeps one state a *chunk*, never one a token.
 
 Shapes: ``q, k [B, H, L, dk]``, ``v [B, H, L, dv]``, ``g, beta [B, H,
 L]``; the result is ``[B, H, L, dv]`` in ``v``'s type. ``q`` and ``k``
@@ -33,10 +39,13 @@ nothing (``beta`` 0, ``g`` 0).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from apex_tpu.ops import dispatch
+from apex_tpu.ops.pallas import gated_delta_rule as _kernels
 
 __all__ = ["gated_delta_rule", "gated_delta_rule_chunked",
            "gated_delta_rule_recurrent"]
@@ -66,6 +75,13 @@ def gated_delta_rule_recurrent(q, k, v, g, beta):
 
 def _mm_hi(x, y):
     return jnp.matmul(x, y, precision=_HI)
+
+
+def _mm(dt, eq, x, y):
+    """A product as the chunked form takes it: both operands in ``dt``,
+    accumulated in float32."""
+    return jnp.einsum(eq, x.astype(dt), y.astype(dt),
+                      preferred_element_type=_F32)
 
 
 def _inv_blocks(a):
@@ -130,9 +146,7 @@ def gated_delta_rule_chunked(q, k, v, g, beta, *, chunk: int = 64):
     def chunks(x):
         return x.reshape(b, h, n, chunk, *x.shape[3:])
 
-    def mm(eq, x, y):
-        return jnp.einsum(eq, x.astype(dt), y.astype(dt),
-                          preferred_element_type=_F32)
+    mm = functools.partial(_mm, dt)
     q, k, v = chunks(q), chunks(k), chunks(v)
     beta = chunks(beta.astype(_F32))[..., None]
     gsum = jnp.cumsum(chunks(g.astype(_F32)), axis=-1)      # G, per chunk
@@ -148,10 +162,27 @@ def gated_delta_rule_chunked(q, k, v, g, beta, *, chunk: int = 64):
     u = jnp.matmul(t_inv, beta * v.astype(_F32), precision=_HI)
     w = jnp.matmul(t_inv, beta * jnp.exp(gsum)[..., None] * k.astype(_F32),
                    precision=_HI)
-    qk = mm("bhnck,bhnsk->bhncs", q, k) * decay
-    q_in = q.astype(_F32) * jnp.exp(gsum)[..., None]
-    last = gsum[..., -1:]
-    k_out = k.astype(_F32) * jnp.exp(last - gsum)[..., None]
+    if dispatch.use_pallas() and _kernels.takes(dk, v.shape[-1], chunk):
+        o = _kernels.chunk_scan(q.astype(dt), k.astype(dt), w, u, gsum)
+    else:
+        qk = mm("bhnck,bhnsk->bhncs", q, k) * decay
+        q_in = q.astype(_F32) * jnp.exp(gsum)[..., None]
+        last = gsum[..., -1:]
+        k_out = k.astype(_F32) * jnp.exp(last - gsum)[..., None]
+        o = _chunk_scan(*(x.astype(dt) for x in (w, u, q_in, k_out, qk)),
+                        jnp.exp(last))
+    return o.reshape(b, h, n * chunk, v.shape[-1])[:, :, :length]
+
+
+def _chunk_scan(w, u, q, k, qk, decay):
+    """The loop over chunks as a ``lax.scan``: what runs off the TPU and at
+    shapes the kernels do not take, and what they are tested against: from
+    the products' operands ``w, q, k [B, H, n, C, dk]``, ``u [B, H, n, C,
+    dv]``, ``qk [B, H, n, C, C]`` (``q`` and ``k`` decayed, ``qk`` masked)
+    and the chunks' decays ``[B, H, n, 1]``, the outputs ``[B, H, n, C,
+    dv]``."""
+    dt = u.dtype
+    mm = functools.partial(_mm, dt)
 
     # the body is recomputed in the backward: the scan keeps its carry, one
     # state a chunk, and its inputs (in the products' type), nothing else
@@ -163,12 +194,10 @@ def gated_delta_rule_chunked(q, k, v, g, beta, *, chunk: int = 64):
         s = s * decay_i[..., None] + mm("bhck,bhcv->bhkv", k_i, d)
         return s, o.astype(dt)
 
-    xs = tuple(jnp.moveaxis(x, 2, 0) for x in (
-        w.astype(dt), u.astype(dt), q_in.astype(dt), k_out.astype(dt),
-        qk.astype(dt), jnp.exp(last)))
-    _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, v.shape[-1]), _F32), xs)
-    o = jnp.moveaxis(o, 0, 2).reshape(b, h, n * chunk, v.shape[-1])
-    return o[:, :, :length]
+    b, h, _, _, dk = w.shape
+    xs = tuple(jnp.moveaxis(x, 2, 0) for x in (w, u, q, k, qk, decay))
+    _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, u.shape[-1]), _F32), xs)
+    return jnp.moveaxis(o, 0, 2)
 
 
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64):

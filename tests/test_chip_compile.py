@@ -254,29 +254,35 @@ def _expert_args():
 
 
 # (id, builder, arguments (trees of (shape, dtype)), temporaries allowed in
-#  GB): the hybrid LM's two new ops, which are jax.numpy and no kernel, at
-#  the published widths and the benchmark cell's 2 x 8192 tokens
+#  GB, the kernels the program has to hold): the hybrid LM's two new ops at
+#  the published widths and the benchmark cell's 2 x 8192 tokens. The delta
+#  rule's chunk-local part is jax.numpy and its loop over chunks the Pallas
+#  pair; the expert layer is jax.numpy and no kernel
 NEW_OPS = [
-    # the chunked scan and its backward: one 128 x 128 state a chunk a
+    # the loop over chunks and its backward: one 128 x 128 state a chunk a
     # head (0.54 GB), never one a token (34 GB)
     ("gated_delta_rule_fwd_bwd-B2H32S8192D128", _delta_rule,
-     [((2, 32, 8192, 128), BF16)] * 3 + [((2, 32, 8192), F32)] * 2, 4.0),
+     [((2, 32, 8192, 128), BF16)] * 3 + [((2, 32, 8192), F32)] * 2, 4.0,
+     ("apex_gdn_fwd", "apex_gdn_bwd")),
     # 512-way routing, the sort, grouped matmuls over 16 held experts
     ("expert_layer_fwd_bwd-N16384E512held16", _expert_layer,
-     _expert_args(), 1.5),
+     _expert_args(), 1.5, ()),
 ]
 
 
-@pytest.mark.parametrize("make_fn,args,temporaries",
-                         [pytest.param(m, a, t, id=i)
-                          for i, m, a, t in NEW_OPS])
+@pytest.mark.parametrize("make_fn,args,temporaries,names",
+                         [pytest.param(m, a, t, n, id=i)
+                          for i, m, a, t, n in NEW_OPS])
 def test_jnp_op_compiles_and_fits_for_v5e(chip, for_chip, make_fn, args,
-                                          temporaries):
+                                          temporaries, names):
     is_spec = lambda x: isinstance(x, tuple) and isinstance(x[0], tuple)
     specs = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
         a[0], a[1], sharding=chip), args, is_leaf=is_spec)
     compiled = jax.jit(make_fn()).lower(*specs).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < temporaries * 1e9
+    text = compiled.as_text()
+    assert [n for n in names
+            if not re.search(rf"%(\w+_)?{n}_*\.\d+ = ", text)] == []
 
 
 def _step(name, skip, **kw):

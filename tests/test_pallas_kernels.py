@@ -424,6 +424,12 @@ def _flash_grad(with_bias):
     return jax.grad(loss, argnums=(0, 1, 2, 3))
 
 
+def _gdn_grad():
+    from apex_tpu.ops.pallas import gated_delta_rule as K
+    return jax.grad(lambda *a: K.chunk_scan(*a).sum(),
+                    argnums=(0, 1, 2, 3, 4))
+
+
 def _site(fn, *args, **kw):
     return lambda: jax.make_jaxpr(lambda *a: fn(*a, **kw))(*args)
 
@@ -440,7 +446,11 @@ def _kernel_sites() -> dict:
     x, wide = _f32(16, 256), _f32(16, L.F_SINGLE_MAX + 1024)
     row, wrow, col = _f32(16), _f32(wide.shape[1]), _f32(256)
     qkv = _f32(2, 256, 128)
+    # two chunks of 64 tokens of two heads of 128
+    gdn = [_f32(1, 2, 2, 64, 128)] * 4 + [_f32(1, 2, 2, 64)]
     return {
+        "apex_gdn_fwd": _site(_gdn_grad(), *gdn),
+        "apex_gdn_bwd": _site(_gdn_grad(), *gdn),
         "apex_mt_scale": _site(P.scale, buf, scale_factor=2.0),
         "apex_mt_axpby": _site(lambda x, y: P.axpby(1.0, x, 2.0, y), buf, buf),
         "apex_mt_l2norm": _site(P.l2norm, buf),
