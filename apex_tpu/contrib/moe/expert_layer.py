@@ -1,6 +1,6 @@
 """A chip's share of a many-expert layer: routing over every expert,
-dropless dispatch to the experts held here, grouped matmuls, a gated
-shared expert.
+dropless dispatch to the experts held here, grouped matmuls, a shared
+expert.
 
 Where :class:`~apex_tpu.contrib.moe.MoEMLP` is the Switch layer (a queue
 of fixed capacity an expert, overflow dropped, a ``[K*N, E]`` one-hot),
@@ -8,8 +8,19 @@ this is the layer of today's many-expert models (hundreds of small SwiGLU
 experts, ten a token, one shared expert every token passes through), as
 one rank of an expert-parallel deployment computes it:
 
-- the router keeps its full width: ``softmax`` over all ``num_experts``
-  in float32, the ``top_k`` largest, their weights divided by their sum;
+- the router keeps its full width and is one of two kinds, named by
+  ``router``. ``"softmax"``: probabilities over all ``num_experts`` in
+  float32, the ``top_k`` largest, their weights divided by their sum; the
+  balancing term is the Switch form over the batch; the shared expert
+  has a sigmoid gate of its own. ``"sigmoid"`` (the bias-balanced router
+  of the DeepSeek-V3 family): scores ``sigmoid(x W_r)`` in float32; the
+  ``top_k`` are chosen on ``score + bias``, where ``bias`` is a float an
+  expert that **no gradient reaches** (the caller's state, moved after
+  each step by :meth:`ExpertLayer.moved_bias`), and weighted by the
+  **unbiased** scores divided by their sum and multiplied by
+  ``routed_scale``; the balancing term is taken a sequence and averaged;
+  the shared expert is ungated. One field, since each of these is the
+  other's consequence in the models that have them;
 - the layer is told ``experts_held = (lo, hi)``, the range of experts
   whose weights it has. The (token, expert) pairs that fall in the range
   are sorted by expert into one buffer of ``dispatch_bound`` rows, each
@@ -33,11 +44,16 @@ one rank of an expert-parallel deployment computes it:
   in the backward (``lax.stop_gradient``); the layer that holds every
   expert differentiates them as written.
 
-``aux`` also carries the router's load-balancing term (Switch form over
-all experts, ``E * sum_e f_e P_e`` with ``f_e`` the share of tokens that
-chose expert ``e`` and ``P_e`` its mean probability), the pairs that fell
-on held experts (``held_pairs``: what the bound is sized from) and the
-load of the fullest held expert over the mean (``load_max_over_mean``).
+``aux`` also carries the router's load-balancing term (softmax: Switch
+form over all experts, ``E * sum_e f_e P_e`` with ``f_e`` the share of
+tokens that chose expert ``e`` and ``P_e`` its mean probability; sigmoid:
+``sum_e f_e P_e`` a sequence of ``T`` tokens, ``f_e = E / (K T)`` times
+the sequence's pairs on ``e`` and ``P_e`` the sequence's mean of ``s_e /
+sum_j s_j``, averaged over the sequences), the pairs that fell on held
+experts (``held_pairs``: what the bound is sized from), the load of the
+fullest held expert over the mean (``load_max_over_mean``) and, for the
+sigmoid router, every expert's pairs (``expert_pairs [E]``: what moves
+the bias).
 """
 
 from __future__ import annotations
@@ -52,6 +68,7 @@ from jax import lax
 __all__ = ["ExpertLayer"]
 
 _F32 = jnp.float32
+ROUTERS = ("softmax", "sigmoid")
 
 
 def _swiglu(x, w_gate, w_up, w_down, eq_in, eq_out):
@@ -70,10 +87,15 @@ class ExpertLayer:
     experts_held: tuple = ()    # (lo, hi) of the experts here; () = all
     shared_ffn: int = 0         # the shared expert's width; 0 = none
     dispatch_bound: int = 0     # rows of the dispatch buffer; 0 = worst case
+    router: str = "softmax"     # the router's kind: the module's text
+    routed_scale: float = 1.0   # the sigmoid router's factor on the weights
 
     tile: ClassVar[int] = 128   # rows a tile: one expert's weights each
 
     def __post_init__(self):
+        if self.router not in ROUTERS:
+            raise ValueError(f"router must be one of {ROUTERS}, got "
+                             f"{self.router!r}")
         lo, hi = self.held
         if not 0 <= lo < hi <= self.num_experts:
             raise ValueError(f"experts_held {self.experts_held} is no range "
@@ -100,7 +122,9 @@ class ExpertLayer:
         if self.shared_ffn:
             s = self.shared_ffn
             p["shared"] = {"w_gate": w(ks[4], d, s), "w_up": w(ks[5], d, s),
-                           "w_down": w(ks[6], s, d), "gate": w(ks[7], d, 1)}
+                           "w_down": w(ks[6], s, d)}
+            if self.router == "softmax":
+                p["shared"]["gate"] = w(ks[7], d, 1)
         return p
 
     def bound(self, n_tokens: int) -> int:
@@ -113,31 +137,67 @@ class ExpertLayer:
         return -(-(pairs + (hi - lo) * (self.tile - 1)) // self.tile) \
             * self.tile
 
-    def route(self, params: dict, x):
-        """``(weights [N, K] float32, experts [N, K], probs [N, E])``."""
+    def route(self, params: dict, x, bias=None):
+        """``(weights [N, K] float32, experts [N, K], scores [N, E])``:
+        the softmax router's probabilities, or the sigmoid router's
+        scores with the experts chosen on ``scores + bias [E]``."""
         logits = jnp.dot(x, params["router"], preferred_element_type=_F32)
+        if self.router == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            _, idx = lax.top_k(scores if bias is None
+                               else scores + lax.stop_gradient(bias),
+                               self.top_k)
+            w = jnp.take_along_axis(scores, idx, axis=-1)
+            return w / jnp.sum(w, axis=-1, keepdims=True) \
+                * self.routed_scale, idx, scores
         probs = jax.nn.softmax(logits, axis=-1)
         w, idx = lax.top_k(probs, self.top_k)
         return w / jnp.sum(w, axis=-1, keepdims=True), idx, probs
 
-    def routed(self, params: dict, x):
-        """The held experts' part of the layer for ``x [N, hidden]``:
-        ``(y [N, hidden] float32, aux)``."""
-        n, d = x.shape
+    @staticmethod
+    def moved_bias(bias, expert_pairs, rate: float):
+        """The sigmoid router's selection bias after a step that sent
+        ``expert_pairs [..., E]`` pairs to each expert: ``rate`` up for an
+        expert below the mean load, ``rate`` down for one above it."""
+        load = expert_pairs.astype(_F32)
+        return bias + rate * jnp.sign(
+            jnp.mean(load, axis=-1, keepdims=True) - load)
+
+    def _balance(self, idx, scores, seqs: int):
+        """``(pairs an expert [E], the load-balancing term)``, the pairs
+        by a fused compare-and-sum: no [pairs, E] one-hot in memory."""
+        n, e_all = scores.shape[0], self.num_experts
+        if self.router == "softmax":
+            counts = jnp.sum(idx[:, :, None] == jnp.arange(e_all), (0, 1))
+            return counts, e_all * jnp.sum(
+                lax.stop_gradient(counts.astype(_F32) / n)
+                * jnp.mean(scores, axis=0))
+        t = n // seqs
+        by_seq = jnp.sum(idx.reshape(seqs, t * self.top_k, 1)
+                         == jnp.arange(e_all), 1)            # [S, E]
+        share = scores / jnp.sum(scores, axis=-1, keepdims=True)
+        f = lax.stop_gradient(by_seq.astype(_F32)
+                              * (e_all / (self.top_k * t)))
+        return jnp.sum(by_seq, 0), jnp.mean(jnp.sum(
+            f * jnp.mean(share.reshape(seqs, t, e_all), axis=1), axis=-1))
+
+    def routed(self, params: dict, x, bias=None):
+        """The held experts' part of the layer for ``x [N, hidden]`` or,
+        in sequences, ``x [S, T, hidden]`` (the sigmoid router balances a
+        sequence): ``(y float32 in x's shape, aux)``. ``bias [E]``: the
+        sigmoid router's selection bias."""
+        shape, d = x.shape, x.shape[-1]
+        x = x.reshape(-1, d)
+        n = x.shape[0]
         k, tm, e_all = self.top_k, self.tile, self.num_experts
         lo, hi = self.held
         held = hi - lo
         rows = self.bound(n)
         with jax.named_scope("moe_route"):              # prof.SCOPES
-            w, idx, probs = self.route(params, x)
+            w, idx, scores = self.route(params, x, bias)
             if held < e_all:    # a share: no reward from held experts only
                 w = lax.stop_gradient(w)
-            # pairs an expert, all experts: a fused compare-and-sum, no
-            # [pairs, E] one-hot in memory
-            counts = jnp.sum(idx[:, :, None] == jnp.arange(e_all), (0, 1))
-            balance = e_all * jnp.sum(
-                lax.stop_gradient(counts.astype(_F32) / n)
-                * jnp.mean(probs, axis=0))
+            counts, balance = self._balance(idx, scores, n // shape[-2])
             # sort the pairs by held expert; pairs on absent experts last
             local = jnp.where((idx >= lo) & (idx < hi), idx - lo, held)
             order = jnp.argsort(local.reshape(-1), stable=True)
@@ -170,23 +230,28 @@ class ExpertLayer:
                          "tmd,tdf->tmf", "tmf,tfd->tmd").reshape(rows, d)
         with jax.named_scope("moe_route"):
             y = jnp.zeros((n, d), _F32).at[tok].add(yb * wb[:, None])
-        return y, {"load_balance_loss": balance,
-                   "overflow_pairs": overflow.astype(jnp.int32),
-                   "held_pairs": jnp.sum(n_e).astype(jnp.int32),
-                   "load_max_over_mean": load}
+        aux = {"load_balance_loss": balance,
+               "overflow_pairs": overflow.astype(jnp.int32),
+               "held_pairs": jnp.sum(n_e).astype(jnp.int32),
+               "load_max_over_mean": load}
+        if self.router == "sigmoid":
+            aux["expert_pairs"] = counts.astype(jnp.int32)
+        return y.reshape(shape), aux
 
     def shared(self, params: dict, x):
-        """The gated shared expert, which every chip computes alike."""
+        """The shared expert, which every chip computes alike (gated
+        under the softmax router, ungated under the sigmoid one)."""
         sp = params["shared"]
         with jax.named_scope("moe_experts"):
-            gate = jax.nn.sigmoid(jnp.dot(x, sp["gate"],
-                                          preferred_element_type=_F32))
+            gate = 1.0 if self.router == "sigmoid" else jax.nn.sigmoid(
+                jnp.dot(x, sp["gate"], preferred_element_type=_F32))
             return gate * _swiglu(x, sp["w_gate"], sp["w_up"], sp["w_down"],
-                                  "nd,df->nf", "nf,fd->nd")
+                                  "...d,df->...f", "...f,fd->...d")
 
-    def apply(self, params: dict, x):
-        """``x [N, hidden]`` -> ``(y [N, hidden] in x's type, aux)``."""
-        y, aux = self.routed(params, x)
+    def apply(self, params: dict, x, bias=None):
+        """``x [N, hidden]`` or ``[S, T, hidden]`` -> ``(y in x's shape
+        and type, aux)``."""
+        y, aux = self.routed(params, x, bias)
         if self.shared_ffn:
             y = y + self.shared(params, x)
         return y.astype(x.dtype), aux

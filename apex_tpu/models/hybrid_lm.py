@@ -1,17 +1,21 @@
-"""A hybrid linear-attention / softmax-attention mixture-of-experts
-decoder: the block of today's Gated DeltaNet hybrids, with the layer
-pattern as data.
+"""A mixture-of-experts decoder whose layers differ: the block of today's
+Gated DeltaNet hybrids and of the latent-attention expert models, with
+the layer pattern as data.
 
     x  = embed[tokens]
-    x += mixer_i(norm0(x))        mixer_i by ``layer_types[i]``:
-                                  "linear" (Gated DeltaNet) or "full"
+    x += mixer_i(norm(x))         mixer_i by ``layer_types[i]``:
+                                  "linear" (Gated DeltaNet), "full"
                                   (gated grouped-query softmax attention)
-    x += experts(norm0(x))        a chip's share of a many-expert layer
-    loss = xent(norm0(x) @ head^T) + aux_coef * sum of the routers'
+                                  or "latent" (latent attention, MLA)
+    x += ffn_i(norm(x))           ffn_i by ``ffn_types[i]``: "experts" (a
+                                  chip's share of a many-expert layer) or
+                                  "dense" (one SwiGLU of ``dense_ffn``)
+    loss = xent(norm(x) @ head^T) + aux_coef * sum of the routers'
            load-balancing terms
 
-``norm0(x; w) = x * rsqrt(mean(x^2) + eps) * (1 + w)`` is the zero-centred
-RMSNorm (weight zero at init); no matrix has a bias; the head is not tied
+``norm(x; w) = x * rsqrt(mean(x^2) + eps) * (1 + w)`` is the zero-centred
+RMSNorm (weight zero at init) or, with ``zero_centred_norm=False``, the
+plain one (``* w``, one at init); no matrix has a bias; the head is not tied
 to the embedding. Where :class:`~apex_tpu.models.TransformerLM` is one
 GPT-2 block repeated, this model's layers differ, so it is a class of its
 own and shares with the dense LM what lies under it: the flash-attention
@@ -39,9 +43,29 @@ with K and V **broadcast to the query heads in front of the kernel**
 broadcast's transpose sums a group's dK and dV); the result times
 ``sigmoid(gate)`` is projected out.
 
+The **latent attention mixer** (``num_heads`` heads; queries and keys
+``qk_nope_dim + qk_rope_dim`` wide over values ``v_head_dim`` wide):
+``q = h W_q``; ``[c | kr] = h W_kva`` with ``c`` the ``kv_lora_rank``-wide
+latent and ``kr`` **one** rotary key head that all heads share; ``[kn |
+v] = norm(c) W_kvb`` a head; rotary positions turn ``q``'s last
+``qk_rope_dim`` and ``kr`` whole; ``k = [kn | kr]`` with ``kr``
+**broadcast to the heads in front of the kernel** (its transpose sums the
+heads' ``dK_rope``). ``flash_attention`` takes one width for ``q``, ``k``
+and ``v``, so ``v`` is padded to the keys' width and the result sliced, as
+the source's own flash path does. Under ``remat`` the keys and values are
+made again from the layer's input in the backward: only the block's input
+is kept.
+
+The **sigmoid router's selection bias** (``router="sigmoid"``) is state
+that no gradient reaches: ``[expert layers, num_experts]`` floats, zero at
+first (:meth:`HybridLM.router_state`), an argument of
+:meth:`HybridLM.loss_with_router_state`, which hands it back moved by the
+step's own pairs an expert (``ExpertLayer.moved_bias``), as a ResNet's
+batch statistics travel through ``train_step.build_step``.
+
 Scopes (``prof.SCOPES``): ``embed``, ``linear_attention``,
-``delta_rule``, ``attention``, ``moe_route``, ``moe_experts``,
-``head_loss``; siblings, never nested.
+``delta_rule``, ``attention``, ``latent_attention``, ``mlp``,
+``moe_route``, ``moe_experts``, ``head_loss``; siblings, never nested.
 """
 
 from __future__ import annotations
@@ -58,14 +82,17 @@ from apex_tpu.ops.gated_delta_rule import gated_delta_rule
 __all__ = ["HybridLM"]
 
 _F32 = jnp.float32
-MIXERS = ("linear", "full")
+MIXERS = ("linear", "full", "latent")
+FFNS = ("experts", "dense")
 
 
-def _norm0(x, w, eps):
-    """Zero-centred RMSNorm over the last axis, in float32."""
+def _norm0(x, w, eps, zero_centred: bool = True):
+    """RMSNorm over the last axis, in float32: zero-centred (``* (1 +
+    w)``) or plain (``* w``)."""
     xf = x.astype(_F32)
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-    return (y * (1.0 + w.astype(_F32))).astype(x.dtype)
+    w = w.astype(_F32)
+    return (y * (1.0 + w if zero_centred else w)).astype(x.dtype)
 
 
 def _rotary(x, theta: float, rot: int):
@@ -93,13 +120,19 @@ def _causal_conv(x, w):
 class HybridLM:
     vocab_size: int
     hidden: int
-    layer_types: tuple          # a mixer kind a layer: "linear" | "full"
+    layer_types: tuple          # a mixer kind a layer, of MIXERS
+    ffn_types: tuple = ()       # an FFN kind a layer, of FFNS; () = experts
     # gated softmax attention
     num_heads: int = 16
     num_kv_heads: int = 2
     head_dim: int = 256
     rotary_dim: int = 64
     rope_theta: float = 1e7
+    # latent attention (num_heads heads, rope_theta)
+    kv_lora_rank: int = 512
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
     # Gated DeltaNet
     linear_k_heads: int = 16
     linear_v_heads: int = 32
@@ -114,8 +147,13 @@ class HybridLM:
     shared_ffn: int = 512
     experts_held: tuple = ()
     dispatch_bound: int = 0
+    router: str = "softmax"     # ExpertLayer's router kind
+    routed_scale: float = 1.0
+    bias_rate: float = 0.001    # the sigmoid router's bias, a step's move
+    dense_ffn: int = 0          # the "dense" FFN's width
     aux_coef: float = 0.001
     rms_eps: float = 1e-6
+    zero_centred_norm: bool = True
     attn_impl: str = "fast"     # "fast": the flash kernels; "default": jnp
     head_chunk: int = 0         # vocabulary columns a step of the head
     remat: bool = False         # recompute each block in the backward
@@ -125,6 +163,10 @@ class HybridLM:
         if bad or not self.layer_types:
             raise ValueError(f"layer_types: a tuple of {MIXERS}, got "
                              f"{self.layer_types}")
+        if set(self.ffn_types) - set(FFNS) \
+                or len(self.ffns) != len(self.layer_types):
+            raise ValueError(f"ffn_types: one of {FFNS} a layer, got "
+                             f"{self.ffn_types}")
         if self.num_heads % self.num_kv_heads \
                 or self.linear_v_heads % self.linear_k_heads:
             raise ValueError("query heads must be a multiple of key/value "
@@ -133,12 +175,28 @@ class HybridLM:
             raise ValueError(f"head_chunk ({self.head_chunk}) must divide "
                              f"vocab_size ({self.vocab_size})")
 
+    @property
+    def ffns(self) -> tuple:
+        return tuple(self.ffn_types) or ("experts",) * len(self.layer_types)
+
     def _experts(self) -> ExpertLayer:
         return ExpertLayer(
             hidden=self.hidden, ffn=self.expert_ffn,
             num_experts=self.num_experts, top_k=self.top_k,
             experts_held=self.experts_held, shared_ffn=self.shared_ffn,
-            dispatch_bound=self.dispatch_bound)
+            dispatch_bound=self.dispatch_bound, router=self.router,
+            routed_scale=self.routed_scale)
+
+    def _norm(self, x, w):
+        return _norm0(x, w, self.rms_eps, self.zero_centred_norm)
+
+    def router_state(self):
+        """The sigmoid router's selection biases at the start, a row an
+        expert layer; ``None`` for a model whose router has none."""
+        if self.router != "sigmoid":
+            return None
+        return jnp.zeros((self.ffns.count("experts"), self.num_experts),
+                         _F32)
 
     # -- parameters ----------------------------------------------------------
     def init(self, key, scale: float = 0.02) -> dict:
@@ -149,11 +207,27 @@ class HybridLM:
 
         def w(*shape):
             return jax.random.normal(next(keys), shape) * scale
-        p = {"embed": w(v, d), "head": w(v, d), "norm_f": jnp.zeros((d,))}
-        for i, kind in enumerate(self.layer_types):
-            lp = {"norm1": jnp.zeros((d,)), "norm2": jnp.zeros((d,)),
-                  "moe": self._experts().init(next(keys), scale)}
-            if kind == "linear":
+
+        def gain(n):        # a norm's weight at the start
+            return (jnp.zeros if self.zero_centred_norm else jnp.ones)((n,))
+        p = {"embed": w(v, d), "head": w(v, d), "norm_f": gain(d)}
+        for i, (kind, ffn) in enumerate(zip(self.layer_types, self.ffns)):
+            lp = {"norm1": gain(d), "norm2": gain(d)}
+            if ffn == "experts":
+                lp["moe"] = self._experts().init(next(keys), scale)
+            else:
+                f = self.dense_ffn
+                lp["mlp"] = {"w_gate": w(d, f), "w_up": w(d, f),
+                             "w_down": w(f, d)}
+            if kind == "latent":
+                h, dn, dr = self.num_heads, self.qk_nope_dim, self.qk_rope_dim
+                r = self.kv_lora_rank
+                lp["latent"] = {
+                    "w_q": w(d, h * (dn + dr)), "w_kva": w(d, r + dr),
+                    "kv_norm": gain(r),
+                    "w_kvb": w(r, h * (dn + self.v_head_dim)),
+                    "w_o": w(h * self.v_head_dim, d)}
+            elif kind == "linear":
                 lp["linear"] = {
                     "w_qkvz": w(d, 2 * kd + 2 * vd),
                     "w_ba": w(d, 2 * self.linear_v_heads),
@@ -166,8 +240,8 @@ class HybridLM:
                 h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
                 lp["attn"] = {
                     "w_q": w(d, h * 2 * hd), "w_k": w(d, kv * hd),
-                    "w_v": w(d, kv * hd), "q_norm": jnp.zeros((hd,)),
-                    "k_norm": jnp.zeros((hd,)), "w_o": w(h * hd, d)}
+                    "w_v": w(d, kv * hd), "q_norm": gain(hd),
+                    "k_norm": gain(hd), "w_o": w(h * hd, d)}
             p[f"layer_{i}"] = lp
         return p
 
@@ -178,7 +252,7 @@ class HybridLM:
         dk, dv = self.linear_k_dim, self.linear_v_dim
         kd, vd = hk * dk, hv * dv
         with jax.named_scope("linear_attention"):
-            h = _norm0(x, lp["norm1"], self.rms_eps)
+            h = self._norm(x, lp["norm1"])
             p = lp["linear"]
             qkvz = h @ p["w_qkvz"]
             ba = (h @ p["w_ba"]).astype(_F32)
@@ -216,14 +290,14 @@ class HybridLM:
         h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
         with jax.named_scope("attention"):
             p = lp["attn"]
-            hid = _norm0(x, lp["norm1"], self.rms_eps)
+            hid = self._norm(x, lp["norm1"])
             qg = (hid @ p["w_q"]).reshape(b, t, h, 2 * hd)
             q, gate = qg[..., :hd], qg[..., hd:]
             k = (hid @ p["w_k"]).reshape(b, t, kv, hd)
             v = (hid @ p["w_v"]).reshape(b, t, kv, hd)
-            q = _rotary(_norm0(q, p["q_norm"], self.rms_eps),
+            q = _rotary(self._norm(q, p["q_norm"]),
                         self.rope_theta, self.rotary_dim)
-            k = _rotary(_norm0(k, p["k_norm"], self.rms_eps),
+            k = _rotary(self._norm(k, p["k_norm"]),
                         self.rope_theta, self.rotary_dim)
             # each key/value head serves h // kv query heads: broadcast in
             # front of the kernel (its transpose sums the group's dK, dV)
@@ -237,62 +311,123 @@ class HybridLM:
                 gate.astype(_F32)).astype(x.dtype)
             return x + a.reshape(b, t, h * hd) @ p["w_o"]
 
-    def _block(self, kind: str, lp, x):
-        x = (self._linear_mixer if kind == "linear"
-             else self._full_mixer)(lp, x)
+    def _latent_mixer(self, lp, x):
+        from apex_tpu.contrib.multihead_attn.flash_attention import (
+            flash_attention, reference_attention)
+        b, t, _ = x.shape
+        h, r = self.num_heads, self.kv_lora_rank
+        dn, dr, dv = self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim
+        with jax.named_scope("latent_attention"):
+            p = lp["latent"]
+            hid = self._norm(x, lp["norm1"])
+            q = (hid @ p["w_q"]).reshape(b, t, h, dn + dr)
+            kva = hid @ p["w_kva"]
+            kv = (self._norm(kva[..., :r], p["kv_norm"])
+                  @ p["w_kvb"]).reshape(b, t, h, dn + dv)
+            q = jnp.concatenate(
+                [q[..., :dn], _rotary(q[..., dn:], self.rope_theta, dr)], -1)
+            # one rotary key head serves every head: broadcast in front of
+            # the kernel (its transpose sums the heads' dK_rope)
+            kr = _rotary(kva[..., None, r:], self.rope_theta, dr)
+            k = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(kr, (b, t, h, dr))], -1)
+            q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, kv[..., dn:]))
+            scale = (dn + dr) ** -0.5
+            if self.attn_impl == "fast":
+                # the kernels take one width: v padded to the keys'
+                v = jnp.pad(v, ((0, 0),) * 3 + ((0, dn + dr - dv),))
+                a = flash_attention(q, k, v, causal=True,
+                                    scale=scale)[..., :dv]
+            else:
+                a = reference_attention(q, k, v, causal=True, scale=scale)
+            return x + a.transpose(0, 2, 1, 3).reshape(b, t, h * dv) \
+                @ p["w_o"]
+
+    def _dense_ffn(self, lp, x):
+        with jax.named_scope("mlp"):
+            h, p = self._norm(x, lp["norm2"]), lp["mlp"]
+            # the products leave in x's type: at 16,384 tokens x 11,264
+            # float32 gates and their cotangents are 0.74 GB apiece
+            g, u = h @ p["w_gate"], h @ p["w_up"]
+            act = jax.nn.silu(g.astype(_F32)) * u.astype(_F32)
+            return x + act.astype(x.dtype) @ p["w_down"]
+
+    def _block(self, kind: str, lp, x, ffn: str = "experts", bias=None):
+        """One layer: ``(x, the expert layer's aux | None)``. ``bias``:
+        the sigmoid router's selection bias of this layer."""
+        x = {"linear": self._linear_mixer, "full": self._full_mixer,
+             "latent": self._latent_mixer}[kind](lp, x)
+        if ffn == "dense":
+            return self._dense_ffn(lp, x), None
         b, t, d = x.shape
         with jax.named_scope("moe_route"):
-            h = _norm0(x, lp["norm2"], self.rms_eps)
-        y, aux = self._experts().apply(lp["moe"], h.reshape(b * t, d))
+            h = self._norm(x, lp["norm2"])
+        if self.router == "sigmoid":    # balances a sequence: [B, T, d]
+            y, aux = self._experts().apply(lp["moe"], h, bias)
+        else:
+            y, aux = self._experts().apply(lp["moe"], h.reshape(b * t, d))
         with jax.named_scope("moe_route"):
             return x + y.reshape(b, t, d), aux
 
     # -- forward, loss -------------------------------------------------------
-    def hidden_states(self, params: dict, tokens):
+    def hidden_states(self, params: dict, tokens, router_bias=None):
         """``tokens [B, T]`` -> (the final norm's output ``[B, T, hidden]``,
         counters): the routers' summed load-balancing term, the pairs past
         the dispatch bound (all layers), the fullest layer's pairs on held
-        experts and the worst layer's held-expert load over the mean."""
+        experts, the worst layer's held-expert load over the mean and, for
+        the sigmoid router, each expert layer's pairs an expert
+        (``expert_pairs [expert layers, num_experts]``). ``router_bias``:
+        that router's selection biases, a row an expert layer."""
         with jax.named_scope("embed"):
             x = params["embed"][tokens]
-        # a run of layers of one kind is one scanned body over the run's
-        # stacked parameters: three Gated DeltaNet layers compile once
-        auxes, first = [], 0
-        for kind, run in itertools.groupby(self.layer_types):
+        # a run of like layers is one scanned body over the run's stacked
+        # parameters: three Gated DeltaNet layers compile once
+        auxes, first, row = [], 0, 0
+        for (kind, ffn), run in itertools.groupby(
+                zip(self.layer_types, self.ffns)):
             n = len(list(run))
+            biased = router_bias is not None and ffn == "experts"
 
-            def block(x, lp, _kind=kind):
-                return self._block(_kind, lp, x)
+            def block(x, xs, _kind=kind, _ffn=ffn, _biased=biased):
+                lp, bias = xs if _biased else (xs, None)
+                return self._block(_kind, lp, x, _ffn, bias)
             if self.remat:
                 block = jax.checkpoint(block)
             layers = [params[f"layer_{i}"] for i in range(first, first + n)]
-            x, aux = jax.lax.scan(
-                block, x, jax.tree.map(lambda *a: jnp.stack(a), *layers))
-            auxes.append(aux)
+            xs = jax.tree.map(lambda *a: jnp.stack(a), *layers)
+            if biased:
+                xs, row = (xs, router_bias[row:row + n]), row + n
+            x, aux = jax.lax.scan(block, x, xs)
+            if aux is not None:
+                auxes.append(aux)
             first += n
         aux = jax.tree.map(lambda *a: jnp.concatenate(a), *auxes)
         with jax.named_scope("head_loss"):
-            x = _norm0(x, params["norm_f"], self.rms_eps)
-        return x, {"load_balance_loss": jnp.sum(aux["load_balance_loss"]),
-                   "moe_overflow_pairs": jnp.sum(aux["overflow_pairs"]),
-                   "moe_held_pairs_max": jnp.max(aux["held_pairs"]),
-                   "expert_load_max_over_mean": jnp.max(
-                       aux["load_max_over_mean"])}
+            x = self._norm(x, params["norm_f"])
+        counters = {"load_balance_loss": jnp.sum(aux["load_balance_loss"]),
+                    "moe_overflow_pairs": jnp.sum(aux["overflow_pairs"]),
+                    "moe_held_pairs_max": jnp.max(aux["held_pairs"]),
+                    "expert_load_max_over_mean": jnp.max(
+                        aux["load_max_over_mean"])}
+        if "expert_pairs" in aux:
+            counters["expert_pairs"] = aux["expert_pairs"]
+        return x, counters
 
-    def apply(self, params: dict, tokens):
+    def apply(self, params: dict, tokens, router_bias=None):
         """Logits ``[B, T, vocab]`` in float32."""
-        x, _ = self.hidden_states(params, tokens)
+        x, _ = self.hidden_states(params, tokens, router_bias)
         with jax.named_scope("head_loss"):
             return jnp.einsum("btd,vd->btv", x, params["head"],
                               preferred_element_type=_F32)
 
-    def loss_with_counters(self, params: dict, tokens):
+    def loss_with_counters(self, params: dict, tokens, router_bias=None):
         """Mean next-token cross-entropy of ``tokens [B, T + 1]`` plus
         ``aux_coef`` times the load-balancing terms, and the step's
         counters (``moe_overflow_pairs``, ``moe_held_pairs_max``,
-        ``expert_load_max_over_mean``)."""
+        ``expert_load_max_over_mean``; the sigmoid router's
+        ``expert_pairs``)."""
         from apex_tpu.contrib.xentropy import linear_cross_entropy
-        x, c = self.hidden_states(params, tokens[:, :-1])
+        x, c = self.hidden_states(params, tokens[:, :-1], router_bias)
         with jax.named_scope("head_loss"):
             losses = linear_cross_entropy(
                 x.reshape(-1, self.hidden), params["head"],
@@ -301,6 +436,18 @@ class HybridLM:
             loss = jnp.mean(losses) + self.aux_coef * c.pop(
                 "load_balance_loss")
         return loss, c
+
+    def loss_with_router_state(self, params: dict, router_bias, tokens):
+        """The sigmoid router's step: the loss under ``router_bias`` (no
+        gradient reaches it), and beside it ``(the biases moved by this
+        step's pairs an expert, counters)``; the counters gain
+        ``router_bias_abs_max``, the largest moved bias."""
+        loss, c = self.loss_with_counters(params, tokens, router_bias)
+        with jax.named_scope("moe_route"):
+            moved = ExpertLayer.moved_bias(router_bias, c["expert_pairs"],
+                                           self.bias_rate)
+            c["router_bias_abs_max"] = jnp.max(jnp.abs(moved))
+        return loss, (moved, c)
 
     def loss(self, params: dict, tokens):
         return self.loss_with_counters(params, tokens)[0]

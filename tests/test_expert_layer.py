@@ -1,7 +1,8 @@
-"""The chip's share of a many-expert layer: against a dense computation of
-the same mathematics, the shares adding up to the uncut layer, no pair on
-a held expert dropped under a skewed router, pairs past the bound
-counted."""
+"""The chip's share of a many-expert layer, under both router kinds:
+against a dense computation of the same mathematics, the shares adding up
+to the uncut layer, no pair on a held expert dropped under a skewed
+router, pairs past the bound counted; the sigmoid router's bias moving
+the choice and not the weights, and its move after a step by hand."""
 
 import jax
 import jax.numpy as jnp
@@ -11,19 +12,23 @@ import pytest
 from apex_tpu.contrib.moe import ExpertLayer
 
 D, F, E, K = 32, 16, 32, 4
+ROUTERS = ["softmax", "sigmoid"]
+SCALE = 2.5     # the sigmoid router's factor on the weights
 
 
 class SmallTiles(ExpertLayer):
     tile = 8        # the layer's is 128: the chip's; no constructor knob
 
 
-def _layer(held=(), **kw):
-    return SmallTiles(hidden=D, ffn=F, num_experts=E, top_k=K,
-                      experts_held=held, shared_ffn=F, **kw)
+def _layer(held=(), router="softmax", experts=E, **kw):
+    return SmallTiles(hidden=D, ffn=F, num_experts=experts, top_k=K,
+                      experts_held=held, shared_ffn=F, router=router,
+                      routed_scale=SCALE if router == "sigmoid" else 1.0,
+                      **kw)
 
 
-def _params(key=0, scale=0.3):
-    return _layer().init(jax.random.key(key), scale)
+def _params(key=0, scale=0.3, **kw):
+    return _layer(**kw).init(jax.random.key(key), scale)
 
 
 def _share(params, lo, hi):
@@ -32,14 +37,23 @@ def _share(params, lo, hi):
                          for k in ("w_gate", "w_up", "w_down")}}
 
 
-def _dense(params, x, lo=0, hi=E):
+def _dense(params, x, lo=0, hi=None, router="softmax", bias=0.0):
     """Every expert of ``[lo, hi)`` over every token, weighted by the
     token's renormalised top-k weight for it (a constant in a share's
-    backward)."""
-    probs = jax.nn.softmax(x @ params["router"], -1)
-    w, idx = jax.lax.top_k(probs, K)
-    w = w / w.sum(-1, keepdims=True)
-    if hi - lo < E:
+    backward). The sigmoid router chooses on score + bias and weighs by
+    the scores themselves, times its factor."""
+    experts = params["router"].shape[1]
+    hi = experts if hi is None else hi
+    if router == "sigmoid":
+        scores = jax.nn.sigmoid(x @ params["router"])
+        _, idx = jax.lax.top_k(scores + bias, K)
+        w = jnp.take_along_axis(scores, idx, -1)
+        w = SCALE * w / w.sum(-1, keepdims=True)
+    else:
+        probs = jax.nn.softmax(x @ params["router"], -1)
+        w, idx = jax.lax.top_k(probs, K)
+        w = w / w.sum(-1, keepdims=True)
+    if hi - lo < experts:
         w = jax.lax.stop_gradient(w)
     y = jnp.zeros_like(x)
     for e in range(lo, hi):
@@ -53,34 +67,78 @@ def _x(n=64, key=1):
     return jax.random.normal(jax.random.key(key), (n, D))
 
 
-def test_the_uncut_layer_is_the_dense_mixture_plus_the_shared_expert():
-    params, x = _params(), _x()
-    layer = _layer()
+@pytest.mark.parametrize("router", ROUTERS)
+def test_the_uncut_layer_is_the_dense_mixture_plus_the_shared_expert(router):
+    params, x = _params(router=router), _x()
+    layer = _layer(router=router)
     y, aux = layer.apply(params, x)
-    want = _dense(params, x) + layer.shared(params, x)
+    want = _dense(params, x, router=router) + layer.shared(params, x)
     np.testing.assert_allclose(y, want, atol=1e-5)
     assert int(aux["overflow_pairs"]) == 0
+    # the shared expert has a gate of its own under the softmax router only
+    assert ("gate" in params["shared"]) == (router == "softmax")
 
 
-@pytest.mark.parametrize("chips", [4, 32])
-def test_the_shares_add_up(chips):
+@pytest.mark.parametrize("router", ROUTERS)
+@pytest.mark.parametrize("experts, chips", [(E, 4), (E, 32), (64, 8)])
+def test_the_shares_add_up(experts, chips, router):
     """The parts all shares give, the shared expert counted once, are the
-    uncut layer; and a share computes its own experts' part, nothing
-    that stands in for the others."""
-    params, x = _params(2), _x(48, 3)
-    uncut, _ = _layer().apply(params, x)
-    per = E // chips
+    uncut layer (the eight shares of a 64-expert layer among them); and a
+    share computes its own experts' part, nothing that stands in for the
+    others."""
+    kind = dict(router=router, experts=experts)
+    params, x = _params(2, **kind), _x(48, 3)
+    bias = 0.0 if router == "softmax" else 0.2 * jax.random.normal(
+        jax.random.key(4), (experts,))
+    uncut, _ = _layer(**kind).apply(params, x, bias)
+    per = experts // chips
     total = 0.0
     for c in range(chips):
         lo, hi = c * per, (c + 1) * per
-        layer = _layer((lo, hi))
-        part, aux = layer.routed(_share(params, lo, hi), x)
-        np.testing.assert_allclose(part, _dense(params, x, lo, hi),
-                                   atol=1e-5)
+        layer = _layer((lo, hi), **kind)
+        part, aux = layer.routed(_share(params, lo, hi), x, bias)
+        np.testing.assert_allclose(
+            part, _dense(params, x, lo, hi, router, bias), atol=1e-5)
         assert int(aux["overflow_pairs"]) == 0
         total = total + part
-    total = total + _layer().shared(params, x)
+    total = total + _layer(**kind).shared(params, x)
     np.testing.assert_allclose(total, uncut, atol=1e-5)
+
+
+def test_the_bias_moves_the_choice_and_not_the_weights():
+    """The sigmoid router chooses on score + bias and weighs by the
+    scores: a bias that lifts expert 5 into every token's choice leaves
+    each chosen expert's weight what its own score makes it, and no
+    gradient reaches the bias."""
+    params, x = _params(14, router="sigmoid"), _x(40, 15)
+    layer = _layer(router="sigmoid")
+    w0, idx0, scores = layer.route(params, x)
+    bias = jnp.zeros((E,)).at[5].set(10.0)
+    w, idx, _ = layer.route(params, x, bias)
+    assert bool(jnp.all(jnp.any(idx == 5, -1)))
+    assert not bool(jnp.all(jnp.any(idx0 == 5, -1)))
+    picked = jnp.take_along_axis(scores, idx, -1)
+    np.testing.assert_allclose(
+        w, SCALE * picked / picked.sum(-1, keepdims=True), rtol=1e-6)
+    np.testing.assert_allclose(w.sum(-1), SCALE, rtol=1e-6)
+    g = jax.grad(lambda b: jnp.sum(jnp.sin(layer.apply(params, x, b)[0])))(
+        bias)
+    assert float(jnp.abs(g).max()) == 0.0
+    # every expert's pairs come out beside the result, under the bias
+    pairs = layer.routed(params, x, bias)[1]["expert_pairs"]
+    assert int(pairs[5]) == 40 and int(pairs.sum()) == 40 * K
+
+
+def test_the_bias_moves_against_the_load_by_hand():
+    """``b_e += u sign(mean(c) - c_e)`` on a skewed count, a row a layer:
+    the overloaded expert goes down, the starved up, one at the mean
+    stays."""
+    pairs = jnp.array([[10, 2, 6, 6], [0, 0, 0, 8]])
+    bias = jnp.array([[0.5, 0.0, -0.25, 0.0], [0.0, 0.0, 0.0, 0.0]])
+    got = ExpertLayer.moved_bias(bias, pairs, 0.01)
+    np.testing.assert_allclose(
+        got, [[0.49, 0.01, -0.25, 0.0], [0.01, 0.01, 0.01, -0.01]],
+        atol=1e-7)
 
 
 @pytest.mark.parametrize("lo,hi", [(8, 16), (0, E)])
@@ -156,6 +214,28 @@ def test_pairs_past_the_bound_are_counted_not_lost_in_silence():
     assert float(jnp.abs(part - sound).max()) > 0       # and it shows
 
 
+def test_the_sigmoid_routers_balancing_term_is_taken_a_sequence():
+    """``sum_e f_e P_e`` a sequence, ``f_e = E / (K T)`` times the
+    sequence's pairs on ``e``, ``P_e`` its mean of ``s_e / sum_j s_j``,
+    averaged over the sequences; its gradient reaches the router."""
+    params = _params(16, router="sigmoid")
+    x = _x(3 * 20, 17).reshape(3, 20, D)
+    layer = _layer(router="sigmoid")
+    y, aux = layer.routed(params, x)
+    assert y.shape == x.shape
+    want = 0.0
+    for seq in x:
+        s = jax.nn.sigmoid(seq @ params["router"])
+        _, idx = jax.lax.top_k(s, K)
+        f = jnp.zeros((E,)).at[idx.reshape(-1)].add(1.0) * E / (K * 20)
+        want = want + jnp.sum(f * (s / s.sum(-1, keepdims=True)).mean(0)) / 3
+    np.testing.assert_allclose(aux["load_balance_loss"], want, rtol=1e-5)
+    flat, _ = layer.routed(params, x.reshape(60, D))    # one sequence of 60
+    np.testing.assert_allclose(flat, y.reshape(60, D), atol=1e-6)
+    g = jax.grad(lambda p: layer.routed(p, x)[1]["load_balance_loss"])(params)
+    assert float(jnp.abs(g["router"]).max()) > 0
+
+
 def test_the_load_balancing_term_is_the_switch_form():
     params, x = _params(10), _x(80, 11)
     _, aux = _layer().routed(params, x)
@@ -168,7 +248,7 @@ def test_the_load_balancing_term_is_the_switch_form():
 
 @pytest.mark.parametrize("kw", [
     dict(experts_held=(8, 4)), dict(experts_held=(0, E + 1)),
-    dict(top_k=0), dict(dispatch_bound=100)])
+    dict(top_k=0), dict(dispatch_bound=100), dict(router="tanh")])
 def test_what_the_layer_refuses(kw):
     base = dict(hidden=D, ffn=F, num_experts=E, top_k=K)
     with pytest.raises(ValueError):

@@ -155,23 +155,34 @@ def test_partial_rotary_turns_the_first_dims_only_and_keeps_norms():
     assert float(jnp.abs(y[:, 5, :, :4] - x[:, 5, :, :4]).max()) > 1e-2
 
 
-def test_every_scope_of_the_step_is_in_the_vocabulary():
+@pytest.mark.parametrize("model, scopes, absent", [
+    ("hybrid", ("embed", "linear_attention", "delta_rule", "attention",
+                "moe_route", "moe_experts", "head_loss"),
+     ("latent_attention", "mlp")),
+    ("latent", ("embed", "latent_attention", "mlp", "moe_route",
+                "moe_experts", "head_loss"),
+     ("attention", "linear_attention", "delta_rule"))])
+def test_every_scope_of_the_step_is_in_the_vocabulary(model, scopes, absent):
     """The model's scopes are siblings in ``prof.SCOPES``; each shows in
-    the compiled step's op names, forward and backward."""
-    lm = _tiny(head_chunk=32, remat=True)
+    the compiled step's op names, forward and backward. Latent attention
+    opens one scope around all of it, the dense FFN the dense LM's
+    ``mlp``."""
+    lm = (_tiny if model == "hybrid" else _latent)(head_chunk=32, remat=True)
     params = lm.init(jax.random.key(7))
-    text = jax.jit(jax.grad(lm.loss)).lower(params, _tokens()).compile() \
+    loss = lm.loss if model == "hybrid" else (
+        lambda p, t: lm.loss_with_router_state(p, lm.router_state(), t)[0])
+    text = jax.jit(jax.grad(loss)).lower(params, _tokens()).compile() \
         .as_text()
     paths = set(re.findall(r'op_name="([^"]*)"', text))
 
     def under(scope, path):     # a whole component: bare, or in jvp( )
         return re.search(rf"(^|[/(]){scope}([/)]|$)", path) is not None
-    for scope in ("embed", "linear_attention", "delta_rule", "attention",
-                  "moe_route", "moe_experts", "head_loss"):
+    for scope in scopes:
         assert scope in prof.SCOPES
         mine = [p for p in paths if under(scope, p)]
         assert any("transpose(" not in p for p in mine), scope
         assert any("transpose(" in p for p in mine), scope
+    assert not any(under(scope, p) for scope in absent for p in paths)
     assert "delta_rule/linear_attention" not in text    # siblings
     assert "linear_attention/delta_rule" not in text
 
@@ -211,3 +222,132 @@ def test_the_step_builder_refuses_counters_across_chips(arm):
     with pytest.raises(NotImplementedError, match="counters"):
         lm_bench.build_train_step(lm, lm.init(jax.random.key(0)), mesh,
                                   half=jnp.bfloat16, **arm)
+
+
+# -- latent attention, a leading dense layer, the sigmoid router's bias -------
+
+def _latent(**kw):
+    base = dict(
+        vocab_size=96, hidden=32, layer_types=("latent",) * 3,
+        ffn_types=("dense", "experts", "experts"), num_heads=4,
+        kv_lora_rank=16, qk_nope_dim=8, qk_rope_dim=4, v_head_dim=8,
+        rope_theta=8e5, num_experts=8, top_k=2, expert_ffn=16, shared_ffn=32,
+        experts_held=(2, 6), router="sigmoid", routed_scale=2.446,
+        dense_ffn=48, rms_eps=1e-5, zero_centred_norm=False)
+    return HybridLM(**{**base, **kw})
+
+
+def test_a_model_that_leads_with_a_dense_layer():
+    """The FFN kind is data a layer as the mixer kind is: the dense layer
+    has one SwiGLU and no router, a run of like layers is still one
+    scanned body (1 + a scan of 2), plain norms start at one, and the
+    result is the layers' one after the other."""
+    lm = _latent()
+    p = lm.init(jax.random.key(0))
+    assert set(p["layer_0"]) == {"norm1", "norm2", "latent", "mlp"}
+    assert set(p["layer_1"]) == {"norm1", "norm2", "latent", "moe"}
+    assert p["layer_0"]["mlp"]["w_gate"].shape == (32, 48)
+    assert "gate" not in p["layer_1"]["moe"]["shared"]      # ungated
+    assert float(p["norm_f"].min()) == 1.0 == float(
+        p["layer_2"]["latent"]["kv_norm"].max())
+    assert p["layer_1"]["latent"]["w_kva"].shape == (32, 16 + 4)
+    assert p["layer_1"]["latent"]["w_kvb"].shape == (16, 4 * (8 + 8))
+    toks = _tokens(key=3)[:, :-1]
+    bias = 0.3 * jax.random.normal(jax.random.key(4), (2, 8))
+    assert lm.router_state().shape == (2, 8)
+    assert _tiny().router_state() is None
+    jaxpr = jax.make_jaxpr(lm.apply)(p, toks, bias)
+    assert [e.params["length"] for e in jaxpr.eqns
+            if e.primitive.name == "scan"] == [1, 2]
+    x = p["embed"][toks]
+    x, aux = lm._block("latent", p["layer_0"], x, "dense")
+    assert aux is None
+    for i in (1, 2):
+        x, _ = lm._block("latent", p[f"layer_{i}"], x, "experts", bias[i - 1])
+    want = jnp.einsum("btd,vd->btv", lm._norm(x, p["norm_f"]), p["head"])
+    np.testing.assert_allclose(lm.apply(p, toks, bias), want, atol=2e-5)
+    with pytest.raises(ValueError):
+        _latent(ffn_types=("dense", "experts"))
+    with pytest.raises(ValueError):
+        _latent(ffn_types=("dense", "experts", "sparse"))
+
+
+@pytest.mark.parametrize("seq", [128, 80])
+def test_the_latent_mixer_against_a_naive_softmax_at_unpadded_widths(seq):
+    """Queries and keys 192 wide (128 + 64 rotary, the rotary key one
+    head shared by all) over values 128 wide: through the flash kernels
+    (``v`` padded to 192, the result sliced), through plain attention, and
+    head by head with nothing padded; forward and the gradients, the
+    shared key's summed over the heads."""
+    kw = dict(hidden=64, num_heads=2, kv_lora_rank=32, qk_nope_dim=128,
+              qk_rope_dim=64, v_head_dim=128, layer_types=("latent",),
+              ffn_types=("dense",))
+    fast, plain = _latent(attn_impl="fast", **kw), _latent(
+        attn_impl="default", **kw)
+    lp = fast.init(jax.random.key(seq), scale=0.2)["layer_0"]
+    lp["norm1"] = lp["norm1"] + 0.1
+    lp["latent"]["kv_norm"] = lp["latent"]["kv_norm"] - 0.2
+    x = jax.random.normal(jax.random.key(1), (2, seq, 64))
+
+    def naive(lp, x):
+        p = lp["latent"]
+        h = _norm0(x, lp["norm1"], 1e-5, False)
+        q = (h @ p["w_q"]).reshape(2, seq, 2, 192)
+        kva = h @ p["w_kva"]
+        kv = (_norm0(kva[..., :32], p["kv_norm"], 1e-5, False)
+              @ p["w_kvb"]).reshape(2, seq, 2, 256)
+        k_r = _rotary(kva[..., None, 32:], 8e5, 64)[:, :, 0]
+        out = []
+        for head in range(2):
+            q_h = jnp.concatenate([q[:, :, head, :128], _rotary(
+                q[:, :, head:head + 1, 128:], 8e5, 64)[:, :, 0]], -1)
+            k_h = jnp.concatenate([kv[:, :, head, :128], k_r], -1)
+            s = jnp.einsum("btd,bsd->bts", q_h, k_h) * 192 ** -0.5
+            s = jnp.where(jnp.tril(jnp.ones((seq, seq), bool)), s, -jnp.inf)
+            out.append(jax.nn.softmax(s, -1) @ kv[:, :, head, 128:])
+        return x + jnp.concatenate(out, -1) @ p["w_o"]
+    want = naive(lp, x)
+    assert float(jnp.abs(want - x).max()) > 1e-2
+    np.testing.assert_allclose(fast._latent_mixer(lp, x), want, atol=2e-5)
+    np.testing.assert_allclose(plain._latent_mixer(lp, x), want, atol=2e-5)
+    w = jax.random.normal(jax.random.key(9), x.shape)
+    g_want = jax.grad(lambda lp, x: jnp.sum(naive(lp, x) * w),
+                      argnums=(0, 1))(lp, x)
+    for lm in (fast, plain):
+        got = jax.grad(lambda lp, x: jnp.sum(lm._latent_mixer(lp, x) * w),
+                       argnums=(0, 1))(lp, x)
+        for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                                jax.tree.leaves(g_want)):
+            np.testing.assert_allclose(a, b, atol=1e-4, err_msg=str(path))
+
+
+def test_the_step_builder_carries_the_routers_biases_beside_the_state():
+    """The biases are state that no gradient reaches: beside the
+    optimizer's in the step's state, moved by each step's own pairs an
+    expert, never a leaf of the flat master."""
+    import lm_bench
+    from apex_tpu.parallel import compile_step_with_plan, make_mesh
+    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
+    lm = _latent(head_chunk=32, remat=True, bias_rate=0.01)
+    params = lm.init(jax.random.key(9))
+    opt, state, step, plan = lm_bench.build_train_step(
+        lm, params, mesh, half=jnp.bfloat16, lr=1e-3)
+    assert opt.state == ()              # handed out, not copied
+    assert state[0][0].master.size >= sum(
+        x.size for x in jax.tree.leaves(params))
+    np.testing.assert_array_equal(state[1], jnp.zeros((2, 8)))
+    toks = _tokens(key=8)
+    run = compile_step_with_plan(step, plan)
+    state, (first, counters) = run(state, toks)
+    pairs = np.asarray(counters["expert_pairs"])
+    assert pairs.shape == (2, 8) and (pairs.sum(-1) == 2 * 32 * 2).all()
+    np.testing.assert_allclose(
+        state[1], 0.01 * np.sign(pairs.mean(-1, keepdims=True) - pairs),
+        atol=1e-7)
+    assert float(counters["router_bias_abs_max"]) == pytest.approx(0.01)
+    for _ in range(5):
+        state, (loss, counters) = run(state, toks)
+    assert float(loss) < float(first)
+    assert int(state[0][0].step) == 6
+    assert 0.01 < float(counters["router_bias_abs_max"]) <= 0.06 + 1e-6
+    assert int(counters["moe_overflow_pairs"]) == 0

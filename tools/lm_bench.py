@@ -28,7 +28,11 @@ def build_train_step(lm, params, mesh, *, half, zero=False, lr=1e-4):
     ``loss_with_counters``) is the body ``compile_step_with_plan(body,
     plan)`` lowers (a 1-device plan is plain jit — the single-chip
     program), and :func:`place_for_plan` puts ``(state, toks)`` where it
-    wants them."""
+    wants them. A model with state that no gradient reaches (``HybridLM``'s
+    sigmoid router: ``router_state()`` is not ``None``) has it beside the
+    optimizer's: ``state`` is ``(opt_state, router_bias)``, and the bias
+    travels through ``build_step`` as a ResNet's batch statistics do, in
+    with the batch and out in ``aux``."""
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
@@ -55,8 +59,10 @@ def build_train_step(lm, params, mesh, *, half, zero=False, lr=1e-4):
             T.build_zero_step(opt, lm.loss, half=half), \
             T.step_plan(mesh, opt.state_pspec())
     opt = FusedAdam(params, lr=lr)
+    router_bias = lm.router_state() if counted else None
     body = T.build_step(
-        opt, counted or lm.loss, half=half,
+        opt, (counted or lm.loss) if router_bias is None
+        else lm.loss_with_router_state, half=half,
         ddp=DistributedDataParallel(axis_name="data") if n_dev > 1
         else None)
 
@@ -64,5 +70,16 @@ def build_train_step(lm, params, mesh, *, half, zero=False, lr=1e-4):
         state, _, loss, counters = body(state, None, toks)
         return state, (loss, counters) if counted else loss
 
-    return opt, opt.init_state(), step, \
-        T.step_plan(mesh, P() if n_dev > 1 else None)
+    def step_with_router_state(state, toks):
+        state, bias = state
+        state, _, loss, (bias, counters) = body(state, None, bias, toks)
+        return (state, bias), (loss, counters)
+
+    plan = T.step_plan(mesh, P() if n_dev > 1 else None)
+    # the constructor's own state goes to the caller, not a copy of it
+    # beside it: weights + state + copy are 28 B a parameter at once, which
+    # a model sized to the chip's memory does not have (ROADMAP S12)
+    state, opt.state = opt.state, ()
+    if router_bias is None:
+        return opt, state, step, plan
+    return opt, (state, router_bias), step_with_router_state, plan
