@@ -26,7 +26,13 @@ one rank of an expert-parallel deployment computes it:
   are sorted by expert into one buffer of ``dispatch_bound`` rows, each
   expert's group starting on a multiple of ``tile`` rows, so that every
   tile of the buffer belongs to one expert and the experts' three matmuls
-  are batched matmuls over tiles, each tile with its expert's weights;
+  are grouped matmuls over tiles, each tile with its expert's weights:
+  on the TPU, at widths of whole lanes, Pallas kernels that look the
+  tile's expert up (``ops/pallas/grouped_matmul.py``: no copy of the
+  weights a tile, no work in the tiles past the last live one); anywhere
+  else, and under ``dispatch.backend("reference")`` as the kernels'
+  oracle, an einsum over each tile's gathered weights. Which runs is
+  read from the platform and the shapes;
 - there is no capacity an expert and no drop: an expert takes whatever
   share of the buffer its tokens need. ``dispatch_bound`` is the one
   static size. Pairs that fall past it are left out **and counted**
@@ -50,7 +56,9 @@ tokens that chose expert ``e`` and ``P_e`` its mean probability; sigmoid:
 ``sum_e f_e P_e`` a sequence of ``T`` tokens, ``f_e = E / (K T)`` times
 the sequence's pairs on ``e`` and ``P_e`` the sequence's mean of ``s_e /
 sum_j s_j``, averaged over the sequences), the pairs that fell on held
-experts (``held_pairs``: what the bound is sized from), the load of the
+experts (``held_pairs``: what the bound is sized from), the tiles of the
+buffer that hold a row (``live_tiles``: what the experts' matmuls
+compute; the rest of the bound is room), the load of the
 fullest held expert over the mean (``load_max_over_mean``) and, for the
 sigmoid router, every expert's pairs (``expert_pairs [E]``: what moves
 the bias).
@@ -65,17 +73,21 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from apex_tpu.ops import dispatch
+from apex_tpu.ops.pallas import grouped_matmul as gmm
+
 __all__ = ["ExpertLayer"]
 
 _F32 = jnp.float32
 ROUTERS = ("softmax", "sigmoid")
 
 
-def _swiglu(x, w_gate, w_up, w_down, eq_in, eq_out):
-    g = jnp.einsum(eq_in, x, w_gate, preferred_element_type=_F32)
-    u = jnp.einsum(eq_in, x, w_up, preferred_element_type=_F32)
-    return jnp.einsum(eq_out, (jax.nn.silu(g) * u).astype(x.dtype), w_down,
-                      preferred_element_type=_F32)
+def _swiglu(x, p, matmuls):
+    """``(silu(x W_gate) * (x W_up)) W_down`` with ``matmuls(x, *ws)``
+    the float32 products of ``x`` with each of ``ws``."""
+    g, u = matmuls(x, p["w_gate"], p["w_up"])
+    y, = matmuls((jax.nn.silu(g) * u).astype(x.dtype), p["w_down"])
+    return y
 
 
 @dataclasses.dataclass(frozen=True)
@@ -223,16 +235,25 @@ class ExpertLayer:
                 (end_tile - tiles_e) * tm + n_e - rows, 0, n_e))
             load = jnp.max(n_e) / jnp.maximum(jnp.mean(n_e.astype(_F32)),
                                               1e-9)
+            live_tiles = jnp.minimum(end_tile[-1], rows // tm).astype(
+                jnp.int32)
         with jax.named_scope("moe_experts"):
-            yb = _swiglu(xb.reshape(rows // tm, tm, d),
-                         params["w_gate"][tile_e], params["w_up"][tile_e],
-                         params["w_down"][tile_e],
-                         "tmd,tdf->tmf", "tmf,tfd->tmd").reshape(rows, d)
+            if dispatch.use_pallas() and gmm.takes(d, self.ffn, tm):
+                yb = _swiglu(xb, params, lambda x, *ws: gmm.grouped_matmul(
+                    x, ws, tile_e, live_tiles))
+            else:       # the kernels' oracle: each tile's weights gathered
+                yb = _swiglu(
+                    xb.reshape(rows // tm, tm, d), params,
+                    lambda x, *ws: [jnp.einsum(
+                        "tmk,tkn->tmn", x, w[tile_e],
+                        preferred_element_type=_F32) for w in ws]
+                ).reshape(rows, d)
         with jax.named_scope("moe_route"):
             y = jnp.zeros((n, d), _F32).at[tok].add(yb * wb[:, None])
         aux = {"load_balance_loss": balance,
                "overflow_pairs": overflow.astype(jnp.int32),
                "held_pairs": jnp.sum(n_e).astype(jnp.int32),
+               "live_tiles": live_tiles,
                "load_max_over_mean": load}
         if self.router == "sigmoid":
             aux["expert_pairs"] = counts.astype(jnp.int32)
@@ -245,8 +266,9 @@ class ExpertLayer:
         with jax.named_scope("moe_experts"):
             gate = 1.0 if self.router == "sigmoid" else jax.nn.sigmoid(
                 jnp.dot(x, sp["gate"], preferred_element_type=_F32))
-            return gate * _swiglu(x, sp["w_gate"], sp["w_up"], sp["w_down"],
-                                  "...d,df->...f", "...f,fd->...d")
+            return gate * _swiglu(x, sp, lambda x, *ws: [jnp.einsum(
+                "...k,kn->...n", x, w, preferred_element_type=_F32)
+                for w in ws])
 
     def apply(self, params: dict, x, bias=None):
         """``x [N, hidden]`` or ``[S, T, hidden]`` -> ``(y in x's shape

@@ -374,7 +374,8 @@ class HybridLM:
         """``tokens [B, T]`` -> (the final norm's output ``[B, T, hidden]``,
         counters): the routers' summed load-balancing term, the pairs past
         the dispatch bound (all layers), the fullest layer's pairs on held
-        experts, the worst layer's held-expert load over the mean and, for
+        experts and tiles of the dispatch buffer that hold a row, the worst
+        layer's held-expert load over the mean and, for
         the sigmoid router, each expert layer's pairs an expert
         (``expert_pairs [expert layers, num_experts]``). ``router_bias``:
         that router's selection biases, a row an expert layer."""
@@ -407,6 +408,7 @@ class HybridLM:
         counters = {"load_balance_loss": jnp.sum(aux["load_balance_loss"]),
                     "moe_overflow_pairs": jnp.sum(aux["overflow_pairs"]),
                     "moe_held_pairs_max": jnp.max(aux["held_pairs"]),
+                    "moe_live_tiles_max": jnp.max(aux["live_tiles"]),
                     "expert_load_max_over_mean": jnp.max(
                         aux["load_max_over_mean"])}
         if "expert_pairs" in aux:
@@ -424,8 +426,8 @@ class HybridLM:
         """Mean next-token cross-entropy of ``tokens [B, T + 1]`` plus
         ``aux_coef`` times the load-balancing terms, and the step's
         counters (``moe_overflow_pairs``, ``moe_held_pairs_max``,
-        ``expert_load_max_over_mean``; the sigmoid router's
-        ``expert_pairs``)."""
+        ``moe_live_tiles_max``, ``expert_load_max_over_mean``; the sigmoid
+        router's ``expert_pairs``)."""
         from apex_tpu.contrib.xentropy import linear_cross_entropy
         x, c = self.hidden_states(params, tokens[:, :-1], router_bias)
         with jax.named_scope("head_loss"):

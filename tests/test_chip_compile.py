@@ -235,38 +235,78 @@ def _delta_rule():
         q, k, v, g, b, chunk=64).astype(F32)), argnums=(0, 1, 2, 3, 4))
 
 
-def _expert_layer():
-    from apex_tpu.contrib.moe import ExpertLayer
-    layer = ExpertLayer(hidden=2048, ffn=512, num_experts=512, top_k=10,
-                        experts_held=(0, 16), shared_ffn=512,
-                        dispatch_bound=12288)
-    return lambda p, x: jax.grad(lambda p, x: jnp.sum(
-        layer.apply(p, x)[0].astype(F32)), argnums=(0, 1))(p, x)
+def _expert_layer(**kw):
+    """The gradient of one ``ExpertLayer`` (with no shared expert: of its
+    routed part alone) in its parameters and its input."""
+    def make():
+        from apex_tpu.contrib.moe import ExpertLayer
+        layer = ExpertLayer(hidden=2048, **kw)
+        return lambda p, x: jax.grad(lambda p, x: jnp.sum(
+            layer.apply(p, x)[0].astype(F32)), argnums=(0, 1))(p, x)
+    return make
 
 
-def _expert_args():
-    d, f, held = 2048, 512, 16
-    return [{"router": ((d, 512), BF16), "w_gate": ((held, d, f), BF16),
-             "w_up": ((held, d, f), BF16), "w_down": ((held, f, d), BF16),
-             "shared": {"w_gate": ((d, f), BF16), "w_up": ((d, f), BF16),
-                        "w_down": ((f, d), BF16), "gate": ((d, 1), BF16)}},
-            ((2 * 8192, d), BF16)]
+def _expert_args(f, experts, held, shared=None):
+    d = 2048
+    p = {"router": ((d, experts), BF16), "w_gate": ((held, d, f), BF16),
+         "w_up": ((held, d, f), BF16), "w_down": ((held, f, d), BF16)}
+    if shared:
+        p["shared"] = shared
+    return [p, ((2, 8192, d), BF16)]
+
+
+_QNEXT_SHARED = {"w_gate": ((2048, 512), BF16), "w_up": ((2048, 512), BF16),
+                 "w_down": ((512, 2048), BF16), "gate": ((2048, 1), BF16)}
+
+
+def _grouped_matmul():
+    from apex_tpu.ops.pallas.grouped_matmul import grouped_matmul
+    return jax.grad(lambda lhs, w, tile_e, live: jnp.sum(grouped_matmul(
+        lhs, (w,), tile_e, live)[0] ** 2), argnums=(0, 1))
+
+
+def _grouped_args(rows, held, depth, width):
+    return [((rows, depth), BF16), ((held, depth, width), BF16),
+            ((rows // 128,), I32), ((), I32)]
 
 
 # (id, builder, arguments (trees of (shape, dtype)), temporaries allowed in
-#  GB, the kernels the program has to hold): the hybrid LM's two new ops at
-#  the published widths and the benchmark cell's 2 x 8192 tokens. The delta
+#  GB, the kernels the program has to hold): the hybrid LM's new ops at the
+#  published widths and the benchmark cells' 2 x 8192 tokens. The delta
 #  rule's chunk-local part is jax.numpy and its loop over chunks the Pallas
-#  pair; the expert layer is jax.numpy and no kernel
+#  pair; the expert layer is jax.numpy around the grouped matmuls' kernels
 NEW_OPS = [
+    # the routed experts' products, both cells' shapes, both directions of a
+    # layer (hidden -> ffn, ffn -> hidden): forward, by rows, by experts
+    ("grouped_matmul_fwd_bwd-kvl-24576x2048x8x1408", _grouped_matmul,
+     _grouped_args(24576, 8, 2048, 1408), 0.5,
+     ("apex_moe_gmm", "apex_moe_tgmm")),
+    ("grouped_matmul_fwd_bwd-kvl-24576x1408x8x2048", _grouped_matmul,
+     _grouped_args(24576, 8, 1408, 2048), 0.5,
+     ("apex_moe_gmm", "apex_moe_tgmm")),
+    ("grouped_matmul_fwd_bwd-qnext-9216x2048x16x512", _grouped_matmul,
+     _grouped_args(9216, 16, 2048, 512), 0.2,
+     ("apex_moe_gmm", "apex_moe_tgmm")),
+    ("grouped_matmul_fwd_bwd-qnext-9216x512x16x2048", _grouped_matmul,
+     _grouped_args(9216, 16, 512, 2048), 0.2,
+     ("apex_moe_gmm", "apex_moe_tgmm")),
+    # one routed layer of kvl_train_s8192 (1.06 GB; the einsum over
+    # gathered weights took 6.89)
+    ("expert_layer_routed_fwd_bwd-kvl-N16384E64held8", _expert_layer(
+        ffn=1408, num_experts=64, top_k=6, experts_held=(0, 8),
+        dispatch_bound=24576, router="sigmoid", routed_scale=2.446),
+     _expert_args(1408, 64, 8), 1.5, ("apex_moe_gmm", "apex_moe_tgmm")),
     # the loop over chunks and its backward: one 128 x 128 state a chunk a
     # head (0.54 GB), never one a token (34 GB)
     ("gated_delta_rule_fwd_bwd-B2H32S8192D128", _delta_rule,
      [((2, 32, 8192, 128), BF16)] * 3 + [((2, 32, 8192), F32)] * 2, 4.0,
      ("apex_gdn_fwd", "apex_gdn_bwd")),
     # 512-way routing, the sort, grouped matmuls over 16 held experts
-    ("expert_layer_fwd_bwd-N16384E512held16", _expert_layer,
-     _expert_args(), 1.5, ()),
+    ("expert_layer_fwd_bwd-N16384E512held16", _expert_layer(
+        ffn=512, num_experts=512, top_k=10, experts_held=(0, 16),
+        shared_ffn=512, dispatch_bound=12288),
+     _expert_args(512, 512, 16, _QNEXT_SHARED), 1.5,
+     ("apex_moe_gmm", "apex_moe_tgmm")),
 ]
 
 
@@ -283,6 +323,10 @@ def test_jnp_op_compiles_and_fits_for_v5e(chip, for_chip, make_fn, args,
     text = compiled.as_text()
     assert [n for n in names
             if not re.search(rf"%(\w+_)?{n}_*\.\d+ = ", text)] == []
+    # no expert's weights gathered a tile, no gradient a tile, of any type:
+    # [tiles, hidden, ffn] or [tiles, ffn, hidden] (kvl: [192, 2048, 1408])
+    assert re.findall(r"\[(192|72|96),(2048,(1408|512)|(1408|512),2048)\]",
+                      text) == []
 
 
 def _step(name, skip, **kw):

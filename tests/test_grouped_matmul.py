@@ -1,0 +1,272 @@
+"""The routed experts' grouped matmuls as Pallas kernels (``apex_moe_gmm``,
+``apex_moe_tgmm``; interpreter mode here) against their oracle, the einsum
+over each tile's gathered weights: values and every gradient, at a power
+of two and an odd multiple of 128 (the two benchmark cells' kinds of
+width), over loads with an expert without a token, a group that ends on a
+tile, one live tile, and more dead tiles than live ones; the layer under
+``jax.checkpoint`` and inside a ``lax.scan`` over stacked layers."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from apex_tpu.contrib.moe import ExpertLayer
+from apex_tpu.ops import dispatch
+from apex_tpu.ops.pallas import grouped_matmul as gmm
+
+TILE = gmm.TILE
+WIDTHS = [(256, 128), (256, 384)]       # (hidden, ffn)
+E, HELD, K = 8, 4, 2
+BOUND = 16 * TILE
+# pairs on each of the four held experts -> live tiles of the 16
+LOADS = {
+    "an_expert_without_a_token": ([600, 0, 500, 300], 12),
+    "a_group_ends_on_a_tile": ([128, 256, 5, 70], 5),
+    "one_live_tile": ([0, 0, 90, 0], 1),
+    "mostly_dead_tiles": ([100, 20, 30, 10], 4),
+}
+
+
+def _tiles(loads, tiles):
+    """``(tile_e, live)`` as ``ExpertLayer.routed`` makes them."""
+    per = [-(-n // TILE) for n in loads]
+    tile_e = np.repeat(np.arange(len(loads)), per)
+    live = len(tile_e)
+    tile_e = np.concatenate([tile_e, np.full(tiles - live, len(loads) - 1)])
+    return jnp.asarray(tile_e, jnp.int32), jnp.asarray(live, jnp.int32)
+
+
+def _oracle(lhs, w, tile_e, live):
+    t = tile_e.shape[0]
+    x = jnp.where((jnp.arange(t) < live)[:, None, None],
+                  lhs.reshape(t, TILE, -1), 0)
+    return jnp.einsum("tmk,tkn->tmn", x, w[tile_e],
+                      preferred_element_type=jnp.float32).reshape(
+                          lhs.shape[0], -1)
+
+
+def _kernels(lhs, w, tile_e, live):
+    out, = gmm.grouped_matmul(lhs, (w,), tile_e, live)
+    return out
+
+
+def _operands(depth, width, dtype, key=0):
+    ks = jax.random.split(jax.random.key(key), 3)
+    return (jax.random.normal(ks[0], (BOUND, depth), dtype),
+            jax.random.normal(ks[1], (HELD, depth, width), dtype) * 0.1,
+            jax.random.normal(ks[2], (BOUND, width)))
+
+
+@pytest.mark.parametrize("load", sorted(LOADS))
+@pytest.mark.parametrize("depth,width", WIDTHS + [(384, 256)])
+def test_kernels_against_the_einsum_over_gathered_weights(depth, width,
+                                                          load):
+    """Both directions of a layer's products (``hidden -> ffn`` and, at
+    (384, 256), ``ffn -> hidden`` with the odd multiple contracted)."""
+    tile_e, live = _tiles(LOADS[load][0], BOUND // TILE)
+    lhs, w, seed = _operands(depth, width, jnp.float32)
+
+    def scalar(fn):
+        return lambda lhs, w: jnp.sum(fn(lhs, w, tile_e, live) * seed)
+    with dispatch.backend("pallas"):
+        got = _kernels(lhs, w, tile_e, live)
+        g_lhs, g_w = jax.grad(scalar(_kernels), (0, 1))(lhs, w)
+    want = _oracle(lhs, w, tile_e, live)
+    w_lhs, w_w = jax.grad(scalar(_oracle), (0, 1))(lhs, w)
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, atol=1e-4)
+    np.testing.assert_allclose(g_lhs, w_lhs, atol=1e-4)
+    np.testing.assert_allclose(g_w, w_w, atol=2e-4)
+    # dead tiles: zeros out and no gradient in, whatever the rows hold
+    rows = int(live) * TILE
+    assert not np.asarray(got[rows:]).any()
+    assert not np.asarray(g_lhs[rows:]).any()
+    for e, n in enumerate(LOADS[load][0]):      # no tile: a zero gradient
+        assert bool(np.asarray(g_w[e]).any()) == (n > 0)
+
+
+def test_bfloat16_operands_accumulate_in_float32_and_round_once():
+    tile_e, live = _tiles([600, 0, 500, 300], BOUND // TILE)
+    lhs, w, seed = _operands(256, 384, jnp.bfloat16, key=1)
+
+    def scalar(fn):
+        return lambda lhs, w: jnp.sum(fn(lhs, w, tile_e, live) * seed)
+    with dispatch.backend("pallas"):
+        got = _kernels(lhs, w, tile_e, live)
+        g_lhs, g_w = jax.grad(scalar(_kernels), (0, 1))(lhs, w)
+    assert (got.dtype, g_lhs.dtype, g_w.dtype) == (
+        jnp.float32, jnp.bfloat16, jnp.bfloat16)
+    f32 = lambda a: a.astype(jnp.float32)
+    np.testing.assert_allclose(got, _oracle(lhs, w, tile_e, live), atol=1e-4)
+    # the gradients against float32 arithmetic on the same rounded
+    # operands and cotangent: one rounding of the result apart
+    w_lhs, w_w = jax.grad(lambda lhs, w: jnp.sum(
+        _oracle(lhs, w, tile_e, live) * f32(seed.astype(jnp.bfloat16))),
+        (0, 1))(f32(lhs), f32(w))
+    for a, b in ((g_lhs, w_lhs), (g_w, w_w)):
+        np.testing.assert_allclose(f32(a), b, rtol=2 ** -7,
+                                   atol=2 ** -8 * float(jnp.abs(b).max()))
+
+
+def test_two_weights_share_a_call_and_their_row_gradients_one_rounding():
+    """A layer's gate and up: two results from one read of the rows, and
+    ``d lhs`` their float32 sum rounded once, not two rounded parts."""
+    tile_e, live = _tiles([128, 256, 5, 70], BOUND // TILE)
+    lhs, w_gate, seed = _operands(256, 384, jnp.bfloat16, key=2)
+    _, w_up, seed_up = _operands(256, 384, jnp.bfloat16, key=3)
+    f32 = lambda a: a.astype(jnp.float32)
+
+    def scalar(fn):
+        def loss(lhs, w_gate, w_up):
+            g, u = fn(lhs, w_gate, w_up)
+            return jnp.sum(g * seed + u * seed_up)
+        return jax.value_and_grad(loss, (0, 1, 2))
+    with dispatch.backend("pallas"):
+        jaxpr = jax.make_jaxpr(scalar(lambda lhs, *ws: gmm.grouped_matmul(
+            lhs, ws, tile_e, live)))(lhs, w_gate, w_up)
+        got, grads = scalar(lambda lhs, *ws: gmm.grouped_matmul(
+            lhs, ws, tile_e, live))(lhs, w_gate, w_up)
+    from tests.test_pallas_kernels import _pallas_names
+    assert sorted(_pallas_names(jaxpr)) == [
+        "apex_moe_gmm", "apex_moe_gmm", "apex_moe_tgmm", "apex_moe_tgmm"]
+    rounded = lambda s: f32(s.astype(jnp.bfloat16))
+    want, w_grads = jax.value_and_grad(lambda lhs, w_gate, w_up: jnp.sum(
+        _oracle(lhs, w_gate, tile_e, live) * rounded(seed)
+        + _oracle(lhs, w_up, tile_e, live) * rounded(seed_up)), (0, 1, 2))(
+            f32(lhs), f32(w_gate), f32(w_up))
+    np.testing.assert_allclose(got, want, rtol=1e-2)
+    assert grads[0].dtype == jnp.bfloat16
+    # one rounding: the float32 sum's nearest bfloat16, half an ulp off
+    np.testing.assert_allclose(f32(grads[0]), w_grads[0], rtol=2 ** -8,
+                               atol=1e-5)
+    for a, b in zip(grads[1:], w_grads[1:]):
+        np.testing.assert_allclose(f32(a), b, rtol=2 ** -7,
+                                   atol=2 ** -8 * float(jnp.abs(b).max()))
+
+
+def _layer(hidden, ffn, **kw):
+    return ExpertLayer(hidden=hidden, ffn=ffn, num_experts=E, top_k=K,
+                       experts_held=(0, HELD), dispatch_bound=BOUND, **kw)
+
+
+def _routed_to(loads, hidden, key=2):
+    """``(params' router, x)`` that send exactly ``loads[e]`` pairs to held
+    expert ``e``: a token's first choice is its held expert, its second an
+    absent one, through the first ``E`` features and an identity router."""
+    first = np.repeat(np.arange(HELD), loads)
+    n = len(first)
+    x = np.asarray(jax.random.normal(jax.random.key(key), (n, hidden))) * 0.5
+    x[:, :E] = 0.0
+    x[np.arange(n), first] = 2.0
+    x[np.arange(n), HELD + np.arange(n) % (E - HELD)] = 1.0
+    router = np.zeros((hidden, E), np.float32)
+    router[:E] = 8.0 * np.eye(E)
+    return jnp.asarray(router), jnp.asarray(x[np.random.RandomState(
+        0).permutation(n)], jnp.float32)
+
+
+def _both_sides(fn, *args):
+    """``fn(*args)`` through the einsum over gathered weights and through
+    the kernels (a wrapper each: a trace is cached by function)."""
+    want = (lambda *a: fn(*a))(*args)
+    with dispatch.backend("pallas"):
+        got = (lambda *a: fn(*a))(*args)
+    return got, want
+
+
+@pytest.mark.parametrize("load", sorted(LOADS))
+@pytest.mark.parametrize("hidden,ffn", WIDTHS)
+def test_the_layer_on_the_kernels_is_the_layer_on_the_einsum(hidden, ffn,
+                                                             load):
+    loads, live = LOADS[load]
+    layer = _layer(hidden, ffn)
+    params = layer.init(jax.random.key(3), 0.1)
+    params["router"], x = _routed_to(loads, hidden)
+
+    def run(params, x):
+        (loss, aux), grads = jax.value_and_grad(
+            lambda p, x: (lambda y, aux: (jnp.sum(jnp.sin(y)), aux))(
+                *layer.routed(p, x)), (0, 1), has_aux=True)(params, x)
+        return loss, aux, grads
+    (loss, aux, grads), (w_loss, w_aux, w_grads) = _both_sides(run, params,
+                                                               x)
+    for side in (aux, w_aux):       # the oracle's count, on both sides
+        assert int(side["live_tiles"]) == live
+        assert int(side["held_pairs"]) == sum(loads)
+        assert int(side["overflow_pairs"]) == 0
+    np.testing.assert_allclose(loss, w_loss, rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(w_grads)):
+        np.testing.assert_allclose(a, b, atol=1e-4)
+    for e, n in enumerate(loads):
+        assert bool(np.asarray(grads[0]["w_down"][e]).any()) == (n > 0)
+
+
+def test_live_tiles_stops_at_the_buffers_end():
+    """Pairs past the bound are counted; the live tiles are the
+    buffer's."""
+    layer = ExpertLayer(hidden=256, ffn=128, num_experts=E, top_k=K,
+                        experts_held=(0, HELD), dispatch_bound=4 * TILE)
+    params = layer.init(jax.random.key(3), 0.1)
+    params["router"], x = _routed_to([300, 200, 100, 90], 256)
+    (_, aux), (_, w_aux) = _both_sides(layer.routed, params, x)
+    for side in (aux, w_aux):
+        assert int(side["live_tiles"]) == 4
+        assert int(side["overflow_pairs"]) == 690 - (300 + 128)
+
+
+@pytest.mark.parametrize("hidden,ffn", WIDTHS)
+def test_under_checkpoint_and_a_scan_over_stacked_layers(hidden, ffn):
+    """What ``HybridLM`` does with a run of expert layers: the blocks
+    recomputed in the backward, one scanned body over two layers' stacked
+    leaves."""
+    layer = _layer(hidden, ffn, shared_ffn=128)
+    stacked = jax.tree.map(lambda *a: jnp.stack(a), *(
+        layer.init(jax.random.key(k), 0.1) for k in (4, 5)))
+    x = jax.random.normal(jax.random.key(6), (2, 200, hidden))
+
+    def loss(stacked, x):
+        @jax.checkpoint
+        def block(x, p):
+            y, aux = layer.apply(p, x)
+            return x + y, aux["live_tiles"]
+        y, live = jax.lax.scan(block, x, stacked)
+        return jnp.sum(jnp.sin(y)), live
+
+    def run(stacked, x):
+        return jax.value_and_grad(loss, (0, 1), has_aux=True)(stacked, x)
+    ((got, live), grads), ((want, w_live), w_grads) = _both_sides(
+        run, stacked, x)
+    np.testing.assert_array_equal(live, w_live)
+    assert live.shape == (2,) and int(live.min()) >= 1
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(w_grads)):
+        np.testing.assert_allclose(a, b, atol=1e-4)
+
+
+def test_the_path_is_read_from_the_platform_and_the_shapes():
+    """The kernels where ``dispatch.use_pallas()`` holds and both widths
+    are whole lanes with tiles of 128; the einsum on the CPU, under
+    ``backend("reference")`` and at any other shape."""
+    from tests.test_pallas_kernels import _pallas_names
+
+    def names(layer, hidden):
+        params = jax.eval_shape(layer.init, jax.random.key(0))
+        x = jax.ShapeDtypeStruct((64, hidden), jnp.float32)
+        return set(_pallas_names(jax.make_jaxpr(
+            lambda p, x: jax.grad(lambda p: jnp.sum(layer.routed(p, x)[0]))(
+                p))(params, x)))
+    kernels = {"apex_moe_gmm", "apex_moe_tgmm"}
+    assert names(_layer(256, 384), 256) == set()            # the CPU
+    with dispatch.backend("pallas"):
+        assert names(_layer(256, 384), 256) == kernels
+        assert names(_layer(256, 192), 256) == set()        # ffn: no lanes
+        assert names(_layer(192, 256), 192) == set()
+
+        class SmallTiles(ExpertLayer):
+            tile = 8
+        assert names(SmallTiles(hidden=256, ffn=128, num_experts=E,
+                                top_k=K), 256) == set()
+        with dispatch.backend("reference"):
+            assert names(_layer(256, 384), 256) == set()
+    assert not gmm.takes(2048, 1400, 128) and gmm.takes(2048, 1408, 128)

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from apex_tpu import prof
+from apex_tpu.contrib.moe import ExpertLayer
 from apex_tpu.models import HybridLM, TransformerLM
 from apex_tpu.models.hybrid_lm import _norm0, _rotary
 
@@ -84,7 +85,10 @@ def test_loss_goes_down_and_counters_come_out():
         first = first if first is not None else float(loss)
     assert float(loss) < first - 0.05
     assert set(c) == {"moe_overflow_pairs", "moe_held_pairs_max",
-                      "expert_load_max_over_mean"}
+                      "moe_live_tiles_max", "expert_load_max_over_mean"}
+    # every group's last tile may be part empty: pairs <= rows of live tiles
+    assert int(c["moe_held_pairs_max"]) <= int(c["moe_live_tiles_max"]) \
+        * ExpertLayer.tile
     assert int(c["moe_overflow_pairs"]) == 0
     assert float(c["expert_load_max_over_mean"]) >= 1.0
     assert float(lm.loss(params, toks)) == pytest.approx(float(

@@ -430,6 +430,12 @@ def _gdn_grad():
                     argnums=(0, 1, 2, 3, 4))
 
 
+def _moe_grad():
+    from apex_tpu.ops.pallas import grouped_matmul as G
+    return jax.grad(lambda lhs, w, tile_e, live: G.grouped_matmul(
+        lhs, (w,), tile_e, live)[0].sum(), argnums=(0, 1))
+
+
 def _site(fn, *args, **kw):
     return lambda: jax.make_jaxpr(lambda *a: fn(*a, **kw))(*args)
 
@@ -448,7 +454,11 @@ def _kernel_sites() -> dict:
     qkv = _f32(2, 256, 128)
     # two chunks of 64 tokens of two heads of 128
     gdn = [_f32(1, 2, 2, 64, 128)] * 4 + [_f32(1, 2, 2, 64)]
+    # two tiles of 128 rows over two experts' [128, 256]
+    moe = [_f32(256, 128), _f32(2, 128, 256), _i32(2), _i32()]
     return {
+        "apex_moe_gmm": _site(_moe_grad(), *moe),
+        "apex_moe_tgmm": _site(_moe_grad(), *moe),
         "apex_gdn_fwd": _site(_gdn_grad(), *gdn),
         "apex_gdn_bwd": _site(_gdn_grad(), *gdn),
         "apex_mt_scale": _site(P.scale, buf, scale_factor=2.0),
