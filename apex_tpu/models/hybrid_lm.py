@@ -1,12 +1,14 @@
 """A mixture-of-experts decoder whose layers differ: the block of today's
-Gated DeltaNet hybrids and of the latent-attention expert models, with
-the layer pattern as data.
+Gated DeltaNet hybrids, of the latent-attention expert models and of the
+short-convolution hybrids, with the layer pattern as data.
 
     x  = embed[tokens]
     x += mixer_i(norm(x))         mixer_i by ``layer_types[i]``:
                                   "linear" (Gated DeltaNet), "full"
-                                  (gated grouped-query softmax attention)
-                                  or "latent" (latent attention, MLA)
+                                  (grouped-query softmax attention, gated
+                                  or not), "latent" (latent attention,
+                                  MLA) or "conv" (the double-gated short
+                                  convolution)
     x += ffn_i(norm(x))           ffn_i by ``ffn_types[i]``: "experts" (a
                                   chip's share of a many-expert layer) or
                                   "dense" (one SwiGLU of ``dense_ffn``)
@@ -15,13 +17,14 @@ the layer pattern as data.
 
 ``norm(x; w) = x * rsqrt(mean(x^2) + eps) * (1 + w)`` is the zero-centred
 RMSNorm (weight zero at init) or, with ``zero_centred_norm=False``, the
-plain one (``* w``, one at init); no matrix has a bias; the head is not tied
-to the embedding. Where :class:`~apex_tpu.models.TransformerLM` is one
-GPT-2 block repeated, this model's layers differ, so it is a class of its
-own and shares with the dense LM what lies under it: the flash-attention
-kernels, the chunked head (``linear_cross_entropy``), recomputation
-(``jax.checkpoint`` a block) and the step builder
-(``apex_tpu.train_step.build_step``).
+plain one (``* w``, one at init); no matrix has a bias; the head is a
+matrix of its own or, with ``tied_head``, the embedding (its gradient is
+then the gather's scatter-add plus the chunked head's). Where
+:class:`~apex_tpu.models.TransformerLM` is one GPT-2 block repeated, this
+model's layers differ, so it is a class of its own and shares with the
+dense LM what lies under it: the flash-attention kernels, the chunked head
+(``linear_cross_entropy``), recomputation (``jax.checkpoint`` a block) and
+the step builder (``apex_tpu.train_step.build_step``).
 
 The **Gated DeltaNet mixer** (``linear_k_heads`` key heads and
 ``linear_v_heads`` value heads of ``linear_k_dim`` / ``linear_v_dim``):
@@ -33,15 +36,24 @@ float32; ``q`` and ``k`` are L2-normalised a head (``q`` scaled by
 ``ops.gated_delta_rule``; its output is RMS-normalised a head, gated by
 ``silu(z)`` and projected out.
 
-The **gated attention mixer** (``num_heads`` query heads over
+The **attention mixer** (``num_heads`` query heads over
 ``num_kv_heads`` key/value heads of ``head_dim``): ``W_q`` gives each
-head its query and an output gate; ``q`` and ``k`` are ``norm0``-ed a
-head; rotary positions turn the first ``rotary_dim`` of a head
-(half-split pairing); causal attention runs through ``flash_attention``
-with K and V **broadcast to the query heads in front of the kernel**
-(the kernels, which three other programs share, stay as they are; the
-broadcast's transpose sums a group's dK and dV); the result times
-``sigmoid(gate)`` is projected out.
+head its query and, with ``attn_gate``, an output gate; ``q`` and ``k``
+are ``norm0``-ed a head; rotary positions turn the first ``rotary_dim``
+of a head (half-split pairing; ``rotary_dim = head_dim`` turns it whole);
+causal attention runs through ``flash_attention`` with K and V
+**broadcast to the query heads in front of the kernel** (the kernels,
+which other programs share, stay as they are, and pad a head narrower
+than a lane tile to 128; the broadcast's transpose sums a group's dK and
+dV); the result, times ``sigmoid(gate)`` where there is a gate, is
+projected out.
+
+The **short-convolution mixer**: ``[B | C | u] = h W_in``, three streams
+``hidden`` wide; ``z = conv(B * u)``, a causal depthwise convolution of
+``conv_kernel`` taps a channel (no bias, zeros before the first token);
+``(C * z) W_out``. No activation function, no softmax, no state beyond
+``conv_kernel - 1`` tokens: two matmuls and a chain of elementwise
+passes between them.
 
 The **latent attention mixer** (``num_heads`` heads; queries and keys
 ``qk_nope_dim + qk_rope_dim`` wide over values ``v_head_dim`` wide):
@@ -64,8 +76,9 @@ step's own pairs an expert (``ExpertLayer.moved_bias``), as a ResNet's
 batch statistics travel through ``train_step.build_step``.
 
 Scopes (``prof.SCOPES``): ``embed``, ``linear_attention``,
-``delta_rule``, ``attention``, ``latent_attention``, ``mlp``,
-``moe_route``, ``moe_experts``, ``head_loss``; siblings, never nested.
+``delta_rule``, ``attention``, ``latent_attention``, ``short_conv``,
+``mlp``, ``moe_route``, ``moe_experts``, ``head_loss``; siblings, never
+nested.
 """
 
 from __future__ import annotations
@@ -82,7 +95,7 @@ from apex_tpu.ops.gated_delta_rule import gated_delta_rule
 __all__ = ["HybridLM"]
 
 _F32 = jnp.float32
-MIXERS = ("linear", "full", "latent")
+MIXERS = ("linear", "full", "latent", "conv")
 FFNS = ("experts", "dense")
 
 
@@ -122,12 +135,13 @@ class HybridLM:
     hidden: int
     layer_types: tuple          # a mixer kind a layer, of MIXERS
     ffn_types: tuple = ()       # an FFN kind a layer, of FFNS; () = experts
-    # gated softmax attention
+    # softmax attention
     num_heads: int = 16
     num_kv_heads: int = 2
     head_dim: int = 256
     rotary_dim: int = 64
     rope_theta: float = 1e7
+    attn_gate: bool = True      # W_q carries an output gate a head
     # latent attention (num_heads heads, rope_theta)
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
@@ -138,7 +152,7 @@ class HybridLM:
     linear_v_heads: int = 32
     linear_k_dim: int = 128
     linear_v_dim: int = 128
-    conv_kernel: int = 4
+    conv_kernel: int = 4        # taps: Gated DeltaNet's, the "conv" mixer's
     delta_chunk: int = 64
     # the expert layer (contrib.moe.ExpertLayer)
     num_experts: int = 512
@@ -154,6 +168,7 @@ class HybridLM:
     aux_coef: float = 0.001
     rms_eps: float = 1e-6
     zero_centred_norm: bool = True
+    tied_head: bool = False     # the head is the embedding
     attn_impl: str = "fast"     # "fast": the flash kernels; "default": jnp
     head_chunk: int = 0         # vocabulary columns a step of the head
     remat: bool = False         # recompute each block in the backward
@@ -190,6 +205,9 @@ class HybridLM:
     def _norm(self, x, w):
         return _norm0(x, w, self.rms_eps, self.zero_centred_norm)
 
+    def _head(self, params):
+        return params["embed" if self.tied_head else "head"]
+
     def router_state(self):
         """The sigmoid router's selection biases at the start, a row an
         expert layer; ``None`` for a model whose router has none."""
@@ -210,7 +228,9 @@ class HybridLM:
 
         def gain(n):        # a norm's weight at the start
             return (jnp.zeros if self.zero_centred_norm else jnp.ones)((n,))
-        p = {"embed": w(v, d), "head": w(v, d), "norm_f": gain(d)}
+        p = {"embed": w(v, d), "norm_f": gain(d)}
+        if not self.tied_head:
+            p["head"] = w(v, d)
         for i, (kind, ffn) in enumerate(zip(self.layer_types, self.ffns)):
             lp = {"norm1": gain(d), "norm2": gain(d)}
             if ffn == "experts":
@@ -227,6 +247,10 @@ class HybridLM:
                     "kv_norm": gain(r),
                     "w_kvb": w(r, h * (dn + self.v_head_dim)),
                     "w_o": w(h * self.v_head_dim, d)}
+            elif kind == "conv":
+                lp["conv"] = {"w_in": w(d, 3 * d),
+                              "taps": w(self.conv_kernel, d),
+                              "w_out": w(d, d)}
             elif kind == "linear":
                 lp["linear"] = {
                     "w_qkvz": w(d, 2 * kd + 2 * vd),
@@ -239,7 +263,8 @@ class HybridLM:
             else:
                 h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
                 lp["attn"] = {
-                    "w_q": w(d, h * 2 * hd), "w_k": w(d, kv * hd),
+                    "w_q": w(d, h * hd * (1 + self.attn_gate)),
+                    "w_k": w(d, kv * hd),
                     "w_v": w(d, kv * hd), "q_norm": gain(hd),
                     "k_norm": gain(hd), "w_o": w(h * hd, d)}
             p[f"layer_{i}"] = lp
@@ -291,8 +316,8 @@ class HybridLM:
         with jax.named_scope("attention"):
             p = lp["attn"]
             hid = self._norm(x, lp["norm1"])
-            qg = (hid @ p["w_q"]).reshape(b, t, h, 2 * hd)
-            q, gate = qg[..., :hd], qg[..., hd:]
+            qg = (hid @ p["w_q"]).reshape(b, t, h, hd * (1 + self.attn_gate))
+            q, gate = qg[..., :hd], qg[..., hd:]    # no attn_gate: empty
             k = (hid @ p["w_k"]).reshape(b, t, kv, hd)
             v = (hid @ p["w_v"]).reshape(b, t, kv, hd)
             q = _rotary(self._norm(q, p["q_norm"]),
@@ -306,10 +331,19 @@ class HybridLM:
                     for a in (k, v))
             attend = flash_attention if self.attn_impl == "fast" \
                 else reference_attention
-            a = attend(q, k, v, causal=True, scale=hd ** -0.5)
-            a = a.transpose(0, 2, 1, 3) * jax.nn.sigmoid(
-                gate.astype(_F32)).astype(x.dtype)
+            a = attend(q, k, v, causal=True,
+                       scale=hd ** -0.5).transpose(0, 2, 1, 3)
+            if self.attn_gate:
+                a = a * jax.nn.sigmoid(gate.astype(_F32)).astype(x.dtype)
             return x + a.reshape(b, t, h * hd) @ p["w_o"]
+
+    def _conv_mixer(self, lp, x):
+        d = self.hidden
+        with jax.named_scope("short_conv"):
+            p = lp["conv"]
+            bcu = self._norm(x, lp["norm1"]) @ p["w_in"]
+            z = _causal_conv(bcu[..., :d] * bcu[..., 2 * d:], p["taps"])
+            return x + (bcu[..., d:2 * d] * z) @ p["w_out"]
 
     def _latent_mixer(self, lp, x):
         from apex_tpu.contrib.multihead_attn.flash_attention import (
@@ -356,7 +390,8 @@ class HybridLM:
         """One layer: ``(x, the expert layer's aux | None)``. ``bias``:
         the sigmoid router's selection bias of this layer."""
         x = {"linear": self._linear_mixer, "full": self._full_mixer,
-             "latent": self._latent_mixer}[kind](lp, x)
+             "latent": self._latent_mixer,
+             "conv": self._conv_mixer}[kind](lp, x)
         if ffn == "dense":
             return self._dense_ffn(lp, x), None
         b, t, d = x.shape
@@ -419,7 +454,7 @@ class HybridLM:
         """Logits ``[B, T, vocab]`` in float32."""
         x, _ = self.hidden_states(params, tokens, router_bias)
         with jax.named_scope("head_loss"):
-            return jnp.einsum("btd,vd->btv", x, params["head"],
+            return jnp.einsum("btd,vd->btv", x, self._head(params),
                               preferred_element_type=_F32)
 
     def loss_with_counters(self, params: dict, tokens, router_bias=None):
@@ -432,7 +467,7 @@ class HybridLM:
         x, c = self.hidden_states(params, tokens[:, :-1], router_bias)
         with jax.named_scope("head_loss"):
             losses = linear_cross_entropy(
-                x.reshape(-1, self.hidden), params["head"],
+                x.reshape(-1, self.hidden), self._head(params),
                 tokens[:, 1:].reshape(-1),
                 chunk=self.head_chunk or self.vocab_size)
             loss = jnp.mean(losses) + self.aux_coef * c.pop(

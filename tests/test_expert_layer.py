@@ -20,9 +20,9 @@ class SmallTiles(ExpertLayer):
     tile = 8        # the layer's is 128: the chip's; no constructor knob
 
 
-def _layer(held=(), router="softmax", experts=E, **kw):
+def _layer(held=(), router="softmax", experts=E, shared=F, **kw):
     return SmallTiles(hidden=D, ffn=F, num_experts=experts, top_k=K,
-                      experts_held=held, shared_ffn=F, router=router,
+                      experts_held=held, shared_ffn=shared, router=router,
                       routed_scale=SCALE if router == "sigmoid" else 1.0,
                       **kw)
 
@@ -80,13 +80,15 @@ def test_the_uncut_layer_is_the_dense_mixture_plus_the_shared_expert(router):
 
 
 @pytest.mark.parametrize("router", ROUTERS)
-@pytest.mark.parametrize("experts, chips", [(E, 4), (E, 32), (64, 8)])
-def test_the_shares_add_up(experts, chips, router):
+@pytest.mark.parametrize("experts, chips, shared", [
+    (E, 4, F), (E, 32, F), (64, 8, F), (64, 8, 0)])
+def test_the_shares_add_up(experts, chips, shared, router):
     """The parts all shares give, the shared expert counted once, are the
-    uncut layer (the eight shares of a 64-expert layer among them); and a
-    share computes its own experts' part, nothing that stands in for the
-    others."""
-    kind = dict(router=router, experts=experts)
+    uncut layer (the eight shares of a 64-expert top-4 layer among them,
+    with a shared expert and, the bias-balanced sigmoid layer of the
+    short-convolution hybrids, with none); and a share computes its own
+    experts' part, nothing that stands in for the others."""
+    kind = dict(router=router, experts=experts, shared=shared)
     params, x = _params(2, **kind), _x(48, 3)
     bias = 0.0 if router == "softmax" else 0.2 * jax.random.normal(
         jax.random.key(4), (experts,))
@@ -101,7 +103,9 @@ def test_the_shares_add_up(experts, chips, router):
             part, _dense(params, x, lo, hi, router, bias), atol=1e-5)
         assert int(aux["overflow_pairs"]) == 0
         total = total + part
-    total = total + _layer(**kind).shared(params, x)
+    assert ("shared" in params) == bool(shared)
+    if shared:
+        total = total + _layer(**kind).shared(params, x)
     np.testing.assert_allclose(total, uncut, atol=1e-5)
 
 

@@ -15,7 +15,7 @@ import pytest
 from apex_tpu import prof
 from apex_tpu.contrib.moe import ExpertLayer
 from apex_tpu.models import HybridLM, TransformerLM
-from apex_tpu.models.hybrid_lm import _norm0, _rotary
+from apex_tpu.models.hybrid_lm import MIXERS, _norm0, _rotary
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
 
@@ -165,13 +165,18 @@ def test_partial_rotary_turns_the_first_dims_only_and_keeps_norms():
      ("latent_attention", "mlp")),
     ("latent", ("embed", "latent_attention", "mlp", "moe_route",
                 "moe_experts", "head_loss"),
-     ("attention", "linear_attention", "delta_rule"))])
+     ("attention", "linear_attention", "delta_rule", "short_conv")),
+    ("conv", ("embed", "short_conv", "attention", "mlp", "moe_route",
+              "moe_experts", "head_loss"),
+     ("latent_attention", "linear_attention", "delta_rule"))])
 def test_every_scope_of_the_step_is_in_the_vocabulary(model, scopes, absent):
     """The model's scopes are siblings in ``prof.SCOPES``; each shows in
     the compiled step's op names, forward and backward. Latent attention
-    opens one scope around all of it, the dense FFN the dense LM's
-    ``mlp``."""
-    lm = (_tiny if model == "hybrid" else _latent)(head_chunk=32, remat=True)
+    and the short convolution open one scope around all of theirs, the
+    dense FFN the dense LM's ``mlp``, the tied head ``head_loss`` and its
+    gather's scatter-add ``embed``."""
+    lm = {"hybrid": _tiny, "latent": _latent, "conv": _conv}[model](
+        head_chunk=32, remat=True)
     params = lm.init(jax.random.key(7))
     loss = lm.loss if model == "hybrid" else (
         lambda p, t: lm.loss_with_router_state(p, lm.router_state(), t)[0])
@@ -355,3 +360,154 @@ def test_the_step_builder_carries_the_routers_biases_beside_the_state():
     assert int(state[0][0].step) == 6
     assert 0.01 < float(counters["router_bias_abs_max"]) <= 0.06 + 1e-6
     assert int(counters["moe_overflow_pairs"]) == 0
+
+
+# -- the short convolution, ungated attention at heads of 64, a tied head -----
+
+def _conv(**kw):
+    base = dict(
+        vocab_size=96, hidden=32,
+        layer_types=("conv", "conv", "conv", "full", "conv"),
+        ffn_types=("dense",) + ("experts",) * 4, num_heads=4, num_kv_heads=2,
+        head_dim=8, rotary_dim=8, rope_theta=1e6, attn_gate=False,
+        conv_kernel=3, num_experts=8, top_k=2, expert_ffn=16, shared_ffn=0,
+        experts_held=(2, 6), router="sigmoid", dense_ffn=48, aux_coef=0.0,
+        rms_eps=1e-5, zero_centred_norm=False, tied_head=True)
+    return HybridLM(**{**base, **kw})
+
+
+def test_a_conv_conv_full_conv_pattern_led_by_a_dense_layer():
+    """The fourth mixer kind as data: a leading dense conv layer, then the
+    period conv, conv, full, conv with experts: scans of 1, 2, 1 and 1,
+    the result the layers' one after the other, no head among the leaves,
+    no shared expert, an ungated ``w_q``."""
+    assert MIXERS == ("linear", "full", "latent", "conv")
+    lm = _conv()
+    p = lm.init(jax.random.key(0))
+    assert "head" not in p
+    assert set(p["layer_0"]) == {"norm1", "norm2", "conv", "mlp"}
+    assert set(p["layer_1"]) == {"norm1", "norm2", "conv", "moe"}
+    assert set(p["layer_3"]) == {"norm1", "norm2", "attn", "moe"}
+    assert {k: v.shape for k, v in p["layer_1"]["conv"].items()} == {
+        "w_in": (32, 96), "taps": (3, 32), "w_out": (32, 32)}
+    assert p["layer_3"]["attn"]["w_q"].shape == (32, 4 * 8)     # no gate
+    assert "shared" not in p["layer_1"]["moe"]
+    toks = _tokens(key=3)[:, :-1]
+    bias = 0.3 * jax.random.normal(jax.random.key(4), (4, 8))
+    assert lm.router_state().shape == (4, 8)
+    jaxpr = jax.make_jaxpr(lm.apply)(p, toks, bias)
+    assert [e.params["length"] for e in jaxpr.eqns
+            if e.primitive.name == "scan"] == [1, 2, 1, 1]
+    x = p["embed"][toks]
+    x, aux = lm._block("conv", p["layer_0"], x, "dense")
+    assert aux is None
+    for i, kind in enumerate(lm.layer_types[1:], 1):
+        x, _ = lm._block(kind, p[f"layer_{i}"], x, "experts", bias[i - 1])
+    want = jnp.einsum("btd,vd->btv", lm._norm(x, p["norm_f"]), p["embed"])
+    np.testing.assert_allclose(lm.apply(p, toks, bias), want, atol=2e-5)
+    # the gated mixer and the untied head are what they were
+    gated = _tiny().init(jax.random.key(0))
+    assert gated["layer_1"]["attn"]["w_q"].shape == (32, 4 * 2 * 16)
+    assert "head" in gated
+
+
+def test_the_conv_mixer_against_a_naive_loop_and_it_is_causal():
+    """``x + (C * conv(B * u)) W_out`` with the convolution written token
+    by token and tap by tap; a changed token moves nothing before it, and
+    everything from it to two tokens on."""
+    lm = _conv()
+    lp = lm.init(jax.random.key(1), scale=0.3)["layer_1"]
+    lp["norm1"] = lp["norm1"] + 0.1 * jax.random.normal(jax.random.key(2),
+                                                        (32,))
+    x = jax.random.normal(jax.random.key(3), (2, 12, 32))
+    p = lp["conv"]
+    bcu = np.asarray(_norm0(x, lp["norm1"], 1e-5, False) @ p["w_in"])
+    b, c, u = bcu[..., :32], bcu[..., 32:64], bcu[..., 64:]
+    taps = np.asarray(p["taps"])
+    z = np.zeros_like(b)
+    for t in range(12):
+        for j in range(3):              # the last tap on the current token
+            if t - 2 + j >= 0:
+                z[:, t] += taps[j] * (b * u)[:, t - 2 + j]
+    want = np.asarray(x) + (c * z) @ np.asarray(p["w_out"])
+    got = lm._conv_mixer(lp, x)
+    assert float(np.abs(want - np.asarray(x)).max()) > 1e-2
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    moved = lm._conv_mixer(lp, x.at[:, 5].add(1.0))
+    np.testing.assert_array_equal(moved[:, :5], got[:, :5])
+    assert all(float(jnp.abs(moved[:, t] - got[:, t]).max()) > 1e-4
+               for t in (5, 6, 7))
+    np.testing.assert_allclose(moved[:, 8:], got[:, 8:], atol=1e-6)
+
+
+@pytest.mark.parametrize("seq", [128, 80])
+def test_the_ungated_mixer_at_heads_of_64_against_a_naive_softmax(seq):
+    """32 wide, 4 query heads over 2 key/value heads of 64, the whole head
+    rotated, no gate: through the flash kernels (which pad the head to 128
+    lanes), through plain attention, and head by head with nothing padded;
+    forward and the gradients."""
+    kw = dict(hidden=64, num_heads=4, num_kv_heads=2, head_dim=64,
+              rotary_dim=64, layer_types=("full",), ffn_types=("dense",))
+    fast, plain = _conv(attn_impl="fast", **kw), _conv(attn_impl="default",
+                                                       **kw)
+    lp = fast.init(jax.random.key(seq), scale=0.2)["layer_0"]
+    lp["norm1"] = lp["norm1"] + 0.1
+    lp["attn"]["q_norm"] = lp["attn"]["q_norm"] - 0.2
+    lp["attn"]["k_norm"] = lp["attn"]["k_norm"] + 0.3
+    x = jax.random.normal(jax.random.key(1), (2, seq, 64))
+
+    def naive(lp, x):
+        p = lp["attn"]
+        h = _norm0(x, lp["norm1"], 1e-5, False)
+        q = (h @ p["w_q"]).reshape(2, seq, 4, 64)
+        k = (h @ p["w_k"]).reshape(2, seq, 2, 64)
+        v = (h @ p["w_v"]).reshape(2, seq, 2, 64)
+        q = _rotary(_norm0(q, p["q_norm"], 1e-5, False), 1e6, 64)
+        k = _rotary(_norm0(k, p["k_norm"], 1e-5, False), 1e6, 64)
+        out = []
+        for head in range(4):           # query head i reads kv head i // 2
+            s = jnp.einsum("btd,bsd->bts", q[:, :, head],
+                           k[:, :, head // 2]) * 64 ** -0.5
+            s = jnp.where(jnp.tril(jnp.ones((seq, seq), bool)), s, -jnp.inf)
+            out.append(jax.nn.softmax(s, -1) @ v[:, :, head // 2])
+        return x + jnp.concatenate(out, -1) @ p["w_o"]
+    want = naive(lp, x)
+    assert float(jnp.abs(want - x).max()) > 1e-2
+    np.testing.assert_allclose(fast._full_mixer(lp, x), want, atol=2e-5)
+    np.testing.assert_allclose(plain._full_mixer(lp, x), want, atol=2e-5)
+    w = jax.random.normal(jax.random.key(9), x.shape)
+    g_want = jax.grad(lambda lp, x: jnp.sum(naive(lp, x) * w),
+                      argnums=(0, 1))(lp, x)
+    for lm in (fast, plain):
+        got = jax.grad(lambda lp, x: jnp.sum(lm._full_mixer(lp, x) * w),
+                       argnums=(0, 1))(lp, x)
+        for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                                jax.tree.leaves(g_want)):
+            np.testing.assert_allclose(a, b, atol=1e-4, err_msg=str(path))
+
+
+def test_the_tied_head_against_an_untied_model_whose_head_is_a_copy():
+    """The loss is the untied model's with ``head = embed``, and the
+    embedding's gradient is that model's two added: the gather's
+    scatter-add plus the chunked head's."""
+    tied = _conv(head_chunk=32, remat=True)
+    untied = _conv(head_chunk=32, remat=True, tied_head=False)
+    params = tied.init(jax.random.key(11), scale=0.1)
+    copy = {**params, "head": params["embed"]}
+    assert jax.tree.structure(untied.init(jax.random.key(0))) \
+        == jax.tree.structure(copy)
+    toks, bias = _tokens(key=12), tied.router_state()
+    loss, g = jax.value_and_grad(
+        lambda p: tied.loss_with_router_state(p, bias, toks)[0])(params)
+    want, g_want = jax.value_and_grad(
+        lambda p: untied.loss_with_router_state(p, bias, toks)[0])(copy)
+    assert float(loss) == pytest.approx(float(want), rel=1e-6)
+    assert float(jnp.linalg.norm(g_want["head"])) > 1e-3
+    assert float(jnp.linalg.norm(g_want["embed"])) > 1e-3
+    np.testing.assert_allclose(g["embed"], g_want["embed"] + g_want["head"],
+                               atol=1e-6)
+    np.testing.assert_allclose(
+        tied.apply(params, toks[:, :-1], bias),
+        untied.apply(copy, toks[:, :-1], bias), atol=1e-6)
+    for i in range(1, 5):       # no balance term, a share: no gradient
+        assert not np.asarray(g[f"layer_{i}"]["moe"]["router"]).any()
