@@ -11,10 +11,18 @@ parallelism possible (apex_tpu.parallel.ring_attention builds on this
 kernel's (out, lse) contract).
 
 Design notes:
-- grid (batch*heads, q_blocks, k_blocks); TPU grids iterate the LAST axis
-  innermost and sequentially, so the (acc, m, l) state lives in VMEM
-  scratch that persists across the k_block sweep (initialized at k==0,
-  finalized at k==nk-1).
+- grid (batch*heads, steps): a q row's sweep over its k blocks, row after
+  row, with the block of each step in a table the kernels and the
+  ``BlockSpec`` index maps read from SMEM (scalar prefetch; where no block
+  is dead the index maps compute the block from the step's number). TPU grids
+  iterate the LAST axis innermost and sequentially, so the (acc, m, l)
+  state lives in VMEM scratch that persists across a row's sweep
+  (initialized on its first step, finalized on its last).
+- a block's kind is known from the offsets alone (``_block_kind``) before
+  any body runs: a dead block (wholly above the causal diagonal or past the
+  k length) gets no step at all, so it is neither fetched nor computed;
+  ``block_census`` counts the dead, the interior (no element masked) and
+  the edge blocks (the diagonal, the k length's last block).
 - softmax statistics are carried as (block_q, 128) lane-replicated tiles
   (the VPU-friendly layout); ``lse`` is emitted lane-replicated and sliced
   by the wrapper.
@@ -33,11 +41,11 @@ Design notes:
 Backward is a pair of Pallas kernels with flash-style recompute (no saved
 probabilities, matching the reference backward exts' recompute-from-saved-
 softmax-stats shape, self_multihead_attn_cuda.cu bwd half):
-- dq kernel: grid (bh, q_blocks, k_blocks), dq accumulates in VMEM scratch
-  across the k sweep; emits per-block ds as the bias gradient when a bias
-  is present.
-- dk/dv kernel: grid (bh, k_blocks, q_blocks), dk/dv accumulate across the
-  q sweep.
+- dq kernel: the forward's grid (rows' sweeps), dq accumulates in VMEM
+  scratch across a sweep; emits per-block ds as the bias gradient when a
+  bias is present (then every block keeps its step: a dead one's are zeros).
+- dk/dv kernel: a k column's sweep over its q blocks, column after column;
+  dk/dv accumulate across a sweep.
 Both recompute p = exp(s - lse) from the forward's saved lse; the dO·O row
 term (delta) and the lse cotangent are folded into one per-row tensor
 host-side. A jnp chunked-scan twin (``_bwd_chunked``) remains as the
@@ -51,6 +59,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -157,47 +166,178 @@ def _masked_scores(s, off_ref, qb, kb, causal):
     return s
 
 
-def _kvb_spec(kvb, block_k):
-    """BlockSpec for the per-key bias [1|BH, 1, Sk]: a (1, 1, block_k)
-    column slice, shared across batch-heads when the leading dim is 1."""
-    shared = kvb.shape[0] == 1
-    return pl.BlockSpec(
-        (1, 1, block_k),
-        (lambda b, i, j: (0, 0, j)) if shared else
-        (lambda b, i, j: (b, 0, j)))
-
-
-def _block_live(off_ref, qb, kb, bq, bk, causal):
-    """False when the (qb, kb) block is entirely masked (above the causal
-    diagonal or past the k length) and its compute can be skipped."""
-    live = kb * bk < off_ref[2]
+def _block_kind(offs, qb, kb, bq, bk, causal):
+    """``(live, interior)`` of score block (qb, kb), from the offsets
+    alone. Not live (dead): wholly above the causal diagonal or past the
+    k length, nothing to compute, and no grid step. Interior: no element
+    masked. Live and not interior (edge): the diagonal and the k length's
+    last block. The kernels act on live alone: a body without masks for
+    the interior blocks measured nothing on the chip (PERF.md, PR 35);
+    ``block_census`` counts all three. ``offs`` indexes as (q_start, k_start,
+    k_len, ...): the kernels' SMEM ref, or plain integers in
+    ``block_census``."""
+    k_lo = kb * bk
+    live = k_lo < offs[2]
+    interior = k_lo + bk <= offs[2]
     if causal:
-        q_max = off_ref[0] + qb * bq + bq - 1
-        k_min = off_ref[1] + kb * bk
-        live = jnp.logical_and(live, q_max >= k_min)
-    return live
+        q_lo = offs[0] + qb * bq
+        k_pos = offs[1] + k_lo
+        live = live & (q_lo + bq - 1 >= k_pos)
+        interior = interior & (q_lo >= k_pos + bk - 1)
+    return live, interior
 
 
-def _fwd_kernel(nk: int, causal: bool, has_bias: bool, has_kvb: bool,
+def _grid_kinds(xp, offs, nq, nk, bq, bk, causal):
+    """``_block_kind`` of every block of an ``nq`` x ``nk`` grid, in numpy
+    or jax.numpy: the blocks' indices [nq, 1] and [1, nk], and live and
+    interior [nq, nk]."""
+    qb = xp.arange(nq, dtype=xp.int32)[:, None]
+    kb = xp.arange(nk, dtype=xp.int32)[None, :]
+    live, interior = (xp.broadcast_to(a, (nq, nk)) for a in _block_kind(
+        offs, qb, kb, bq, bk, causal))
+    return qb, kb, live, interior
+
+
+# a step's code: qb | kb << 12 | live << 24 | first << 25 | last << 26
+_IDX, _KB, _LIVE, _FIRST, _LAST = 0xFFF, 12, 24, 25, 26
+
+
+def _steps(known, offs, nq, nk, bq, bk, causal, by_col=False, every=False):
+    """The grid's steps in order, int32 [T], one code each: the block
+    (qb, kb) a step holds, whether it is live, and whether it is the first
+    and the last step of its line (a q row's sweep over k blocks;
+    ``by_col``: a k column's sweep over q blocks), where the accumulators
+    start and the results are written. Dead blocks get no step, so they cost nothing
+    (``every``: they keep theirs, for a result that has a block each); a
+    line with no live block keeps one dead step, which writes its zeros.
+    ``known`` offsets (plain integers, not traced) give exactly the steps
+    there are, as constants; traced ones (``known`` None: a ring step's
+    shard positions) as many as there are blocks, and the steps past the
+    last one repeat its block as dead steps that are neither first nor
+    last: nothing to fetch, nothing to do."""
+    xp, offs = (jnp, offs) if known is None else (np, known)
+    assert max(nq, nk) <= _IDX + 1
+    qb, kb, live, _ = _grid_kinds(xp, offs, nq, nk, bq, bk, causal)
+    code = qb | kb << _KB | live.astype(xp.int32) << _LIVE
+    if by_col:
+        live, code = live.T, code.T
+    lines, sweep = live.shape
+    line = xp.broadcast_to(xp.arange(lines)[:, None], live.shape).reshape(-1)
+    # a line with no live block keeps its first step
+    keep = xp.ones_like(live) if every else live | (
+        (xp.arange(sweep)[None, :] == 0) & ~live.any(axis=1, keepdims=True))
+    keep, code = keep.reshape(-1), code.reshape(-1)
+    order = xp.argsort(~keep, stable=True)      # the kept steps, in order
+    n = keep.sum()
+    t = xp.arange(nq * nk if known is None else int(n))
+    at = order[xp.minimum(t, n - 1)]
+    line = line[at]
+    edge = xp.full((1,), -1, line.dtype)
+    first = line != xp.concatenate([edge, line[:-1]])
+    last = (line != xp.concatenate([line[1:], edge])) | (t == n - 1)
+    code = xp.where(t < n, code[at] | first << _FIRST | last << _LAST,
+                    code[at] & (_IDX | _IDX << _KB))
+    return xp.asarray(code, xp.int32)
+
+
+def _block_of(code):
+    """The (qb, kb) of a step's code."""
+    return code & _IDX, (code >> _KB) & _IDX
+
+
+def _step_block(b, t, steps, offs=None):
+    """Index maps: the (b, qb, kb) of step ``t``."""
+    return (b, *_block_of(steps[t]))
+
+
+def _at(steps, known, nq, nk, by_col=False):
+    """What the index maps read a step's block from: the table, or, where
+    the table is the identity (offsets known, every block kept: a grid
+    with nothing dead), the step's number alone. Reading the table in
+    each of a call's 8-10 index maps costs a live step 0.08-0.15 us on the
+    v5e, 8% of a long grid's time, which buys nothing where no step is
+    gone (PERF.md, PR 35)."""
+    if known is None or len(steps) != nq * nk:
+        return _step_block
+    if by_col:
+        return lambda b, t, *_: (b, t % nq, t // nq)
+    return lambda b, t, *_: (b, t // nk, t % nk)
+
+
+def _run_step(code, init, body, finalize):
+    """One grid step: start the line's accumulators on its first step, run
+    ``body`` on a live block, write the line's results on its last."""
+    pl.when((code >> _FIRST) & 1 == 1)(init)
+    pl.when((code >> _LIVE) & 1 == 1)(body)
+    pl.when((code >> _LAST) & 1 == 1)(finalize)
+
+
+def block_census(sq: int, sk: int, block_q: int, block_k: int, causal: bool,
+                 q_start: int = 0, k_start: int = 0,
+                 k_len: Optional[int] = None) -> dict:
+    """Grid steps by kind for one batch-head, ``{"dead", "interior",
+    "edge"}``, from the kernels' own predicate: of a kernel over ``sq`` x
+    ``sk`` scores in ``block_q`` x ``block_k`` blocks, the blocks that get
+    no step (with traced offsets an empty one), and of those that get one
+    the blocks no mask touches and the blocks one does. ``sq``, ``sk``: the lengths the grid tiles
+    (the backward's are the forward's padded ones); ``k_len``: the
+    unpadded key length, ``sk`` by default."""
+    _, _, live, interior = _grid_kinds(
+        np, (q_start, k_start, sk if k_len is None else k_len),
+        -(-sq // block_q), -(-sk // block_k), block_q, block_k, causal)
+    return {"dead": int((~live).sum()), "interior": int(interior.sum()),
+            "edge": int((live & ~interior).sum())}
+
+
+def _spec(shape, block_index, at=_step_block):
+    """A BlockSpec whose block is ``block_index(b, qb, kb)``, with ``at``
+    mapping a grid step's indices (and the scalar prefetch) to the (b, qb,
+    kb) it holds: by default through the step table."""
+    return pl.BlockSpec(shape, lambda *g: block_index(*at(*g)))
+
+
+def _in_specs(block_q, block_k, d, bias, kvb, backward=False, **at):
+    """BlockSpecs of q, k, v (``backward``: then dO, lse, delta), then
+    the bias and the per-key bias [1|BH, 1, Sk] where present (either is
+    shared across batch-heads when its leading dim is 1)."""
+    rows = _spec((1, block_q, d), lambda b, i, j: (b, i, 0), **at)
+    cols = _spec((1, block_k, d), lambda b, i, j: (b, j, 0), **at)
+    specs = [rows, cols, cols]
+    if backward:
+        stat = _spec((1, block_q, LANES), lambda b, i, j: (b, i, 0), **at)
+        specs += [rows, stat, stat]
+    if bias is not None:
+        specs.append(_spec(
+            (1, block_q, block_k),
+            (lambda b, i, j: (0, i, j)) if bias.shape[0] == 1 else
+            (lambda b, i, j: (b, i, j)), **at))
+    if kvb is not None:
+        specs.append(_spec(
+            (1, 1, block_k),
+            (lambda b, i, j: (0, 0, j)) if kvb.shape[0] == 1 else
+            (lambda b, i, j: (b, 0, j)), **at))
+    return specs
+
+
+def _fwd_kernel(causal: bool, has_bias: bool, has_kvb: bool,
                 scale: float, dropout: float, *refs):
     refs = list(refs)
-    off_ref, q_ref, k_ref, v_ref = refs[:4]
-    del refs[:4]
+    steps_ref, off_ref, q_ref, k_ref, v_ref = refs[:5]
+    del refs[:5]
     bias_ref = refs.pop(0) if has_bias else None
     kvb_ref = refs.pop(0) if has_kvb else None
     o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
 
-    bh_i, qb, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    bq = q_ref.shape[1]
-    bk = k_ref.shape[1]
+    # program_id must be read OUTSIDE pl.when bodies: interpret mode only
+    # substitutes grid indices for top-level reads
+    code = steps_ref[pl.program_id(1)]
+    bh_i, (qb, kb) = pl.program_id(0), _block_of(code)
 
-    @pl.when(kb == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(_block_live(off_ref, qb, kb, bq, bk, causal))
     def _body():
         q = q_ref[0].astype(jnp.float32)           # [bq, d]
         k = k_ref[0].astype(jnp.float32)           # [bk, d]
@@ -235,7 +375,6 @@ def _fwd_kernel(nk: int, causal: bool, has_bias: bool, has_kvb: bool,
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(kb == nk - 1)
     def _finalize():
         l = l_ref[:, :1]
         safe_l = jnp.where(l > 0.0, l, 1.0)
@@ -243,9 +382,11 @@ def _fwd_kernel(nk: int, causal: bool, has_bias: bool, has_kvb: bool,
         lse = jnp.where(l > 0.0, m_ref[:, :1] + jnp.log(safe_l), NEG_INF)
         lse_ref[...] = jnp.broadcast_to(lse, lse_ref.shape)
 
+    _run_step(code, _init, _body, _finalize)
+
 
 def _flash_fwd(q, k, v, bias, kvb, offs, *, causal, scale, block_q, block_k,
-               dropout=0.0):
+               dropout=0.0, known=None):
     """q,k,v: [BH, S, D], pre-padded so block sizes divide S and D == lane
     multiple. offs: int32[4] = (q_start, k_start, k_len, seed) — k_len is
     the UNPADDED key length, masked in-kernel (no O(S^2) pad-bias tensor);
@@ -258,48 +399,36 @@ def _flash_fwd(q, k, v, bias, kvb, offs, *, causal, scale, block_q, block_k,
     nq = sq // block_q
     nk = sk // block_k
 
-    in_specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),                     # offs
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),  # q
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),  # k
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),  # v
-    ]
-    args = [offs, q, k, v]
     has_bias = bias is not None
-    if has_bias:
-        bb = bias.shape[0]
-        in_specs.append(pl.BlockSpec(
-            (1, block_q, block_k),
-            (lambda b, i, j: (0, i, j)) if bb == 1 else
-            (lambda b, i, j: (b, i, j))))
-        args.append(bias)
     has_kvb = kvb is not None
-    if has_kvb:
-        in_specs.append(_kvb_spec(kvb, block_k))
-        args.append(kvb)
+    args = [q, k, v] + [a for a in (bias, kvb) if a is not None]
 
-    kernel = functools.partial(_fwd_kernel, nk, causal, has_bias, has_kvb,
+    steps = _steps(known, offs, nq, nk, block_q, block_k, causal)
+    at = _at(steps, known, nq, nk)
+    kernel = functools.partial(_fwd_kernel, causal, has_bias, has_kvb,
                                float(scale), float(dropout))
     o, lse = pl.pallas_call(
         kernel,
-        grid=(bh, nq, nk),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, LANES), lambda b, i, j: (b, i, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,                          # steps, offs
+            grid=(bh, len(steps)),
+            in_specs=_in_specs(block_q, block_k, d, bias, kvb, at=at),
+            out_specs=[
+                _spec((1, block_q, d), lambda b, i, j: (b, i, 0), at),
+                _spec((1, block_q, LANES), lambda b, i, j: (b, i, 0), at),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+            ]),
         out_shape=[
             _sds((bh, sq, d), q.dtype, vma=_vma(q, k, v)),
             _sds((bh, sq, LANES), jnp.float32, vma=_vma(q, k, v)),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-        ],
         interpret=_interpret(),
         name="apex_flash_fwd",
-    )(*args)
+    )(steps, offs, *args)
     return o, lse[:, :, 0]
 
 
@@ -347,29 +476,24 @@ def _recompute_p_ds(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
     return pd, ds, q, k, do
 
 
-def _bwd_dq_kernel(nk: int, causal: bool, has_bias: bool, has_kvb: bool,
+def _bwd_dq_kernel(causal: bool, has_bias: bool, has_kvb: bool,
                    emit_dbias: bool, scale: float, dropout: float, *refs):
     refs = list(refs)
-    (off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref) = refs[:7]
-    del refs[:7]
+    (steps_ref, off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+     dlt_ref) = refs[:8]
+    del refs[:8]
     bias_ref = refs.pop(0) if has_bias else None
     kvb_ref = refs.pop(0) if has_kvb else None
     dq_ref = refs.pop(0)
     dbias_ref = refs.pop(0) if emit_dbias else None
     dq_acc = refs.pop(0)
 
-    # program_id must be read OUTSIDE pl.when bodies: interpret mode only
-    # substitutes grid indices for top-level reads
-    bh_i, qb, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    code = steps_ref[pl.program_id(1)]
+    bh_i, (qb, kb) = pl.program_id(0), _block_of(code)
 
-    @pl.when(kb == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    live = _block_live(off_ref, qb, kb, bq, bk, causal)
-
-    @pl.when(live)
     def _body():
         _, ds, _, k, _ = _recompute_p_ds(
             off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
@@ -380,34 +504,34 @@ def _bwd_dq_kernel(nk: int, causal: bool, has_bias: bool, has_kvb: bool,
             ds * scale, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if dbias_ref is not None:
-        @pl.when(jnp.logical_not(live))
-        def _zero_dbias():
-            dbias_ref[0] = jnp.zeros_like(dbias_ref[0])
-
-    @pl.when(kb == nk - 1)
     def _finalize():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
+    _run_step(code, _init, _body, _finalize)
 
-def _bwd_dkv_kernel(nq: int, causal: bool, has_bias: bool, has_kvb: bool,
+    if dbias_ref is not None:   # every block has its step: zeros on a dead one
+        @pl.when((code >> _LIVE) & 1 == 0)
+        def _zero_dbias():
+            dbias_ref[0] = jnp.zeros_like(dbias_ref[0])
+
+
+def _bwd_dkv_kernel(causal: bool, has_bias: bool, has_kvb: bool,
                     scale: float, dropout: float, *refs):
     refs = list(refs)
-    (off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref) = refs[:7]
-    del refs[:7]
+    (steps_ref, off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+     dlt_ref) = refs[:8]
+    del refs[:8]
     bias_ref = refs.pop(0) if has_bias else None
     kvb_ref = refs.pop(0) if has_kvb else None
     dk_ref, dv_ref, dk_acc, dv_acc = refs
 
-    bh_i, kb, qb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    code = steps_ref[pl.program_id(1)]
+    bh_i, (qb, kb) = pl.program_id(0), _block_of(code)
 
-    @pl.when(qb == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(_block_live(off_ref, qb, kb, bq, bk, causal))
     def _body():
         pd, ds, q, _, do = _recompute_p_ds(
             off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
@@ -419,10 +543,11 @@ def _bwd_dkv_kernel(nq: int, causal: bool, has_bias: bool, has_kvb: bool,
             ds * scale, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # [bk, d]
 
-    @pl.when(qb == nq - 1)
     def _finalize():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    _run_step(code, _init, _body, _finalize)
 
 
 def _bwd_dbias_kernel(nbh: int, causal: bool, has_kvb: bool, scale: float,
@@ -444,7 +569,7 @@ def _bwd_dbias_kernel(nbh: int, causal: bool, has_kvb: bool, scale: float,
     def _init():
         ds_acc[...] = jnp.zeros_like(ds_acc)
 
-    @pl.when(_block_live(off_ref, qb, kb, bq, bk, causal))
+    @pl.when(_block_kind(off_ref, qb, kb, bq, bk, causal)[0])
     def _body():
         _, ds, *_ = _recompute_p_ds(
             off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
@@ -457,7 +582,7 @@ def _bwd_dbias_kernel(nbh: int, causal: bool, has_kvb: bool, scale: float,
 
 
 def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
-                bias_grad, dropout=0.0):
+                bias_grad, dropout=0.0, known=None):
     """Pallas flash backward over the padded residuals. Returns
     (dq, dk, dv, dbias) with dbias None when no bias was supplied and
     zeros when ``bias_grad`` is False (mask-only biases)."""
@@ -482,52 +607,37 @@ def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
     lse_r = jnp.broadcast_to(lse[..., None], (*lse.shape, LANES))
     dlt_r = jnp.broadcast_to(delta[..., None], (*delta.shape, LANES))
 
-    stat_spec_i = pl.BlockSpec((1, block_q, LANES), lambda b, i, j: (b, i, 0))
-    common = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),                      # offs
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),   # q
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),   # k
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),   # v
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),   # do
-        stat_spec_i,                                                # lse
-        stat_spec_i,                                                # delta
-    ]
-    args = [offs, q, k, v, do, lse_r, dlt_r]
-    opt_specs = []
-    if has_bias:
-        bb = bias.shape[0]
-        bias_spec = pl.BlockSpec(
-            (1, block_q, block_k),
-            (lambda b, i, j: (0, i, j)) if bb == 1 else
-            (lambda b, i, j: (b, i, j)))
-        args.append(bias)
-        opt_specs.append(bias_spec)
-    if has_kvb:
-        kvb_spec = _kvb_spec(kvb, block_k)
-        args.append(kvb)
-        opt_specs.append(kvb_spec)
-
+    args = [q, k, v, do, lse_r, dlt_r] + [
+        a for a in (bias, kvb) if a is not None]
     vma = _vma(q, k, v, do)
 
-    # --- dq (+ per-bh dbias) over grid (bh, nq, nk) ------------------------
-    dq_out_specs = [pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))]
+    in_specs = functools.partial(_in_specs, block_q, block_k, d, bias, kvb,
+                                 backward=True)
+
+    # --- dq (+ per-bh dbias): each q row's sweep over its k blocks ---------
+    steps = _steps(known, offs, nq, nk, block_q, block_k, causal,
+                   every=dbias_in_dq)
+    at = _at(steps, known, nq, nk)
+    dq_out_specs = [_spec((1, block_q, d), lambda b, i, j: (b, i, 0), at)]
     dq_out_shape = [_sds((bh, sq, d), q.dtype, vma=vma)]
     if dbias_in_dq:
-        dq_out_specs.append(pl.BlockSpec(
-            (1, block_q, block_k), lambda b, i, j: (b, i, j)))
+        dq_out_specs.append(_spec(
+            (1, block_q, block_k), lambda b, i, j: (b, i, j), at))
         dq_out_shape.append(
             _sds((bh, sq, sk), jnp.float32, vma=vma))
     dq_res = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, nk, causal, has_bias, has_kvb,
+        functools.partial(_bwd_dq_kernel, causal, has_bias, has_kvb,
                           dbias_in_dq, float(scale), float(dropout)),
-        grid=(bh, nq, nk),
-        in_specs=common + opt_specs,
-        out_specs=dq_out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,                          # steps, offs
+            grid=(bh, len(steps)),
+            in_specs=in_specs(at=at),
+            out_specs=dq_out_specs,
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
         out_shape=dq_out_shape,
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
         name="apex_flash_bwd_dq",
-    )(*args)
+    )(steps, offs, *args)
     if dbias_in_dq:
         dq, dbias = dq_res
         dbias = dbias.astype(bias.dtype)
@@ -539,47 +649,40 @@ def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
             functools.partial(_bwd_dbias_kernel, bh, causal, has_kvb,
                               float(scale), float(dropout)),
             grid=(nq, nk, bh),
-            in_specs=[common[0]] + [
-                pl.BlockSpec(s.block_shape,
-                             lambda i, j, b, _m=s.index_map: _m(b, i, j))
-                for s in common[1:] + opt_specs
-            ],
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]        # offs
+            + in_specs(at=lambda i, j, b: (b, i, j)),
             out_specs=pl.BlockSpec((1, block_q, block_k),
                                    lambda i, j, b: (0, i, j)),
             out_shape=_sds((1, sq, sk), jnp.float32, vma=vma),
             scratch_shapes=[pltpu.VMEM((block_q, block_k), jnp.float32)],
             interpret=_interpret(),
             name="apex_flash_bwd_dbias",
-        )(*args).astype(bias.dtype)
+        )(offs, *args).astype(bias.dtype)
     if has_bias and not emit_dbias:
         dbias = jnp.zeros_like(bias)
 
-    # --- dk / dv over grid (bh, nk, nq) ------------------------------------
-    def _swap(spec):
-        # same block shapes, but grid axes are (b, kb, qb): j := axis 1,
-        # i := axis 2
-        return pl.BlockSpec(spec.block_shape,
-                            lambda b, j, i, _m=spec.index_map: _m(b, i, j))
-
-    dkv_in_specs = [common[0]] + [_swap(s) for s in common[1:] + opt_specs]
+    # --- dk / dv: each k column's sweep over its q blocks ------------------
+    steps = _steps(known, offs, nq, nk, block_q, block_k, causal,
+                   by_col=True)
+    at = _at(steps, known, nq, nk, by_col=True)
+    col = _spec((1, block_k, d), lambda b, i, j: (b, j, 0), at)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, nq, causal, has_bias, has_kvb,
+        functools.partial(_bwd_dkv_kernel, causal, has_bias, has_kvb,
                           float(scale), float(dropout)),
-        grid=(bh, nk, nq),
-        in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,                          # steps, offs
+            grid=(bh, len(steps)),
+            in_specs=in_specs(at=at),
+            out_specs=[col, col],
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32)]),
         out_shape=[
             _sds((bh, sk, d), k.dtype, vma=vma),
             _sds((bh, sk, d), v.dtype, vma=vma),
         ],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=_interpret(),
         name="apex_flash_bwd_dkv",
-    )(*args)
+    )(steps, offs, *args)
     return dq, dk, dv, dbias
 
 
@@ -737,9 +840,9 @@ def _bwd_chunked(res, do, dlse, *, causal, scale, block_k, bias_grad=True,
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12, 13))
 def _flash_core(q, k, v, bias, kvb, causal, scale, block_q, block_k,
-                bwd_block_q, bwd_block_k, bias_grad, dropout, offs):
+                bwd_block_q, bwd_block_k, bias_grad, dropout, known, offs):
     """Returns (o, lse). lse is a true primal output with a correct
     cotangent path (its gradient folds into ds — needed by ring attention,
     which differentiates through the (o, lse) shard merge).
@@ -752,13 +855,16 @@ def _flash_core(q, k, v, bias, kvb, causal, scale, block_q, block_k,
     independently (their VMEM working set is ~3x the forward's); must
     divide the padded sequence lengths."""
     return _flash_fwd(q, k, v, bias, kvb, offs, causal=causal, scale=scale,
-                      block_q=block_q, block_k=block_k, dropout=dropout)
+                      block_q=block_q, block_k=block_k, dropout=dropout,
+                      known=known)
 
 
 def _flash_core_fwd(q, k, v, bias, kvb, causal, scale, block_q, block_k,
-                    bwd_block_q, bwd_block_k, bias_grad, dropout, offs):
+                    bwd_block_q, bwd_block_k, bias_grad, dropout, known,
+                    offs):
     o, lse = _flash_fwd(q, k, v, bias, kvb, offs, causal=causal, scale=scale,
-                        block_q=block_q, block_k=block_k, dropout=dropout)
+                        block_q=block_q, block_k=block_k, dropout=dropout,
+                        known=known)
     return (o, lse), (q, k, v, bias, kvb, offs, lse, o)
 
 
@@ -794,7 +900,7 @@ def flash_min_s() -> int:
 
 
 def _flash_core_bwd(causal, scale, block_q, block_k, bwd_block_q,
-                    bwd_block_k, bias_grad, dropout, res, cts):
+                    bwd_block_k, bias_grad, dropout, known, res, cts):
     do, dlse = cts
     if _bwd_impl() == "chunked":
         # the chunked path exists for O(S*block) MEMORY: keep its k-chunk
@@ -810,7 +916,7 @@ def _flash_core_bwd(causal, scale, block_q, block_k, bwd_block_q,
                                         scale=scale, block_q=bwd_block_q,
                                         block_k=bwd_block_k,
                                         bias_grad=bias_grad,
-                                        dropout=dropout)
+                                        dropout=dropout, known=known)
     kvb, offs = res[4], res[5]
     d_kvb = None if kvb is None else jnp.zeros_like(kvb)
     d_offs = jnp.zeros_like(offs)  # int32 cotangent placeholder
@@ -818,6 +924,59 @@ def _flash_core_bwd(causal, scale, block_q, block_k, bwd_block_q,
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
+
+
+def block_sizes(sq: int, sk: int, block_q: Optional[int] = None,
+                block_k: Optional[int] = None,
+                bwd_block_q: Optional[int] = None,
+                bwd_block_k: Optional[int] = None):
+    """``(block_q, block_k, bwd_block_q, bwd_block_k)`` as
+    ``flash_attention`` tiles ``sq`` x ``sk`` scores: what was given, and
+    its defaults for the rest."""
+    # Adaptive default: wide blocks keep the MXU matmuls fat and cut the
+    # grid-step count up to 16x vs a fixed 128 — at S=16k the fixed size
+    # meant 262k sequential grid steps and the kernel ran
+    # grid-overhead-bound (~1.5% MFU, docs/PERF.md r03). The pick is
+    # divisor-aware (largest of 512/384/256/128 dividing the 128-rounded
+    # length) so mid-length sequences don't pay pad blowup; note a wider
+    # block changes the online-softmax accumulation ORDER for
+    # 128 < S <= 512 vs the old fixed-128 blocking (allclose, not
+    # bitwise, vs previous builds).
+    if block_q is None:
+        block_q = _pick_block(sq)
+    if block_k is None:
+        block_k = _pick_block(sk)
+    block_q = min(block_q, _round_up(sq, 16))
+    block_k = min(block_k, _round_up(sk, 16))
+    qpad = (-sq) % block_q
+    kpad = (-sk) % block_k
+    # Backward blocks default to the forward's CAPPED at q<=256 (k can
+    # stay wide): the bwd kernels hold ~3x the forward's VMEM working
+    # set, and the r4 on-chip sweep (docs/PERF.md r04 block sweep) measured
+    # bwd 512x512 at 162.8 ms vs 18.4 ms for 256x512 at S=4096 — a VMEM
+    # spill cliff. 256x512 was the sweep's best; the cap costs <7% vs
+    # any other measured combo and avoids the 9x cliff. Overrides must
+    # tile the padded lengths (the backward runs over the same padded
+    # residuals).
+    if bwd_block_q is None:
+        bwd_block_q = block_q
+        if block_q > 256:
+            # largest of {256, 192, 128} dividing the padded length
+            # (block_q in {384, 512} guarantees a hit); sequences whose
+            # own block is an odd size <= 256 keep it — one big tile
+            # beats a sliver tile
+            for cand in (256, 192, 128):
+                if (sq + qpad) % cand == 0:
+                    bwd_block_q = cand
+                    break
+    if bwd_block_k is None:
+        bwd_block_k = block_k
+    for name, blk, sz in (("bwd_block_q", bwd_block_q, sq + qpad),
+                          ("bwd_block_k", bwd_block_k, sk + kpad)):
+        if sz % blk:
+            raise ValueError(f"{name}={blk} must divide the padded "
+                             f"sequence length {sz}")
+    return block_q, block_k, bwd_block_q, bwd_block_k
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -875,49 +1034,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if scale is None:
         scale = 1.0 / float(d) ** 0.5
 
-    # Adaptive default: wide blocks keep the MXU matmuls fat and cut the
-    # grid-step count up to 16x vs a fixed 128 — at S=16k the fixed size
-    # meant 262k sequential grid steps and the kernel ran
-    # grid-overhead-bound (~1.5% MFU, docs/PERF.md r03). The pick is
-    # divisor-aware (largest of 512/384/256/128 dividing the 128-rounded
-    # length) so mid-length sequences don't pay pad blowup; note a wider
-    # block changes the online-softmax accumulation ORDER for
-    # 128 < S <= 512 vs the old fixed-128 blocking (allclose, not
-    # bitwise, vs previous builds).
-    if block_q is None:
-        block_q = _pick_block(sq)
-    if block_k is None:
-        block_k = _pick_block(sk)
-    block_q = min(block_q, _round_up(sq, 16))
-    block_k = min(block_k, _round_up(sk, 16))
+    block_q, block_k, bwd_block_q, bwd_block_k = block_sizes(
+        sq, sk, block_q, block_k, bwd_block_q, bwd_block_k)
     qpad = (-sq) % block_q
     kpad = (-sk) % block_k
-    # Backward blocks default to the forward's CAPPED at q<=256 (k can
-    # stay wide): the bwd kernels hold ~3x the forward's VMEM working
-    # set, and the r4 on-chip sweep (docs/PERF.md r04 block sweep) measured
-    # bwd 512x512 at 162.8 ms vs 18.4 ms for 256x512 at S=4096 — a VMEM
-    # spill cliff. 256x512 was the sweep's best; the cap costs <7% vs
-    # any other measured combo and avoids the 9x cliff. Overrides must
-    # tile the padded lengths (the backward runs over the same padded
-    # residuals).
-    if bwd_block_q is None:
-        bwd_block_q = block_q
-        if block_q > 256:
-            # largest of {256, 192, 128} dividing the padded length
-            # (block_q in {384, 512} guarantees a hit); sequences whose
-            # own block is an odd size <= 256 keep it — one big tile
-            # beats a sliver tile
-            for cand in (256, 192, 128):
-                if (sq + qpad) % cand == 0:
-                    bwd_block_q = cand
-                    break
-    if bwd_block_k is None:
-        bwd_block_k = block_k
-    for name, blk, sz in (("bwd_block_q", bwd_block_q, sq + qpad),
-                          ("bwd_block_k", bwd_block_k, sk + kpad)):
-        if sz % blk:
-            raise ValueError(f"{name}={blk} must divide the padded "
-                             f"sequence length {sz}")
     dpad = (-d) % LANES
 
     qq, kk, vv, bb = q, k, v, bias
@@ -948,9 +1068,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       jnp.asarray(k_start, jnp.int32),
                       jnp.asarray(sk, jnp.int32),
                       jnp.asarray(dropout_seed, jnp.int32)])
+    # offsets that are plain integers are known when the grids are made
+    known = (int(q_start), int(k_start), sk) if all(
+        isinstance(x, (int, np.integer)) for x in (q_start, k_start)) else None
     out, lse = _flash_core(qq, kk, vv, bb, kvb, causal, float(scale),
                            block_q, block_k, bwd_block_q, bwd_block_k,
-                           bool(bias_grad), float(dropout_rate), offs)
+                           bool(bias_grad), float(dropout_rate), known, offs)
     lse = lse[:, :sq]
     out = out[:, :sq, :d]
 

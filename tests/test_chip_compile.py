@@ -176,6 +176,21 @@ KERNELS = [
     ("flash_fwd_bwd-B2H16S8192D256", lambda: _flash(True),
      _qkv(2, 16, 8192, 256),
      ("apex_flash_fwd", "apex_flash_bwd_dq", "apex_flash_bwd_dkv")),
+    # LFM2's attention layer (32 heads of 64, two side by side in a lane
+    # tile's 128) and the dense LM's (24 rows of 2048, 16 heads of 128):
+    # with the two above, every shape a benchmark cell gives the kernels
+    ("flash_fwd-B2H32S8192D128", lambda: _flash(False),
+     _qkv(2, 32, 8192, 128),
+     ("apex_flash_fwd",)),
+    ("flash_fwd_bwd-B2H32S8192D128", lambda: _flash(True),
+     _qkv(2, 32, 8192, 128),
+     ("apex_flash_fwd", "apex_flash_bwd_dq", "apex_flash_bwd_dkv")),
+    ("flash_fwd-B24H16S2048D128", lambda: _flash(False),
+     _qkv(24, 16, 2048, 128),
+     ("apex_flash_fwd",)),
+    ("flash_fwd_bwd-B24H16S2048D128", lambda: _flash(True),
+     _qkv(24, 16, 2048, 128),
+     ("apex_flash_fwd", "apex_flash_bwd_dq", "apex_flash_bwd_dkv")),
     ("layer_norm_fwd_bwd-F1024", lambda: _layer_norm(1024),
      _ln_args(8 * 4096, 1024),
      ("apex_ln_fwd", "apex_ln_bwd")),

@@ -4,6 +4,7 @@ apex/contrib/test/multihead_attn/test_self_multihead_attn.py asserts
 fast-vs-default parity for outputs and input grads)."""
 
 import functools
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +15,11 @@ from apex_tpu.contrib.multihead_attn import (
     SelfMultiheadAttn, EncdecMultiheadAttn,
     flash_attention, reference_attention)
 from apex_tpu.contrib.multihead_attn.flash_attention import NEG_INF
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# the module: the package's ``flash_attention`` is the function
+fa = sys.modules["apex_tpu.contrib.multihead_attn.flash_attention"]
 
 # On real TPU, fp32 matmul operands pass through the MXU as bf16 by default
 # (both the kernel and the jnp oracle, with different rounding structure) —
@@ -600,3 +606,619 @@ class TestReferenceModuleSurface:
                               bias=False, impl="fast")
         SelfMultiheadAttn(self.E, self.H, mask_additive=True, bias=False,
                           impl="default")   # allowed by the reference
+
+
+# ---------------------------------------------------------------------------
+# Block kinds (PR 35): the kernels against a frozen copy of their parent's
+# ---------------------------------------------------------------------------
+# The three kernels as they stood before they classified their blocks
+# (commit e79671d): a rectangle of grid steps, the index maps moving on
+# every one, dead or live. Kept here, and nowhere in the package, as the
+# fixed point the kernels' outputs and gradients must equal bit for bit.
+
+def _parent_masked_scores(s, off_ref, qb, kb, causal):
+    """Apply causal (global positions from SMEM offsets) and k-length
+    (local padding, offs[2]) masks to a [bq, bk] score block."""
+    bq, bk = s.shape
+    k_local = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    s = jnp.where(k_local < off_ref[2], s, NEG_INF)
+    if causal:
+        q_pos = off_ref[0] + qb * bq + \
+            jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        k_pos = off_ref[1] + kb * bk + \
+            jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+    return s
+
+
+def _parent_kvb_spec(kvb, block_k):
+    """BlockSpec for the per-key bias [1|BH, 1, Sk]: a (1, 1, block_k)
+    column slice, shared across batch-heads when the leading dim is 1."""
+    shared = kvb.shape[0] == 1
+    return pl.BlockSpec(
+        (1, 1, block_k),
+        (lambda b, i, j: (0, 0, j)) if shared else
+        (lambda b, i, j: (b, 0, j)))
+
+
+def _parent_block_live(off_ref, qb, kb, bq, bk, causal):
+    """False when the (qb, kb) block is entirely masked (above the causal
+    diagonal or past the k length) and its compute can be skipped."""
+    live = kb * bk < off_ref[2]
+    if causal:
+        q_max = off_ref[0] + qb * bq + bq - 1
+        k_min = off_ref[1] + kb * bk
+        live = jnp.logical_and(live, q_max >= k_min)
+    return live
+
+
+def _parent_fwd_kernel(nk: int, causal: bool, has_bias: bool, has_kvb: bool,
+                scale: float, dropout: float, *refs):
+    refs = list(refs)
+    off_ref, q_ref, k_ref, v_ref = refs[:4]
+    del refs[:4]
+    bias_ref = refs.pop(0) if has_bias else None
+    kvb_ref = refs.pop(0) if has_kvb else None
+    o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+
+    bh_i, qb, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    bq = q_ref.shape[1]
+    bk = k_ref.shape[1]
+
+    @pl.when(kb == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(_parent_block_live(off_ref, qb, kb, bq, bk, causal))
+    def _body():
+        q = q_ref[0].astype(jnp.float32)           # [bq, d]
+        k = k_ref[0].astype(jnp.float32)           # [bk, d]
+        v = v_ref[0].astype(jnp.float32)           # [bk, d]
+
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale    # [bq, bk]
+        if has_bias:
+            s = s + bias_ref[0].astype(jnp.float32)
+        if has_kvb:
+            s = s + kvb_ref[0].astype(jnp.float32)  # (1, bk) row-broadcast
+        s = _parent_masked_scores(s, off_ref, qb, kb, causal)
+
+        m_prev = m_ref[:, :1]                      # [bq, 1]
+        row_max = jnp.max(s, axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, row_max)
+        # Rows with nothing unmasked yet must keep p == 0 (exp(NEG - NEG)
+        # would otherwise contribute 1).
+        p = jnp.where(s > NEG_INF * 0.5, jnp.exp(s - m_new), 0.0)  # [bq, bk]
+        alpha = jnp.exp(m_prev - m_new)            # [bq, 1]
+
+        l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+        # dropout on the (to-be-normalized) probabilities: the softmax
+        # denominator keeps ALL probs (reference dropout.h semantics —
+        # dropout is applied to softmax results), so l accumulates the
+        # undropped p while acc accumulates the masked, rescaled p.
+        pa = p
+        if dropout > 0.0:
+            keep = fa._keep_mask(off_ref, bh_i, qb, kb, p.shape, dropout)
+            pa = jnp.where(keep, p, 0.0) * (1.0 / (1.0 - dropout))
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            pa, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(kb == nk - 1)
+    def _finalize():
+        l = l_ref[:, :1]
+        safe_l = jnp.where(l > 0.0, l, 1.0)
+        o_ref[0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
+        lse = jnp.where(l > 0.0, m_ref[:, :1] + jnp.log(safe_l), NEG_INF)
+        lse_ref[...] = jnp.broadcast_to(lse, lse_ref.shape)
+
+
+def _parent_flash_fwd(q, k, v, bias, kvb, offs, *, causal, scale, block_q, block_k,
+               dropout=0.0):
+    """q,k,v: [BH, S, D], pre-padded so block sizes divide S and D == lane
+    multiple. offs: int32[4] = (q_start, k_start, k_len, seed) — k_len is
+    the UNPADDED key length, masked in-kernel (no O(S^2) pad-bias tensor);
+    seed drives the in-kernel dropout mask when ``dropout`` > 0.
+    kvb: optional per-KEY additive bias [1|BH, 1, Sk] (key-padding masks)
+    — O(S) instead of the O(S^2) bias tensor.
+    Returns (o, lse[BH,S])."""
+    bh, sq, d = q.shape
+    sk = k.shape[1]
+    nq = sq // block_q
+    nk = sk // block_k
+
+    in_specs = [
+        pl.BlockSpec(memory_space=pltpu.SMEM),                     # offs
+        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),  # q
+        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),  # k
+        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),  # v
+    ]
+    args = [offs, q, k, v]
+    has_bias = bias is not None
+    if has_bias:
+        bb = bias.shape[0]
+        in_specs.append(pl.BlockSpec(
+            (1, block_q, block_k),
+            (lambda b, i, j: (0, i, j)) if bb == 1 else
+            (lambda b, i, j: (b, i, j))))
+        args.append(bias)
+    has_kvb = kvb is not None
+    if has_kvb:
+        in_specs.append(_parent_kvb_spec(kvb, block_k))
+        args.append(kvb)
+
+    kernel = functools.partial(_parent_fwd_kernel, nk, causal, has_bias, has_kvb,
+                               float(scale), float(dropout))
+    o, lse = pl.pallas_call(
+        kernel,
+        grid=(bh, nq, nk),
+        in_specs=in_specs,
+        out_specs=[
+            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, fa.LANES), lambda b, i, j: (b, i, 0)),
+        ],
+        out_shape=[
+            fa._sds((bh, sq, d), q.dtype, vma=fa._vma(q, k, v)),
+            fa._sds((bh, sq, fa.LANES), jnp.float32, vma=fa._vma(q, k, v)),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, fa.LANES), jnp.float32),
+            pltpu.VMEM((block_q, fa.LANES), jnp.float32),
+        ],
+        interpret=fa._interpret(),
+        name="apex_flash_fwd",
+    )(*args)
+    return o, lse[:, :, 0]
+
+
+def _parent_recompute_p_ds(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
+                    bias_ref, kvb_ref, bh_i, qb, kb, causal, scale, dropout):
+    """Shared bwd block math: recompute p from saved lse, return (pd, ds, q,
+    k, do) as fp32 — ``pd`` is the (dropout-masked, rescaled) probability
+    used for dv. ds = p * (mask*dp/keep - delta); delta = rowsum(dO·O)
+    already equals sum_k pd*dp so no extra correction is needed, and the
+    lse cotangent is pre-folded into delta host-side (lse is dropout-free,
+    and d(lse)/ds = p undropped, which is exactly the factor outside)."""
+    q = q_ref[0].astype(jnp.float32)               # [bq, d]
+    k = k_ref[0].astype(jnp.float32)               # [bk, d]
+    v = v_ref[0].astype(jnp.float32)               # [bk, d]
+    do = do_ref[0].astype(jnp.float32)             # [bq, d]
+    lse = lse_ref[0][:, :1]                        # [bq, 1]
+    delta = dlt_ref[0][:, :1]                      # [bq, 1]
+
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    if bias_ref is not None:
+        s = s + bias_ref[0].astype(jnp.float32)
+    if kvb_ref is not None:
+        s = s + kvb_ref[0].astype(jnp.float32)
+    s = _parent_masked_scores(s, off_ref, qb, kb, causal)
+
+    # exp(NEG - NEG) guard: fully-masked rows have lse == NEG_INF
+    p = jnp.where(s > NEG_INF * 0.5, jnp.exp(s - lse), 0.0)   # [bq, bk]
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)                   # [bq, bk]
+    if dropout > 0.0:
+        keep = fa._keep_mask(off_ref, bh_i, qb, kb, p.shape, dropout)
+        inv = 1.0 / (1.0 - dropout)
+        pd = jnp.where(keep, p, 0.0) * inv
+        dp = jnp.where(keep, dp, 0.0) * inv
+    else:
+        pd = p
+    ds = p * (dp - delta)
+    return pd, ds, q, k, do
+
+
+def _parent_bwd_dq_kernel(nk: int, causal: bool, has_bias: bool, has_kvb: bool,
+                   emit_dbias: bool, scale: float, dropout: float, *refs):
+    refs = list(refs)
+    (off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref) = refs[:7]
+    del refs[:7]
+    bias_ref = refs.pop(0) if has_bias else None
+    kvb_ref = refs.pop(0) if has_kvb else None
+    dq_ref = refs.pop(0)
+    dbias_ref = refs.pop(0) if emit_dbias else None
+    dq_acc = refs.pop(0)
+
+    # program_id must be read OUTSIDE pl.when bodies: interpret mode only
+    # substitutes grid indices for top-level reads
+    bh_i, qb, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+
+    @pl.when(kb == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    live = _parent_block_live(off_ref, qb, kb, bq, bk, causal)
+
+    @pl.when(live)
+    def _body():
+        _, ds, _, k, _ = _parent_recompute_p_ds(
+            off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
+            bias_ref, kvb_ref, bh_i, qb, kb, causal, scale, dropout)
+        if dbias_ref is not None:
+            dbias_ref[0] = ds
+        dq_acc[...] += jax.lax.dot_general(
+            ds * scale, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    if dbias_ref is not None:
+        @pl.when(jnp.logical_not(live))
+        def _zero_dbias():
+            dbias_ref[0] = jnp.zeros_like(dbias_ref[0])
+
+    @pl.when(kb == nk - 1)
+    def _finalize():
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+
+
+def _parent_bwd_dkv_kernel(nq: int, causal: bool, has_bias: bool, has_kvb: bool,
+                    scale: float, dropout: float, *refs):
+    refs = list(refs)
+    (off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref) = refs[:7]
+    del refs[:7]
+    bias_ref = refs.pop(0) if has_bias else None
+    kvb_ref = refs.pop(0) if has_kvb else None
+    dk_ref, dv_ref, dk_acc, dv_acc = refs
+
+    bh_i, kb, qb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+
+    @pl.when(qb == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(_parent_block_live(off_ref, qb, kb, bq, bk, causal))
+    def _body():
+        pd, ds, q, _, do = _parent_recompute_p_ds(
+            off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
+            bias_ref, kvb_ref, bh_i, qb, kb, causal, scale, dropout)
+        dv_acc[...] += jax.lax.dot_general(
+            pd, do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)               # [bk, d]
+        dk_acc[...] += jax.lax.dot_general(
+            ds * scale, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)               # [bk, d]
+
+    @pl.when(qb == nq - 1)
+    def _finalize():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _parent_bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
+                bias_grad, dropout=0.0):
+    """Pallas flash backward over the padded residuals. Returns
+    (dq, dk, dv, dbias) with dbias None when no bias was supplied and
+    zeros when ``bias_grad`` is False (mask-only biases)."""
+    q, k, v, bias, kvb, offs, lse, o = res
+    bh, sq, d = q.shape
+    sk = k.shape[1]
+    nq = sq // block_q
+    nk = sk // block_k
+    has_bias = bias is not None
+    has_kvb = kvb is not None
+    emit_dbias = has_bias and bias_grad
+    # broadcast bias grads accumulate over bh in a dedicated kernel
+    dbias_in_dq = emit_dbias and bias.shape[0] != 1
+
+    do = do.astype(jnp.float32)
+    # delta = rowsum(dO * O); the lse cotangent folds into the same
+    # per-row subtraction: ds = p * (dp - (delta - dlse)).
+    delta = jnp.sum(do * o.astype(jnp.float32), axis=-1)       # [bh, sq]
+    if dlse is not None:
+        delta = delta - dlse.astype(jnp.float32)
+    # lane-replicate row stats (the TPU-friendly [.., sq, 128] layout)
+    lse_r = jnp.broadcast_to(lse[..., None], (*lse.shape, fa.LANES))
+    dlt_r = jnp.broadcast_to(delta[..., None], (*delta.shape, fa.LANES))
+
+    stat_spec_i = pl.BlockSpec((1, block_q, fa.LANES), lambda b, i, j: (b, i, 0))
+    common = [
+        pl.BlockSpec(memory_space=pltpu.SMEM),                      # offs
+        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),   # q
+        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),   # k
+        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),   # v
+        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),   # do
+        stat_spec_i,                                                # lse
+        stat_spec_i,                                                # delta
+    ]
+    args = [offs, q, k, v, do, lse_r, dlt_r]
+    opt_specs = []
+    if has_bias:
+        bb = bias.shape[0]
+        bias_spec = pl.BlockSpec(
+            (1, block_q, block_k),
+            (lambda b, i, j: (0, i, j)) if bb == 1 else
+            (lambda b, i, j: (b, i, j)))
+        args.append(bias)
+        opt_specs.append(bias_spec)
+    if has_kvb:
+        kvb_spec = _parent_kvb_spec(kvb, block_k)
+        args.append(kvb)
+        opt_specs.append(kvb_spec)
+
+    vma = fa._vma(q, k, v, do)
+
+    # --- dq (+ per-bh dbias) over grid (bh, nq, nk) ------------------------
+    dq_out_specs = [pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))]
+    dq_out_shape = [fa._sds((bh, sq, d), q.dtype, vma=vma)]
+    if dbias_in_dq:
+        dq_out_specs.append(pl.BlockSpec(
+            (1, block_q, block_k), lambda b, i, j: (b, i, j)))
+        dq_out_shape.append(
+            fa._sds((bh, sq, sk), jnp.float32, vma=vma))
+    dq_res = pl.pallas_call(
+        functools.partial(_parent_bwd_dq_kernel, nk, causal, has_bias, has_kvb,
+                          dbias_in_dq, float(scale), float(dropout)),
+        grid=(bh, nq, nk),
+        in_specs=common + opt_specs,
+        out_specs=dq_out_specs,
+        out_shape=dq_out_shape,
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        interpret=fa._interpret(),
+        name="apex_flash_bwd_dq",
+    )(*args)
+    if dbias_in_dq:
+        dq, dbias = dq_res
+        dbias = dbias.astype(bias.dtype)
+    else:
+        (dq,) = dq_res if isinstance(dq_res, (list, tuple)) else (dq_res,)
+        dbias = None
+    assert not (emit_dbias and not dbias_in_dq), "per-bh bias only"
+    if has_bias and not emit_dbias:
+        dbias = jnp.zeros_like(bias)
+
+    # --- dk / dv over grid (bh, nk, nq) ------------------------------------
+    def _swap(spec):
+        # same block shapes, but grid axes are (b, kb, qb): j := axis 1,
+        # i := axis 2
+        return pl.BlockSpec(spec.block_shape,
+                            lambda b, j, i, _m=spec.index_map: _m(b, i, j))
+
+    dkv_in_specs = [common[0]] + [_swap(s) for s in common[1:] + opt_specs]
+    dk, dv = pl.pallas_call(
+        functools.partial(_parent_bwd_dkv_kernel, nq, causal, has_bias, has_kvb,
+                          float(scale), float(dropout)),
+        grid=(bh, nk, nq),
+        in_specs=dkv_in_specs,
+        out_specs=[
+            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+        ],
+        out_shape=[
+            fa._sds((bh, sk, d), k.dtype, vma=vma),
+            fa._sds((bh, sk, d), v.dtype, vma=vma),
+        ],
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
+        interpret=fa._interpret(),
+        name="apex_flash_bwd_dkv",
+    )(*args)
+    return dq, dk, dv, dbias
+
+
+def _parent_kernels(monkeypatch):
+    # the parent's grids were rectangles: no use for offsets known ahead
+    monkeypatch.setattr(fa, "_flash_fwd", lambda *a, known=None, **kw:
+                        _parent_flash_fwd(*a, **kw))
+    monkeypatch.setattr(fa, "_bwd_pallas", lambda *a, known=None, **kw:
+                        _parent_bwd_pallas(*a, **kw))
+
+
+def _shard(q_start, k_start, s=512):
+    """A ring step's call: one shard of queries against one of keys."""
+    return dict(sq=s, sk=s, causal=True, q_start=q_start, k_start=k_start,
+                block_q=256, block_k=256)
+
+
+# sq, sk, d, then flash_attention's keywords; blocks default to 512 x 512
+# forward and 256 x 512 backward from S = 1024 up
+KIND_CASES = {
+    "causal_d128": dict(sq=1536, sk=1536, d=128, causal=True),
+    "causal_d256": dict(sq=1024, sk=1024, d=256, causal=True),
+    "full_d128": dict(sq=1024, sk=1024, d=128),
+    "key_pad": dict(sq=600, sk=1100, block_q=512, block_k=512),
+    "causal_key_pad": dict(sq=1100, sk=1100, causal=True,
+                           block_q=512, block_k=512),
+    "causal_cross": dict(sq=512, sk=1536, causal=True, q_start=700),
+    "dead_columns": dict(sq=300, sk=520, block_k=512, bwd_block_k=128),
+    "small": dict(sq=37, sk=53, d=24, causal=True),
+    "ring_dead": _shard(0, 1024),
+    "ring_interior": _shard(1024, 0),
+    "ring_diagonal": _shard(512, 512),
+    "ring_straddle": _shard(512, 384),
+    "bias": dict(sq=768, sk=768, causal=True, bias=True),
+    "bias_full": dict(sq=512, sk=640, bias=True, block_q=256, block_k=256),
+    "kv_bias": dict(sq=768, sk=768, causal=True, kv_bias=True),
+    "kv_bias_full": dict(sq=512, sk=700, kv_bias=True),
+    "dropout": dict(sq=1024, sk=1024, causal=True, dropout_rate=0.2,
+                    dropout_seed=11),
+    "dropout_bias": dict(sq=512, sk=512, causal=True, bias=True,
+                         kv_bias=True, dropout_rate=0.1, dropout_seed=3,
+                         block_q=256, block_k=256),
+}
+
+
+def _kind_case(sq, sk, d=128, bias=False, kv_bias=False, traced=False,
+               q_start=0, k_start=0, **kw):
+    bh = 2
+    ks = jax.random.split(jax.random.key(sq + sk + d), 7)
+    q, k, v = (jax.random.normal(kk, (bh, s, d), jnp.float32).astype(
+        jnp.bfloat16) for kk, s in zip(ks, (sq, sk, sk)))
+    # cotangents of o and of lse (the ring's merge differentiates both)
+    do = jax.random.normal(ks[3], (bh, sq, d), jnp.float32).astype(
+        jnp.bfloat16)
+    dlse = jax.random.normal(ks[4], (bh, sq), jnp.float32)
+    operands = [q, k, v]
+    if bias:        # a bias that masks: the NEG_INF guard's case
+        b = jax.random.normal(ks[5], (bh, sq, sk), jnp.float32)
+        operands.append(jnp.where(b > 1.0, NEG_INF, b))
+    if kv_bias:     # two keys in three padded out
+        operands.append(jnp.where(
+            jax.random.uniform(ks[6], (bh, sk)) > 0.33, 0.0, NEG_INF))
+
+    def run(q_start, k_start, *ops):
+        def f(q, k, v, *rest):
+            rest = list(rest)
+            return flash_attention(
+                q, k, v, rest.pop(0) if bias else None,
+                kv_bias=rest.pop(0) if kv_bias else None,
+                q_start=q_start, k_start=k_start, return_lse=True, **kw)
+        out, vjp = jax.vjp(f, *ops)
+        return out, vjp((do, dlse))[:4 if bias else 3]
+
+    if traced:      # a ring step's shard offsets: a block a step, dead too
+        return jax.jit(run)(jnp.int32(q_start), jnp.int32(k_start),
+                            *operands)
+    return run(q_start, k_start, *operands)     # known: live blocks only
+
+
+@pytest.mark.parametrize("case", sorted(KIND_CASES))
+@pytest.mark.parametrize("traced", [False, True], ids=["known", "traced"])
+def test_block_kinds_bitwise_equal_parent(case, traced, monkeypatch):
+    """o, lse, dq, dk, dv (and dbias) of the kernels that give dead blocks
+    no step (offsets known) or an empty one (traced) are the parent's, bit
+    for bit: a live block's body, and the order of a line's blocks, are
+    what they were."""
+    got = _kind_case(traced=traced, **KIND_CASES[case])
+    _parent_kernels(monkeypatch)
+    want = _kind_case(traced=traced, **KIND_CASES[case])
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    names = ("o", "lse", "dq", "dk", "dv", "dbias")[:len(want)]
+    for name, a, b in zip(names, got, want, strict=True):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert a.shape == b.shape, name
+        np.testing.assert_array_equal(a.view(np.uint32), b.view(np.uint32),
+                                      err_msg=f"{case}: {name}")
+    assert np.isfinite(np.asarray(got[0], np.float32)).all()
+
+
+def _census_case(backward, sq, sk, causal=False, q_start=0, k_start=0,
+                 block_q=None, block_k=None, bwd_block_q=None,
+                 bwd_block_k=None, **_):
+    """block_census's arguments for a case's forward or backward grid
+    (the backward tiles the forward's padded lengths)."""
+    fq, fk, bq, bk = fa.block_sizes(sq, sk, block_q, block_k, bwd_block_q,
+                                    bwd_block_k)
+    c = dict(sq=sq, sk=sk, block_q=fq, block_k=fk, causal=causal,
+             q_start=q_start, k_start=k_start, k_len=sk)
+    if backward:
+        c.update(sq=-(-sq // fq) * fq, sk=-(-sk // fk) * fk, block_q=bq,
+                 block_k=bk)
+    return c
+
+
+@pytest.mark.parametrize("case", sorted(KIND_CASES))
+@pytest.mark.parametrize("backward", [False, True])
+def test_block_census_counts_the_mask(case, backward):
+    """block_census against a brute-force count over the element-wise
+    mask the kernels apply: a block with no element kept is dead, with
+    every element kept interior, anything else edge."""
+    c = _census_case(backward, **KIND_CASES[case])
+    bq, bk = c["block_q"], c["block_k"]
+    nq, nk = -(-c["sq"] // bq), -(-c["sk"] // bk)
+    k_local = np.arange(nk * bk)[None, :]
+    keep = np.broadcast_to(k_local < c["k_len"], (nq * bq, nk * bk))
+    if c["causal"]:
+        keep = keep & (c["q_start"] + np.arange(nq * bq)[:, None]
+                       >= c["k_start"] + k_local)
+    blocks = keep.reshape(nq, bq, nk, bk).transpose(0, 2, 1, 3).reshape(
+        nq * nk, -1)
+    want = {"dead": int((~blocks.any(1)).sum()),
+            "interior": int(blocks.all(1).sum())}
+    want["edge"] = nq * nk - want["dead"] - want["interior"]
+    assert fa.block_census(**c) == want
+    assert sum(want.values()) == nq * nk
+
+
+@pytest.mark.parametrize("case", sorted(KIND_CASES))
+@pytest.mark.parametrize("by_col", [False, True], ids=["rows", "columns"])
+def test_step_table_sweeps_the_live_blocks(case, by_col):
+    """The kernels' grid: every live block once, line after line (a q
+    row's k blocks; ``by_col`` a k column's q blocks), each line's first
+    and last step flagged, a line with no live block keeping one dead
+    step; traced offsets pad the same steps to a block a step."""
+    c = _census_case(by_col, **KIND_CASES[case])
+    bq, bk, causal = c["block_q"], c["block_k"], c["causal"]
+    nq, nk = -(-c["sq"] // bq), -(-c["sk"] // bk)
+    offs = (c["q_start"], c["k_start"], c["k_len"])
+    live = np.broadcast_to(fa._block_kind(
+        offs, np.arange(nq)[:, None], np.arange(nk)[None, :], bq, bk,
+        causal)[0], (nq, nk))
+
+    def decode(steps):
+        steps = np.asarray(steps)
+        return (steps & fa._IDX, (steps >> fa._KB) & fa._IDX,
+                (steps >> fa._LIVE) & 1, (steps >> fa._FIRST) & 1,
+                (steps >> fa._LAST) & 1)
+
+    known = fa._steps(offs, None, nq, nk, bq, bk, causal, by_col)
+    qb, kb, is_live, first, last = decode(known)
+    census = fa.block_census(**c)
+    lines = live.T if by_col else live
+    assert len(known) == census["interior"] + census["edge"] \
+        + int((~lines.any(1)).sum())
+    assert (is_live == live[qb, kb]).all()
+    got = np.zeros((nq, nk), int)
+    np.add.at(got, (qb, kb), 1)
+    assert (got[live] == 1).all() and got.sum() == len(known)
+    # a line's steps are consecutive and in order, first and last flagged
+    line, sweep = (kb, qb) if by_col else (qb, kb)
+    assert (np.diff(line) >= 0).all()
+    assert (np.diff(sweep)[np.diff(line) == 0] > 0).all()
+    starts = np.r_[True, np.diff(line) != 0]
+    assert (first == starts).all() and (last == np.r_[starts[1:], True]).all()
+    assert sorted(set(line)) == list(range(len(lines)))
+
+    traced = fa._steps(None, jnp.asarray(offs), nq, nk, bq, bk, causal,
+                       by_col)
+    assert len(traced) == nq * nk
+    np.testing.assert_array_equal(traced[:len(known)], known)
+    tq, tk, is_live, first, last = decode(traced[len(known):])
+    assert (tq == qb[-1]).all() and (tk == kb[-1]).all()     # no fetch
+    assert not (is_live.any() or first.any() or last.any())
+
+    every = fa._steps(offs, None, nq, nk, bq, bk, causal, by_col, every=True)
+    qb, kb, is_live, _, _ = decode(every)
+    assert len(every) == nq * nk and (is_live == live[qb, kb]).all()
+    assert len(set(zip(qb, kb))) == nq * nk
+    # where the table is the identity the index maps compute the block
+    # from the step's number; anywhere else they read the table
+    at = fa._at(every, offs, nq, nk, by_col)
+    assert all(at(7, t) == (7, qb[t], kb[t]) for t in range(nq * nk))
+    for steps, given in ((known, offs), (traced, None)):
+        at = fa._at(steps, given, nq, nk, by_col)
+        assert (at is fa._step_block) == (
+            given is None or len(steps) < nq * nk)
+        b, tq, tk = at(7, len(known) - 1, np.asarray(steps), None)
+        assert (b, tq, tk) == (7, decode(known)[0][-1], decode(known)[1][-1])
+
+
+def test_block_census_of_the_cells():
+    """What tools/kernel_bench.py prints and docs/API.md tabulates."""
+    assert fa.block_census(8192, 8192, 512, 512, True) == {
+        "dead": 120, "interior": 120, "edge": 16}
+    assert fa.block_census(8192, 8192, 256, 512, True) == {
+        "dead": 240, "interior": 240, "edge": 32}
+    assert fa.block_census(2048, 2048, 512, 512, True) == {
+        "dead": 6, "interior": 6, "edge": 4}
+    assert fa.block_census(2048, 2048, 256, 512, True) == {
+        "dead": 12, "interior": 12, "edge": 8}
+    # one ring step over four shards of 2,048: a shard wholly in the
+    # future, wholly in the past, and the diagonal's own
+    assert fa.block_census(2048, 2048, 512, 512, True, 0, 2048) == {
+        "dead": 16, "interior": 0, "edge": 0}
+    assert fa.block_census(2048, 2048, 512, 512, True, 4096, 2048) == {
+        "dead": 0, "interior": 16, "edge": 0}
+    # no mask but the k length: its last block is the only edge
+    assert fa.block_census(1024, 1100, 512, 512, False) == {
+        "dead": 0, "interior": 4, "edge": 2}

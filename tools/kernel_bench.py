@@ -8,7 +8,9 @@ the end (docs/PERF.md "Optimizer / BN kernels" keeps the r03-r05 rows).
 Benchmarks:
   flash    : flash attention fwd+bwd vs jnp reference_attention, causal,
              S in {1k, 4k, 16k} (16k jnp fwd+bwd materializes S^2 — may OOM;
-             recorded as such)
+             recorded as such), then the kernels alone at the benchmark
+             cells' shapes; each row with its block_census (grid steps
+             that are dead / interior / edge, forward and backward)
   flash_crossover : the impl='auto' dispatch sweep, S in {512..8192};
              prints the measured flash_min_s (the committed constant is
              flash_attention.DEFAULT_FLASH_MIN_S)
@@ -115,14 +117,29 @@ def _stamp(row):
     return stamp_result(row, "kernel_bench")
 
 
-def record(bench, config, pallas_s, xla_s):
+def record(bench, config, pallas_s, xla_s, **more):
     row = {"bench": bench, "config": config,
            "pallas_ms": None if pallas_s is None else round(pallas_s * 1e3, 3),
-           "xla_ms": None if xla_s is None else round(xla_s * 1e3, 3)}
+           "xla_ms": None if xla_s is None else round(xla_s * 1e3, 3), **more}
     if pallas_s and xla_s:
         row["speedup_vs_xla"] = round(xla_s / pallas_s, 2)
     results.append(row)
     print(json.dumps(_stamp(row)), flush=True)
+
+
+FLASH_CELL_SHAPES = ((160, 8192, 256), (64, 8192, 128), (32, 8192, 256),
+                     (384, 2048, 128))
+
+
+def _flash_census(s):
+    """Grid steps by kind, ``dead/interior/edge``, of causal self-attention
+    over ``s`` tokens at the kernels' default blocks."""
+    from apex_tpu.contrib.multihead_attn.flash_attention import (
+        block_census, block_sizes)
+    fq, fk, bq, bk = block_sizes(s, s)
+    return {name: "/".join(str(n) for n in block_census(
+        s, s, *blocks, causal=True).values())
+        for name, blocks in (("fwd", (fq, fk)), ("bwd", (bq, bk)))}
 
 
 def bench_flash(steps):
@@ -149,7 +166,19 @@ def bench_flash(steps):
         n = max(2, steps // max(1, s // 1024))
         tp = time_fn(f"flash_s{s}_pallas", f_pallas, q, k, v, steps=n)
         tx = time_fn(f"flash_s{s}_xla", f_xla, q, k, v, steps=n)
-        record("flash_fwd_bwd", f"bh{bh} s{s} d{d} causal bf16", tp, tx)
+        record("flash_fwd_bwd", f"bh{bh} s{s} d{d} causal bf16", tp, tx,
+               block_census=_flash_census(s))
+
+    # the benchmark's training cells (kvl, lfm2, qnext, cgpt): the kernels
+    # alone at the shapes each gives them. S^2 scores do not fit: no XLA.
+    for bh, s, d in FLASH_CELL_SHAPES:
+        ks = jax.random.split(jax.random.key(0), 3)
+        q, k, v = (jax.random.normal(kk, (bh, s, d), jnp.bfloat16)
+                   for kk in ks)
+        tp = time_fn(f"flash_bh{bh}_s{s}_d{d}_pallas", f_pallas, q, k, v,
+                     steps=max(2, steps // 4))
+        record("flash_fwd_bwd", f"bh{bh} s{s} d{d} causal bf16", tp, None,
+               block_census=_flash_census(s))
 
 
 def bench_flash_blocks(steps):
@@ -534,8 +563,12 @@ def main():
                   f"(baseline {r['baseline'] or '-'}) | "
                   f"{f'{vs}x' if vs is not None else '-'} |")
         else:
-            print(f"| {r['bench']} | {r['config']} | {r['pallas_ms']} | "
-                  f"{r['xla_ms']} | {r.get('speedup_vs_xla', '-')} |")
+            census = r.get("block_census")
+            config = r["config"] + (
+                f" (dead/interior/edge: fwd {census['fwd']}, "
+                f"bwd {census['bwd']})" if census else "")
+            print(f"| {r['bench']} | {config} | {r['pallas_ms']} | "
+                  f"{r['xla_ms'] or '-'} | {r.get('speedup_vs_xla', '-')} |")
 
 
 if __name__ == "__main__":
