@@ -78,7 +78,11 @@ batch statistics travel through ``train_step.build_step``.
 Scopes (``prof.SCOPES``): ``embed``, ``linear_attention``,
 ``delta_rule``, ``attention``, ``latent_attention``, ``short_conv``,
 ``mlp``, ``moe_route``, ``moe_experts``, ``head_loss``; siblings, never
-nested.
+nested. Regions (``prof.REGIONS``), around the scopes: ``layer_stack``
+(a run's stacked leaves, the counters' ``concatenate``) and ``layer_scan``
+(the run's ``lax.scan``), opened in :meth:`HybridLM.hidden_states`. What
+``jax.checkpoint`` re-runs in the backward carries JAX's own path
+component ``rematted_computation``; a test pins it.
 """
 
 from __future__ import annotations
@@ -430,14 +434,19 @@ class HybridLM:
             if self.remat:
                 block = jax.checkpoint(block)
             layers = [params[f"layer_{i}"] for i in range(first, first + n)]
-            xs = jax.tree.map(lambda *a: jnp.stack(a), *layers)
-            if biased:
-                xs, row = (xs, router_bias[row:row + n]), row + n
-            x, aux = jax.lax.scan(block, x, xs)
+            # prof.REGIONS: what the run adds around its blocks, whose
+            # ops keep their own scopes (metadata only)
+            with jax.named_scope("layer_stack"):
+                xs = jax.tree.map(lambda *a: jnp.stack(a), *layers)
+                if biased:
+                    xs, row = (xs, router_bias[row:row + n]), row + n
+            with jax.named_scope("layer_scan"):
+                x, aux = jax.lax.scan(block, x, xs)
             if aux is not None:
                 auxes.append(aux)
             first += n
-        aux = jax.tree.map(lambda *a: jnp.concatenate(a), *auxes)
+        with jax.named_scope("layer_stack"):    # the runs' counters joined
+            aux = jax.tree.map(lambda *a: jnp.concatenate(a), *auxes)
         with jax.named_scope("head_loss"):
             x = self._norm(x, params["norm_f"])
         counters = {"load_balance_loss": jnp.sum(aux["load_balance_loss"]),

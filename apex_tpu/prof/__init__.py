@@ -39,8 +39,8 @@ import jax
 
 from apex_tpu.prof.peaks import ChipPeak, PEAKS, chip_peak  # noqa: F401
 
-__all__ = ["annotate", "mark", "SCOPES", "trace", "analyze", "CostReport",
-           "init",
+__all__ = ["annotate", "mark", "SCOPES", "REGIONS", "trace", "analyze",
+           "CostReport", "init",
            "OpStats", "top_ops", "format_top_ops", "RooflineSummary",
            "roofline", "gaps", "Gap", "GapReport", "TimelineEvent",
            "attribute_gaps", "format_gaps",
@@ -119,6 +119,27 @@ SCOPES = ("embed", "attention", "mlp", "head_loss",     # models/transformer
           "linear_attention", "delta_rule",             # models/hybrid_lm
           "latent_attention", "short_conv",
           "moe_route", "moe_experts")                   # contrib/moe
+
+# Regions: names of *structure that encloses scopes*, opened with the same
+# ``jax.named_scope`` / :func:`mark` around what a program adds around its
+# scoped work, where that machinery has a cost of its own (a run of like
+# layers as one ``lax.scan`` over stacked parameters). A region is no scope
+# and is in no ``benchmarks/scopes/*.json``: an op under a scope is its
+# scope's wherever the scope sits in the path (``trace_scope`` steps over
+# every other component), and only an op with no scope, its own or its
+# reader's, goes to the innermost region of its path. Rule: scope first,
+# else innermost region, else unowned. Both are opened in
+# ``models/hybrid_lm.py`` ``HybridLM.hidden_states``, siblings:
+# ``layer_stack`` around a run's ``jnp.stack`` of its layers' leaves, the
+# selection biases' rows and the ``concatenate`` of the runs' counters
+# (backward: the stacked gradients taken apart), ``layer_scan`` around the
+# run's ``lax.scan`` (the ``while`` less its body, the loop-level
+# ``dynamic_slice`` / ``dynamic_update_slice`` of stacked operands, results,
+# residuals and gradients, the compiler's copies at the loop's boundary).
+# The benchmark's copy is ``benchmarks/regions/<family>.json`` (a test holds
+# the sets equal); its ``trace_region`` reader gives each region's own time
+# a step and what is left with neither name (``unowned_pct``).
+REGIONS = ("layer_stack", "layer_scan")                 # models/hybrid_lm
 
 
 @contextlib.contextmanager

@@ -3,6 +3,8 @@ data, grouped-query flash attention against plain attention at head size
 256, the scopes its step opens, and its counters out of the step beside
 the loss while the dense LM's step returns what it did."""
 
+import contextlib
+import functools
 import os
 import re
 import sys
@@ -175,13 +177,7 @@ def test_every_scope_of_the_step_is_in_the_vocabulary(model, scopes, absent):
     and the short convolution open one scope around all of theirs, the
     dense FFN the dense LM's ``mlp``, the tied head ``head_loss`` and its
     gather's scatter-add ``embed``."""
-    lm = {"hybrid": _tiny, "latent": _latent, "conv": _conv}[model](
-        head_chunk=32, remat=True)
-    params = lm.init(jax.random.key(7))
-    loss = lm.loss if model == "hybrid" else (
-        lambda p, t: lm.loss_with_router_state(p, lm.router_state(), t)[0])
-    text = jax.jit(jax.grad(loss)).lower(params, _tokens()).compile() \
-        .as_text()
+    text = "\n".join(_grad_lines(model))
     paths = set(re.findall(r'op_name="([^"]*)"', text))
 
     def under(scope, path):     # a whole component: bare, or in jvp( )
@@ -194,6 +190,115 @@ def test_every_scope_of_the_step_is_in_the_vocabulary(model, scopes, absent):
     assert not any(under(scope, p) for scope in absent for p in paths)
     assert "delta_rule/linear_attention" not in text    # siblings
     assert "linear_attention/delta_rule" not in text
+
+
+@functools.lru_cache(maxsize=None)
+def _grad_lines(model: str, remat: bool = True, regions: bool = True):
+    """The instruction lines of the tiny model's compiled loss gradient
+    (the hybrid pattern with a run of two, the latent or the conv
+    pattern); ``regions`` false: with ``prof.REGIONS`` opening nothing,
+    the program as it was before them."""
+    lm = {"hybrid": functools.partial(
+              _tiny, layer_types=("linear", "linear", "full")),
+          "latent": _latent, "conv": _conv}[model](
+        head_chunk=32, remat=remat)
+    params = lm.init(jax.random.key(7))
+    loss = lm.loss if model == "hybrid" else (
+        lambda p, t: lm.loss_with_router_state(p, lm.router_state(), t)[0])
+    real = jax.named_scope
+    with pytest.MonkeyPatch.context() as mp:
+        if not regions:
+            mp.setattr(jax, "named_scope", lambda name: (
+                contextlib.nullcontext() if name in prof.REGIONS
+                else real(name)))
+        text = jax.jit(jax.grad(loss)).lower(params, _tokens()).compile() \
+            .as_text()
+    return tuple(line for line in text.splitlines() if " = " in line)
+
+
+def _paths(lines) -> list:
+    return [m.group(1) for m in (re.search(r'op_name="([^"]*)"', line)
+                                 for line in lines) if m]
+
+
+@pytest.mark.parametrize("model", ["hybrid", "latent", "conv"])
+def test_the_regions_enclose_the_scopes_and_move_none(model):
+    """``prof.REGIONS`` around a run's stacked leaves and its scan:
+    metadata only (the instructions are the ones without them), every
+    instruction resolves to the scope it resolved to before, and what had
+    no scope at the runs' level now has a region: the scans' ``while``s
+    and loop-level slices ``layer_scan``, the stacked leaves and the
+    counters' ``concatenate`` ``layer_stack``, forward and backward."""
+    from benchmarks.readers import trace_region, trace_scope
+    assert not set(prof.REGIONS) & set(prof.SCOPES)
+    mine, before = _grad_lines(model), _grad_lines(model, regions=False)
+    strip = re.compile(r", metadata=\{[^}]*\}")
+    assert [strip.sub("", x) for x in mine] \
+        == [strip.sub("", x) for x in before]
+    a, b = _paths(mine), _paths(before)
+    assert len(a) == len(b) > 1000
+    assert not any(trace_region.region_of(p) for p in b)
+    assert [trace_scope.scope_of(p) for p in a] \
+        == [trace_scope.scope_of(p) for p in b]
+    assert [re.sub(r"layer_(scan|stack)", "", p) for p in a] == b
+    free = [p for p in a if trace_scope.scope_of(p) is None]
+    whiles = [p for p in free if p.endswith("/while")]
+    assert whiles and {trace_region.region_of(p) for p in whiles} \
+        == {"layer_scan"}
+    assert {"transpose(" in p for p in whiles} == {True, False}
+    for op in ("dynamic_slice", "dynamic_update_slice"):
+        level = [p for p in free if p.endswith("/while/body/" + op)]
+        assert level and {trace_region.region_of(p) for p in level} \
+            == {"layer_scan"}
+    stack = [p for p in free if trace_region.region_of(p) == "layer_stack"]
+    assert any(p.endswith("jvp(layer_stack)/concatenate") for p in stack)
+    assert any(p.endswith("transpose(jvp(layer_stack))/split")
+               for p in stack)
+    # siblings: neither region inside the other
+    assert not any("layer_scan" in p and "layer_stack" in p for p in a)
+
+
+@pytest.mark.parametrize("model, mixers", [
+    ("hybrid", ("linear_attention", "attention")),
+    ("latent", ("latent_attention",)),
+    ("conv", ("short_conv", "attention"))])
+def test_what_the_backward_runs_again_carries_jax_checkpoints_own_name(
+        model, mixers):
+    """The recomputed forward has no scope of the program's: the span is
+    the path component ``jax.checkpoint`` writes on what it runs again
+    (``benchmarks/regions/hybrid_lm.json`` ``recomputed``, which
+    ``trace_region`` reads). Pinned here, so that a JAX that renames it
+    fails a test and not a metric: in the backward ``while`` every scoped
+    op sits under ``checkpoint`` with the component right after it or
+    not at all, each mixer has a recomputed matmul and a true backward
+    one, and without ``remat`` only the delta rule's own checkpoint
+    writes it."""
+    from benchmarks.readers import trace_region, trace_scope
+    name, = trace_region.patterns(key="recomputed")
+    wrappers = {"closed_call", "checkpoint", name}
+    again, back = {}, {}
+    for p in _paths(_grad_lines(model)):
+        scope = trace_scope.scope_of(p)
+        _, _, rest = p.partition("transpose(jvp(layer_scan))/while/body/")
+        if not rest or scope is None:
+            continue
+        parts = rest.split("/")
+        lead = parts[:parts.index(scope)]
+        assert set(lead) <= wrappers and "checkpoint" in lead, p
+        if name in lead:
+            assert lead[lead.index(name) - 1] == "checkpoint", p
+        (again if name in lead else back).setdefault(scope, []).append(p)
+    for mixer in mixers:
+        assert any(p.endswith("dot_general") for p in again[mixer]), mixer
+        assert any(p.endswith("dot_general") for p in back[mixer]), mixer
+    assert set(again) == set(back)
+    # forward, nothing is recomputed (a reduction's inner computation is
+    # named by the tail of its path alone)
+    assert not any(name in p for p in _paths(_grad_lines(model))
+                   if p.startswith("jit(") and "transpose(" not in p)
+    held = [p for p in _paths(_grad_lines(model, remat=False)) if name in p]
+    assert all(trace_scope.scope_of(p) == "delta_rule" for p in held)
+    assert bool(held) == (model == "hybrid")
 
 
 def test_the_step_builder_hands_the_counters_out_beside_the_loss():
