@@ -60,11 +60,17 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 MAX_BLOCK = 512  # upper bound for _pick_block's divisor-aware sizing
+# jax.ad_checkpoint.checkpoint_name of the forward kernel's two outputs under
+# differentiation: a jax.checkpoint around the call whose policy is
+# save_only_these_names(*SAVED_NAMES) keeps them and runs apex_flash_fwd
+# once; under any other policy the names do nothing
+SAVED_NAMES = ("apex_flash_out", "apex_flash_lse")
 
 
 def _pick_block(s: int) -> int:
@@ -865,6 +871,7 @@ def _flash_core_fwd(q, k, v, bias, kvb, causal, scale, block_q, block_k,
     o, lse = _flash_fwd(q, k, v, bias, kvb, offs, causal=causal, scale=scale,
                         block_q=block_q, block_k=block_k, dropout=dropout,
                         known=known)
+    o, lse = map(checkpoint_name, (o, lse), SAVED_NAMES)
     return (o, lse), (q, k, v, bias, kvb, offs, lse, o)
 
 

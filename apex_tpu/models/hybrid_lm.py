@@ -65,8 +65,8 @@ v] = norm(c) W_kvb`` a head; rotary positions turn ``q``'s last
 heads' ``dK_rope``). ``flash_attention`` takes one width for ``q``, ``k``
 and ``v``, so ``v`` is padded to the keys' width and the result sliced, as
 the source's own flash path does. Under ``remat`` the keys and values are
-made again from the layer's input in the backward: only the block's input
-is kept.
+made again from the layer's input in the backward: the block's input is
+kept, and the two arrays the flash forward kernel made.
 
 The **sigmoid router's selection bias** (``router="sigmoid"``) is state
 that no gradient reaches: ``[expert layers, num_experts]`` floats, zero at
@@ -83,6 +83,14 @@ nested. Regions (``prof.REGIONS``), around the scopes: ``layer_stack``
 (the run's ``lax.scan``), opened in :meth:`HybridLM.hidden_states`. What
 ``jax.checkpoint`` re-runs in the backward carries JAX's own path
 component ``rematted_computation``; a test pins it.
+
+``remat`` recomputes each block in the backward and keeps what the
+attention kernel made: the block's ``jax.checkpoint`` saves the names
+``flash_attention.SAVED_NAMES`` (the forward kernel's output and
+log-sum-exp, ``bf16[B * heads, T, head width padded to 128 lanes]`` and
+``f32[B * heads, T]`` a flash layer), so ``apex_flash_fwd`` runs once a
+step, and nothing else. A block with no flash kernel (a Gated DeltaNet
+or conv layer, ``attn_impl="default"``) saves its input alone.
 """
 
 from __future__ import annotations
@@ -94,6 +102,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.contrib.moe.expert_layer import ExpertLayer
+from apex_tpu.contrib.multihead_attn.flash_attention import SAVED_NAMES
 from apex_tpu.ops.gated_delta_rule import gated_delta_rule
 
 __all__ = ["HybridLM"]
@@ -175,7 +184,8 @@ class HybridLM:
     tied_head: bool = False     # the head is the embedding
     attn_impl: str = "fast"     # "fast": the flash kernels; "default": jnp
     head_chunk: int = 0         # vocabulary columns a step of the head
-    remat: bool = False         # recompute each block in the backward
+    remat: bool = False         # recompute each block in the backward,
+    #                             but for the flash forward's (o, lse)
 
     def __post_init__(self):
         bad = set(self.layer_types) - set(MIXERS)
@@ -432,7 +442,9 @@ class HybridLM:
                 lp, bias = xs if _biased else (xs, None)
                 return self._block(_kind, lp, x, _ffn, bias)
             if self.remat:
-                block = jax.checkpoint(block)
+                block = jax.checkpoint(
+                    block, policy=jax.checkpoint_policies
+                    .save_only_these_names(*SAVED_NAMES))
             layers = [params[f"layer_{i}"] for i in range(first, first + n)]
             # prof.REGIONS: what the run adds around its blocks, whose
             # ops keep their own scopes (metadata only)

@@ -192,19 +192,26 @@ def test_every_scope_of_the_step_is_in_the_vocabulary(model, scopes, absent):
     assert "linear_attention/delta_rule" not in text
 
 
+def _model_and_loss(model: str, **kw):
+    """The tiny model of a pattern (``hybrid`` with a run of two,
+    ``latent``, ``conv``) and its loss of ``(params, tokens)``."""
+    lm = {"hybrid": functools.partial(
+              _tiny, layer_types=("linear", "linear", "full")),
+          "latent": _latent, "conv": _conv}[model](head_chunk=32, **kw)
+    if model == "hybrid":
+        return lm, lm.loss
+    return lm, lambda p, t: lm.loss_with_router_state(
+        p, lm.router_state(), t)[0]
+
+
 @functools.lru_cache(maxsize=None)
 def _grad_lines(model: str, remat: bool = True, regions: bool = True):
     """The instruction lines of the tiny model's compiled loss gradient
     (the hybrid pattern with a run of two, the latent or the conv
     pattern); ``regions`` false: with ``prof.REGIONS`` opening nothing,
     the program as it was before them."""
-    lm = {"hybrid": functools.partial(
-              _tiny, layer_types=("linear", "linear", "full")),
-          "latent": _latent, "conv": _conv}[model](
-        head_chunk=32, remat=remat)
+    lm, loss = _model_and_loss(model, remat=remat)
     params = lm.init(jax.random.key(7))
-    loss = lm.loss if model == "hybrid" else (
-        lambda p, t: lm.loss_with_router_state(p, lm.router_state(), t)[0])
     real = jax.named_scope
     with pytest.MonkeyPatch.context() as mp:
         if not regions:
@@ -299,6 +306,62 @@ def test_what_the_backward_runs_again_carries_jax_checkpoints_own_name(
     held = [p for p in _paths(_grad_lines(model, remat=False)) if name in p]
     assert all(trace_scope.scope_of(p) == "delta_rule" for p in held)
     assert bool(held) == (model == "hybrid")
+
+
+@pytest.mark.parametrize("model, bodies", [
+    ("hybrid", 1), ("latent", 2), ("conv", 1)])
+def test_remat_keeps_what_the_flash_forward_made_and_nothing_else(
+        model, bodies, monkeypatch):
+    """Under ``remat`` a block is recomputed but for the two arrays the
+    flash forward kernel made (``flash_attention.SAVED_NAMES``): the loss
+    gradient's jaxpr holds ``apex_flash_fwd`` once a flash layer's scan
+    body, where a plain ``jax.checkpoint(block)`` holds it twice, and the
+    backward kernels as before; the gradient is bitwise the plain
+    checkpoint's and within tolerance of no ``remat``; a model with no
+    flash kernel has the jaxpr it has without the policy."""
+    def eqns(jaxpr):    # every equation, those of inner jaxprs included
+        for e in jaxpr.eqns:
+            yield e
+            for v in e.params.values():
+                for x in v if isinstance(v, (list, tuple)) else (v,):
+                    x = getattr(x, "jaxpr", x)
+                    if hasattr(x, "eqns"):
+                        yield from eqns(x)
+
+    def traced(**kw):   # the gradient function of a trace of its own
+        lm, loss = _model_and_loss(model, **kw)
+        return (jax.grad(lambda *a: loss(*a)), lm.init(jax.random.key(7)),
+                _tokens())
+
+    def read(**kw):     # (the flash kernels in the jaxpr, its equations)
+        grad, *args = traced(**kw)
+        jaxpr = jax.make_jaxpr(grad)(*args)
+        names = re.findall(r"name=(apex_flash_(?:fwd|bwd_\w+))", str(jaxpr))
+        return ({k: names.count(k) for k in set(names)},
+                [(e.primitive.name, [str(v.aval) for v in e.outvars])
+                 for e in eqns(jaxpr.jaxpr)])
+
+    def gradient(**kw):
+        grad, *args = traced(**kw)
+        return jax.tree.leaves(jax.jit(grad)(*args))
+    kept, _ = read(remat=True)
+    _, no_kernel = read(remat=True, attn_impl="default")
+    g_kept, g_none = gradient(remat=True), gradient(remat=False)
+    # the parent's wrapper: jax.checkpoint(block, policy=None)
+    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                        lambda *names: None)
+    plain, _ = read(remat=True)
+    _, no_kernel_plain = read(remat=True, attn_impl="default")
+    g_plain = gradient(remat=True)
+    backward = {"apex_flash_bwd_dq": bodies, "apex_flash_bwd_dkv": bodies}
+    assert kept == {"apex_flash_fwd": bodies, **backward}
+    assert plain == {"apex_flash_fwd": 2 * bodies, **backward}
+    for a, b, c in zip(g_kept, g_plain, g_none):
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_allclose(a, c, atol=2e-5)
+    # (printed, the two differ in how inner jaxprs are numbered and shared)
+    assert len(no_kernel) > 1000 and no_kernel == no_kernel_plain
+    assert not any(e[0] in ("pallas_call", "name") for e in no_kernel)
 
 
 def test_the_step_builder_hands_the_counters_out_beside_the_loss():

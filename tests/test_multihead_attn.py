@@ -4,6 +4,7 @@ apex/contrib/test/multihead_attn/test_self_multihead_attn.py asserts
 fast-vs-default parity for outputs and input grads)."""
 
 import functools
+import re
 import sys
 
 import jax
@@ -1222,3 +1223,29 @@ def test_block_census_of_the_cells():
     # no mask but the k length: its last block is the only edge
     assert fa.block_census(1024, 1100, 512, 512, False) == {
         "dead": 0, "interior": 4, "edge": 2}
+
+
+@pytest.mark.parametrize("policy, forwards", [
+    (None, 2), ("nothing_saveable", 2), ("saved_names", 1)])
+def test_the_forwards_two_outputs_are_saved_by_name(policy, forwards):
+    """Differentiated, ``o`` and ``lse`` carry ``fa.SAVED_NAMES``: a
+    ``jax.checkpoint`` whose policy saves those names runs
+    ``apex_flash_fwd`` once where one that saves nothing runs it again
+    in the backward (the names alone change nothing), and the gradient is
+    bitwise the one without ``jax.checkpoint``."""
+    q, k, v = _qkv(bh=2, sq=64, sk=64)
+
+    def f(q, k, v):
+        return jnp.sum(flash_attention(q * 2.0, k, v, causal=True) ** 2)
+    policy = {None: None, "nothing_saveable":
+              jax.checkpoint_policies.nothing_saveable,
+              "saved_names": jax.checkpoint_policies.save_only_these_names(
+                  *fa.SAVED_NAMES)}[policy]
+    grad = jax.grad(jax.checkpoint(f, policy=policy), argnums=(0, 1, 2))
+    text = str(jax.make_jaxpr(grad)(q, k, v))
+    assert fa.SAVED_NAMES == ("apex_flash_out", "apex_flash_lse")
+    assert all(f"name[name={name}]" in text for name in fa.SAVED_NAMES)
+    assert len(re.findall(r"name=apex_flash_fwd\b", text)) == forwards
+    assert len(re.findall(r"name=apex_flash_bwd_dq\b", text)) == 1
+    for a, b in zip(grad(q, k, v), jax.grad(f, argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_array_equal(a, b)
