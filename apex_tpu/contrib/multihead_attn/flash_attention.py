@@ -19,10 +19,13 @@ Design notes:
   state lives in VMEM scratch that persists across a row's sweep
   (initialized on its first step, finalized on its last).
 - a block's kind is known from the offsets alone (``_block_kind``) before
-  any body runs: a dead block (wholly above the causal diagonal or past the
-  k length) gets no step at all, so it is neither fetched nor computed;
-  ``block_census`` counts the dead, the interior (no element masked) and
-  the edge blocks (the diagonal, the k length's last block).
+  any body runs: a dead block (wholly above the causal diagonal, past the
+  k length, or wholly farther back than a sliding ``window`` reaches) gets
+  no step at all, so it is neither fetched nor computed; ``block_census``
+  counts the dead, the interior (no element masked) and the edge blocks
+  (the diagonal, the window's trailing edge, the k length's last block).
+  With a window the grid is the band: a row's sweep is as long as the
+  window is wide, whatever the sequence's length.
 - softmax statistics are carried as (block_q, 128) lane-replicated tiles
   (the VPU-friendly layout); ``lse`` is emitted lane-replicated and sliced
   by the wrapper.
@@ -66,6 +69,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 MAX_BLOCK = 512  # upper bound for _pick_block's divisor-aware sizing
+# with a window the grid is the band, a few blocks a line, and a grid step
+# is the unit of cost: the widest blocks that fit VMEM win. On the v5e at
+# [64, 8192, 128], window 1024 (PERF.md, PR 39), forward 512 x 512 7.04 ms,
+# 512 x 1024 5.25, 1024 x 1024 4.65 (1024 x 2048 does not fit); backward
+# dq + dkv 256 x 512 13.98, 1024 x 1024 11.48, 512 x 512 10.84; the same
+# order at windows of 256 and 4096 to within a tenth. At heads wider than
+# a lane tile 1024 x 1024 passes the kernel's 16 MB of VMEM (17.2 at 256),
+# so those keep the causal defaults
+WINDOW_BLOCK = 1024
+WINDOW_BWD_BLOCK = 512
 # jax.ad_checkpoint.checkpoint_name of the forward kernel's two outputs under
 # differentiation: a jax.checkpoint around the call whose policy is
 # save_only_these_names(*SAVED_NAMES) keeps them and runs apex_flash_fwd
@@ -73,16 +86,16 @@ MAX_BLOCK = 512  # upper bound for _pick_block's divisor-aware sizing
 SAVED_NAMES = ("apex_flash_out", "apex_flash_lse")
 
 
-def _pick_block(s: int) -> int:
-    """Largest block in {MAX_BLOCK, 384, 256, 128} that divides the
-    128-rounded sequence length (no pad blowup); sub-128 sequences use
-    their own 16-rounded length."""
+def _pick_block(s: int, most: int = MAX_BLOCK) -> int:
+    """Largest block in {1024, 512, 384, 256, 128} up to ``most`` that
+    divides the 128-rounded sequence length (no pad blowup); sub-128
+    sequences use their own 16-rounded length."""
     from apex_tpu.ops.pallas._common import round_up
     if s <= 128:
         return max(16, round_up(s, 16))
     sp = round_up(s, 128)
-    for b in (MAX_BLOCK, 384, 256, 128):
-        if sp % b == 0:
+    for b in (1024, 512, 384, 256, 128):
+        if b <= most and sp % b == 0:
             return b
     return 128
 NEG_INF = -1.0e30
@@ -157,8 +170,9 @@ def _keep_mask(off_ref, bh, qb, kb, shape, rate):
     return bits >= jnp.uint32(_drop_threshold(rate))
 
 
-def _masked_scores(s, off_ref, qb, kb, causal):
-    """Apply causal (global positions from SMEM offsets) and k-length
+def _masked_scores(s, off_ref, qb, kb, causal, window=None):
+    """Apply causal (global positions from SMEM offsets), sliding-window
+    (``window`` keys back from the query, itself included) and k-length
     (local padding, offs[2]) masks to a [bq, bk] score block."""
     bq, bk = s.shape
     k_local = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -169,14 +183,17 @@ def _masked_scores(s, off_ref, qb, kb, causal):
         k_pos = off_ref[1] + kb * bk + \
             jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+        if window is not None:
+            s = jnp.where(q_pos - k_pos < window, s, NEG_INF)
     return s
 
 
-def _block_kind(offs, qb, kb, bq, bk, causal):
+def _block_kind(offs, qb, kb, bq, bk, causal, window=None):
     """``(live, interior)`` of score block (qb, kb), from the offsets
-    alone. Not live (dead): wholly above the causal diagonal or past the
-    k length, nothing to compute, and no grid step. Interior: no element
-    masked. Live and not interior (edge): the diagonal and the k length's
+    alone. Not live (dead): wholly above the causal diagonal, past the
+    k length or farther back than ``window - 1`` keys, nothing to compute,
+    and no grid step. Interior: no element masked. Live and not interior
+    (edge): the diagonal, the window's trailing edge and the k length's
     last block. The kernels act on live alone: a body without masks for
     the interior blocks measured nothing on the chip (PERF.md, PR 35);
     ``block_census`` counts all three. ``offs`` indexes as (q_start, k_start,
@@ -190,17 +207,20 @@ def _block_kind(offs, qb, kb, bq, bk, causal):
         k_pos = offs[1] + k_lo
         live = live & (q_lo + bq - 1 >= k_pos)
         interior = interior & (q_lo >= k_pos + bk - 1)
+        if window is not None:      # visible: 0 <= q_pos - k_pos < window
+            live = live & (q_lo - (k_pos + bk - 1) < window)
+            interior = interior & (q_lo + bq - 1 - k_pos < window)
     return live, interior
 
 
-def _grid_kinds(xp, offs, nq, nk, bq, bk, causal):
+def _grid_kinds(xp, offs, nq, nk, bq, bk, causal, window=None):
     """``_block_kind`` of every block of an ``nq`` x ``nk`` grid, in numpy
     or jax.numpy: the blocks' indices [nq, 1] and [1, nk], and live and
     interior [nq, nk]."""
     qb = xp.arange(nq, dtype=xp.int32)[:, None]
     kb = xp.arange(nk, dtype=xp.int32)[None, :]
     live, interior = (xp.broadcast_to(a, (nq, nk)) for a in _block_kind(
-        offs, qb, kb, bq, bk, causal))
+        offs, qb, kb, bq, bk, causal, window))
     return qb, kb, live, interior
 
 
@@ -208,7 +228,8 @@ def _grid_kinds(xp, offs, nq, nk, bq, bk, causal):
 _IDX, _KB, _LIVE, _FIRST, _LAST = 0xFFF, 12, 24, 25, 26
 
 
-def _steps(known, offs, nq, nk, bq, bk, causal, by_col=False, every=False):
+def _steps(known, offs, nq, nk, bq, bk, causal, by_col=False, every=False,
+           window=None):
     """The grid's steps in order, int32 [T], one code each: the block
     (qb, kb) a step holds, whether it is live, and whether it is the first
     and the last step of its line (a q row's sweep over k blocks;
@@ -223,7 +244,7 @@ def _steps(known, offs, nq, nk, bq, bk, causal, by_col=False, every=False):
     last: nothing to fetch, nothing to do."""
     xp, offs = (jnp, offs) if known is None else (np, known)
     assert max(nq, nk) <= _IDX + 1
-    qb, kb, live, _ = _grid_kinds(xp, offs, nq, nk, bq, bk, causal)
+    qb, kb, live, _ = _grid_kinds(xp, offs, nq, nk, bq, bk, causal, window)
     code = qb | kb << _KB | live.astype(xp.int32) << _LIVE
     if by_col:
         live, code = live.T, code.T
@@ -280,17 +301,20 @@ def _run_step(code, init, body, finalize):
 
 def block_census(sq: int, sk: int, block_q: int, block_k: int, causal: bool,
                  q_start: int = 0, k_start: int = 0,
-                 k_len: Optional[int] = None) -> dict:
+                 k_len: Optional[int] = None,
+                 window: Optional[int] = None) -> dict:
     """Grid steps by kind for one batch-head, ``{"dead", "interior",
     "edge"}``, from the kernels' own predicate: of a kernel over ``sq`` x
     ``sk`` scores in ``block_q`` x ``block_k`` blocks, the blocks that get
     no step (with traced offsets an empty one), and of those that get one
     the blocks no mask touches and the blocks one does. ``sq``, ``sk``: the lengths the grid tiles
     (the backward's are the forward's padded ones); ``k_len``: the
-    unpadded key length, ``sk`` by default."""
+    unpadded key length, ``sk`` by default; ``window``: as
+    ``flash_attention``'s (the band's blocks are live, the rest dead)."""
     _, _, live, interior = _grid_kinds(
         np, (q_start, k_start, sk if k_len is None else k_len),
-        -(-sq // block_q), -(-sk // block_k), block_q, block_k, causal)
+        -(-sq // block_q), -(-sk // block_k), block_q, block_k, causal,
+        window)
     return {"dead": int((~live).sum()), "interior": int(interior.sum()),
             "edge": int((live & ~interior).sum())}
 
@@ -325,7 +349,7 @@ def _in_specs(block_q, block_k, d, bias, kvb, backward=False, **at):
     return specs
 
 
-def _fwd_kernel(causal: bool, has_bias: bool, has_kvb: bool,
+def _fwd_kernel(causal: bool, window, has_bias: bool, has_kvb: bool,
                 scale: float, dropout: float, *refs):
     refs = list(refs)
     steps_ref, off_ref, q_ref, k_ref, v_ref = refs[:5]
@@ -356,7 +380,7 @@ def _fwd_kernel(causal: bool, has_bias: bool, has_kvb: bool,
             s = s + bias_ref[0].astype(jnp.float32)
         if has_kvb:
             s = s + kvb_ref[0].astype(jnp.float32)  # (1, bk) row-broadcast
-        s = _masked_scores(s, off_ref, qb, kb, causal)
+        s = _masked_scores(s, off_ref, qb, kb, causal, window)
 
         m_prev = m_ref[:, :1]                      # [bq, 1]
         row_max = jnp.max(s, axis=1, keepdims=True)
@@ -392,7 +416,7 @@ def _fwd_kernel(causal: bool, has_bias: bool, has_kvb: bool,
 
 
 def _flash_fwd(q, k, v, bias, kvb, offs, *, causal, scale, block_q, block_k,
-               dropout=0.0, known=None):
+               dropout=0.0, known=None, window=None):
     """q,k,v: [BH, S, D], pre-padded so block sizes divide S and D == lane
     multiple. offs: int32[4] = (q_start, k_start, k_len, seed) — k_len is
     the UNPADDED key length, masked in-kernel (no O(S^2) pad-bias tensor);
@@ -409,9 +433,10 @@ def _flash_fwd(q, k, v, bias, kvb, offs, *, causal, scale, block_q, block_k,
     has_kvb = kvb is not None
     args = [q, k, v] + [a for a in (bias, kvb) if a is not None]
 
-    steps = _steps(known, offs, nq, nk, block_q, block_k, causal)
+    steps = _steps(known, offs, nq, nk, block_q, block_k, causal,
+                   window=window)
     at = _at(steps, known, nq, nk)
-    kernel = functools.partial(_fwd_kernel, causal, has_bias, has_kvb,
+    kernel = functools.partial(_fwd_kernel, causal, window, has_bias, has_kvb,
                                float(scale), float(dropout))
     o, lse = pl.pallas_call(
         kernel,
@@ -433,7 +458,9 @@ def _flash_fwd(q, k, v, bias, kvb, offs, *, causal, scale, block_q, block_k,
             _sds((bh, sq, LANES), jnp.float32, vma=_vma(q, k, v)),
         ],
         interpret=_interpret(),
-        name="apex_flash_fwd",
+        # a windowed call has a name of its own: a trace tells a window
+        # layer's kernels from a full layer's
+        name="apex_flash_fwd" if window is None else "apex_flash_win_fwd",
     )(steps, offs, *args)
     return o, lse[:, :, 0]
 
@@ -443,7 +470,8 @@ def _flash_fwd(q, k, v, bias, kvb, offs, *, causal, scale, block_q, block_k,
 # ---------------------------------------------------------------------------
 
 def _recompute_p_ds(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
-                    bias_ref, kvb_ref, bh_i, qb, kb, causal, scale, dropout):
+                    bias_ref, kvb_ref, bh_i, qb, kb, causal, scale, dropout,
+                    window=None):
     """Shared bwd block math: recompute p from saved lse, return (pd, ds, q,
     k, do) as fp32 — ``pd`` is the (dropout-masked, rescaled) probability
     used for dv. ds = p * (mask*dp/keep - delta); delta = rowsum(dO·O)
@@ -464,7 +492,7 @@ def _recompute_p_ds(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
         s = s + bias_ref[0].astype(jnp.float32)
     if kvb_ref is not None:
         s = s + kvb_ref[0].astype(jnp.float32)
-    s = _masked_scores(s, off_ref, qb, kb, causal)
+    s = _masked_scores(s, off_ref, qb, kb, causal, window)
 
     # exp(NEG - NEG) guard: fully-masked rows have lse == NEG_INF
     p = jnp.where(s > NEG_INF * 0.5, jnp.exp(s - lse), 0.0)   # [bq, bk]
@@ -482,7 +510,7 @@ def _recompute_p_ds(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
     return pd, ds, q, k, do
 
 
-def _bwd_dq_kernel(causal: bool, has_bias: bool, has_kvb: bool,
+def _bwd_dq_kernel(causal: bool, window, has_bias: bool, has_kvb: bool,
                    emit_dbias: bool, scale: float, dropout: float, *refs):
     refs = list(refs)
     (steps_ref, off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -503,7 +531,7 @@ def _bwd_dq_kernel(causal: bool, has_bias: bool, has_kvb: bool,
     def _body():
         _, ds, _, k, _ = _recompute_p_ds(
             off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
-            bias_ref, kvb_ref, bh_i, qb, kb, causal, scale, dropout)
+            bias_ref, kvb_ref, bh_i, qb, kb, causal, scale, dropout, window)
         if dbias_ref is not None:
             dbias_ref[0] = ds
         dq_acc[...] += jax.lax.dot_general(
@@ -521,7 +549,7 @@ def _bwd_dq_kernel(causal: bool, has_bias: bool, has_kvb: bool,
             dbias_ref[0] = jnp.zeros_like(dbias_ref[0])
 
 
-def _bwd_dkv_kernel(causal: bool, has_bias: bool, has_kvb: bool,
+def _bwd_dkv_kernel(causal: bool, window, has_bias: bool, has_kvb: bool,
                     scale: float, dropout: float, *refs):
     refs = list(refs)
     (steps_ref, off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -541,7 +569,7 @@ def _bwd_dkv_kernel(causal: bool, has_bias: bool, has_kvb: bool,
     def _body():
         pd, ds, q, _, do = _recompute_p_ds(
             off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
-            bias_ref, kvb_ref, bh_i, qb, kb, causal, scale, dropout)
+            bias_ref, kvb_ref, bh_i, qb, kb, causal, scale, dropout, window)
         dv_acc[...] += jax.lax.dot_general(
             pd, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # [bk, d]
@@ -556,8 +584,8 @@ def _bwd_dkv_kernel(causal: bool, has_bias: bool, has_kvb: bool,
     _run_step(code, _init, _body, _finalize)
 
 
-def _bwd_dbias_kernel(nbh: int, causal: bool, has_kvb: bool, scale: float,
-                      dropout: float, *refs):
+def _bwd_dbias_kernel(nbh: int, causal: bool, window, has_kvb: bool,
+                      scale: float, dropout: float, *refs):
     """Broadcast-bias gradient: grid (nq, nk, bh) with bh INNERMOST so the
     single (1, bq, bk) output block is revisited on consecutive iterations
     while ds accumulates over batch*heads in VMEM — never materializing a
@@ -575,11 +603,11 @@ def _bwd_dbias_kernel(nbh: int, causal: bool, has_kvb: bool, scale: float,
     def _init():
         ds_acc[...] = jnp.zeros_like(ds_acc)
 
-    @pl.when(_block_kind(off_ref, qb, kb, bq, bk, causal)[0])
+    @pl.when(_block_kind(off_ref, qb, kb, bq, bk, causal, window)[0])
     def _body():
         _, ds, *_ = _recompute_p_ds(
             off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
-            bias_ref, kvb_ref, b, qb, kb, causal, scale, dropout)
+            bias_ref, kvb_ref, b, qb, kb, causal, scale, dropout, window)
         ds_acc[...] += ds
 
     @pl.when(b == nbh - 1)
@@ -588,7 +616,7 @@ def _bwd_dbias_kernel(nbh: int, causal: bool, has_kvb: bool, scale: float,
 
 
 def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
-                bias_grad, dropout=0.0, known=None):
+                bias_grad, dropout=0.0, known=None, window=None):
     """Pallas flash backward over the padded residuals. Returns
     (dq, dk, dv, dbias) with dbias None when no bias was supplied and
     zeros when ``bias_grad`` is False (mask-only biases)."""
@@ -622,7 +650,7 @@ def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
 
     # --- dq (+ per-bh dbias): each q row's sweep over its k blocks ---------
     steps = _steps(known, offs, nq, nk, block_q, block_k, causal,
-                   every=dbias_in_dq)
+                   every=dbias_in_dq, window=window)
     at = _at(steps, known, nq, nk)
     dq_out_specs = [_spec((1, block_q, d), lambda b, i, j: (b, i, 0), at)]
     dq_out_shape = [_sds((bh, sq, d), q.dtype, vma=vma)]
@@ -632,7 +660,7 @@ def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
         dq_out_shape.append(
             _sds((bh, sq, sk), jnp.float32, vma=vma))
     dq_res = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, causal, has_bias, has_kvb,
+        functools.partial(_bwd_dq_kernel, causal, window, has_bias, has_kvb,
                           dbias_in_dq, float(scale), float(dropout)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,                          # steps, offs
@@ -642,7 +670,8 @@ def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
         out_shape=dq_out_shape,
         interpret=_interpret(),
-        name="apex_flash_bwd_dq",
+        name="apex_flash_bwd_dq" if window is None
+        else "apex_flash_win_bwd_dq",
     )(steps, offs, *args)
     if dbias_in_dq:
         dq, dbias = dq_res
@@ -652,7 +681,7 @@ def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
         dbias = None
     if emit_dbias and not dbias_in_dq:
         dbias = pl.pallas_call(
-            functools.partial(_bwd_dbias_kernel, bh, causal, has_kvb,
+            functools.partial(_bwd_dbias_kernel, bh, causal, window, has_kvb,
                               float(scale), float(dropout)),
             grid=(nq, nk, bh),
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]        # offs
@@ -669,11 +698,11 @@ def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
 
     # --- dk / dv: each k column's sweep over its q blocks ------------------
     steps = _steps(known, offs, nq, nk, block_q, block_k, causal,
-                   by_col=True)
+                   by_col=True, window=window)
     at = _at(steps, known, nq, nk, by_col=True)
     col = _spec((1, block_k, d), lambda b, i, j: (b, j, 0), at)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, causal, has_bias, has_kvb,
+        functools.partial(_bwd_dkv_kernel, causal, window, has_bias, has_kvb,
                           float(scale), float(dropout)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,                          # steps, offs
@@ -687,7 +716,8 @@ def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
             _sds((bh, sk, d), v.dtype, vma=vma),
         ],
         interpret=_interpret(),
-        name="apex_flash_bwd_dkv",
+        name="apex_flash_bwd_dkv" if window is None
+        else "apex_flash_win_bwd_dkv",
     )(steps, offs, *args)
     return dq, dk, dv, dbias
 
@@ -699,15 +729,16 @@ def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
 def reference_attention(q, k, v, bias=None, *, kv_bias=None,
                         causal=False, scale=None,
                         q_start=0, k_start=0, return_lse=False,
-                        dropout_rate=0.0, dropout_seed=0):
+                        dropout_rate=0.0, dropout_seed=0, window=None):
     """Unfused jnp attention with the same (out, lse) contract — the
     impl='default' path (reference: the torch-composed SelfAttnFunc,
     apex/contrib/multihead_attn/self_multihead_attn_func.py:4) and the
     numerics oracle for the kernel tests. ``dropout_rate`` applies
     dropout to the softmax probabilities with the SAME coordinate-hash
     mask as the flash kernel, so the two impls agree bit-for-bit on which
-    weights are dropped."""
+    weights are dropped. ``window``: as ``flash_attention``'s."""
     import math
+    _check_window(window, causal)
     sq, d = q.shape[-2], q.shape[-1]
     sk = k.shape[-2]
     if scale is None:
@@ -722,6 +753,8 @@ def reference_attention(q, k, v, bias=None, *, kv_bias=None,
         q_pos = jnp.asarray(q_start, jnp.int32) + jnp.arange(sq)[:, None]
         k_pos = jnp.asarray(k_start, jnp.int32) + jnp.arange(sk)[None, :]
         s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+        if window is not None:
+            s = jnp.where(q_pos - k_pos < window, s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     m = jnp.maximum(m, NEG_INF)
     p = jnp.where(s > NEG_INF * 0.5, jnp.exp(s - m), 0.0)
@@ -745,7 +778,7 @@ def reference_attention(q, k, v, bias=None, *, kv_bias=None,
 
 
 def _bwd_chunked(res, do, dlse, *, causal, scale, block_k, bias_grad=True,
-                 dropout=0.0):
+                 dropout=0.0, window=None):
     """Flash backward: recompute p per K/V block from (q, k, v, lse), scan
     over blocks accumulating dq and emitting (dk, dv) — O(S·block) memory
     (the flash backward recurrence; replaces saving the S×S softmax the way
@@ -797,8 +830,10 @@ def _bwd_chunked(res, do, dlse, *, causal, scale, block_k, bias_grad=True,
         s = jnp.where(k_local[None, None, :] < k_len, s, NEG_INF)
         if causal:
             k_pos = jnp.asarray(k_start, jnp.int32) + k_local
-            s = jnp.where(q_pos[None, :, None] >= k_pos[None, None, :],
-                          s, NEG_INF)
+            ahead = q_pos[None, :, None] - k_pos[None, None, :]
+            s = jnp.where(ahead >= 0, s, NEG_INF)
+            if window is not None:
+                s = jnp.where(ahead < window, s, NEG_INF)
         p = jnp.where(s > NEG_INF * 0.5,
                       jnp.exp(s - lse[:, :, None]), 0.0)   # [bh, sq, bk]
         dp = jnp.einsum("bqd,bkd->bqk", do, vjf)
@@ -846,8 +881,8 @@ def _bwd_chunked(res, do, dlse, *, causal, scale, block_k, bias_grad=True,
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12, 13))
-def _flash_core(q, k, v, bias, kvb, causal, scale, block_q, block_k,
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12, 13, 14))
+def _flash_core(q, k, v, bias, kvb, causal, window, scale, block_q, block_k,
                 bwd_block_q, bwd_block_k, bias_grad, dropout, known, offs):
     """Returns (o, lse). lse is a true primal output with a correct
     cotangent path (its gradient folds into ds — needed by ring attention,
@@ -862,15 +897,15 @@ def _flash_core(q, k, v, bias, kvb, causal, scale, block_q, block_k,
     divide the padded sequence lengths."""
     return _flash_fwd(q, k, v, bias, kvb, offs, causal=causal, scale=scale,
                       block_q=block_q, block_k=block_k, dropout=dropout,
-                      known=known)
+                      known=known, window=window)
 
 
-def _flash_core_fwd(q, k, v, bias, kvb, causal, scale, block_q, block_k,
-                    bwd_block_q, bwd_block_k, bias_grad, dropout, known,
-                    offs):
+def _flash_core_fwd(q, k, v, bias, kvb, causal, window, scale, block_q,
+                    block_k, bwd_block_q, bwd_block_k, bias_grad, dropout,
+                    known, offs):
     o, lse = _flash_fwd(q, k, v, bias, kvb, offs, causal=causal, scale=scale,
                         block_q=block_q, block_k=block_k, dropout=dropout,
-                        known=known)
+                        known=known, window=window)
     o, lse = map(checkpoint_name, (o, lse), SAVED_NAMES)
     return (o, lse), (q, k, v, bias, kvb, offs, lse, o)
 
@@ -906,7 +941,7 @@ def flash_min_s() -> int:
     return int(env) if env else DEFAULT_FLASH_MIN_S
 
 
-def _flash_core_bwd(causal, scale, block_q, block_k, bwd_block_q,
+def _flash_core_bwd(causal, window, scale, block_q, block_k, bwd_block_q,
                     bwd_block_k, bias_grad, dropout, known, res, cts):
     do, dlse = cts
     if _bwd_impl() == "chunked":
@@ -917,13 +952,14 @@ def _flash_core_bwd(causal, scale, block_q, block_k, bwd_block_q,
                                          scale=scale,
                                          block_k=min(bwd_block_k, 128),
                                          bias_grad=bias_grad,
-                                         dropout=dropout)
+                                         dropout=dropout, window=window)
     else:
         dq, dk, dv, dbias = _bwd_pallas(res, do, dlse, causal=causal,
                                         scale=scale, block_q=bwd_block_q,
                                         block_k=bwd_block_k,
                                         bias_grad=bias_grad,
-                                        dropout=dropout, known=known)
+                                        dropout=dropout, known=known,
+                                        window=window)
     kvb, offs = res[4], res[5]
     d_kvb = None if kvb is None else jnp.zeros_like(kvb)
     d_offs = jnp.zeros_like(offs)  # int32 cotangent placeholder
@@ -933,13 +969,42 @@ def _flash_core_bwd(causal, scale, block_q, block_k, bwd_block_q,
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
+def _check_window(window, causal) -> None:
+    if window is None:
+        return
+    if not isinstance(window, (int, np.integer)) or window < 1:
+        raise ValueError(f"window must be a plain positive integer (it "
+                         f"shapes the grids), got {window!r}")
+    if not causal:
+        raise ValueError("a window needs causal=True: it reaches back "
+                         "from the query's own position")
+
+
+def _capped(block: int, padded: int, most: int) -> int:
+    """``block``, or where it is wider than ``most`` the largest of {512,
+    384, 256, 192, 128} up to ``most`` that divides the padded length (a
+    block of 384, 512 or 1024 guarantees a hit)."""
+    if block > most:
+        for cand in (512, 384, 256, 192, 128):
+            if cand <= most and padded % cand == 0:
+                return cand
+    return block
+
+
 def block_sizes(sq: int, sk: int, block_q: Optional[int] = None,
                 block_k: Optional[int] = None,
                 bwd_block_q: Optional[int] = None,
-                bwd_block_k: Optional[int] = None):
+                bwd_block_k: Optional[int] = None,
+                window: Optional[int] = None, d: Optional[int] = None):
     """``(block_q, block_k, bwd_block_q, bwd_block_k)`` as
     ``flash_attention`` tiles ``sq`` x ``sk`` scores: what was given, and
-    its defaults for the rest."""
+    its defaults for the rest; with a ``window``, at heads of up to ``d``
+    = 128 lanes, the band's defaults (``WINDOW_BLOCK`` forward,
+    ``WINDOW_BWD_BLOCK`` backward). A ``window`` needs the head width
+    ``d``: the band's blocks do not fit VMEM at wider heads."""
+    if window is not None and d is None:
+        raise ValueError("block_sizes: a window needs the head width d")
+    band = window is not None and d <= LANES
     # Adaptive default: wide blocks keep the MXU matmuls fat and cut the
     # grid-step count up to 16x vs a fixed 128 — at S=16k the fixed size
     # meant 262k sequential grid steps and the kernel ran
@@ -949,10 +1014,11 @@ def block_sizes(sq: int, sk: int, block_q: Optional[int] = None,
     # block changes the online-softmax accumulation ORDER for
     # 128 < S <= 512 vs the old fixed-128 blocking (allclose, not
     # bitwise, vs previous builds).
+    most = WINDOW_BLOCK if band else MAX_BLOCK
     if block_q is None:
-        block_q = _pick_block(sq)
+        block_q = _pick_block(sq, most)
     if block_k is None:
-        block_k = _pick_block(sk)
+        block_k = _pick_block(sk, most)
     block_q = min(block_q, _round_up(sq, 16))
     block_k = min(block_k, _round_up(sk, 16))
     qpad = (-sq) % block_q
@@ -965,19 +1031,18 @@ def block_sizes(sq: int, sk: int, block_q: Optional[int] = None,
     # any other measured combo and avoids the 9x cliff. Overrides must
     # tile the padded lengths (the backward runs over the same padded
     # residuals).
+    # With a window (the v5e of PR 39, WINDOW_BLOCK's readings) 512 x 512
+    # was the best backward and no cliff: both sides cap at
+    # WINDOW_BWD_BLOCK.
     if bwd_block_q is None:
-        bwd_block_q = block_q
-        if block_q > 256:
-            # largest of {256, 192, 128} dividing the padded length
-            # (block_q in {384, 512} guarantees a hit); sequences whose
-            # own block is an odd size <= 256 keep it — one big tile
-            # beats a sliver tile
-            for cand in (256, 192, 128):
-                if (sq + qpad) % cand == 0:
-                    bwd_block_q = cand
-                    break
+        # largest of {256, 192, 128} dividing the padded length; sequences
+        # whose own block is an odd size <= 256 keep it — one big tile
+        # beats a sliver tile
+        bwd_block_q = _capped(block_q, sq + qpad,
+                              WINDOW_BWD_BLOCK if band else 256)
     if bwd_block_k is None:
-        bwd_block_k = block_k
+        bwd_block_k = _capped(block_k, sk + kpad, WINDOW_BWD_BLOCK) \
+            if band else block_k
     for name, blk, sz in (("bwd_block_q", bwd_block_q, sq + qpad),
                           ("bwd_block_k", bwd_block_k, sk + kpad)):
         if sz % blk:
@@ -998,7 +1063,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     return_lse: bool = False,
                     bias_grad: bool = True,
                     dropout_rate: float = 0.0,
-                    dropout_seed=0):
+                    dropout_seed=0,
+                    window: Optional[int] = None):
     """Fused attention over [B, H, S, D] (or [BH, S, D]) inputs.
 
     bias: optional additive [1|BH, Sq, Sk] (or [B, H, Sq, Sk]) score bias —
@@ -1027,7 +1093,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     self_multihead_attn.py:24) — the [Sq, Sk] mask is never materialized;
     it is recomputed from a coordinate hash (``dropout_bits``) in the fwd
     and bwd kernels. ``dropout_seed`` may be a traced int32 scalar.
+    ``window``: a sliding window under ``causal``, a plain integer: query
+    ``i`` sees key ``j`` iff ``0 <= i - j < window`` in global positions
+    (itself and the ``window - 1`` before it). The kernels' grids are cut
+    to the band (a block wholly outside it gets no step: ``block_census``),
+    the default blocks are the band's (``block_sizes``: at heads of up to
+    128, up to 1024 x 1024 forward and 512 x 512 backward), and the three
+    calls are named ``apex_flash_win_fwd`` / ``_win_bwd_dq``
+    / ``_win_bwd_dkv``. ``None``: no window, the causal call as it was.
     """
+    _check_window(window, causal)
     squeeze = q.ndim == 4
     if squeeze:
         b, h, _, _ = q.shape
@@ -1041,11 +1116,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if scale is None:
         scale = 1.0 / float(d) ** 0.5
 
+    dpad = (-d) % LANES
     block_q, block_k, bwd_block_q, bwd_block_k = block_sizes(
-        sq, sk, block_q, block_k, bwd_block_q, bwd_block_k)
+        sq, sk, block_q, block_k, bwd_block_q, bwd_block_k, window, d + dpad)
     qpad = (-sq) % block_q
     kpad = (-sk) % block_k
-    dpad = (-d) % LANES
 
     qq, kk, vv, bb = q, k, v, bias
     if dpad:
@@ -1078,7 +1153,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # offsets that are plain integers are known when the grids are made
     known = (int(q_start), int(k_start), sk) if all(
         isinstance(x, (int, np.integer)) for x in (q_start, k_start)) else None
-    out, lse = _flash_core(qq, kk, vv, bb, kvb, causal, float(scale),
+    out, lse = _flash_core(qq, kk, vv, bb, kvb, causal, window, float(scale),
                            block_q, block_k, bwd_block_q, bwd_block_k,
                            bool(bias_grad), float(dropout_rate), known, offs)
     lse = lse[:, :sq]

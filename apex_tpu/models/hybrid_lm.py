@@ -6,9 +6,10 @@ short-convolution hybrids, with the layer pattern as data.
     x += mixer_i(norm(x))         mixer_i by ``layer_types[i]``:
                                   "linear" (Gated DeltaNet), "full"
                                   (grouped-query softmax attention, gated
-                                  or not), "latent" (latent attention,
-                                  MLA) or "conv" (the double-gated short
-                                  convolution)
+                                  or not), "window" (the same over a
+                                  sliding window), "latent" (latent
+                                  attention, MLA) or "conv" (the
+                                  double-gated short convolution)
     x += ffn_i(norm(x))           ffn_i by ``ffn_types[i]``: "experts" (a
                                   chip's share of a many-expert layer) or
                                   "dense" (one SwiGLU of ``dense_ffn``)
@@ -46,7 +47,15 @@ causal attention runs through ``flash_attention`` with K and V
 which other programs share, stay as they are, and pad a head narrower
 than a lane tile to 128; the broadcast's transpose sums a group's dK and
 dV); the result, times ``sigmoid(gate)`` where there is a gate, is
-projected out.
+projected out. The **window mixer** (``"window"``) is that mixer, leaf for
+leaf, with query ``i`` seeing key ``j`` iff ``0 <= i - j < window``:
+``flash_attention(window=)``, whose grids are cut to the band, under a
+scope of its own. **Rotary tables go by layer kind**: a window layer turns
+its heads with the plain table at ``rope_theta``, a full layer with
+``rope_yarn``'s where that is given: YaRN's frequencies (interpolated by
+``factor`` below the ramp over ``original positions``, extrapolated above
+it, blended between ``beta_fast`` and ``beta_slow`` rotations) and its
+``attention_factor`` on ``cos`` and ``sin``.
 
 The **short-convolution mixer**: ``[B | C | u] = h W_in``, three streams
 ``hidden`` wide; ``z = conv(B * u)``, a causal depthwise convolution of
@@ -76,8 +85,9 @@ step's own pairs an expert (``ExpertLayer.moved_bias``), as a ResNet's
 batch statistics travel through ``train_step.build_step``.
 
 Scopes (``prof.SCOPES``): ``embed``, ``linear_attention``,
-``delta_rule``, ``attention``, ``latent_attention``, ``short_conv``,
-``mlp``, ``moe_route``, ``moe_experts``, ``head_loss``; siblings, never
+``delta_rule``, ``attention``, ``window_attention``,
+``latent_attention``, ``short_conv``, ``mlp``, ``moe_route``,
+``moe_experts``, ``head_loss``; siblings, never
 nested. Regions (``prof.REGIONS``), around the scopes: ``layer_stack``
 (a run's stacked leaves, the counters' ``concatenate``) and ``layer_scan``
 (the run's ``lax.scan``), opened in :meth:`HybridLM.hidden_states`. What
@@ -96,7 +106,10 @@ or conv layer, ``attn_impl="default"``) saves its input alone.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -108,7 +121,7 @@ from apex_tpu.ops.gated_delta_rule import gated_delta_rule
 __all__ = ["HybridLM"]
 
 _F32 = jnp.float32
-MIXERS = ("linear", "full", "latent", "conv")
+MIXERS = ("linear", "full", "latent", "conv", "window")
 FFNS = ("experts", "dense")
 
 
@@ -121,13 +134,44 @@ def _norm0(x, w, eps, zero_centred: bool = True):
     return (y * (1.0 + w if zero_centred else w)).astype(x.dtype)
 
 
-def _rotary(x, theta: float, rot: int):
+class Yarn(NamedTuple):
+    """The "full" layers' YaRN rotary scaling (``HybridLM.rope_yarn``)."""
+    factor: float               # what the slow pairs' frequency is divided by
+    positions: int              # the original positions
+    beta_fast: float            # turns over them above which a pair is kept
+    beta_slow: float            # turns below which it is divided by factor
+    attention_factor: float     # on cos and on sin
+
+
+def _yarn(freq, theta: float, rot: int, yarn: Yarn):
+    """YaRN's frequencies from the plain ones ``freq [rot / 2]``. A pair
+    that turns more than ``beta_fast`` times over the original positions
+    keeps its frequency, one that turns less than ``beta_slow`` times has
+    it divided by ``factor``, a linear ramp over the pairs between (its
+    ends truncated to whole pairs)."""
+    def pair(turns):        # the pair that turns ``turns`` times
+        return rot * math.log(yarn.positions / (2 * math.pi * turns)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(pair(yarn.beta_fast)), 0)
+    high = min(math.ceil(pair(yarn.beta_slow)), rot - 1)
+    ramp = jnp.clip((jnp.arange(rot // 2, dtype=_F32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return freq / yarn.factor * ramp + freq * (1.0 - ramp)
+
+
+def _rotary(x, theta: float, rot: int, yarn: Optional[Yarn] = None):
     """Rotary positions on the first ``rot`` of the last axis of
-    ``x [B, T, H, D]``, half-split pairing."""
+    ``x [B, T, H, D]``, half-split pairing; ``yarn``: ``_yarn``'s
+    frequencies and its attention factor on ``cos`` and ``sin``."""
     half = rot // 2
     freq = theta ** (-jnp.arange(half, dtype=_F32) / half)
+    if yarn:
+        freq = _yarn(freq, theta, rot, yarn)
     ang = jnp.arange(x.shape[1], dtype=_F32)[:, None] * freq    # [T, half]
     cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    if yarn:
+        cos, sin = (cos * yarn.attention_factor,
+                    sin * yarn.attention_factor)
     x1, x2 = x[..., :half].astype(_F32), x[..., half:rot].astype(_F32)
     turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
     return jnp.concatenate([turned.astype(x.dtype), x[..., rot:]], -1)
@@ -155,6 +199,9 @@ class HybridLM:
     rotary_dim: int = 64
     rope_theta: float = 1e7
     attn_gate: bool = True      # W_q carries an output gate a head
+    window: int = 0             # keys a "window" layer's query sees
+    rope_yarn: Optional[Yarn] = None    # the "full" layers' rotary
+    #                             scaling; None = the plain table
     # latent attention (num_heads heads, rope_theta)
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
@@ -200,6 +247,11 @@ class HybridLM:
                 or self.linear_v_heads % self.linear_k_heads:
             raise ValueError("query heads must be a multiple of key/value "
                              "heads, value heads of key heads")
+        if "window" in self.layer_types and self.window < 1:
+            raise ValueError("a \"window\" layer needs window >= 1")
+        if self.rope_yarn is not None \
+                and not isinstance(self.rope_yarn, Yarn):
+            raise ValueError("rope_yarn: a hybrid_lm.Yarn or None")
         if self.head_chunk and self.vocab_size % self.head_chunk:
             raise ValueError(f"head_chunk ({self.head_chunk}) must divide "
                              f"vocab_size ({self.vocab_size})")
@@ -322,12 +374,15 @@ class HybridLM:
             of = of * jax.nn.silu(z.reshape(b, t, hv, dv).astype(_F32))
             return x + of.reshape(b, t, vd).astype(x.dtype) @ p["w_out"]
 
-    def _full_mixer(self, lp, x):
+    def _full_mixer(self, lp, x, window=None):
+        """The attention mixer; ``window``: the window mixer, which sees
+        that many keys and turns its heads with the plain table."""
         from apex_tpu.contrib.multihead_attn.flash_attention import (
             flash_attention, reference_attention)
         b, t, _ = x.shape
         h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        with jax.named_scope("attention"):
+        yarn = None if window else self.rope_yarn
+        with jax.named_scope("window_attention" if window else "attention"):
             p = lp["attn"]
             hid = self._norm(x, lp["norm1"])
             qg = (hid @ p["w_q"]).reshape(b, t, h, hd * (1 + self.attn_gate))
@@ -335,9 +390,9 @@ class HybridLM:
             k = (hid @ p["w_k"]).reshape(b, t, kv, hd)
             v = (hid @ p["w_v"]).reshape(b, t, kv, hd)
             q = _rotary(self._norm(q, p["q_norm"]),
-                        self.rope_theta, self.rotary_dim)
+                        self.rope_theta, self.rotary_dim, yarn)
             k = _rotary(self._norm(k, p["k_norm"]),
-                        self.rope_theta, self.rotary_dim)
+                        self.rope_theta, self.rotary_dim, yarn)
             # each key/value head serves h // kv query heads: broadcast in
             # front of the kernel (its transpose sums the group's dK, dV)
             q = q.transpose(0, 2, 1, 3)
@@ -345,7 +400,7 @@ class HybridLM:
                     for a in (k, v))
             attend = flash_attention if self.attn_impl == "fast" \
                 else reference_attention
-            a = attend(q, k, v, causal=True,
+            a = attend(q, k, v, causal=True, window=window,
                        scale=hd ** -0.5).transpose(0, 2, 1, 3)
             if self.attn_gate:
                 a = a * jax.nn.sigmoid(gate.astype(_F32)).astype(x.dtype)
@@ -404,6 +459,8 @@ class HybridLM:
         """One layer: ``(x, the expert layer's aux | None)``. ``bias``:
         the sigmoid router's selection bias of this layer."""
         x = {"linear": self._linear_mixer, "full": self._full_mixer,
+             "window": functools.partial(self._full_mixer,
+                                         window=self.window),
              "latent": self._latent_mixer,
              "conv": self._conv_mixer}[kind](lp, x)
         if ffn == "dense":

@@ -117,7 +117,7 @@ SCOPES = ("embed", "attention", "mlp", "head_loss",     # models/transformer
           "stem", r"stage\d+_block\d+", "head",          # models/resnet
           "amp_cast", "amp_scale", "optimizer", "collective",
           "linear_attention", "delta_rule",             # models/hybrid_lm
-          "latent_attention", "short_conv",
+          "latent_attention", "short_conv", "window_attention",
           "moe_route", "moe_experts")                   # contrib/moe
 
 # Regions: names of *structure that encloses scopes*, opened with the same

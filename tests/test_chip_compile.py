@@ -185,6 +185,17 @@ KERNELS = [
     ("flash_fwd_bwd-B2H32S8192D128", lambda: _flash(True),
      _qkv(2, 32, 8192, 128),
      ("apex_flash_fwd", "apex_flash_bwd_dq", "apex_flash_bwd_dkv")),
+    # a sliding-window layer's band (window 1024, the band's default
+    # blocks: 1024 x 1024 forward, 512 x 512 backward) at heads of 128
+    # and, the widest the blocks must still fit VMEM at, of 256
+    ("flash_win_fwd_bwd-B2H32S8192D128W1024",
+     lambda: _flash(True, window=1024), _qkv(2, 32, 8192, 128),
+     ("apex_flash_win_fwd", "apex_flash_win_bwd_dq",
+      "apex_flash_win_bwd_dkv")),
+    ("flash_win_fwd_bwd-B2H16S8192D256W1024",
+     lambda: _flash(True, window=1024), _qkv(2, 16, 8192, 256),
+     ("apex_flash_win_fwd", "apex_flash_win_bwd_dq",
+      "apex_flash_win_bwd_dkv")),
     ("flash_fwd-B24H16S2048D128", lambda: _flash(False),
      _qkv(24, 16, 2048, 128),
      ("apex_flash_fwd",)),

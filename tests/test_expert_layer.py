@@ -20,8 +20,8 @@ class SmallTiles(ExpertLayer):
     tile = 8        # the layer's is 128: the chip's; no constructor knob
 
 
-def _layer(held=(), router="softmax", experts=E, shared=F, **kw):
-    return SmallTiles(hidden=D, ffn=F, num_experts=experts, top_k=K,
+def _layer(held=(), router="softmax", experts=E, shared=F, top=K, **kw):
+    return SmallTiles(hidden=D, ffn=F, num_experts=experts, top_k=top,
                       experts_held=held, shared_ffn=shared, router=router,
                       routed_scale=SCALE if router == "sigmoid" else 1.0,
                       **kw)
@@ -37,7 +37,7 @@ def _share(params, lo, hi):
                          for k in ("w_gate", "w_up", "w_down")}}
 
 
-def _dense(params, x, lo=0, hi=None, router="softmax", bias=0.0):
+def _dense(params, x, lo=0, hi=None, router="softmax", bias=0.0, top=K):
     """Every expert of ``[lo, hi)`` over every token, weighted by the
     token's renormalised top-k weight for it (a constant in a share's
     backward). The sigmoid router chooses on score + bias and weighs by
@@ -46,12 +46,12 @@ def _dense(params, x, lo=0, hi=None, router="softmax", bias=0.0):
     hi = experts if hi is None else hi
     if router == "sigmoid":
         scores = jax.nn.sigmoid(x @ params["router"])
-        _, idx = jax.lax.top_k(scores + bias, K)
+        _, idx = jax.lax.top_k(scores + bias, top)
         w = jnp.take_along_axis(scores, idx, -1)
         w = SCALE * w / w.sum(-1, keepdims=True)
     else:
         probs = jax.nn.softmax(x @ params["router"], -1)
-        w, idx = jax.lax.top_k(probs, K)
+        w, idx = jax.lax.top_k(probs, top)
         w = w / w.sum(-1, keepdims=True)
     if hi - lo < experts:
         w = jax.lax.stop_gradient(w)
@@ -80,15 +80,18 @@ def test_the_uncut_layer_is_the_dense_mixture_plus_the_shared_expert(router):
 
 
 @pytest.mark.parametrize("router", ROUTERS)
-@pytest.mark.parametrize("experts, chips, shared", [
-    (E, 4, F), (E, 32, F), (64, 8, F), (64, 8, 0)])
-def test_the_shares_add_up(experts, chips, shared, router):
+@pytest.mark.parametrize("experts, chips, shared, top", [
+    (E, 4, F, K), (E, 32, F, K), (64, 8, F, K), (64, 8, 0, K),
+    (64, 4, 0, 8)])
+def test_the_shares_add_up(experts, chips, shared, top, router):
     """The parts all shares give, the shared expert counted once, are the
     uncut layer (the eight shares of a 64-expert top-4 layer among them,
     with a shared expert and, the bias-balanced sigmoid layer of the
-    short-convolution hybrids, with none); and a share computes its own
-    experts' part, nothing that stands in for the others."""
-    kind = dict(router=router, experts=experts, shared=shared)
+    short-convolution hybrids, with none; the four shares of a 64-expert
+    top-8 layer with none, the softmax layer of the sliding-window
+    models); and a share computes its own experts' part, nothing that
+    stands in for the others."""
+    kind = dict(router=router, experts=experts, shared=shared, top=top)
     params, x = _params(2, **kind), _x(48, 3)
     bias = 0.0 if router == "softmax" else 0.2 * jax.random.normal(
         jax.random.key(4), (experts,))
@@ -100,7 +103,7 @@ def test_the_shares_add_up(experts, chips, shared, router):
         layer = _layer((lo, hi), **kind)
         part, aux = layer.routed(_share(params, lo, hi), x, bias)
         np.testing.assert_allclose(
-            part, _dense(params, x, lo, hi, router, bias), atol=1e-5)
+            part, _dense(params, x, lo, hi, router, bias, top), atol=1e-5)
         assert int(aux["overflow_pairs"]) == 0
         total = total + part
     assert ("shared" in params) == bool(shared)

@@ -17,7 +17,7 @@ import pytest
 from apex_tpu import prof
 from apex_tpu.contrib.moe import ExpertLayer
 from apex_tpu.models import HybridLM, TransformerLM
-from apex_tpu.models.hybrid_lm import MIXERS, _norm0, _rotary
+from apex_tpu.models.hybrid_lm import MIXERS, Yarn, _norm0, _rotary
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
 
@@ -170,11 +170,15 @@ def test_partial_rotary_turns_the_first_dims_only_and_keeps_norms():
      ("attention", "linear_attention", "delta_rule", "short_conv")),
     ("conv", ("embed", "short_conv", "attention", "mlp", "moe_route",
               "moe_experts", "head_loss"),
-     ("latent_attention", "linear_attention", "delta_rule"))])
+     ("latent_attention", "linear_attention", "delta_rule")),
+    ("window", ("embed", "window_attention", "attention", "moe_route",
+                "moe_experts", "head_loss"),
+     ("latent_attention", "linear_attention", "short_conv", "mlp"))])
 def test_every_scope_of_the_step_is_in_the_vocabulary(model, scopes, absent):
     """The model's scopes are siblings in ``prof.SCOPES``; each shows in
-    the compiled step's op names, forward and backward. Latent attention
-    and the short convolution open one scope around all of theirs, the
+    the compiled step's op names, forward and backward. Latent attention,
+    the short convolution and the window mixer open one scope around all
+    of theirs (the window model's full layers keep ``attention``), the
     dense FFN the dense LM's ``mlp``, the tied head ``head_loss`` and its
     gather's scatter-add ``embed``."""
     text = "\n".join(_grad_lines(model))
@@ -194,11 +198,13 @@ def test_every_scope_of_the_step_is_in_the_vocabulary(model, scopes, absent):
 
 def _model_and_loss(model: str, **kw):
     """The tiny model of a pattern (``hybrid`` with a run of two,
-    ``latent``, ``conv``) and its loss of ``(params, tokens)``."""
+    ``latent``, ``conv``, ``window``) and its loss of ``(params,
+    tokens)``."""
     lm = {"hybrid": functools.partial(
               _tiny, layer_types=("linear", "linear", "full")),
-          "latent": _latent, "conv": _conv}[model](head_chunk=32, **kw)
-    if model == "hybrid":
+          "latent": _latent, "conv": _conv,
+          "window": _window}[model](head_chunk=32, **kw)
+    if lm.router == "softmax":
         return lm, lm.loss
     return lm, lambda p, t: lm.loss_with_router_state(
         p, lm.router_state(), t)[0]
@@ -549,7 +555,7 @@ def test_a_conv_conv_full_conv_pattern_led_by_a_dense_layer():
     period conv, conv, full, conv with experts: scans of 1, 2, 1 and 1,
     the result the layers' one after the other, no head among the leaves,
     no shared expert, an ungated ``w_q``."""
-    assert MIXERS == ("linear", "full", "latent", "conv")
+    assert MIXERS[:4] == ("linear", "full", "latent", "conv")
     lm = _conv()
     p = lm.init(jax.random.key(0))
     assert "head" not in p
@@ -679,3 +685,18 @@ def test_the_tied_head_against_an_untied_model_whose_head_is_a_copy():
         untied.apply(copy, toks[:, :-1], bias), atol=1e-6)
     for i in range(1, 5):       # no balance term, a share: no gradient
         assert not np.asarray(g[f"layer_{i}"]["moe"]["router"]).any()
+
+
+# -- the window model (its tests: test_hybrid_lm_window.py) -------------------
+
+def _window(**kw):
+    """Three sliding-window layers to one full layer under YaRN, 8 query
+    heads over 1 key/value head, a softmax router with no shared expert."""
+    base = dict(
+        vocab_size=96, hidden=32,
+        layer_types=("window", "window", "window", "full"), num_heads=8,
+        num_kv_heads=1, head_dim=8, rotary_dim=8, rope_theta=5e5,
+        attn_gate=False, window=12, rope_yarn=Yarn(4.0, 16, 4.0, 1.0, 1.2),
+        num_experts=8, top_k=3, expert_ffn=16, shared_ffn=0,
+        experts_held=(2, 6), zero_centred_norm=False)
+    return HybridLM(**{**base, **kw})

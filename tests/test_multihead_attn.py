@@ -1008,11 +1008,14 @@ def _parent_bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
 
 
 def _parent_kernels(monkeypatch):
-    # the parent's grids were rectangles: no use for offsets known ahead
-    monkeypatch.setattr(fa, "_flash_fwd", lambda *a, known=None, **kw:
-                        _parent_flash_fwd(*a, **kw))
-    monkeypatch.setattr(fa, "_bwd_pallas", lambda *a, known=None, **kw:
-                        _parent_bwd_pallas(*a, **kw))
+    # the parent's grids were rectangles: no use for offsets known ahead,
+    # and it had no window (the calls here give none)
+    monkeypatch.setattr(
+        fa, "_flash_fwd", lambda *a, known=None, window=None, **kw:
+        _parent_flash_fwd(*a, **kw))
+    monkeypatch.setattr(
+        fa, "_bwd_pallas", lambda *a, known=None, window=None, **kw:
+        _parent_bwd_pallas(*a, **kw))
 
 
 def _shard(q_start, k_start, s=512):
