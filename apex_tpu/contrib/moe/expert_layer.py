@@ -32,7 +32,13 @@ one rank of an expert-parallel deployment computes it:
   weights a tile, no work in the tiles past the last live one); anywhere
   else, and under ``dispatch.backend("reference")`` as the kernels'
   oracle, an einsum over each tile's gathered weights. Which runs is
-  read from the platform and the shapes;
+  read from the platform and the shapes. The rows of the buffer that
+  hold no pair (the end of a group's last tile, the tiles past the last
+  group) are not masked: they hold some token's row, finite, and a
+  weight of zero, and the weight is what the combine multiplies by (in
+  the down projection's kernel, on the tile it holds, or after the
+  einsum), so such a row adds nothing to the result and gets and gives
+  no gradient;
 - there is no capacity an expert and no drop: an expert takes whatever
   share of the buffer its tokens need. ``dispatch_bound`` is the one
   static size. Pairs that fall past it are left out **and counted**
@@ -67,6 +73,7 @@ the bias).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import ClassVar
 
 import jax
@@ -82,11 +89,12 @@ _F32 = jnp.float32
 ROUTERS = ("softmax", "sigmoid")
 
 
-def _swiglu(x, p, matmuls):
+def _swiglu(x, p, matmuls, down=None):
     """``(silu(x W_gate) * (x W_up)) W_down`` with ``matmuls(x, *ws)``
-    the float32 products of ``x`` with each of ``ws``."""
+    the float32 products of ``x`` with each of ``ws`` (``down``: the
+    last product's own, where it is another)."""
     g, u = matmuls(x, p["w_gate"], p["w_up"])
-    y, = matmuls((jax.nn.silu(g) * u).astype(x.dtype), p["w_down"])
+    y, = (down or matmuls)((jax.nn.silu(g) * u).astype(x.dtype), p["w_down"])
     return y
 
 
@@ -229,7 +237,11 @@ class ExpertLayer:
             pair = order[jnp.clip(first[tile_e][:, None] + off, 0,
                                   n * k - 1).reshape(rows)]
             tok = pair // k
-            xb = jnp.where(live[:, None], x[tok], 0)
+            # a dead row (a group's last tile past its pairs, the tiles
+            # past the last group) holds some token's row under no mask:
+            # its weight is zero, which keeps it out of the result and of
+            # every gradient
+            xb = x[tok]
             wb = jnp.where(live, w.reshape(-1)[pair], 0.0)
             overflow = jnp.sum(jnp.clip(
                 (end_tile - tiles_e) * tm + n_e - rows, 0, n_e))
@@ -239,17 +251,22 @@ class ExpertLayer:
                 jnp.int32)
         with jax.named_scope("moe_experts"):
             if dispatch.use_pallas() and gmm.takes(d, self.ffn, tm):
-                yb = _swiglu(xb, params, lambda x, *ws: gmm.grouped_matmul(
-                    x, ws, tile_e, live_tiles))
+                def matmuls(x, *ws, scale=None):
+                    return gmm.grouped_matmul(x, ws, tile_e, live_tiles,
+                                              scale)
+                # the pairs' weights ride the down projection: its kernel
+                # multiplies the float32 tile it holds and writes it once
+                yb = _swiglu(xb, params, matmuls,
+                             functools.partial(matmuls, scale=wb))
             else:       # the kernels' oracle: each tile's weights gathered
                 yb = _swiglu(
                     xb.reshape(rows // tm, tm, d), params,
                     lambda x, *ws: [jnp.einsum(
                         "tmk,tkn->tmn", x, w[tile_e],
                         preferred_element_type=_F32) for w in ws]
-                ).reshape(rows, d)
+                ).reshape(rows, d) * wb[:, None]
         with jax.named_scope("moe_route"):
-            y = jnp.zeros((n, d), _F32).at[tok].add(yb * wb[:, None])
+            y = jnp.zeros((n, d), _F32).at[tok].add(yb)
         aux = {"load_balance_loss": balance,
                "overflow_pairs": overflow.astype(jnp.int32),
                "held_pairs": jnp.sum(n_e).astype(jnp.int32),

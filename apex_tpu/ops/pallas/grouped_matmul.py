@@ -14,7 +14,11 @@ names the expert's block of ``w [held, K, N]``:
 - :func:`grouped_matmul` ``out[t] = lhs[t] @ w[tile_e[t]]`` (``apex_moe_gmm``):
   consecutive tiles of one expert name the same block, which the pipeline
   does not fetch again, so an expert's weights are read once a pass. A
-  layer's gate and up matrices share a call (one read of ``lhs``). The
+  layer's gate and up matrices share a call (one read of ``lhs``). With
+  a ``scale`` a row (the down projection's: a pair's weight in the
+  combine) the kernel stores ``(lhs[t] @ w) * scale[t]``, the float32
+  product times the float32 scale while the tile is in VMEM, where XLA
+  would read the result back and write it again. The
   same kernel contracts the blocks' other axis for the backward by rows,
   ``d lhs[t] = sum over the call's w of d out[t] @ w[tile_e[t]]^T``, in
   float32 and rounded once: no transposed copy of ``w``, no partial ``d
@@ -56,6 +60,7 @@ from apex_tpu.ops.pallas._common import LANES, interpret_mode, vma
 __all__ = ["grouped_matmul", "takes"]
 
 TILE = 128                  # rows a tile: ExpertLayer.tile
+_SUB = 8                    # float32 rows a register: tiles a block of scales
 _F32 = jnp.float32
 _VMEM_LIMIT = 64 << 20
 _BLOCK_BUDGET = 40 << 20    # what a call's blocks may take of it
@@ -94,13 +99,18 @@ _PARAMS = pltpu.CompilerParams(
     vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def _gmm_kernel(te_ref, live_ref, *refs, n: int, fan_in: bool):
+def _gmm_kernel(te_ref, live_ref, *refs, n: int, fan_in: bool,
+                scaled: bool = False):
     """``n`` products a tile. Fanning out, ``refs`` are the tile's rows,
-    ``n`` weight blocks and ``n`` results, ``o_i = lhs w_i``; fanning in,
-    ``n`` tiles of rows, ``n`` blocks and one result, ``o = sum_i lhs_i
-    w_i^T`` summed in float32 and rounded once."""
-    live = pl.program_id(1) < live_ref[0]
-    outs = refs[2 * n:] if fan_in else refs[1 + n:]
+    ``n`` weight blocks, with ``scaled`` the scales of ``_SUB`` tiles (a
+    tile a row: the array stays unpadded in HBM), and ``n`` results,
+    ``o_i = (lhs w_i) * scale``; fanning in, ``n`` tiles of rows, ``n``
+    blocks and one result, ``o = sum_i lhs_i w_i^T`` summed in float32
+    and rounded once."""
+    t = pl.program_id(1)
+    live = t < live_ref[0]
+    ins = 2 * n if fan_in else 1 + n
+    outs = refs[ins + scaled:]
 
     @pl.when(live)
     def _():
@@ -110,8 +120,18 @@ def _gmm_kernel(te_ref, live_ref, *refs, n: int, fan_in: bool):
                 for lhs, w in zip(refs[:n], refs[n:2 * n])).astype(
                     outs[0].dtype)
         else:
+            if scaled:
+                # the tile's scales lie along the lanes; one a row of the
+                # product is the diagonal of their broadcast, summed a row
+                # (one term and zeros: exact)
+                at = [jax.lax.broadcasted_iota(jnp.int32, (TILE, TILE), a)
+                      for a in (0, 1)]
+                scale = jnp.sum(jnp.where(
+                    at[0] == at[1], refs[ins][pl.ds(t % _SUB, 1), :], 0.0),
+                    axis=1, keepdims=True)
             for w, o in zip(refs[1:1 + n], outs):
-                o[...] = _dot(refs[0][...], w[...], _NN).astype(o.dtype)
+                out = _dot(refs[0][...], w[...], _NN)
+                o[...] = (out * scale if scaled else out).astype(o.dtype)
 
     @pl.when(jnp.logical_not(live))
     def _():
@@ -119,13 +139,19 @@ def _gmm_kernel(te_ref, live_ref, *refs, n: int, fan_in: bool):
             o[...] = jnp.zeros_like(o)
 
 
-def _gmm(lhs: tuple, ws: tuple, tile_e, live, fan_in: bool, out_dtype):
-    """One ``lhs [rows, K]`` times each ``w[tile_e] [K, N]`` of ``ws``,
-    or with ``fan_in`` the sum of each ``lhs [rows, N]`` times its
+def _gmm(lhs: tuple, ws: tuple, tile_e, live, fan_in: bool, out_dtype,
+         scale=None):
+    """One ``lhs [rows, K]`` times each ``w[tile_e] [K, N]`` of ``ws``
+    (each row times its ``scale [rows]`` float32 where there is one), or
+    with ``fan_in`` the sum of each ``lhs [rows, N]`` times its
     ``w[tile_e]^T``, a tile at a time: a list of results."""
     n, (rows, depth) = len(ws), lhs[0].shape
     width = ws[0].shape[1 if fan_in else 2]
     outs = 1 if fan_in else n
+    # a tile's scales a row, in whole blocks of _SUB tiles
+    scales = () if scale is None else (jnp.pad(
+        scale.reshape(rows // TILE, TILE),
+        ((0, -(rows // TILE) % _SUB), (0, 0))),)
     # two buffers a block; a float32 product in front of each result
     cut = _cut(width, 2 * n * depth * ws[0].dtype.itemsize
                + 2 * TILE * outs * (4 + jnp.dtype(out_dtype).itemsize),
@@ -138,12 +164,16 @@ def _gmm(lhs: tuple, ws: tuple, tile_e, live, fan_in: bool, out_dtype):
             te[_live(t, lv)], 0, j))
     lhs_spec = pl.BlockSpec((TILE, depth), lambda j, t, te, lv: (
         _live(t, lv), 0))
+    scale_spec = pl.BlockSpec((_SUB, TILE), lambda j, t, te, lv: (
+        _live(t, lv) // _SUB, 0))
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, n=n, fan_in=fan_in),
+        functools.partial(_gmm_kernel, n=n, fan_in=fan_in,
+                          scaled=bool(scales)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(width // cut, rows // TILE),
-            in_specs=[lhs_spec] * len(lhs) + [w_spec] * n,
+            in_specs=([lhs_spec] * len(lhs) + [w_spec] * n
+                      + [scale_spec] * len(scales)),
             out_specs=[pl.BlockSpec((TILE, cut),
                                     lambda j, t, te, lv: (t, j))] * outs),
         out_shape=[jax.ShapeDtypeStruct((rows, width), out_dtype,
@@ -151,7 +181,7 @@ def _gmm(lhs: tuple, ws: tuple, tile_e, live, fan_in: bool, out_dtype):
         compiler_params=_PARAMS,
         interpret=interpret_mode(),
         name="apex_moe_gmm",
-    )(tile_e, live, *lhs, *ws)
+    )(tile_e, live, *lhs, *ws, *scales)
 
 
 def _tgmm_kernel(te_ref, live_ref, lhs_ref, do_ref, zeros_ref, o_ref,
@@ -217,29 +247,47 @@ def _tgmm(lhs, dout, tile_e, live, held: int, out_dtype):
 
 
 @jax.custom_vjp
-def grouped_matmul(lhs, ws: tuple, tile_e, live):
+def grouped_matmul(lhs, ws: tuple, tile_e, live, scale=None):
     """For each ``w [held, K, N]`` of ``ws`` (one shape: a layer's gate
     and up matrices share a call and one read of ``lhs``) ``out [rows,
     N]`` float32 with ``out[t] = lhs[t] @ w[tile_e[t]]`` for each tile
     ``t`` of ``TILE`` rows of ``lhs [rows, K]``, ``tile_e [rows / TILE]``
     int32 never decreasing, and zeros in the tiles at or past ``live``
-    (int32 scalar), whatever ``lhs`` holds there. Differentiable in
-    ``lhs`` and ``ws``; ``d lhs`` is summed over ``ws`` in float32 inside
-    one call."""
-    return tuple(_gmm((lhs,), ws, tile_e, live.reshape(1), False, _F32))
+    (int32 scalar), whatever ``lhs`` holds there. With ``scale [rows]``
+    or ``[rows, 1]`` float32, row ``r`` of every result is multiplied by
+    ``scale[r]`` in float32 before it is stored. A row whose scale is 0
+    gives and takes nothing, whatever finite values ``lhs`` holds there:
+    zeros out, a zero row of ``d lhs``, nothing added to ``d ws``; that is
+    how a dead row in a live tile's tail needs no mask.
+
+    Differentiable in ``lhs``, ``ws`` and ``scale``; ``d lhs`` is summed
+    over ``ws`` in float32 inside one call, from the cotangent times the
+    scale. ``d scale[r] = sum(d out[r] * (lhs[r] @ w))`` takes the
+    unscaled products again and a sum a row: dead code, which the compiler
+    drops, where no gradient reaches ``scale`` (an expert layer's share,
+    whose weights are constants in the backward)."""
+    return tuple(_gmm((lhs,), ws, tile_e, live.reshape(1), False, _F32,
+                      scale))
 
 
-def _grouped_matmul_fwd(lhs, ws, tile_e, live):
-    return grouped_matmul(lhs, ws, tile_e, live), (lhs, ws, tile_e, live)
+def _grouped_matmul_fwd(lhs, ws, tile_e, live, scale=None):
+    return grouped_matmul(lhs, ws, tile_e, live, scale), (
+        lhs, ws, tile_e, live, scale)
 
 
 def _grouped_matmul_bwd(residuals, douts):
-    lhs, ws, tile_e, live = residuals
-    douts, live = tuple(d.astype(lhs.dtype) for d in douts), live.reshape(1)
+    lhs, ws, tile_e, live, scale = residuals
+    live, d_scale = live.reshape(1), None
+    if scale is not None:
+        plain = _gmm((lhs,), ws, tile_e, live, False, _F32)
+        d_scale = sum(jnp.sum(d * o, axis=1)
+                      for d, o in zip(douts, plain)).reshape(scale.shape)
+        douts = [d * scale.reshape(-1, 1) for d in douts]
+    douts = tuple(d.astype(lhs.dtype) for d in douts)
     d_lhs, = _gmm(douts, ws, tile_e, live, True, lhs.dtype)
     return d_lhs, tuple(
         _tgmm(lhs, d, tile_e, live, w.shape[0], w.dtype)
-        for d, w in zip(douts, ws)), None, None
+        for d, w in zip(douts, ws)), None, None, d_scale
 
 
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)

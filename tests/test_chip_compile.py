@@ -272,8 +272,7 @@ def _expert_layer(**kw):
     return make
 
 
-def _expert_args(f, experts, held, shared=None):
-    d = 2048
+def _expert_args(f, experts, held, shared=None, d=2048):
     p = {"router": ((d, experts), BF16), "w_gate": ((held, d, f), BF16),
          "w_up": ((held, d, f), BF16), "w_down": ((held, f, d), BF16)}
     if shared:
@@ -336,15 +335,19 @@ NEW_OPS = [
 ]
 
 
+def _specs(args, chip):
+    """Trees of ``(shape, dtype)`` as arguments on the described chip."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a[0], a[1], sharding=chip), args,
+        is_leaf=lambda x: isinstance(x, tuple) and isinstance(x[0], tuple))
+
+
 @pytest.mark.parametrize("make_fn,args,temporaries,names",
                          [pytest.param(m, a, t, n, id=i)
                           for i, m, a, t, n in NEW_OPS])
 def test_jnp_op_compiles_and_fits_for_v5e(chip, for_chip, make_fn, args,
                                           temporaries, names):
-    is_spec = lambda x: isinstance(x, tuple) and isinstance(x[0], tuple)
-    specs = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-        a[0], a[1], sharding=chip), args, is_leaf=is_spec)
-    compiled = jax.jit(make_fn()).lower(*specs).compile()
+    compiled = jax.jit(make_fn()).lower(*_specs(args, chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < temporaries * 1e9
     text = compiled.as_text()
     assert [n for n in names
@@ -353,6 +356,40 @@ def test_jnp_op_compiles_and_fits_for_v5e(chip, for_chip, make_fn, args,
     # [tiles, hidden, ffn] or [tiles, ffn, hidden] (kvl: [192, 2048, 1408])
     assert re.findall(r"\[(192|72|96),(2048,(1408|512)|(1408|512),2048)\]",
                       text) == []
+
+
+def test_the_combine_and_the_dead_rows_cost_no_pass_for_v5e(chip, for_chip):
+    """One routed layer of ``mellum2_train_s8192`` (a share: 16 of 64
+    experts, a bound of 65,536 rows of 2304): the pairs' weights reach
+    ``apex_moe_gmm`` as the down projection's scale, so under
+    ``moe_route`` the program multiplies no ``f32[65536, 2304]`` by them
+    and selects no ``bf16[65536, 2304]`` for the dead rows, and it holds
+    the kernels' four calls and no fifth: the scale's own gradient, the
+    unscaled products again, is dead code where the weights are constants
+    of the backward."""
+    from apex_tpu.contrib.moe import ExpertLayer
+    layer = ExpertLayer(hidden=2304, ffn=896, num_experts=64, top_k=8,
+                        experts_held=(0, 16), dispatch_bound=65536)
+    text = jax.jit(jax.grad(lambda p, x: jnp.sum(jnp.sin(layer.routed(
+        p, x)[0])), argnums=(0, 1))).lower(*_specs(
+            _expert_args(896, 64, 16, d=2304), chip)).compile().as_text()
+    # a computation's name -> its instructions; a fusion is its root's
+    bodies = {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%?([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text,
+        re.M | re.S)}
+    buffer = r" = (f32|bf16)\[65536,2304\]\S* "
+    passes = []
+    for line in "".join(body for name, body in bodies.items()
+                        if "fused_computation" not in name).splitlines():
+        if not (re.search(buffer, line) and "moe_route" in line):
+            continue
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        root = line if not called else re.search(
+            r"^\s*ROOT [^\n]*", bodies[called.group(1)], re.M).group()
+        passes += re.findall(buffer + r"(multiply|select)\(", root)
+    assert passes == []
+    assert len(re.findall(r"%(\w+_)?apex_moe_gmm_*\.\d+ = ", text)) == 4
+    assert len(re.findall(r"%(\w+_)?apex_moe_tgmm_*\.\d+ = ", text)) == 3
 
 
 def _step(name, skip, **kw):

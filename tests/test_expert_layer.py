@@ -2,7 +2,9 @@
 against a dense computation of the same mathematics, the shares adding up
 to the uncut layer, no pair on a held expert dropped under a skewed
 router, pairs past the bound counted; the sigmoid router's bias moving
-the choice and not the weights, and its move after a step by hand."""
+the choice and not the weights, and its move after a step by hand; a
+buffer whose dead rows hold tokens' rows under no mask, which their zero
+weights keep out of the result and of every gradient."""
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +63,17 @@ def _dense(params, x, lo=0, hi=None, router="softmax", bias=0.0, top=K):
         h = jax.nn.silu(x @ params["w_gate"][e]) * (x @ params["w_up"][e])
         y = y + w_e * (h @ params["w_down"][e])
     return y
+
+
+def _dense_loss(params, lo, hi, router="softmax"):
+    """``sum(sin(_dense))`` as a function of a share's leaves and ``x``,
+    the share's experts set into the whole layer's ``params``."""
+    def loss(p, x):
+        full = {**params, **{k: params[k].at[lo:hi].set(p[k])
+                             for k in ("w_gate", "w_up", "w_down")},
+                "router": p["router"]}
+        return jnp.sum(jnp.sin(_dense(full, x, lo, hi, router)))
+    return loss
 
 
 def _x(n=64, key=1):
@@ -156,14 +169,8 @@ def test_gradients_are_the_dense_mixtures(lo, hi):
 
     def ours(p, x):
         return jnp.sum(jnp.sin(layer.routed(p, x)[0]))
-
-    def dense(p, x):
-        full = {**params, **{k: params[k].at[lo:hi].set(p[k])
-                             for k in ("w_gate", "w_up", "w_down")},
-                "router": p["router"]}
-        return jnp.sum(jnp.sin(_dense(full, x, lo, hi)))
     got = jax.grad(ours, argnums=(0, 1))(mine, x)
-    want = jax.grad(dense, argnums=(0, 1))(mine, x)
+    want = jax.grad(_dense_loss(params, lo, hi), argnums=(0, 1))(mine, x)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, atol=1e-5)
 
@@ -188,21 +195,78 @@ def test_a_shares_router_learns_from_the_balancing_term_alone():
     assert float(jnp.abs(balance["router"]).max()) > 0
 
 
+def _skewed(router, big=()):
+    """A router that sends nearly every token to experts 8..10 (a
+    constant column of ``x`` that it weighs heavily), and ``x`` with the
+    rows ``big`` a thousand times larger and sent to experts 0..3 (a
+    second column), which the share ``(8, 16)`` does not hold."""
+    params, x = _params(6, router=router), _x(96, 7)
+    params = {**params, "router": params["router"] * 0.05}
+    x = x.at[:, 0].set(4.0).at[:, 1].set(0.0)
+    params["router"] = params["router"].at[0, 8:11].set(3.0) \
+        .at[1, :].set(0.0).at[1, 0:K].set(1.0)
+    big = jnp.asarray(big, jnp.int32)
+    return params, x.at[big].multiply(1e3).at[big, 0].set(0.0) \
+        .at[big, 1].set(1e3)
+
+
 def test_no_pair_on_a_held_expert_is_dropped_under_a_skewed_router():
     """Nearly every token sends a pair to each of experts 8..10: there
     is no capacity an expert to drop them, and the default bound holds
     the worst case."""
-    params, x = _params(6), _x(96, 7)
-    params = {**params, "router": params["router"] * 0.05}
-    # the skew: a constant column in x that the router weighs heavily
-    x = x.at[:, 0].set(4.0)
-    params["router"] = params["router"].at[0, 8:11].set(3.0)
+    params, x = _skewed("softmax")
     lo, hi = 8, 16
     layer = _layer((lo, hi))
     part, aux = layer.routed(_share(params, lo, hi), x)
     assert float(aux["load_max_over_mean"]) > 2.0        # skewed indeed
     assert int(aux["overflow_pairs"]) == 0
     np.testing.assert_allclose(part, _dense(params, x, lo, hi), atol=1e-5)
+
+
+@pytest.mark.parametrize("router", ROUTERS)
+@pytest.mark.parametrize("lo,hi", [(8, 16), (0, E)])
+def test_what_the_dead_rows_hold_reaches_nothing(lo, hi, router):
+    """A bound four times the worst case under a skewed router: most of
+    the buffer is dead rows, and a dead row holds some token's row of
+    ``x`` as it is (the pairs that follow in the sorted order: the next
+    group's, those on absent experts), kept out by its zero weight
+    alone. The result and every gradient are the dense mixture's, with
+    rows of ``x`` a thousand times the others among those that only dead
+    rows hold (the share; the whole layer has no such token); and to the
+    bit what the worst-case bound, with a quarter of the dead rows,
+    gives."""
+    big = (3, 40, 95) if hi - lo < E else ()
+    params, x = _skewed(router, big)
+    mine = _share(params, lo, hi)
+    tight = _layer((lo, hi), router=router)
+    roomy = _layer((lo, hi), router=router,
+                   dispatch_bound=4 * tight.bound(x.shape[0]))
+
+    def run(layer):
+        return jax.value_and_grad(lambda p, x: (lambda y, aux: (
+            jnp.sum(jnp.sin(y)), (y, aux)))(*layer.routed(p, x)),
+            (0, 1), has_aux=True)(mine, x)
+    (_, (y, aux)), grads = run(roomy)
+    (_, (y1, _)), grads1 = run(tight)
+    assert float(aux["load_max_over_mean"]) > 2.0
+    assert int(aux["overflow_pairs"]) == 0
+    # at least three quarters of the roomy buffer holds no pair
+    assert int(aux["live_tiles"]) * 4 <= roomy.dispatch_bound // roomy.tile
+    if big:     # sent to absent experts alone: in the buffer, never live
+        _, idx, _ = roomy.route(mine, x[jnp.asarray(big)])
+        assert bool(jnp.all(idx < K))
+
+    np.testing.assert_allclose(y, _dense(params, x, lo, hi, router),
+                               atol=1e-5)
+    want = jax.grad(_dense_loss(params, lo, hi, router), (0, 1))(mine, x)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            a, b, atol=1e-5 * max(1.0, float(jnp.abs(b).max())))
+    for i in big:       # no held expert saw them: no gradient, exactly
+        assert not np.asarray(grads[1][i]).any()
+    np.testing.assert_array_equal(y, y1)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(grads1)):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_pairs_past_the_bound_are_counted_not_lost_in_silence():

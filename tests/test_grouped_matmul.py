@@ -4,7 +4,10 @@ over each tile's gathered weights: values and every gradient, at a power
 of two and an odd multiple of 128 (the two benchmark cells' kinds of
 width), over loads with an expert without a token, a group that ends on a
 tile, one live tile, and more dead tiles than live ones; the layer under
-``jax.checkpoint`` and inside a ``lax.scan`` over stacked layers."""
+``jax.checkpoint`` and inside a ``lax.scan`` over stacked layers. Each
+with and without a scale a row (the combine's weights on the down
+projection): the product times the scale, the scale's own gradient, and
+rows of scale zero that hold anything finite and give and take nothing."""
 
 import jax
 import jax.numpy as jnp
@@ -46,9 +49,21 @@ def _oracle(lhs, w, tile_e, live):
                           lhs.shape[0], -1)
 
 
-def _kernels(lhs, w, tile_e, live):
-    out, = gmm.grouped_matmul(lhs, (w,), tile_e, live)
+def _kernels(lhs, w, tile_e, live, scale=None):
+    out, = gmm.grouped_matmul(lhs, (w,), tile_e, live, scale)
     return out
+
+
+def _scaled_oracle(lhs, w, tile_e, live, scale):
+    return _oracle(lhs, w, tile_e, live) * scale.reshape(-1, 1)
+
+
+def _live_rows(loads, tiles):
+    """Which rows of the buffer hold a pair: the first ``loads[e]`` of
+    expert ``e``'s whole tiles."""
+    rows = [np.arange(-(-n // TILE) * TILE) < n for n in loads]
+    return np.concatenate(rows + [np.zeros(
+        tiles * TILE - sum(len(r) for r in rows), bool)])
 
 
 def _operands(depth, width, dtype, key=0):
@@ -60,24 +75,36 @@ def _operands(depth, width, dtype, key=0):
 
 @pytest.mark.parametrize("load", sorted(LOADS))
 @pytest.mark.parametrize("depth,width", WIDTHS + [(384, 256)])
-def test_kernels_against_the_einsum_over_gathered_weights(depth, width,
-                                                          load):
+@pytest.mark.parametrize("scaled", [False, True], ids=["plain", "scaled"])
+def test_kernels_against_the_einsum_over_gathered_weights(scaled, depth,
+                                                          width, load):
     """Both directions of a layer's products (``hidden -> ffn`` and, at
-    (384, 256), ``ffn -> hidden`` with the odd multiple contracted)."""
+    (384, 256), ``ffn -> hidden`` with the odd multiple contracted);
+    ``scaled``: times a scale a row, one shape of it a case, against the
+    einsum times the scale, the scale's gradient with the others."""
     tile_e, live = _tiles(LOADS[load][0], BOUND // TILE)
     lhs, w, seed = _operands(depth, width, jnp.float32)
+    scale = (jax.random.normal(jax.random.key(7), (
+        (BOUND, 1) if depth == 384 else (BOUND,))),) if scaled else ()
 
     def scalar(fn):
-        return lambda lhs, w: jnp.sum(fn(lhs, w, tile_e, live) * seed)
+        return lambda lhs, w, *scale: jnp.sum(
+            fn(lhs, w, tile_e, live, *scale) * seed)
+    over = tuple(range(2 + scaled))
     with dispatch.backend("pallas"):
-        got = _kernels(lhs, w, tile_e, live)
-        g_lhs, g_w = jax.grad(scalar(_kernels), (0, 1))(lhs, w)
-    want = _oracle(lhs, w, tile_e, live)
-    w_lhs, w_w = jax.grad(scalar(_oracle), (0, 1))(lhs, w)
+        got = _kernels(lhs, w, tile_e, live, *scale)
+        g_lhs, g_w, *g_scale = jax.grad(scalar(_kernels), over)(
+            lhs, w, *scale)
+    oracle = _scaled_oracle if scaled else _oracle
+    want = oracle(lhs, w, tile_e, live, *scale)
+    w_lhs, w_w, *w_scale = jax.grad(scalar(oracle), over)(lhs, w, *scale)
     assert got.dtype == jnp.float32
     np.testing.assert_allclose(got, want, atol=1e-4)
     np.testing.assert_allclose(g_lhs, w_lhs, atol=1e-4)
     np.testing.assert_allclose(g_w, w_w, atol=2e-4)
+    for a, b in zip(g_scale, w_scale):
+        assert a.shape == scale[0].shape
+        np.testing.assert_allclose(a, b, atol=2e-4)
     # dead tiles: zeros out and no gradient in, whatever the rows hold
     rows = int(live) * TILE
     assert not np.asarray(got[rows:]).any()
@@ -86,27 +113,68 @@ def test_kernels_against_the_einsum_over_gathered_weights(depth, width,
         assert bool(np.asarray(g_w[e]).any()) == (n > 0)
 
 
-def test_bfloat16_operands_accumulate_in_float32_and_round_once():
+@pytest.mark.parametrize("scaled", [False, True], ids=["plain", "scaled"])
+def test_bfloat16_operands_accumulate_in_float32_and_round_once(scaled):
+    """``scaled``: the float32 product times the float32 scale, the
+    result never rounded between them; the cotangent times the scale in
+    float32, rounded where it enters the backward's products."""
     tile_e, live = _tiles([600, 0, 500, 300], BOUND // TILE)
     lhs, w, seed = _operands(256, 384, jnp.bfloat16, key=1)
+    scale = jax.random.normal(jax.random.key(8), (BOUND,)) if scaled \
+        else jnp.ones((BOUND,))
+    by = (scale,) if scaled else ()
 
     def scalar(fn):
-        return lambda lhs, w: jnp.sum(fn(lhs, w, tile_e, live) * seed)
+        return lambda lhs, w: jnp.sum(fn(lhs, w, tile_e, live, *by) * seed)
     with dispatch.backend("pallas"):
-        got = _kernels(lhs, w, tile_e, live)
+        got = _kernels(lhs, w, tile_e, live, *by)
         g_lhs, g_w = jax.grad(scalar(_kernels), (0, 1))(lhs, w)
     assert (got.dtype, g_lhs.dtype, g_w.dtype) == (
         jnp.float32, jnp.bfloat16, jnp.bfloat16)
     f32 = lambda a: a.astype(jnp.float32)
-    np.testing.assert_allclose(got, _oracle(lhs, w, tile_e, live), atol=1e-4)
+    np.testing.assert_allclose(
+        got, _scaled_oracle(lhs, w, tile_e, live, scale), atol=1e-4)
     # the gradients against float32 arithmetic on the same rounded
     # operands and cotangent: one rounding of the result apart
     w_lhs, w_w = jax.grad(lambda lhs, w: jnp.sum(
-        _oracle(lhs, w, tile_e, live) * f32(seed.astype(jnp.bfloat16))),
+        _oracle(lhs, w, tile_e, live) * f32(
+            (seed * scale[:, None]).astype(jnp.bfloat16))),
         (0, 1))(f32(lhs), f32(w))
     for a, b in ((g_lhs, w_lhs), (g_w, w_w)):
         np.testing.assert_allclose(f32(a), b, rtol=2 ** -7,
                                    atol=2 ** -8 * float(jnp.abs(b).max()))
+
+
+@pytest.mark.parametrize("load", sorted(LOADS))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_rows_of_scale_zero_give_and_take_nothing(dtype, load):
+    """What ``ExpertLayer`` leans on for its dead rows: where the scale
+    is zero (a live tile's tail, the tiles past the last live one) the
+    rows may hold anything finite, here large values, and the result,
+    ``d lhs`` and ``d w`` are to the bit what zeros there leave."""
+    loads = LOADS[load][0]
+    tile_e, live = _tiles(loads, BOUND // TILE)
+    lhs, w, seed = _operands(384, 256, dtype, key=4)
+    holds = _live_rows(loads, BOUND // TILE)
+    assert holds.sum() == sum(loads) and not holds.all()
+    scale = jnp.where(holds, jax.random.normal(jax.random.key(9),
+                                               (BOUND,)), 0.0)
+
+    def run(lhs):
+        return jax.value_and_grad(lambda lhs, w: (lambda y: (
+            jnp.sum(y * seed), y))(_kernels(lhs, w, tile_e, live, scale)),
+            (0, 1), has_aux=True)(lhs, w)
+    with dispatch.backend("pallas"):
+        (_, y), (g_lhs, g_w) = run(jnp.where(holds[:, None], lhs,
+                                              3e4 * (1 + jnp.abs(lhs))))
+        (_, y0), (g_lhs0, g_w0) = run(jnp.where(holds[:, None], lhs, 0))
+    np.testing.assert_array_equal(y, y0)
+    np.testing.assert_array_equal(g_lhs, g_lhs0)
+    np.testing.assert_array_equal(g_w, g_w0)
+    assert not np.asarray(y)[~holds].any()
+    assert not np.asarray(g_lhs)[~holds].any()
+    assert np.asarray(y)[holds].any()
 
 
 def test_two_weights_share_a_call_and_their_row_gradients_one_rounding():
@@ -145,9 +213,9 @@ def test_two_weights_share_a_call_and_their_row_gradients_one_rounding():
                                    atol=2 ** -8 * float(jnp.abs(b).max()))
 
 
-def _layer(hidden, ffn, **kw):
+def _layer(hidden, ffn, experts_held=(0, HELD), **kw):
     return ExpertLayer(hidden=hidden, ffn=ffn, num_experts=E, top_k=K,
-                       experts_held=(0, HELD), dispatch_bound=BOUND, **kw)
+                       experts_held=experts_held, dispatch_bound=BOUND, **kw)
 
 
 def _routed_to(loads, hidden, key=2):
@@ -202,6 +270,26 @@ def test_the_layer_on_the_kernels_is_the_layer_on_the_einsum(hidden, ffn,
         assert bool(np.asarray(grads[0]["w_down"][e]).any()) == (n > 0)
 
 
+@pytest.mark.parametrize("hidden,ffn", WIDTHS)
+def test_a_layer_that_holds_every_expert_learns_its_router_through_the_scale(
+        hidden, ffn):
+    """Where every expert is held the tokens' weights are differentiated:
+    they reach the down projection's kernel as its scale, whose gradient
+    is the router's, the einsum side's and the kernels' alike."""
+    layer = _layer(hidden, ffn, experts_held=())
+    params = layer.init(jax.random.key(3), 0.1)
+    x = jax.random.normal(jax.random.key(5), (300, hidden))
+
+    def run(params, x):
+        return jax.value_and_grad(lambda p, x: jnp.sum(jnp.sin(
+            layer.routed(p, x)[0])), (0, 1))(params, x)
+    (loss, grads), (w_loss, w_grads) = _both_sides(run, params, x)
+    np.testing.assert_allclose(loss, w_loss, rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(w_grads)):
+        np.testing.assert_allclose(a, b, atol=1e-4)
+    assert float(jnp.abs(grads[0]["router"]).max()) > 1e-3
+
+
 def test_live_tiles_stops_at_the_buffers_end():
     """Pairs past the bound are counted; the live tiles are the
     buffer's."""
@@ -216,11 +304,13 @@ def test_live_tiles_stops_at_the_buffers_end():
 
 
 @pytest.mark.parametrize("hidden,ffn", WIDTHS)
-def test_under_checkpoint_and_a_scan_over_stacked_layers(hidden, ffn):
+@pytest.mark.parametrize("held", [(0, HELD), ()], ids=["a_share", "whole"])
+def test_under_checkpoint_and_a_scan_over_stacked_layers(held, hidden, ffn):
     """What ``HybridLM`` does with a run of expert layers: the blocks
     recomputed in the backward, one scanned body over two layers' stacked
-    leaves."""
-    layer = _layer(hidden, ffn, shared_ffn=128)
+    leaves. A share's weights are constants of its backward; the whole
+    layer's get their gradient through the down projection's scale."""
+    layer = _layer(hidden, ffn, experts_held=held, shared_ffn=128)
     stacked = jax.tree.map(lambda *a: jnp.stack(a), *(
         layer.init(jax.random.key(k), 0.1) for k in (4, 5)))
     x = jax.random.normal(jax.random.key(6), (2, 200, hidden))

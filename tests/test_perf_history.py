@@ -246,6 +246,11 @@ class TestCheck:
         alerts = H.verdict_alerts(check)
         assert len(alerts) == 1 and alerts[0]["source"] == "perf_history"
         side = str(tmp_path / "TELEM_hist.jsonl")
+        # the pending-note channel is the process's: an alert a logger-less
+        # SLOMonitor of an earlier test file on this worker left there would
+        # be drained into this sidecar too (which files share a worker goes
+        # by their durations)
+        M._PENDING_NOTES.clear()
         lg = M.MetricsLogger(side, run="perf_history")
         for a in alerts:
             lg.log_alert(**a)
