@@ -125,21 +125,36 @@ def exposed_seconds(events, pattern: str) -> float:
     return sum(e - s for s, e in mine) - hidden
 
 
+_PS = 1e12      # a trace's times are whole picoseconds; ``load`` hands seconds
+
+
 def self_times(events) -> dict:
     """``{name: seconds}`` of each operation's own time: its duration
-    less the time its nested operations cover."""
+    less the time its nested operations cover.
+
+    Starts and ends are compared in whole picoseconds, the trace's own
+    unit; the times themselves stay the seconds they came in. In float
+    seconds an end reads an ulp past the start it abuts (``s + d > s'``
+    where the trace has ``s + d == s'``): in a loop body whose operations
+    abut, the finished sibling stayed open and the next operation was
+    not taken off the ``while`` that holds both (5-17 ms a step in the
+    hybrid cells, PR 37). What holds now, and a test holds it: **the own
+    times of one device line sum to ``busy_seconds`` of that line**
+    wherever operations nest or abut and never partly overlap."""
     out = {}
-    stack = []          # [name, end, own seconds]
+    stack = []          # [name, end in picoseconds, own seconds]
 
     def close(upto):
         while stack and stack[-1][1] <= upto:
             name, _, own = stack.pop()
             out[name] = out.get(name, 0.0) + max(own, 0.0)
-    for name, s, d in sorted(events, key=lambda ev: (ev[1], -ev[2])):
-        close(s)
-        if stack and s + d <= stack[-1][1]:     # nested, not just overlapping
-            stack[-1][2] -= d
-        stack.append([name, s + d, d])
+    spans = sorted(((round(s * _PS), round(d * _PS), name, d)
+                    for name, s, d in events), key=lambda x: (x[0], -x[1]))
+    for start, length, name, d in spans:
+        close(start)
+        if stack and start + length <= stack[-1][1]:    # nested, not just
+            stack[-1][2] -= d                           # overlapping
+        stack.append([name, start + length, d])
     close(float("inf"))
     return out
 

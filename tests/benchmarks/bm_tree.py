@@ -135,6 +135,40 @@ def scopes_are_data(spec) -> None:
         assert all(s["pattern"] and s["opened"] for s in family["scopes"])
 
 
+def region_metrics(spec) -> dict:
+    """``{name: entry}`` of the ``per_layer`` entries that the reader
+    ``trace_region`` reads, in the list's order: found by the reader
+    their files name, wherever they stand and whatever a later PR
+    appended, and by no list of names or cells written in a test."""
+    out = {}
+    for m in spec.bm["per_layer"]:
+        with open(os.path.join(spec.root, "benchmarks", "layer_metrics",
+                               m["name"] + ".json")) as f:
+            if json.load(f)["reader"] == "trace_region":
+                out[m["name"]] = m
+    return out
+
+
+def region_metrics_name_their_cells(spec, cell_name: str) -> None:
+    """A region metric is read from the device's trace, is a time or a
+    share of one (lower is better), and **names its cells**: a program
+    opens ``prof.REGIONS`` or it does not, so without ``workloads`` the
+    metric would be owed by every cell that reports what it moves. Its
+    cells are its entry's own ``workloads`` and nothing else: a cell
+    listed there reports it through ``trace_region`` and one that is not
+    does not. A later configuration joins by appending its cell's name."""
+    listed = region_metrics(spec)
+    assert listed
+    reported = {m["name"]: m for m in spec.per_layer(spec.cell(cell_name))}
+    for name, entry in listed.items():
+        assert (entry["source"], entry["better"]) == (
+            "device_trace", "lower"), name
+        assert entry["workloads"], name
+        assert (name in reported) == (cell_name in entry["workloads"]), name
+        if name in reported:
+            assert reported[name]["reader"] == "trace_region"
+
+
 def everything_holds(spec) -> None:
     """Every invariant above, on every cell and configuration of ``spec``."""
     file_is_valid(spec)
@@ -142,5 +176,6 @@ def everything_holds(spec) -> None:
     for w in spec.bm["workloads"]:
         every_file_of_a_cell_is_found_by_name(spec, w["name"])
         limits_were_read_on_the_chip(spec, w["name"])
+        region_metrics_name_their_cells(spec, w["name"])
     for c in spec.bm["configs"]:
         widths_are_as_published(spec, c["name"])

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import bm_tree
 from benchmarks import spec as S, weights as W, weights_mellum2 as WM
 from benchmarks.drivers import train_mellum2
 from benchmarks.reference import mellum2 as R
@@ -236,33 +237,30 @@ def test_the_file_states_the_cut_and_the_programs_bounds():
     cell = spec.cell(CELL)
     assert cell["traffic"] == "train-fixed-16k-s8192" and cell["chips"] == 1
     assert spec.traffic(cell)["lr"] == 1e-4
-    reported = {m["name"] for m in spec.per_layer(cell)}
+    reported = {m["name"]: m for m in spec.per_layer(cell)}
     assert {"adam_kernel_roofline", "moe_route_ms_per_step",
             "moe_experts_ms_per_step", "moe_held_pairs_max",
             "moe_overflow_pairs", "head_loss_ms_per_step",
-            "device_idle_pct.lm"} <= reported
-    assert not reported & {"recompute_flash_ms_per_step",
-                           "router_bias_abs_max"}
-    # the cell's own four metrics are files and no entries: a
-    # configuration PR may only append to ``per_layer``, and
-    # ``test_bm_trace_region.py`` holds its last five names and their
-    # ``workloads`` as PR 37 left them; a ``benchmark`` PR lists them
-    listed = {m["name"] for m in spec.bm["per_layer"]}
+            "device_idle_pct.lm"} <= set(reported)
+    assert "router_bias_abs_max" not in reported        # no bias here
+    # the cell's own four metrics are entries (PR 41) that name this
+    # cell, each read by the reader its file names
+    listed = {m["name"]: m for m in spec.bm["per_layer"]}
     for name, reader in (("window_attention_ms_per_step", "trace_scope"),
                          ("backward_ms_per_step.mellum2", "trace_scope"),
                          ("flash_attn_roofline.mellum2",
                           "trace_kernel_roofline"),
                          ("window_flash_roofline", "trace_kernel_roofline")):
-        assert name not in listed
+        assert CELL in listed[name]["workloads"]
         with open(os.path.join(S.HERE, "layer_metrics", name + ".json")) as f:
             m = json.load(f)
-        assert m["reader"] == reader
+        assert reported[name]["reader"] == m["reader"] == reader
+        assert reported[name]["args"] == m["args"]
         assert callable(spec.plugin("readers", reader).read)
         if "work" in m["args"]:
             assert callable(spec.plugin("work", m["args"]["work"]).total)
-    assert not reported & {"layer_scan_ms_per_step", "unowned_pct",
-                           "layer_stack_ms_per_step",
-                           "recompute_ms_per_step"}
+    # its runs of layers are scanned under the regions, like its siblings'
+    assert set(bm_tree.region_metrics(spec)) <= set(reported)
 
 
 def _run(cfg, per_chip, steps):
@@ -295,15 +293,39 @@ def test_both_flash_work_functions_by_hand():
     assert flash_attn_window_train.step_flops(wide, 2) == 4 * full
 
 
-def test_the_driver_states_the_flash_grids_blocks_by_kind():
-    """A window layer at the cell's sizes: the blocks outside the band
-    are dead, in the forward's blocks and in the backward's."""
-    census = _driver(_cfg()).census()
-    assert census["window_forward"] == {
-        "blocks": [1024, 1024], "dead": 49, "interior": 0, "edge": 15}
-    assert census["window_backward"] == {
-        "blocks": [512, 512], "dead": 211, "interior": 15, "edge": 30}
-    assert census["full_forward"] == {
-        "blocks": [512, 512], "dead": 120, "interior": 120, "edge": 16}
-    assert census["full_backward"] == {
-        "blocks": [256, 512], "dead": 240, "interior": 240, "edge": 32}
+@pytest.mark.parametrize("kind", ["window_forward", "window_backward",
+                                  "full_forward", "full_backward"])
+def test_the_driver_states_the_flash_grids_blocks_by_kind(kind):
+    """A window layer and the full layer at the cell's sizes, forward and
+    backward: the blocks are what the program's ``block_sizes`` answers
+    for those shapes (no size is written here: a ``perf_opt`` PR may move
+    a default), and the census over them is the grid they give, counted
+    here from the band's own inequality ``0 <= row - column < window``:
+    dead outside it, interior where every pair of the block is visible,
+    edge between; the live blocks hold the visible pairs the work
+    function counts and the interior ones no more than those."""
+    import importlib
+    # the package exports a function under the module's name
+    fa = importlib.import_module(
+        "apex_tpu.contrib.multihead_attn.flash_attention")
+    cfg = _cfg()
+    s = cfg["input"]["seq"]
+    window = cfg["sliding_window"] if kind.startswith("window") else None
+    sizes = fa.block_sizes(s, s, window=window, d=cfg["head_dim"])
+    q, k = sizes[:2] if kind.endswith("forward") else sizes[2:]
+    got = _driver(cfg).census()[kind]
+    assert got["blocks"] == [q, k]
+    assert got["dead"] + got["interior"] + got["edge"] == (s // q) * (s // k)
+    i, j = np.arange(s // q)[:, None], np.arange(s // k)[None, :]
+    least = i * q - (j + 1) * k + 1             # row - column over a block
+    most = (i + 1) * q - 1 - j * k
+    live = (most >= 0) & (least < (window or s))
+    interior = (least >= 0) & (most < (window or s))
+    assert got == {"blocks": [q, k], "dead": int((~live).sum()),
+                   "interior": int(interior.sum()),
+                   "edge": int((live & ~interior).sum())}
+    assert got["dead"] > 0 and got["edge"] > 0
+    pairs = flash_attn_window_train.visible_pairs(
+        cfg, "sliding_attention" if window else "full_attention")
+    assert got["interior"] * q * k <= pairs \
+        <= (got["interior"] + got["edge"]) * q * k

@@ -2,7 +2,9 @@
 every file a cell names found by name, and a ``model_config`` PR acted
 out on a copy of the tree: a cell, a configuration, a traffic mix, limits,
 a driver, a scopes file and a per-layer metric are new files plus entries
-and one appended name, and every invariant holds on the copy."""
+(the metric's at the end of ``per_layer``) and the cell's name appended to
+the ``workloads`` of the metrics it joins, the region metrics among them,
+and every invariant holds on the copy."""
 
 import copy
 import json
@@ -67,9 +69,14 @@ class Driver(train_lm.Driver):
 def test_new_cell_config_traffic_and_metric_are_new_files_plus_entries(
         tmp_path, bm, capsys):
     """What a ``model_config`` PR does, on a copy of the tree: files
-    added, entries added, the cell's name appended to ``train_tok_s``'s
-    ``workloads``, no file that was there edited. Every invariant of
-    these tests then holds on the copy, and the new cell rehearses."""
+    added, entries added (the per-layer one **at the list's end**), the
+    cell's name appended to ``train_tok_s``'s ``workloads`` and to those
+    of the region metrics (found by their reader, as the tests find
+    them), no file that was there edited. Every invariant of these tests
+    then holds on the copy, the whole of ``test_bm_trace_region.py``'s
+    test of the file's shape among them: a test that pins the list's
+    tail, a metric's ``workloads`` or the cells that may report a metric
+    fails here first. The new cell then rehearses."""
     root = tmp_path / "copy"
     shutil.copytree(os.path.join(S.ROOT, "benchmarks"), root / "benchmarks",
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -113,6 +120,11 @@ def test_new_cell_config_traffic_and_metric_are_new_files_plus_entries(
         "name": "steps_in_window", "unit": "count", "better": "higher",
         "source": "program_counter", "layer": "models (models/transformer.py)",
         "moves": "train_tok_s", "workloads": ["cgpt111m_train"]})
+    regions = list(T.region_metrics(T.COMMITTED))
+    assert regions
+    for m in new["per_layer"]:
+        if m["name"] in regions:
+            m["workloads"] = m["workloads"] + ["cgpt111m_train"]
     (root / "BENCHMARK.json").write_text(json.dumps(new))
 
     spec = S.Spec(str(root))
@@ -123,7 +135,14 @@ def test_new_cell_config_traffic_and_metric_are_new_files_plus_entries(
     assert [m["name"] for m in spec.end_to_end(cell)] == [
         "train_tok_s", "setup_s"]
     assert [(m["name"], m["reader"]) for m in spec.per_layer(cell)] == [
+        *((name, "trace_region") for name in regions),
         ("steps_in_window", "steps_counted")]
+    # ``test_bm_trace_region.py``'s test of the committed file's shape,
+    # whole, on every cell of the copy: the cells that reported a region
+    # metric still do, the new one does, and no other cell does
+    assert list(T.region_metrics(spec)) == regions
+    for w in spec.bm["workloads"]:
+        T.region_metrics_name_their_cells(spec, w["name"])
     # the modules are the copy's own, the scopes too
     steps = spec.plugin("readers", "steps_counted")
     assert steps.read(type("Run", (), {"rec": {"steps": 7}})) == 7

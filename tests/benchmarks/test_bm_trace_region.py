@@ -28,9 +28,7 @@ from benchmarks.readers import trace_region as R, trace_scope as T
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 CELL = "kvl_train_s8192"
-HYBRID = ["qnext_train_s8192", "kvl_train_s8192", "lfm2_train_s8192"]
-NEW = ["layer_scan_ms_per_step", "layer_stack_ms_per_step", "unowned_pct",
-       "recompute_ms_per_step", "recompute_flash_ms_per_step"]
+REGION_METRICS = list(bm_tree.region_metrics(bm_tree.COMMITTED))
 
 
 def _metric(name: str) -> dict:
@@ -77,7 +75,8 @@ def run(make_run):
     (_metric("layer_stack_ms_per_step")["args"], 1.5),
     (_metric("unowned_pct")["args"], 100 * 2 / 36),
     (_metric("recompute_ms_per_step")["args"], 3.5),
-    (_metric("recompute_flash_ms_per_step")["args"], 1.0),
+    (dict(what="recompute_ms_per_step",
+          instruction=r"^%(\w+_)?apex_flash_fwd"), 1.0),
     (dict(what="region_ms_per_step", region="^layer_"), 6.5),
     (dict(what="region_ms_per_step", region="^layer_norm$"), None),
     (dict(what="recompute_ms_per_step", instruction="^%fusion"), 2.5),
@@ -89,10 +88,15 @@ def test_each_what_on_the_hand_written_trace(run, args, want):
     assert got == (want if want is None else pytest.approx(want))
 
 
-@pytest.mark.parametrize("name", NEW)
-def test_the_new_metrics_files_name_this_reader(name):
-    assert _metric(name)["reader"] == "trace_region"
-    assert callable(S.plugin("readers", "trace_region").read)
+@pytest.mark.parametrize("name", REGION_METRICS)
+def test_a_listed_region_metrics_file_asks_what_this_reader_knows(
+        name, run):
+    """Each ``per_layer`` entry whose file names this reader (found so,
+    not by a list here): the file's arguments are ones ``read`` takes,
+    a ``what`` it does not know is refused, and on the hand-written
+    trace it reads a time or share above 0 or nothing, never 0."""
+    got = R.read(run, **_metric(name)["args"])
+    assert got is None or got > 0
 
 
 def test_scan_plus_stack_plus_unowned_is_what_trace_scope_leaves_unscoped(
@@ -158,7 +162,7 @@ def test_a_trace_without_scopes_or_without_recomputation_gives_nothing(
         make_run):
     run = make_run(lambda text: text.replace("/attention/", "/mixer/")
                    .replace("/optimizer/", "/update/"))
-    for name in NEW:
+    for name in REGION_METRICS:
         assert R.read(run, **_metric(name)["args"]) is None
     run = make_run(lambda text: text.replace("rematted_computation/", ""))
     assert R.read(run, "recompute_ms_per_step") is None
@@ -171,7 +175,7 @@ def test_a_run_is_split_once_and_its_file_parsed_once(run, monkeypatch):
     real = xplane.self_times
     monkeypatch.setattr(xplane, "self_times",
                         lambda ev: calls.append(1) or real(ev))
-    for name in NEW:
+    for name in REGION_METRICS:
         R.read(run, **_metric(name)["args"])
     assert len(calls) == 1
     T.read(run, what="unscoped_pct")            # the same cached parse
@@ -249,19 +253,14 @@ def test_a_configuration_pr_adds_a_regions_file_beside_its_scopes_file(
 
 
 @pytest.mark.parametrize("cell", bm_tree.PROVED)
-def test_the_hybrid_cells_report_the_five_and_no_other_cell_does(cell):
-    spec = S.Spec()                     # validates BENCHMARK.json
-    metrics = {m["name"]: m for m in spec.per_layer(spec.cell(cell))}
-    if cell not in HYBRID:
-        assert not set(NEW) & set(metrics)
-        return
-    for name in NEW:
-        m = metrics[name]
-        assert (m["source"], m["moves"], m["better"], m["reader"]) == (
-            "device_trace", "train_tok_s", "lower", "trace_region")
-        assert m["workloads"] == HYBRID
-    # appended: what the benchmark had comes first, in its order
-    assert [m["name"] for m in spec.bm["per_layer"]][-5:] == NEW
+def test_a_region_metric_is_reported_by_the_cells_it_names_and_no_other(
+        cell):
+    """The whole of this test is one function of a ``Spec``, with no name
+    of a cell or a metric in it: ``test_bm_spec.py`` acts a configuration
+    PR out on a copy (an entry appended after these, its cell joined to
+    their ``workloads``) and runs the same function on every cell there,
+    so what would refuse that PR here refuses it there first."""
+    bm_tree.region_metrics_name_their_cells(bm_tree.COMMITTED, cell)
 
 
 # -- the names in the program ----------------------------------------------
