@@ -67,6 +67,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.ops.key_set import SELECT_SPAN, unpack_select
+
 LANES = 128
 MAX_BLOCK = 512  # upper bound for _pick_block's divisor-aware sizing
 # with a window the grid is the band, a few blocks a line, and a grid step
@@ -168,6 +170,18 @@ def _keep_mask(off_ref, bh, qb, kb, shape, rate):
         jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     bits = dropout_bits(off_ref[3], bh, q_pos, k_pos)
     return bits >= jnp.uint32(_drop_threshold(rate))
+
+
+def _selected(s, sel, kb):
+    """A [bq, bk] score block with the keys outside the packed set ``sel``
+    ([bq, 128] words of the block's span) masked."""
+    bk = s.shape[1]
+    first = (kb % (SELECT_SPAN // bk)) * (bk // LANES)
+    keep = [jax.lax.shift_right_logical(
+        sel, jnp.full(sel.shape, first + c, sel.dtype)) & 1
+        for c in range(bk // LANES)]
+    keep = keep[0] if len(keep) == 1 else jnp.concatenate(keep, axis=1)
+    return jnp.where(keep != 0, s, NEG_INF)
 
 
 def _masked_scores(s, off_ref, qb, kb, causal, window=None):
@@ -326,10 +340,13 @@ def _spec(shape, block_index, at=_step_block):
     return pl.BlockSpec(shape, lambda *g: block_index(*at(*g)))
 
 
-def _in_specs(block_q, block_k, d, bias, kvb, backward=False, **at):
+def _in_specs(block_q, block_k, d, bias, kvb, backward=False, heads=0,
+              **at):
     """BlockSpecs of q, k, v (``backward``: then dO, lse, delta), then
     the bias and the per-key bias [1|BH, 1, Sk] where present (either is
-    shared across batch-heads when its leading dim is 1)."""
+    shared across batch-heads when its leading dim is 1), then, with
+    ``heads`` (the batch-heads that share a row of a packed ``select``),
+    the [block_q, 128] words of the key block's span."""
     rows = _spec((1, block_q, d), lambda b, i, j: (b, i, 0), **at)
     cols = _spec((1, block_k, d), lambda b, i, j: (b, j, 0), **at)
     specs = [rows, cols, cols]
@@ -346,16 +363,22 @@ def _in_specs(block_q, block_k, d, bias, kvb, backward=False, **at):
             (1, 1, block_k),
             (lambda b, i, j: (0, 0, j)) if kvb.shape[0] == 1 else
             (lambda b, i, j: (b, 0, j)), **at))
+    if heads:
+        specs.append(_spec(
+            (1, block_q, LANES),
+            lambda b, i, j: (b // heads, i, j * block_k // SELECT_SPAN),
+            **at))
     return specs
 
 
 def _fwd_kernel(causal: bool, window, has_bias: bool, has_kvb: bool,
-                scale: float, dropout: float, *refs):
+                scale: float, dropout: float, has_sel: bool, *refs):
     refs = list(refs)
     steps_ref, off_ref, q_ref, k_ref, v_ref = refs[:5]
     del refs[:5]
     bias_ref = refs.pop(0) if has_bias else None
     kvb_ref = refs.pop(0) if has_kvb else None
+    sel_ref = refs.pop(0) if has_sel else None
     o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
 
     # program_id must be read OUTSIDE pl.when bodies: interpret mode only
@@ -381,6 +404,8 @@ def _fwd_kernel(causal: bool, window, has_bias: bool, has_kvb: bool,
         if has_kvb:
             s = s + kvb_ref[0].astype(jnp.float32)  # (1, bk) row-broadcast
         s = _masked_scores(s, off_ref, qb, kb, causal, window)
+        if has_sel:
+            s = _selected(s, sel_ref[0], kb)
 
         m_prev = m_ref[:, :1]                      # [bq, 1]
         row_max = jnp.max(s, axis=1, keepdims=True)
@@ -415,14 +440,31 @@ def _fwd_kernel(causal: bool, window, has_bias: bool, has_kvb: bool,
     _run_step(code, _init, _body, _finalize)
 
 
+def _heads(sel, bh: int) -> int:
+    """The batch-heads that share a row of the packed set ``sel`` [B, Sq,
+    W]; 0 without a set."""
+    return 0 if sel is None else bh // sel.shape[0]
+
+
+def _family(window, sel) -> str:
+    """What ``flash_`` reads in a call's name: a windowed call and one over
+    a selected key set have names of their own (``apex_flash_win_fwd``,
+    ``apex_flash_sel_fwd``), so a trace tells a layer kind's kernels
+    apart."""
+    return "flash_sel_" if sel is not None else \
+        "flash_" if window is None else "flash_win_"
+
+
 def _flash_fwd(q, k, v, bias, kvb, offs, *, causal, scale, block_q, block_k,
-               dropout=0.0, known=None, window=None):
+               dropout=0.0, known=None, window=None, sel=None):
     """q,k,v: [BH, S, D], pre-padded so block sizes divide S and D == lane
     multiple. offs: int32[4] = (q_start, k_start, k_len, seed) — k_len is
     the UNPADDED key length, masked in-kernel (no O(S^2) pad-bias tensor);
     seed drives the in-kernel dropout mask when ``dropout`` > 0.
     kvb: optional per-KEY additive bias [1|BH, 1, Sk] (key-padding masks)
     — O(S) instead of the O(S^2) bias tensor.
+    sel: optional packed key set [B, Sq, W] (``pack_select``), shared by a
+    row's BH / B heads.
     Returns (o, lse[BH,S])."""
     bh, sq, d = q.shape
     sk = k.shape[1]
@@ -431,19 +473,20 @@ def _flash_fwd(q, k, v, bias, kvb, offs, *, causal, scale, block_q, block_k,
 
     has_bias = bias is not None
     has_kvb = kvb is not None
-    args = [q, k, v] + [a for a in (bias, kvb) if a is not None]
+    args = [q, k, v] + [a for a in (bias, kvb, sel) if a is not None]
 
     steps = _steps(known, offs, nq, nk, block_q, block_k, causal,
                    window=window)
     at = _at(steps, known, nq, nk)
     kernel = functools.partial(_fwd_kernel, causal, window, has_bias, has_kvb,
-                               float(scale), float(dropout))
+                               float(scale), float(dropout), sel is not None)
     o, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,                          # steps, offs
             grid=(bh, len(steps)),
-            in_specs=_in_specs(block_q, block_k, d, bias, kvb, at=at),
+            in_specs=_in_specs(block_q, block_k, d, bias, kvb, at=at,
+                               heads=_heads(sel, bh)),
             out_specs=[
                 _spec((1, block_q, d), lambda b, i, j: (b, i, 0), at),
                 _spec((1, block_q, LANES), lambda b, i, j: (b, i, 0), at),
@@ -458,9 +501,7 @@ def _flash_fwd(q, k, v, bias, kvb, offs, *, causal, scale, block_q, block_k,
             _sds((bh, sq, LANES), jnp.float32, vma=_vma(q, k, v)),
         ],
         interpret=_interpret(),
-        # a windowed call has a name of its own: a trace tells a window
-        # layer's kernels from a full layer's
-        name="apex_flash_fwd" if window is None else "apex_flash_win_fwd",
+        name="apex_flash_fwd".replace("flash_", _family(window, sel)),
     )(steps, offs, *args)
     return o, lse[:, :, 0]
 
@@ -471,7 +512,7 @@ def _flash_fwd(q, k, v, bias, kvb, offs, *, causal, scale, block_q, block_k,
 
 def _recompute_p_ds(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
                     bias_ref, kvb_ref, bh_i, qb, kb, causal, scale, dropout,
-                    window=None):
+                    window=None, sel_ref=None):
     """Shared bwd block math: recompute p from saved lse, return (pd, ds, q,
     k, do) as fp32 — ``pd`` is the (dropout-masked, rescaled) probability
     used for dv. ds = p * (mask*dp/keep - delta); delta = rowsum(dO·O)
@@ -493,6 +534,8 @@ def _recompute_p_ds(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
     if kvb_ref is not None:
         s = s + kvb_ref[0].astype(jnp.float32)
     s = _masked_scores(s, off_ref, qb, kb, causal, window)
+    if sel_ref is not None:
+        s = _selected(s, sel_ref[0], kb)
 
     # exp(NEG - NEG) guard: fully-masked rows have lse == NEG_INF
     p = jnp.where(s > NEG_INF * 0.5, jnp.exp(s - lse), 0.0)   # [bq, bk]
@@ -510,14 +553,24 @@ def _recompute_p_ds(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
     return pd, ds, q, k, do
 
 
-def _bwd_dq_kernel(causal: bool, window, has_bias: bool, has_kvb: bool,
-                   emit_dbias: bool, scale: float, dropout: float, *refs):
-    refs = list(refs)
-    (steps_ref, off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-     dlt_ref) = refs[:8]
+def _bwd_refs(refs, has_bias, has_kvb, has_sel):
+    """A backward kernel's leading refs taken off ``refs``: (steps, offs,
+    the six of ``_recompute_p_ds``, bias, kvb, sel | None)."""
+    steps_ref, off_ref = refs[:2]
+    six = refs[2:8]
     del refs[:8]
     bias_ref = refs.pop(0) if has_bias else None
     kvb_ref = refs.pop(0) if has_kvb else None
+    sel_ref = refs.pop(0) if has_sel else None
+    return steps_ref, off_ref, six, bias_ref, kvb_ref, sel_ref
+
+
+def _bwd_dq_kernel(causal: bool, window, has_bias: bool, has_kvb: bool,
+                   emit_dbias: bool, scale: float, dropout: float,
+                   has_sel: bool, *refs):
+    refs = list(refs)
+    steps_ref, off_ref, six, bias_ref, kvb_ref, sel_ref = _bwd_refs(
+        refs, has_bias, has_kvb, has_sel)
     dq_ref = refs.pop(0)
     dbias_ref = refs.pop(0) if emit_dbias else None
     dq_acc = refs.pop(0)
@@ -530,8 +583,8 @@ def _bwd_dq_kernel(causal: bool, window, has_bias: bool, has_kvb: bool,
 
     def _body():
         _, ds, _, k, _ = _recompute_p_ds(
-            off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
-            bias_ref, kvb_ref, bh_i, qb, kb, causal, scale, dropout, window)
+            off_ref, *six, bias_ref, kvb_ref, bh_i, qb, kb, causal, scale,
+            dropout, window, sel_ref)
         if dbias_ref is not None:
             dbias_ref[0] = ds
         dq_acc[...] += jax.lax.dot_general(
@@ -550,13 +603,10 @@ def _bwd_dq_kernel(causal: bool, window, has_bias: bool, has_kvb: bool,
 
 
 def _bwd_dkv_kernel(causal: bool, window, has_bias: bool, has_kvb: bool,
-                    scale: float, dropout: float, *refs):
+                    scale: float, dropout: float, has_sel: bool, *refs):
     refs = list(refs)
-    (steps_ref, off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-     dlt_ref) = refs[:8]
-    del refs[:8]
-    bias_ref = refs.pop(0) if has_bias else None
-    kvb_ref = refs.pop(0) if has_kvb else None
+    steps_ref, off_ref, six, bias_ref, kvb_ref, sel_ref = _bwd_refs(
+        refs, has_bias, has_kvb, has_sel)
     dk_ref, dv_ref, dk_acc, dv_acc = refs
 
     code = steps_ref[pl.program_id(1)]
@@ -568,8 +618,8 @@ def _bwd_dkv_kernel(causal: bool, window, has_bias: bool, has_kvb: bool,
 
     def _body():
         pd, ds, q, _, do = _recompute_p_ds(
-            off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
-            bias_ref, kvb_ref, bh_i, qb, kb, causal, scale, dropout, window)
+            off_ref, *six, bias_ref, kvb_ref, bh_i, qb, kb, causal, scale,
+            dropout, window, sel_ref)
         dv_acc[...] += jax.lax.dot_general(
             pd, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # [bk, d]
@@ -616,7 +666,7 @@ def _bwd_dbias_kernel(nbh: int, causal: bool, window, has_kvb: bool,
 
 
 def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
-                bias_grad, dropout=0.0, known=None, window=None):
+                bias_grad, dropout=0.0, known=None, window=None, sel=None):
     """Pallas flash backward over the padded residuals. Returns
     (dq, dk, dv, dbias) with dbias None when no bias was supplied and
     zeros when ``bias_grad`` is False (mask-only biases)."""
@@ -642,11 +692,12 @@ def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
     dlt_r = jnp.broadcast_to(delta[..., None], (*delta.shape, LANES))
 
     args = [q, k, v, do, lse_r, dlt_r] + [
-        a for a in (bias, kvb) if a is not None]
+        a for a in (bias, kvb, sel) if a is not None]
     vma = _vma(q, k, v, do)
 
     in_specs = functools.partial(_in_specs, block_q, block_k, d, bias, kvb,
-                                 backward=True)
+                                 backward=True,
+                                 heads=_heads(sel, bh))
 
     # --- dq (+ per-bh dbias): each q row's sweep over its k blocks ---------
     steps = _steps(known, offs, nq, nk, block_q, block_k, causal,
@@ -661,7 +712,8 @@ def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
             _sds((bh, sq, sk), jnp.float32, vma=vma))
     dq_res = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal, window, has_bias, has_kvb,
-                          dbias_in_dq, float(scale), float(dropout)),
+                          dbias_in_dq, float(scale), float(dropout),
+                          sel is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,                          # steps, offs
             grid=(bh, len(steps)),
@@ -670,8 +722,7 @@ def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
         out_shape=dq_out_shape,
         interpret=_interpret(),
-        name="apex_flash_bwd_dq" if window is None
-        else "apex_flash_win_bwd_dq",
+        name="apex_flash_bwd_dq".replace("flash_", _family(window, sel)),
     )(steps, offs, *args)
     if dbias_in_dq:
         dq, dbias = dq_res
@@ -703,7 +754,7 @@ def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
     col = _spec((1, block_k, d), lambda b, i, j: (b, j, 0), at)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal, window, has_bias, has_kvb,
-                          float(scale), float(dropout)),
+                          float(scale), float(dropout), sel is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,                          # steps, offs
             grid=(bh, len(steps)),
@@ -716,8 +767,7 @@ def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
             _sds((bh, sk, d), v.dtype, vma=vma),
         ],
         interpret=_interpret(),
-        name="apex_flash_bwd_dkv" if window is None
-        else "apex_flash_win_bwd_dkv",
+        name="apex_flash_bwd_dkv".replace("flash_", _family(window, sel)),
     )(steps, offs, *args)
     return dq, dk, dv, dbias
 
@@ -729,14 +779,15 @@ def _bwd_pallas(res, do, dlse, *, causal, scale, block_q, block_k,
 def reference_attention(q, k, v, bias=None, *, kv_bias=None,
                         causal=False, scale=None,
                         q_start=0, k_start=0, return_lse=False,
-                        dropout_rate=0.0, dropout_seed=0, window=None):
+                        dropout_rate=0.0, dropout_seed=0, window=None,
+                        select=None):
     """Unfused jnp attention with the same (out, lse) contract — the
     impl='default' path (reference: the torch-composed SelfAttnFunc,
     apex/contrib/multihead_attn/self_multihead_attn_func.py:4) and the
     numerics oracle for the kernel tests. ``dropout_rate`` applies
     dropout to the softmax probabilities with the SAME coordinate-hash
     mask as the flash kernel, so the two impls agree bit-for-bit on which
-    weights are dropped. ``window``: as ``flash_attention``'s."""
+    weights are dropped. ``window``, ``select``: as ``flash_attention``'s."""
     import math
     _check_window(window, causal)
     sq, d = q.shape[-2], q.shape[-1]
@@ -755,6 +806,11 @@ def reference_attention(q, k, v, bias=None, *, kv_bias=None,
         s = jnp.where(q_pos >= k_pos, s, NEG_INF)
         if window is not None:
             s = jnp.where(q_pos - k_pos < window, s, NEG_INF)
+    if select is not None:
+        keep = unpack_select(select, sk)            # [B, Sq, Sk]
+        keep = keep[:, None] if q.ndim == 4 else jnp.repeat(
+            keep, q.shape[0] // keep.shape[0], axis=0)
+        s = jnp.where(keep, s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     m = jnp.maximum(m, NEG_INF)
     p = jnp.where(s > NEG_INF * 0.5, jnp.exp(s - m), 0.0)
@@ -880,10 +936,16 @@ def _bwd_chunked(res, do, dlse, *, causal, scale, block_k, bias_grad=True,
 # custom_vjp wiring
 # ---------------------------------------------------------------------------
 
+def _with(sel) -> dict:
+    """The kernels' ``sel`` keyword, given only where there is a set."""
+    return {} if sel is None else {"sel": sel}
+
+
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12, 13, 14))
-def _flash_core(q, k, v, bias, kvb, causal, window, scale, block_q, block_k,
-                bwd_block_q, bwd_block_k, bias_grad, dropout, known, offs):
+                   nondiff_argnums=(6, 7, 8, 9, 10, 11, 12, 13, 14, 15))
+def _flash_core(q, k, v, bias, kvb, sel, causal, window, scale, block_q,
+                block_k, bwd_block_q, bwd_block_k, bias_grad, dropout, known,
+                offs):
     """Returns (o, lse). lse is a true primal output with a correct
     cotangent path (its gradient folds into ds — needed by ring attention,
     which differentiates through the (o, lse) shard merge).
@@ -891,23 +953,24 @@ def _flash_core(q, k, v, bias, kvb, causal, window, scale, block_q, block_k,
     mask) and returns a zero cotangent without computing/materializing the
     O(S^2) dbias. ``kvb`` (per-key additive bias, always mask-semantics)
     likewise gets a zero cotangent. ``dropout`` is the static rate; the
-    mask is recomputed from offs[3] (seed) in fwd and bwd.
+    mask is recomputed from offs[3] (seed) in fwd and bwd. ``sel`` (a
+    packed key set, ``pack_select``) is data, not a bias: zero cotangent.
     ``bwd_block_q``/``bwd_block_k`` size the backward kernels
     independently (their VMEM working set is ~3x the forward's); must
     divide the padded sequence lengths."""
     return _flash_fwd(q, k, v, bias, kvb, offs, causal=causal, scale=scale,
                       block_q=block_q, block_k=block_k, dropout=dropout,
-                      known=known, window=window)
+                      known=known, window=window, **_with(sel))
 
 
-def _flash_core_fwd(q, k, v, bias, kvb, causal, window, scale, block_q,
+def _flash_core_fwd(q, k, v, bias, kvb, sel, causal, window, scale, block_q,
                     block_k, bwd_block_q, bwd_block_k, bias_grad, dropout,
                     known, offs):
     o, lse = _flash_fwd(q, k, v, bias, kvb, offs, causal=causal, scale=scale,
                         block_q=block_q, block_k=block_k, dropout=dropout,
-                        known=known, window=window)
+                        known=known, window=window, **_with(sel))
     o, lse = map(checkpoint_name, (o, lse), SAVED_NAMES)
-    return (o, lse), (q, k, v, bias, kvb, offs, lse, o)
+    return (o, lse), ((q, k, v, bias, kvb, offs, lse, o), sel)
 
 
 def _bwd_impl() -> str:
@@ -944,6 +1007,10 @@ def flash_min_s() -> int:
 def _flash_core_bwd(causal, window, scale, block_q, block_k, bwd_block_q,
                     bwd_block_k, bias_grad, dropout, known, res, cts):
     do, dlse = cts
+    res, sel = res
+    if sel is not None and _bwd_impl() == "chunked":
+        raise NotImplementedError("select= has the Pallas backward alone "
+                                  "(APEX_TPU_FLASH_BWD=chunked has none)")
     if _bwd_impl() == "chunked":
         # the chunked path exists for O(S*block) MEMORY: keep its k-chunk
         # at 128 regardless of the kernel block size (a 512 chunk would
@@ -959,11 +1026,12 @@ def _flash_core_bwd(causal, window, scale, block_q, block_k, bwd_block_q,
                                         block_k=bwd_block_k,
                                         bias_grad=bias_grad,
                                         dropout=dropout, known=known,
-                                        window=window)
+                                        window=window, **_with(sel))
     kvb, offs = res[4], res[5]
     d_kvb = None if kvb is None else jnp.zeros_like(kvb)
     d_offs = jnp.zeros_like(offs)  # int32 cotangent placeholder
-    return dq, dk, dv, dbias, d_kvb, d_offs
+    d_sel = None if sel is None else jnp.zeros_like(sel)
+    return dq, dk, dv, dbias, d_kvb, d_sel, d_offs
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -1064,7 +1132,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     bias_grad: bool = True,
                     dropout_rate: float = 0.0,
                     dropout_seed=0,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None,
+                    select: Optional[jax.Array] = None):
     """Fused attention over [B, H, S, D] (or [BH, S, D]) inputs.
 
     bias: optional additive [1|BH, Sq, Sk] (or [B, H, Sq, Sk]) score bias —
@@ -1101,6 +1170,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     128, up to 1024 x 1024 forward and 512 x 512 backward), and the three
     calls are named ``apex_flash_win_fwd`` / ``_win_bwd_dq``
     / ``_win_bwd_dkv``. ``None``: no window, the causal call as it was.
+    ``select``: a per-query set of keys, data and not structure: int32
+    [B, Sq, 128 * ceil(Sk / 4096)] as ``pack_select`` packs a bool [B, Sq,
+    Sk] (a bit a key), shared by a row's BH / B heads; a key outside the
+    set is masked like one above the diagonal (``causal`` and the key
+    length still apply). It has a zero cotangent. The grids stay the
+    causal ones, since which tiles hold a selected key is known only on
+    the device, and a tile that holds none is masked like any other and
+    not skipped. Key blocks are 512, 256 or 128
+    wide (whole bits of a packed word), and the three calls are named
+    ``apex_flash_sel_fwd`` / ``_sel_bwd_dq`` / ``_sel_bwd_dkv``. Neither
+    a ``bias`` nor a ``window`` goes with it. ``None``: today's call.
     """
     _check_window(window, causal)
     squeeze = q.ndim == 4
@@ -1117,8 +1197,25 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         scale = 1.0 / float(d) ** 0.5
 
     dpad = (-d) % LANES
+    if select is not None:
+        if bias is not None or window is not None:
+            raise ValueError("select= goes with neither a bias nor a window")
+        if select.ndim != 3 or bh % select.shape[0] or select.shape[1:] != (
+                sq, LANES * -(-sk // SELECT_SPAN)):
+            raise ValueError(
+                f"select must be [B, {sq}, {LANES * -(-sk // SELECT_SPAN)}] "
+                f"with B dividing {bh} (pack_select), got {select.shape}")
+        # key blocks are whole bits of a packed word: 128 keys at the least
+        if block_k is None:
+            block_k = next(b for b in (512, 256, 128)
+                           if _round_up(sk, LANES) % b == 0)
+        for blk in (block_k, bwd_block_k or block_k):
+            if blk % LANES or SELECT_SPAN % blk:
+                raise ValueError(f"with select= a key block is 128 to 4096 "
+                                 f"keys and divides 4096, got {blk}")
     block_q, block_k, bwd_block_q, bwd_block_k = block_sizes(
-        sq, sk, block_q, block_k, bwd_block_q, bwd_block_k, window, d + dpad)
+        sq, sk if select is None else _round_up(sk, LANES), block_q, block_k,
+        bwd_block_q, bwd_block_k, window, d + dpad)
     qpad = (-sq) % block_q
     kpad = (-sk) % block_k
 
@@ -1153,9 +1250,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # offsets that are plain integers are known when the grids are made
     known = (int(q_start), int(k_start), sk) if all(
         isinstance(x, (int, np.integer)) for x in (q_start, k_start)) else None
-    out, lse = _flash_core(qq, kk, vv, bb, kvb, causal, window, float(scale),
-                           block_q, block_k, bwd_block_q, bwd_block_k,
-                           bool(bias_grad), float(dropout_rate), known, offs)
+    if select is not None and qpad:
+        select = jnp.pad(select, ((0, 0), (0, qpad), (0, 0)))
+    out, lse = _flash_core(qq, kk, vv, bb, kvb, select, causal, window,
+                           float(scale), block_q, block_k, bwd_block_q,
+                           bwd_block_k, bool(bias_grad), float(dropout_rate),
+                           known, offs)
     lse = lse[:, :sq]
     out = out[:, :sq, :d]
 

@@ -7,14 +7,17 @@ short-convolution hybrids, with the layer pattern as data.
                                   "linear" (Gated DeltaNet), "full"
                                   (grouped-query softmax attention, gated
                                   or not), "window" (the same over a
-                                  sliding window), "latent" (latent
-                                  attention, MLA) or "conv" (the
-                                  double-gated short convolution)
+                                  sliding window), "sparse" (the same
+                                  over a learned per-query key set),
+                                  "latent" (latent attention, MLA) or
+                                  "conv" (the double-gated short
+                                  convolution)
     x += ffn_i(norm(x))           ffn_i by ``ffn_types[i]``: "experts" (a
                                   chip's share of a many-expert layer) or
                                   "dense" (one SwiGLU of ``dense_ffn``)
     loss = xent(norm(x) @ head^T) + aux_coef * sum of the routers'
-           load-balancing terms
+           load-balancing terms + index_coef * sum of the "sparse"
+           layers' indexer losses
 
 ``norm(x; w) = x * rsqrt(mean(x^2) + eps) * (1 + w)`` is the zero-centred
 RMSNorm (weight zero at init) or, with ``zero_centred_norm=False``, the
@@ -57,6 +60,26 @@ its heads with the plain table at ``rope_theta``, a full layer with
 it, blended between ``beta_fast`` and ``beta_slow`` rotations) and its
 ``attention_factor`` on ``cos`` and ``sin``.
 
+The **sparse mixer** (``"sparse"``: DeepSeek-V3.2's lightning indexer
+over the ungated attention mixer, leaf for leaf, as Keye-VL-2.0 has it):
+beside ``attn`` a layer holds ``index = {w_q [d, index_heads x index_dim],
+w_k [index_dim, d], w_w [index_heads, d], k_norm {w, b}}`` (the two narrow
+projections lie ``[out, in]``: under XLA ``ops.flat.unflatten``'s slice
+and reshape of a ``[d, 16]`` leaf becomes a view of the whole flat master
+in rows of 16, which the TPU's tiling pads eightfold in HBM). On ``hbar = stop_gradient(norm(x))``: ``qI = rot(hbar w_q)`` a head,
+``kI = rot(LayerNorm(hbar w_k^T))`` (one head), ``w = hbar w_w^T *
+(index_heads
+index_dim)^-1/2`` in float32, ``I[t, s] = sum_j w[t, j] relu(qI[t, j] .
+kI[s])``; query ``t`` attends to the ``min(t + 1, index_topk)`` keys ``s <=
+t`` of largest ``I[t, s]``, exactly that many, one set for all heads
+(``ops.sparse_index.select_keys`` into ``flash_attention(select=)``), and
+the indexer learns from ``L_I = mean_t KL(p[t] || softmax_{S_t} I[t])``
+with ``p`` the detached head-mean of the attention's probabilities
+(``ops.sparse_index.index_loss``). The two ``stop_gradient``s part the
+gradients: ``index`` learns from ``L_I`` alone, every other leaf from the
+rest of the loss alone. It runs under two sibling scopes,
+``sparse_attention`` (the mixer less its indexer) and ``sparse_index``.
+
 The **short-convolution mixer**: ``[B | C | u] = h W_in``, three streams
 ``hidden`` wide; ``z = conv(B * u)``, a causal depthwise convolution of
 ``conv_kernel`` taps a channel (no bias, zeros before the first token);
@@ -85,8 +108,8 @@ step's own pairs an expert (``ExpertLayer.moved_bias``), as a ResNet's
 batch statistics travel through ``train_step.build_step``.
 
 Scopes (``prof.SCOPES``): ``embed``, ``linear_attention``,
-``delta_rule``, ``attention``, ``window_attention``,
-``latent_attention``, ``short_conv``, ``mlp``, ``moe_route``,
+``delta_rule``, ``attention``, ``window_attention``, ``sparse_attention``,
+``sparse_index``, ``latent_attention``, ``short_conv``, ``mlp``, ``moe_route``,
 ``moe_experts``, ``head_loss``; siblings, never
 nested. Regions (``prof.REGIONS``), around the scopes: ``layer_stack``
 (a run's stacked leaves, the counters' ``concatenate``) and ``layer_scan``
@@ -100,7 +123,10 @@ attention kernel made: the block's ``jax.checkpoint`` saves the names
 log-sum-exp, ``bf16[B * heads, T, head width padded to 128 lanes]`` and
 ``f32[B * heads, T]`` a flash layer), so ``apex_flash_fwd`` runs once a
 step, and nothing else. A block with no flash kernel (a Gated DeltaNet
-or conv layer, ``attn_impl="default"``) saves its input alone.
+or conv layer, ``attn_impl="default"``) saves its input alone. A
+``"sparse"`` block also keeps ``ops.sparse_index.SAVED_NAMES``: its packed
+key sets (``int32[B, T, T / 32]``) and the indexer's gradient, so neither
+the search nor the indexer's loss runs again.
 """
 
 from __future__ import annotations
@@ -121,7 +147,7 @@ from apex_tpu.ops.gated_delta_rule import gated_delta_rule
 __all__ = ["HybridLM"]
 
 _F32 = jnp.float32
-MIXERS = ("linear", "full", "latent", "conv", "window")
+MIXERS = ("linear", "full", "latent", "conv", "window", "sparse")
 FFNS = ("experts", "dense")
 
 
@@ -202,6 +228,11 @@ class HybridLM:
     window: int = 0             # keys a "window" layer's query sees
     rope_yarn: Optional[Yarn] = None    # the "full" layers' rotary
     #                             scaling; None = the plain table
+    # the "sparse" layers' indexer (ops.sparse_index)
+    index_heads: int = 16
+    index_dim: int = 64
+    index_topk: int = 2048      # keys a query attends to
+    index_coef: float = 1.0     # on the indexer's loss
     # latent attention (num_heads heads, rope_theta)
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
@@ -247,6 +278,9 @@ class HybridLM:
                 or self.linear_v_heads % self.linear_k_heads:
             raise ValueError("query heads must be a multiple of key/value "
                              "heads, value heads of key heads")
+        if "sparse" in self.layer_types and self.attn_gate:
+            raise ValueError("a \"sparse\" layer has no output gate "
+                             "(attn_gate=False)")
         if "window" in self.layer_types and self.window < 1:
             raise ValueError("a \"window\" layer needs window >= 1")
         if self.rope_yarn is not None \
@@ -333,6 +367,13 @@ class HybridLM:
                     "w_k": w(d, kv * hd),
                     "w_v": w(d, kv * hd), "q_norm": gain(hd),
                     "k_norm": gain(hd), "w_o": w(h * hd, d)}
+                if kind == "sparse":
+                    hi, di = self.index_heads, self.index_dim
+                    lp["index"] = {
+                        "w_q": w(d, hi * di), "w_k": w(di, d),
+                        "w_w": w(hi, d),
+                        "k_norm": {"w": jnp.ones((di,)),
+                                   "b": jnp.zeros((di,))}}
             p[f"layer_{i}"] = lp
         return p
 
@@ -374,6 +415,22 @@ class HybridLM:
             of = of * jax.nn.silu(z.reshape(b, t, hv, dv).astype(_F32))
             return x + of.reshape(b, t, vd).astype(x.dtype) @ p["w_out"]
 
+    def _qkv(self, p, hid, yarn=None):
+        """The attention mixers' heads from the layer's normed input:
+        ``(q [B, T, heads, hd], k, v [B, T, kv heads, hd], the output
+        gate)``, ``q`` and ``k`` normed a head and rotated."""
+        b, t, _ = hid.shape
+        h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        qg = (hid @ p["w_q"]).reshape(b, t, h, hd * (1 + self.attn_gate))
+        q, gate = qg[..., :hd], qg[..., hd:]    # no attn_gate: empty
+        k = (hid @ p["w_k"]).reshape(b, t, kv, hd)
+        v = (hid @ p["w_v"]).reshape(b, t, kv, hd)
+        q = _rotary(self._norm(q, p["q_norm"]),
+                    self.rope_theta, self.rotary_dim, yarn)
+        k = _rotary(self._norm(k, p["k_norm"]),
+                    self.rope_theta, self.rotary_dim, yarn)
+        return q, k, v, gate
+
     def _full_mixer(self, lp, x, window=None):
         """The attention mixer; ``window``: the window mixer, which sees
         that many keys and turns its heads with the plain table."""
@@ -384,15 +441,7 @@ class HybridLM:
         yarn = None if window else self.rope_yarn
         with jax.named_scope("window_attention" if window else "attention"):
             p = lp["attn"]
-            hid = self._norm(x, lp["norm1"])
-            qg = (hid @ p["w_q"]).reshape(b, t, h, hd * (1 + self.attn_gate))
-            q, gate = qg[..., :hd], qg[..., hd:]    # no attn_gate: empty
-            k = (hid @ p["w_k"]).reshape(b, t, kv, hd)
-            v = (hid @ p["w_v"]).reshape(b, t, kv, hd)
-            q = _rotary(self._norm(q, p["q_norm"]),
-                        self.rope_theta, self.rotary_dim, yarn)
-            k = _rotary(self._norm(k, p["k_norm"]),
-                        self.rope_theta, self.rotary_dim, yarn)
+            q, k, v, gate = self._qkv(p, self._norm(x, lp["norm1"]), yarn)
             # each key/value head serves h // kv query heads: broadcast in
             # front of the kernel (its transpose sums the group's dK, dV)
             q = q.transpose(0, 2, 1, 3)
@@ -405,6 +454,72 @@ class HybridLM:
             if self.attn_gate:
                 a = a * jax.nn.sigmoid(gate.astype(_F32)).astype(x.dtype)
             return x + a.reshape(b, t, h * hd) @ p["w_o"]
+
+    def _index(self, p, hid):
+        """The indexer of a "sparse" layer on the layer's normed input:
+        ``(qI [B, T, heads, dim], kI [B, T, dim], w [B, T, heads] float32,
+        the packed key sets)``. It reads the input and hands nothing back
+        through it: it learns from its own loss alone."""
+        from apex_tpu.ops import sparse_index
+        b, t, _ = hid.shape
+        hi, di = self.index_heads, self.index_dim
+        with jax.named_scope("sparse_index"):
+            hbar = jax.lax.stop_gradient(hid)
+            qi = _rotary((hbar @ p["w_q"]).reshape(b, t, hi, di),
+                         self.rope_theta, di)
+            ki = (hbar @ p["w_k"].T).astype(_F32)
+            ki = ki - jnp.mean(ki, -1, keepdims=True)
+            ki = ki * jax.lax.rsqrt(jnp.mean(ki * ki, -1, keepdims=True)
+                                    + self.rms_eps) \
+                * p["k_norm"]["w"].astype(_F32) \
+                + p["k_norm"]["b"].astype(_F32)
+            ki = _rotary(ki.astype(hid.dtype)[:, :, None], self.rope_theta,
+                         di)[:, :, 0]
+            w = (hbar @ p["w_w"].T).astype(_F32) * (hi * di) ** -0.5
+            select = sparse_index.select_keys(
+                qi, ki, w, self.index_topk,
+                impl="fast" if self.attn_impl == "fast" else "reference")
+        return qi, ki, w, select
+
+    def first_selection(self, params: dict, tokens):
+        """The packed key sets (``ops.key_set.pack_select``: ``int32
+        [B, T, 128 * ceil(T / 4096)]``) that layer 0, a "sparse" layer,
+        selects for ``tokens [B, T]``: what its flash kernels read in a
+        step on these parameters, for a check outside the step."""
+        lp = params["layer_0"]
+        hid = self._norm(params["embed"][tokens], lp["norm1"])
+        return self._index(lp["index"], hid)[-1]
+
+    def _sparse_mixer(self, lp, x):
+        """``(x out, the indexer's aux)``: its loss, the pairs selected
+        and the share of 512 x 512 tiles that hold one."""
+        from apex_tpu.contrib.multihead_attn.flash_attention import (
+            flash_attention, reference_attention)
+        from apex_tpu.ops import sparse_index
+        b, t, _ = x.shape
+        h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        impl = "fast" if self.attn_impl == "fast" else "reference"
+        with jax.named_scope("sparse_attention"):
+            hid = self._norm(x, lp["norm1"])
+            q, k, v = (a.transpose(0, 2, 1, 3)
+                       for a in self._qkv(lp["attn"], hid)[:3])
+        qi, ki, w, select = self._index(lp["index"], hid)
+        with jax.named_scope("sparse_attention"):
+            attend = flash_attention if self.attn_impl == "fast" \
+                else reference_attention
+            a, lse = attend(q, *(jnp.repeat(a, h // kv, axis=1)
+                                 for a in (k, v)), causal=True,
+                            select=select, scale=hd ** -0.5, return_lse=True)
+        with jax.named_scope("sparse_index"):
+            aux = {"index_loss": sparse_index.index_loss(
+                       qi, ki, w, q, k, lse, select, scale=hd ** -0.5,
+                       impl=impl),
+                   "select_pairs": jnp.sum(jax.lax.population_count(select)),
+                   "select_live_tile_pct": sparse_index.live_tile_pct(
+                       select)}
+        with jax.named_scope("sparse_attention"):
+            return x + a.transpose(0, 2, 1, 3).reshape(b, t, h * hd) \
+                @ lp["attn"]["w_o"], aux
 
     def _conv_mixer(self, lp, x):
         d = self.hidden
@@ -456,13 +571,21 @@ class HybridLM:
             return x + act.astype(x.dtype) @ p["w_down"]
 
     def _block(self, kind: str, lp, x, ffn: str = "experts", bias=None):
-        """One layer: ``(x, the expert layer's aux | None)``. ``bias``:
-        the sigmoid router's selection bias of this layer."""
+        """One layer: ``(x, the expert layer's aux | None)``, and for a
+        "sparse" layer ``(x, (that, the indexer's aux))``. ``bias``: the
+        sigmoid router's selection bias of this layer."""
+        if kind == "sparse":
+            x, index_aux = self._sparse_mixer(lp, x)
+            x, aux = self._ffn(lp, x, ffn, bias)
+            return x, (aux, index_aux)
         x = {"linear": self._linear_mixer, "full": self._full_mixer,
              "window": functools.partial(self._full_mixer,
                                          window=self.window),
              "latent": self._latent_mixer,
              "conv": self._conv_mixer}[kind](lp, x)
+        return self._ffn(lp, x, ffn, bias)
+
+    def _ffn(self, lp, x, ffn: str, bias):
         if ffn == "dense":
             return self._dense_ffn(lp, x), None
         b, t, d = x.shape
@@ -483,13 +606,20 @@ class HybridLM:
         experts and tiles of the dispatch buffer that hold a row, the worst
         layer's held-expert load over the mean and, for
         the sigmoid router, each expert layer's pairs an expert
-        (``expert_pairs [expert layers, num_experts]``). ``router_bias``:
+        (``expert_pairs [expert layers, num_experts]``); with "sparse"
+        layers, their summed indexer loss, the pairs they selected and the
+        fullest layer's share of 512 x 512 tiles that hold one.
+        ``router_bias``:
         that router's selection biases, a row an expert layer."""
         with jax.named_scope("embed"):
             x = params["embed"][tokens]
         # a run of like layers is one scanned body over the run's stacked
         # parameters: three Gated DeltaNet layers compile once
-        auxes, first, row = [], 0, 0
+        auxes, indexers, first, row = [], [], 0, 0
+        saved = SAVED_NAMES
+        if "sparse" in self.layer_types:
+            from apex_tpu.ops import sparse_index
+            saved += sparse_index.SAVED_NAMES
         for (kind, ffn), run in itertools.groupby(
                 zip(self.layer_types, self.ffns)):
             n = len(list(run))
@@ -501,7 +631,7 @@ class HybridLM:
             if self.remat:
                 block = jax.checkpoint(
                     block, policy=jax.checkpoint_policies
-                    .save_only_these_names(*SAVED_NAMES))
+                    .save_only_these_names(*saved))
             layers = [params[f"layer_{i}"] for i in range(first, first + n)]
             # prof.REGIONS: what the run adds around its blocks, whose
             # ops keep their own scopes (metadata only)
@@ -511,11 +641,16 @@ class HybridLM:
                     xs, row = (xs, router_bias[row:row + n]), row + n
             with jax.named_scope("layer_scan"):
                 x, aux = jax.lax.scan(block, x, xs)
+            if kind == "sparse":
+                aux, index_aux = aux
+                indexers.append(index_aux)
             if aux is not None:
                 auxes.append(aux)
             first += n
         with jax.named_scope("layer_stack"):    # the runs' counters joined
             aux = jax.tree.map(lambda *a: jnp.concatenate(a), *auxes)
+            index_aux = jax.tree.map(lambda *a: jnp.concatenate(a),
+                                     *indexers) if indexers else {}
         with jax.named_scope("head_loss"):
             x = self._norm(x, params["norm_f"])
         counters = {"load_balance_loss": jnp.sum(aux["load_balance_loss"]),
@@ -526,6 +661,12 @@ class HybridLM:
                         aux["load_max_over_mean"])}
         if "expert_pairs" in aux:
             counters["expert_pairs"] = aux["expert_pairs"]
+        if index_aux:
+            counters.update(
+                index_loss=jnp.sum(index_aux["index_loss"]),
+                select_pairs=jnp.sum(index_aux["select_pairs"]),
+                select_live_tile_pct=jnp.max(
+                    index_aux["select_live_tile_pct"]))
         return x, counters
 
     def apply(self, params: dict, tokens, router_bias=None):
@@ -537,10 +678,12 @@ class HybridLM:
 
     def loss_with_counters(self, params: dict, tokens, router_bias=None):
         """Mean next-token cross-entropy of ``tokens [B, T + 1]`` plus
-        ``aux_coef`` times the load-balancing terms, and the step's
+        ``aux_coef`` times the load-balancing terms (plus ``index_coef``
+        times the "sparse" layers' indexer losses), and the step's
         counters (``moe_overflow_pairs``, ``moe_held_pairs_max``,
         ``moe_live_tiles_max``, ``expert_load_max_over_mean``; the sigmoid
-        router's ``expert_pairs``)."""
+        router's ``expert_pairs``; the sparse layers' ``index_loss``,
+        ``select_pairs``, ``select_live_tile_pct``)."""
         from apex_tpu.contrib.xentropy import linear_cross_entropy
         x, c = self.hidden_states(params, tokens[:, :-1], router_bias)
         with jax.named_scope("head_loss"):
@@ -550,6 +693,8 @@ class HybridLM:
                 chunk=self.head_chunk or self.vocab_size)
             loss = jnp.mean(losses) + self.aux_coef * c.pop(
                 "load_balance_loss")
+            if "index_loss" in c:       # stays among the counters too
+                loss = loss + self.index_coef * c["index_loss"]
         return loss, c
 
     def loss_with_router_state(self, params: dict, router_bias, tokens):

@@ -71,6 +71,13 @@ def _flash(bwd, **kw):
         fwd(q, k, v).astype(F32) ** 2), argnums=(0, 1, 2))
 
 
+def _flash_sel(q, k, v, select):
+    from apex_tpu.contrib.multihead_attn import flash_attention
+    return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True, select=select).astype(F32) ** 2),
+        argnums=(0, 1, 2))(q, k, v)
+
+
 def _qkv(b, h, s, d):
     return [((b, h, s, d), BF16)] * 3
 
@@ -196,6 +203,12 @@ KERNELS = [
      lambda: _flash(True, window=1024), _qkv(2, 16, 8192, 256),
      ("apex_flash_win_fwd", "apex_flash_win_bwd_dq",
       "apex_flash_win_bwd_dkv")),
+    # Keye-VL 2.0's sparse layer: one row of 16,384, 32 heads of 128 over a
+    # packed per-query key set shared by the heads (a bit a key)
+    ("flash_sel_fwd_bwd-B1H32S16384D128", lambda: _flash_sel,
+     _qkv(1, 32, 16384, 128) + [((1, 16384, 512), I32)],
+     ("apex_flash_sel_fwd", "apex_flash_sel_bwd_dq",
+      "apex_flash_sel_bwd_dkv")),
     ("flash_fwd-B24H16S2048D128", lambda: _flash(False),
      _qkv(24, 16, 2048, 128),
      ("apex_flash_fwd",)),
@@ -284,6 +297,23 @@ _QNEXT_SHARED = {"w_gate": ((2048, 512), BF16), "w_up": ((2048, 512), BF16),
                  "w_down": ((512, 2048), BF16), "gate": ((2048, 1), BF16)}
 
 
+def _indexer_args(s, heads=16, dim=64):
+    return [((1, s, heads, dim), BF16), ((1, s, dim), BF16),
+            ((1, s, heads), F32)]
+
+
+def _select_keys(qi, ki, w):
+    from apex_tpu.ops import sparse_index
+    return sparse_index.select_keys(qi, ki, w, 2048)
+
+
+def _index_loss(qi, ki, w, q, k, lse, select):
+    from apex_tpu.ops import sparse_index
+    return jax.value_and_grad(lambda qi, ki, w: sparse_index.index_loss(
+        qi, ki, w, q, k, lse, select, scale=128 ** -0.5),
+        argnums=(0, 1, 2))(qi, ki, w)
+
+
 def _grouped_matmul():
     from apex_tpu.ops.pallas.grouped_matmul import grouped_matmul
     return jax.grad(lambda lhs, w, tile_e, live: jnp.sum(grouped_matmul(
@@ -326,6 +356,16 @@ NEW_OPS = [
     ("gated_delta_rule_fwd_bwd-B2H32S8192D128", _delta_rule,
      [((2, 32, 8192, 128), BF16)] * 3 + [((2, 32, 8192), F32)] * 2, 4.0,
      ("apex_gdn_fwd", "apex_gdn_bwd")),
+    # Keye-VL 2.0's lightning indexer at the published shapes (16 heads of
+    # 64 over one key head, a row of 16,384, the 2,048 best keys a query):
+    # the search a chunk of queries at a time, never [S, S] float32 (1 GB)
+    ("sparse_index_select-B1S16384H16D64", lambda: _select_keys,
+     _indexer_args(16384), 0.5, ("apex_idx_scores",)),
+    ("sparse_index_loss_fwd_bwd-B1S16384H16D64", lambda: _index_loss,
+     _indexer_args(16384) + [((1, 32, 16384, 128), BF16),
+                             ((1, 4, 16384, 128), BF16),
+                             ((1, 32, 16384), F32), ((1, 16384, 512), I32)],
+     1.0, ("apex_idx_scores", "apex_idx_probs", "apex_idx_grad")),
     # 512-way routing, the sort, grouped matmuls over 16 held experts
     ("expert_layer_fwd_bwd-N16384E512held16", _expert_layer(
         ffn=512, num_experts=512, top_k=10, experts_held=(0, 16),

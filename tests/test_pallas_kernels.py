@@ -443,7 +443,8 @@ def _site(fn, *args, **kw):
 def _kernel_sites() -> dict:
     """``{documented name: thunk tracing the wrapper that holds the site}``."""
     from apex_tpu.ops.pallas import (decode_attn as D, layer_norm as L,
-                                     welford as W, xentropy as X)
+                                     sparse_index as I, welford as W,
+                                     xentropy as X)
     n = 128 * 16
     buf, rows = _f32(n), _i32(n // 128)
     hp = dict(lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-8, step=1)
@@ -456,7 +457,14 @@ def _kernel_sites() -> dict:
     gdn = [_f32(1, 2, 2, 64, 128)] * 4 + [_f32(1, 2, 2, 64)]
     # two tiles of 128 rows over two experts' [128, 256]
     moe = [_f32(256, 128), _f32(2, 128, 256), _i32(2), _i32()]
+    # the indexer's kernels: 64 queries of 2 heads against 128 keys; the
+    # first site is also apex_idx_scores (tests/test_sparse_index.py)
+    idx = [_f32(1, 2, 64, 128), _f32(1, 1, 128, 128), _f32(1, 64, 128)]
     return {
+        "apex_idx_probs": _site(lambda *a: I.pair_sum(*a, 0, probs=True),
+                                *idx),
+        "apex_idx_grad": _site(lambda *a: I.grad(*a, 0), *idx,
+                               _f32(1, 64, 128)),
         "apex_moe_gmm": _site(_moe_grad(), *moe),
         "apex_moe_tgmm": _site(_moe_grad(), *moe),
         "apex_gdn_fwd": _site(_gdn_grad(), *gdn),
