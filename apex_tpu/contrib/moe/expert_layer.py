@@ -37,8 +37,39 @@ one rank of an expert-parallel deployment computes it:
   group) are not masked: they hold some token's row, finite, and a
   weight of zero, and the weight is what the combine multiplies by (in
   the down projection's kernel, on the tile it holds, or after the
-  einsum), so such a row adds nothing to the result and gets and gives
-  no gradient;
+  einsum), so such a row gets and gives no gradient in the kernels;
+- the map between pairs and rows is kept both ways (:meth:`placed`).
+  Rows to pairs, ``pair [rows]`` and ``tok = pair // top_k``: what the
+  grouped matmuls' operand is gathered by (``xb = x[tok]``). Pairs to
+  rows, ``pos [N, top_k]`` and ``ok [N, top_k]``: the row a pair sits
+  in, its group's first row plus its rank among the pairs on its expert
+  (a running count an expert over the pairs: no inverse permutation),
+  and whether it sits in one at all (false for a pair on an absent
+  expert or past the bound). ``pos.reshape(-1)[pair[r]] == r`` on every
+  live row ``r``, and no ``pos`` with ``ok`` true names a dead row;
+- the two sums from rows back to tokens go by that map and scatter
+  nothing: the combine is ``y[n] = sum_j ok[n, j] * yb[pos[n, j]]`` in
+  float32 (its transpose the rows' gather ``dy[tok]``), and the transpose
+  of ``xb = x[tok]`` is ``dx[n] = sum_j ok[n, j] * dxb[pos[n, j]]``, added
+  in float32 and rounded to ``x``'s type once (two ``custom_vjp``s, above
+  the choice of kernels or einsum). A pair that is not ``ok`` adds
+  nothing **by the mask**, whatever a dead row holds. On the TPU both are
+  the kernel of ``ops/pallas/row_sum.py`` (``apex_moe_rowsum``), which is
+  handed ``pos`` an expert a column and uses what the sort gives: the
+  tokens of a block that chose one expert sit in consecutive rows of its
+  group, so a block fetches a short run of the buffer an expert and adds
+  its rows by a 0/1 product. Anywhere else they are one gather of ``[N,
+  d]`` a slot and one pass that adds them. As JAX writes them the two are
+  scatter-adds by ``tok`` (``zeros.at[tok].add(yb)``, and ``x[tok]``
+  transposed), which XLA runs on the TPU as a pass over the buffer and a
+  serial pass over the tokens: 6.9 and 6.5 ms a layer of 16,384 tokens,
+  top-8, 65,536 rows of 2304 on the v5e, where the kernel takes 2.2 and
+  1.0 and the gather-sum in XLA 8.4 and 6.6. **The rule** is
+  ``row_sum.takes``, a static function of the row's width and bytes, the
+  buffer's rows and the experts held, written once beside its readings
+  (the five cells' shares and whole layers): rows in whole lanes and a
+  round's chunks of every held expert within the kernel's VMEM; any
+  number of tokens;
 - there is no capacity an expert and no drop: an expert takes whatever
   share of the buffer its tokens need. ``dispatch_bound`` is the one
   static size. Pairs that fall past it are left out **and counted**
@@ -81,7 +112,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from apex_tpu.ops import dispatch
-from apex_tpu.ops.pallas import grouped_matmul as gmm
+from apex_tpu.ops.pallas import grouped_matmul as gmm, row_sum
 
 __all__ = ["ExpertLayer"]
 
@@ -96,6 +127,70 @@ def _swiglu(x, p, matmuls, down=None):
     g, u = matmuls(x, p["w_gate"], p["w_up"])
     y, = (down or matmuls)((jax.nn.silu(g) * u).astype(x.dtype), p["w_down"])
     return y
+
+
+def _sum_rows(buf, where, held, dtype):
+    """``sum_j ok[n, j] * buf[pos[n, j]]`` for ``where = (pos, ok, local)``
+    (:meth:`ExpertLayer.placed`'s map and the pairs' experts of the
+    ``held``), added in float32 and rounded to ``dtype`` once: the rows of
+    the buffer a token's pairs hold. A pair with ``ok`` false adds nothing
+    whatever the row it names holds.
+
+    On the TPU, where ``ops/pallas/row_sum.py`` takes the shapes, its
+    kernel, which is handed the same rows an expert a column and fetches
+    each live row once or twice; anywhere else one gather of ``[n, d]`` a
+    slot ``j`` and one pass that adds them (XLA's gather fuses into no sum,
+    and one gather of ``[n, top_k, d]`` would pad ``top_k`` to whole
+    sublanes)."""
+    pos, ok, local = where
+    if dispatch.use_pallas() and row_sum.takes(
+            buf.shape[1], buf.shape[0], held, buf.dtype.itemsize):
+        return row_sum.sum_rows(buf, row_sum.columns(
+            jnp.where(ok, pos, -1), local, held), dtype)
+
+    def slot(a, j):
+        return lax.index_in_dim(a, j, axis=1, keepdims=False)
+    return functools.reduce(jnp.add, (
+        jnp.where(slot(ok, j)[:, None], buf[slot(pos, j)].astype(_F32), 0.0)
+        for j in range(pos.shape[1]))).astype(dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_of(x, tok, where, held):
+    """``x[tok]``, the buffer's rows; its transpose is the sum of the
+    rows' cotangents by ``where`` (:func:`_sum_rows`), not a scatter-add
+    by ``tok``: added in float32 and rounded to ``x``'s type once."""
+    return x[tok]
+
+
+def _rows_of_fwd(x, tok, where, held):
+    return x[tok], where
+
+
+def _rows_of_bwd(held, where, dxb):
+    return _sum_rows(dxb, where, held, dxb.dtype), None, None
+
+
+_rows_of.defvjp(_rows_of_fwd, _rows_of_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combined(yb, tok, where, held):
+    """``zeros.at[tok].add(yb)`` over the live rows as the sum by
+    ``where`` (float32, as ``yb`` is); its transpose is the rows' gather
+    ``dy[tok]``."""
+    return _sum_rows(yb, where, held, _F32)
+
+
+def _combined_fwd(yb, tok, where, held):
+    return _combined(yb, tok, where, held), tok
+
+
+def _combined_bwd(held, tok, dy):
+    return dy[tok], None, None
+
+
+_combined.defvjp(_combined_fwd, _combined_bwd)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -201,6 +296,50 @@ class ExpertLayer:
         return jnp.sum(by_seq, 0), jnp.mean(jnp.sum(
             f * jnp.mean(share.reshape(seqs, t, e_all), axis=1), axis=-1))
 
+    def placed(self, local, n_e, rows: int) -> dict:
+        """Where the pairs sit in a buffer of ``rows`` rows, both ways.
+        ``local [N, K]``: a pair's held expert, ``held`` for an absent one;
+        ``n_e [held]``: the pairs an expert. Rows to pairs: ``tile_e
+        [rows / tile]`` a tile's expert, ``pair [rows]`` the pair a row
+        holds (``n * K + j``; some pair's for a dead row) and ``live
+        [rows]``, with the groups' ``tiles_e`` / ``end_tile [held]``. Pairs
+        to rows: ``pos [N, K]`` the row a pair sits in and
+        ``ok [N, K]``, false for a pair on an absent expert or past the
+        bound (its ``pos`` is 0). ``pos.reshape(-1)[pair[r]] == r`` on
+        every live row ``r``, and ``ok`` holds on exactly those pairs."""
+        n, k = local.shape
+        tm, held = self.tile, n_e.shape[0]
+        # sort the pairs by held expert; pairs on absent experts last
+        order = jnp.argsort(local.reshape(-1), stable=True)
+        first = jnp.cumsum(n_e) - n_e       # in the sorted pairs
+        tiles_e = -(-n_e // tm)             # whole tiles an expert
+        end_tile = jnp.cumsum(tiles_e)
+        # a tile belongs to one expert, so what a row needs of its
+        # expert is looked up a tile (rows // tm of them) and spread
+        tile = jnp.arange(rows // tm)
+        tile_e = jnp.minimum(
+            jnp.searchsorted(end_tile, tile, side="right",
+                             method="compare_all"), held - 1)
+        off = ((tile - (end_tile - tiles_e)[tile_e]) * tm)[:, None] \
+            + jnp.arange(tm)                # [tiles, tm]: row in group
+        live = (off < n_e[tile_e][:, None]).reshape(rows)
+        pair = order[jnp.clip(first[tile_e][:, None] + off, 0,
+                              n * k - 1).reshape(rows)]
+        # the same map from the pairs' side: a pair's row is its group's
+        # first row and its rank among the group's pairs, which a count
+        # of the earlier pairs an expert gives (the tokens before it by a
+        # running sum, then the token's own): no inverse permutation
+        hot = local[:, :, None] == jnp.arange(held)     # [n, k, held]
+        mine = jnp.sum(hot, axis=1, dtype=jnp.int32)
+        rank = (jnp.cumsum(mine, axis=0) - mine)[:, None] \
+            + jnp.cumsum(hot, axis=1, dtype=jnp.int32) - hot
+        pos = jnp.sum(jnp.where(
+            hot, (end_tile - tiles_e) * tm + rank, 0), axis=2)
+        ok = (local < held) & (pos < rows)
+        return {"tiles_e": tiles_e, "end_tile": end_tile, "tile_e": tile_e,
+                "pair": pair, "live": live,
+                "pos": jnp.where(ok, pos, 0), "ok": ok}
+
     def routed(self, params: dict, x, bias=None):
         """The held experts' part of the layer for ``x [N, hidden]`` or,
         in sequences, ``x [S, T, hidden]`` (the sigmoid router balances a
@@ -218,30 +357,19 @@ class ExpertLayer:
             if held < e_all:    # a share: no reward from held experts only
                 w = lax.stop_gradient(w)
             counts, balance = self._balance(idx, scores, n // shape[-2])
-            # sort the pairs by held expert; pairs on absent experts last
             local = jnp.where((idx >= lo) & (idx < hi), idx - lo, held)
-            order = jnp.argsort(local.reshape(-1), stable=True)
             n_e = counts[lo:hi]
-            first = jnp.cumsum(n_e) - n_e       # in the sorted pairs
-            tiles_e = -(-n_e // tm)             # whole tiles an expert
-            end_tile = jnp.cumsum(tiles_e)
-            # a tile belongs to one expert, so what a row needs of its
-            # expert is looked up a tile (rows // tm of them) and spread
-            tile = jnp.arange(rows // tm)
-            tile_e = jnp.minimum(
-                jnp.searchsorted(end_tile, tile, side="right",
-                                 method="compare_all"), held - 1)
-            off = ((tile - (end_tile - tiles_e)[tile_e]) * tm)[:, None] \
-                + jnp.arange(tm)                # [tiles, tm]: row in group
-            live = (off < n_e[tile_e][:, None]).reshape(rows)
-            pair = order[jnp.clip(first[tile_e][:, None] + off, 0,
-                                  n * k - 1).reshape(rows)]
+            put = self.placed(local, n_e, rows)
+            tiles_e, end_tile, tile_e = (
+                put["tiles_e"], put["end_tile"], put["tile_e"])
+            pair, live = put["pair"], put["live"]
+            where = (put["pos"], put["ok"], local)
             tok = pair // k
             # a dead row (a group's last tile past its pairs, the tiles
             # past the last group) holds some token's row under no mask:
-            # its weight is zero, which keeps it out of the result and of
-            # every gradient
-            xb = x[tok]
+            # its weight is zero, which keeps it out of the kernels'
+            # gradients, and no pair's ``pos`` names it
+            xb = _rows_of(x, tok, where, held)
             wb = jnp.where(live, w.reshape(-1)[pair], 0.0)
             overflow = jnp.sum(jnp.clip(
                 (end_tile - tiles_e) * tm + n_e - rows, 0, n_e))
@@ -266,7 +394,7 @@ class ExpertLayer:
                         preferred_element_type=_F32) for w in ws]
                 ).reshape(rows, d) * wb[:, None]
         with jax.named_scope("moe_route"):
-            y = jnp.zeros((n, d), _F32).at[tok].add(yb)
+            y = _combined(yb, tok, where, held)
         aux = {"load_balance_loss": balance,
                "overflow_pairs": overflow.astype(jnp.int32),
                "held_pairs": jnp.sum(n_e).astype(jnp.int32),

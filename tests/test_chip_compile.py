@@ -432,6 +432,41 @@ def test_the_combine_and_the_dead_rows_cost_no_pass_for_v5e(chip, for_chip):
     assert len(re.findall(r"%(\w+_)?apex_moe_tgmm_*\.\d+ = ", text)) == 3
 
 
+@pytest.mark.parametrize("hidden,ffn,experts,held,bound,was,kernels", [
+    pytest.param(2304, 896, 64, 16, 65536, 2.10, 2, id="mellum2"),
+    pytest.param(2048, 768, 128, 16, 65536, 1.58, 2, id="keye"),
+    pytest.param(2304, 896, 64, 64, 0, 4.86, 1, id="a_whole_layer_of_64")])
+def test_the_two_sums_scatter_nothing_and_fit_for_v5e(
+        chip, for_chip, hidden, ffn, experts, held, bound, was, kernels):
+    """One routed layer of ``mellum2_train_s8192`` and one of
+    ``keye_train_s16384`` (16,384 tokens, top-8, 16 experts held, a bound
+    of 65,536 rows): the loss gradient scatters into no ``[tokens,
+    hidden]`` array and gathers none a slot either: the combine and ``dx``
+    are ``apex_moe_rowsum``, one call each, which holds a block's sum and
+    a round's chunks in VMEM and nothing in HBM, so the layer's
+    temporaries stay within a hundredth of the parent's (``was``, the GB
+    its layer read here; a ``[tokens, top_k, hidden]`` float32 array, which
+    a gather-sum in XLA writes, would be 1.2 and 1.1 GB more). And the
+    first where a chip holds all 64 experts (the worst-case bound, 139,264
+    rows), which ``row_sum.takes`` for ``dx``'s bfloat16 rows and not for
+    the combine's float32 ones (its readings): that sum is ``top_k``
+    gathers of ``f32[tokens, hidden]`` added in one pass, no scatter and no
+    ``[tokens, top_k, hidden]`` array either."""
+    from apex_tpu.contrib.moe import ExpertLayer
+    layer = ExpertLayer(hidden=hidden, ffn=ffn, num_experts=experts,
+                        top_k=8, experts_held=(0, held), dispatch_bound=bound)
+    compiled = jax.jit(jax.grad(lambda p, x: jnp.sum(jnp.sin(layer.routed(
+        p, x)[0])), argnums=(0, 1))).lower(*_specs(
+            _expert_args(ffn, experts, held, d=hidden), chip)).compile()
+    text = compiled.as_text()
+    wide = re.findall(rf" = (\w+)\[16384,{hidden}\]\S* (scatter|gather)\(",
+                      text)
+    assert wide == [("f32", "gather")] * (8 * (2 - kernels))
+    assert len(re.findall(r"%(\w+_)?apex_moe_rowsum_*\.\d+ = ",
+                          text)) == kernels
+    assert compiled.memory_analysis().temp_size_in_bytes < was * 1.01e9
+
+
 def _step(name, skip, **kw):
     """``multi_tensor.<name>`` over (g, p, m, v[, segment ids]) and, with
     ``skip``, a traced overflow flag as the last argument."""

@@ -4,14 +4,20 @@ to the uncut layer, no pair on a held expert dropped under a skewed
 router, pairs past the bound counted; the sigmoid router's bias moving
 the choice and not the weights, and its move after a step by hand; a
 buffer whose dead rows hold tokens' rows under no mask, which their zero
-weights keep out of the result and of every gradient."""
+weights keep out of the result and of every gradient; the map between
+pairs and rows read both ways, and the two sums between tokens and rows
+as gather-sums by it: the row a pair names is the row that holds it, a
+dead row is kept out by the mask, the gradients are the oracle's, the
+gradient's program scatters nothing into ``[tokens, hidden]``, and what
+the sums add runs under ``moe_route``."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from apex_tpu.contrib.moe import ExpertLayer
+from apex_tpu.analysis import walker
+from apex_tpu.contrib.moe import ExpertLayer, expert_layer
 
 D, F, E, K = 32, 16, 32, 4
 ROUTERS = ["softmax", "sigmoid"]
@@ -324,3 +330,161 @@ def test_what_the_layer_refuses(kw):
     base = dict(hidden=D, ffn=F, num_experts=E, top_k=K)
     with pytest.raises(ValueError):
         SmallTiles(**{**base, **kw})
+
+
+# -- the map between pairs and rows, and the two sums that go by it ----------
+
+def _routing(case):
+    """``(layer, local [N, K], n_e [held], rows)`` of a case: a router's
+    own choice for a share or the whole layer, or one made by hand."""
+    n = 48
+    if case in ("softmax-share", "sigmoid-share", "softmax-whole",
+                "sigmoid-whole", "overflow"):
+        router = case.split("-")[0] if "-" in case else "softmax"
+        held = (0, E) if case.endswith("whole") else (8, 16)
+        layer = _layer(held, router=router,
+                       dispatch_bound=32 if case == "overflow" else 0)
+        _, idx, _ = layer.route(_params(20, router=router), _x(n, 21))
+    else:
+        layer = _layer((8, 16))
+        # K distinct experts a token, as top_k's are, of 0..7
+        idx = np.argsort(np.asarray(jax.random.uniform(
+            jax.random.key(22), (n, 8))), axis=1)[:, :K]
+        if case == "an-expert-with-no-pair":    # 8..15 but 11, and absent
+            idx = np.where(idx == 3, 20, 8 + idx)
+        else:                                   # every held pair on 9
+            assert case == "all-on-one-expert"
+            idx[:, 0] = 9
+        idx = jnp.asarray(idx)
+    lo, hi = layer.held
+    local = jnp.where((idx >= lo) & (idx < hi), idx - lo, hi - lo)
+    n_e = jnp.sum(local.reshape(-1, 1) == jnp.arange(hi - lo), 0)
+    return layer, local, n_e, layer.bound(n)
+
+
+CASES = ["softmax-share", "sigmoid-share", "softmax-whole", "sigmoid-whole",
+         "an-expert-with-no-pair", "all-on-one-expert", "overflow"]
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_a_pairs_row_is_the_row_that_holds_it(case):
+    """``pos`` names, from the pair's side, the row ``pair`` gives it:
+    on every live row ``r``, ``pos[pair[r]] == r`` and ``ok`` holds;
+    ``ok`` is false exactly on the pairs no live row holds, which are
+    those on absent experts and those past the bound."""
+    layer, local, n_e, rows = _routing(case)
+    at = jax.tree.map(np.asarray, layer.placed(local, n_e, rows))
+    pair, live = at["pair"], at["live"]
+    pos, ok = at["pos"].reshape(-1), at["ok"].reshape(-1)
+    r = np.flatnonzero(live)
+    np.testing.assert_array_equal(pos[pair[r]], r)
+    assert ok[pair[r]].all() and ok.sum() == live.sum()
+    held = np.asarray(local).reshape(-1) < n_e.shape[0]
+    assert not ok[~held].any()
+    lost = int(held.sum()) - int(ok.sum())      # held, yet in no row
+    if case == "overflow":
+        assert lost > 0 and live.sum() <= rows
+    else:
+        assert lost == 0
+    if case == "an-expert-with-no-pair":
+        assert int(n_e[3]) == 0 and int(n_e.min()) == 0
+    if case == "all-on-one-expert":
+        assert int(n_e[1]) == 48 == int(n_e.sum())
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_the_mask_keeps_a_dead_row_out_of_the_combine(case):
+    """The combine by ``pos`` / ``ok`` is ``zeros.at[tok].add`` over the
+    live rows, with the dead rows holding a large finite junk value: no
+    pair names one, so what it holds reaches nothing (the mask, not the
+    zeros); and its transpose is ``dy[tok]``."""
+    layer, local, n_e, rows = _routing(case)
+    at = layer.placed(local, n_e, rows)
+    tok, live = at["pair"] // K, at["live"]
+    yb = jax.random.normal(jax.random.key(23), (rows, D))
+    want = jnp.zeros((local.shape[0], D)).at[tok].add(
+        jnp.where(live[:, None], yb, 0.0))
+    junk = jnp.where(live[:, None], yb, 3e37)
+    got, back = jax.vjp(lambda yb: expert_layer._combined(
+        yb, tok, (at["pos"], at["ok"], local), n_e.shape[0]), junk)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    dy = jax.random.normal(jax.random.key(24), got.shape)
+    np.testing.assert_array_equal(back(dy)[0], dy[tok])
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_dx_is_added_in_float32_and_rounded_once(case):
+    """The transpose of ``xb = x[tok]`` with ``x`` in bfloat16: the sum by
+    ``pos`` / ``ok`` adds a token's rows' cotangents in float32 and rounds
+    once, so it is no further from the sum in float64 than the scatter-add
+    JAX writes for ``x[tok]`` (the parent's ``dx``), which rounds at every
+    addition; and what a dead row's cotangent holds reaches nothing."""
+    layer, local, n_e, rows = _routing(case)
+    at = layer.placed(local, n_e, rows)
+    tok, live = at["pair"] // K, np.asarray(at["live"])
+    x = _x(local.shape[0], 25).astype(jnp.bfloat16)
+    dxb = jax.random.normal(jax.random.key(26), (rows, D)).astype(
+        jnp.bfloat16)
+    zeroed = jnp.where(live[:, None], dxb, 0)     # as a weight of zero does
+    old, = jax.vjp(lambda x: x[tok], x)[1](zeroed)
+    new, = jax.vjp(lambda x: expert_layer._rows_of(
+        x, tok, (at["pos"], at["ok"], local), n_e.shape[0]), x)[1](
+            jnp.where(live[:, None], dxb, 3e37))
+    assert new.dtype == old.dtype == jnp.bfloat16
+    exact = np.zeros(x.shape)
+    np.add.at(exact, np.asarray(tok)[live], np.asarray(dxb, np.float64)[live])
+
+    def off(dx):
+        return float(np.abs(np.asarray(dx, np.float64) - exact).max())
+    assert off(new) <= off(old) < 0.1
+    # once rounded: the float64 sum's nearest bfloat16
+    np.testing.assert_array_equal(
+        np.asarray(new), np.asarray(jnp.asarray(exact, jnp.bfloat16)))
+
+
+def _loss_gradient(layer, n=64):
+    params = _share(_params(28), *layer.held)
+    return jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(jnp.sin(
+        layer.routed(p, x)[0])), (0, 1)))(params, _x(n, 29))
+
+
+def test_the_gradients_program_scatters_nothing_into_the_tokens():
+    """As JAX writes them the combine and ``dx`` are two scatter-adds
+    into ``[tokens, hidden]``; the layer's loss gradient holds none, and
+    gathers ``[tokens, hidden]`` a slot of ``top_k`` twice instead."""
+    def count(fn, prim, *args):
+        return sum(v.eqn.primitive.name == prim
+                   and v.eqn.outvars[0].aval.shape == (64, D)
+                   for v in walker.iter_eqns(fn(*args)))
+    tok = jnp.arange(128) % 64
+    plain = jax.make_jaxpr(jax.grad(lambda x: jnp.sum(jnp.sin(
+        jnp.zeros((64, D)).at[tok].add(jnp.cos(x[tok]))))))
+    assert count(plain, "scatter-add", _x()) == 2
+    layer = _layer((8, 16))
+    assert count(_loss_gradient, "scatter-add", layer) == 0
+    assert count(_loss_gradient, "gather", layer) == 2 * K
+
+
+def test_what_the_sums_add_runs_under_moe_route():
+    """Every gather of the routed layer's loss gradient that reads or
+    makes a ``[rows, hidden]`` or ``[tokens, hidden]`` array (the two
+    sums' ``top_k`` a sum, ``x[tok]`` and ``dy[tok]``: forward and in
+    both backward rules), every equation over the maps' ``[tokens,
+    top_k, held]`` counts and ``[tokens, top_k]`` positions, and every
+    add and select over ``[tokens, hidden]`` outside the experts
+    carries ``moe_route`` in its name stack."""
+    layer = _layer((8, 16))
+    rows, held = layer.bound(64), 8
+    maps, wide = {(64, K, held), (64, K)}, {(64, D), (rows, D)}
+    back = []
+    for v in walker.iter_eqns(_loss_gradient(layer)):
+        eqn, name = v.eqn, v.eqn.primitive.name
+        shapes = {a.aval.shape for a in eqn.outvars + eqn.invars
+                  if hasattr(a.aval, "shape")}
+        if shapes & maps or (shapes & wide and name == "gather") or (
+                (64, D) in shapes and name in ("add", "select_n")
+                and v.scope != "moe_experts"):
+            assert v.scope == "moe_route", (name, v.scope)
+            back += [name] * ("transpose" in str(eqn.source_info.name_stack))
+    # dx's gathers, selects and adds; dy[tok]
+    assert back.count("gather") == K + 1 and back.count("add") == K - 1

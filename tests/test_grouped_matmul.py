@@ -303,14 +303,59 @@ def test_live_tiles_stops_at_the_buffers_end():
         assert int(side["overflow_pairs"]) == 690 - (300 + 128)
 
 
+def _dense_block(p, x, lo, hi):
+    """An expert layer with a shared expert as a dense mixture: every held
+    expert over every token, by ``jax.numpy`` alone and in ``x``'s type.
+    Nothing of ``ExpertLayer``: no buffer, no map, no kernel."""
+    probs = jax.nn.softmax(x @ p["router"], -1)
+    w, idx = jax.lax.top_k(probs, K)
+    w = w / w.sum(-1, keepdims=True)
+    if hi - lo < E:
+        w = jax.lax.stop_gradient(w)
+
+    def swiglu(x, gate, up, down):
+        return (jax.nn.silu(x @ gate) * (x @ up)) @ down
+    y = sum(jnp.sum(jnp.where(idx == e, w, 0.0), -1, keepdims=True) * swiglu(
+        x, p["w_gate"][e - lo], p["w_up"][e - lo], p["w_down"][e - lo])
+        for e in range(lo, hi))
+    s = p["shared"]
+    return y + jax.nn.sigmoid(x @ s["gate"]) * swiglu(
+        x, s["w_gate"], s["w_up"], s["w_down"])
+
+
+# the most a gradient's leaf is off the dense float64 layers', in the
+# leaf's largest value, on the kernels' side and on the einsum's (readings
+# here, PR 43), and the most the two sides are apart in the same measure
+_SCAN_OFF = {("a_share", 128): (1.9e-5, 1.9e-5, 4.3e-6),
+             ("a_share", 384): (6.8e-5, 7.1e-5, 1.3e-5),
+             ("whole", 128): (4.6e-5, 4.2e-5, 7.2e-6),
+             ("whole", 384): (1.8e-4, 1.5e-4, 8.8e-5)}
+
+
 @pytest.mark.parametrize("hidden,ffn", WIDTHS)
-@pytest.mark.parametrize("held", [(0, HELD), ()], ids=["a_share", "whole"])
+@pytest.mark.parametrize("held", ["a_share", "whole"])
 def test_under_checkpoint_and_a_scan_over_stacked_layers(held, hidden, ffn):
     """What ``HybridLM`` does with a run of expert layers: the blocks
     recomputed in the backward, one scanned body over two layers' stacked
     leaves. A share's weights are constants of its backward; the whole
-    layer's get their gradient through the down projection's scale."""
-    layer = _layer(hidden, ffn, experts_held=held, shared_ffn=128)
+    layer's get their gradient through the down projection's scale.
+
+    Each side is held to the two layers as dense mixtures differentiated
+    by JAX alone in float64 (:func:`_dense_block`, which shares no code
+    with the layer), leaf by leaf at three times its reading. The sides
+    themselves are not equal to the bit inside one compiled scan body: the
+    combine's float32 sum is ``apex_moe_rowsum``'s on the kernels' side
+    (three bfloat16 parts a row, added by the MXU), and on the einsum's
+    XLA:CPU takes the product with the pairs' weights again inside each
+    gather's loop and contracts it into the addition (the forward of one
+    layer under ``jit`` is an ulp apart for it, where ``yb`` itself and its
+    scatter-add are equal to the bit); the sine of sums in the hundreds
+    over two layers spreads an ulp to 2e-6 .. 9e-5 of a leaf's largest.
+    That is a tenth to a half of what float32 costs either side against
+    float64, and the test holds them to it: no further from each other
+    than from the reference."""
+    layer = _layer(hidden, ffn, experts_held=(0, HELD) if held == "a_share"
+                   else (), shared_ffn=128)
     stacked = jax.tree.map(lambda *a: jnp.stack(a), *(
         layer.init(jax.random.key(k), 0.1) for k in (4, 5)))
     x = jax.random.normal(jax.random.key(6), (2, 200, hidden))
@@ -330,14 +375,31 @@ def test_under_checkpoint_and_a_scan_over_stacked_layers(held, hidden, ffn):
     np.testing.assert_array_equal(live, w_live)
     assert live.shape == (2,) and int(live.min()) >= 1
     np.testing.assert_allclose(got, want, rtol=1e-5)
-    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(w_grads)):
-        np.testing.assert_allclose(a, b, atol=1e-4)
+
+    def dense(stacked, x):
+        for i in range(2):
+            x = x + _dense_block(jax.tree.map(lambda a: a[i], stacked), x,
+                                 *layer.held)
+        return jnp.sum(jnp.sin(x))
+    with jax.enable_x64():
+        exact, d_exact = jax.value_and_grad(dense, (0, 1))(*jax.tree.map(
+            lambda a: jnp.asarray(np.asarray(a), jnp.float64), (stacked, x)))
+    np.testing.assert_allclose(got, float(exact), rtol=2e-4)
+    kernels_off, einsum_off, apart = _SCAN_OFF[held, ffn]
+    for a, b, r in zip(*map(jax.tree.leaves, (grads, w_grads, d_exact))):
+        a, b, r = (np.asarray(v, np.float64) for v in (a, b, r))
+        top = max(float(np.abs(r).max()), 1.0)
+        off_a, off_b = np.abs(a - r).max(), np.abs(b - r).max()
+        assert off_a <= 3 * kernels_off * top and off_b <= 3 * einsum_off * top
+        assert np.abs(a - b).max() <= min(max(off_a, off_b), 3 * apart * top)
 
 
 def test_the_path_is_read_from_the_platform_and_the_shapes():
     """The kernels where ``dispatch.use_pallas()`` holds and both widths
     are whole lanes with tiles of 128; the einsum on the CPU, under
-    ``backend("reference")`` and at any other shape."""
+    ``backend("reference")`` and at any other shape. The combine's sum
+    (``apex_moe_rowsum``, above that choice) goes by ``hidden`` and the
+    buffer's rows alone."""
     from tests.test_pallas_kernels import _pallas_names
 
     def names(layer, hidden):
@@ -346,11 +408,11 @@ def test_the_path_is_read_from_the_platform_and_the_shapes():
         return set(_pallas_names(jax.make_jaxpr(
             lambda p, x: jax.grad(lambda p: jnp.sum(layer.routed(p, x)[0]))(
                 p))(params, x)))
-    kernels = {"apex_moe_gmm", "apex_moe_tgmm"}
+    kernels, the_sum = {"apex_moe_gmm", "apex_moe_tgmm"}, {"apex_moe_rowsum"}
     assert names(_layer(256, 384), 256) == set()            # the CPU
     with dispatch.backend("pallas"):
-        assert names(_layer(256, 384), 256) == kernels
-        assert names(_layer(256, 192), 256) == set()        # ffn: no lanes
+        assert names(_layer(256, 384), 256) == kernels | the_sum
+        assert names(_layer(256, 192), 256) == the_sum      # ffn: no lanes
         assert names(_layer(192, 256), 192) == set()
 
         class SmallTiles(ExpertLayer):

@@ -443,8 +443,8 @@ def _site(fn, *args, **kw):
 def _kernel_sites() -> dict:
     """``{documented name: thunk tracing the wrapper that holds the site}``."""
     from apex_tpu.ops.pallas import (decode_attn as D, layer_norm as L,
-                                     sparse_index as I, welford as W,
-                                     xentropy as X)
+                                     row_sum as R, sparse_index as I,
+                                     welford as W, xentropy as X)
     n = 128 * 16
     buf, rows = _f32(n), _i32(n // 128)
     hp = dict(lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-8, step=1)
@@ -467,6 +467,9 @@ def _kernel_sites() -> dict:
                                _f32(1, 64, 128)),
         "apex_moe_gmm": _site(_moe_grad(), *moe),
         "apex_moe_tgmm": _site(_moe_grad(), *moe),
+        # a block of tokens' rows on two experts, of a buffer of 256
+        "apex_moe_rowsum": _site(R.sum_rows, _f32(256, 128),
+                                 _i32(R.BLOCK, 2)),
         "apex_gdn_fwd": _site(_gdn_grad(), *gdn),
         "apex_gdn_bwd": _site(_gdn_grad(), *gdn),
         "apex_mt_scale": _site(P.scale, buf, scale_factor=2.0),
