@@ -13,8 +13,12 @@ queries' head weights ``w [B, T, H]`` (float32, every scale folded in):
   lower key first (``jax.lax.top_k``'s order), as the packed set
   ``flash_attention(select=)`` reads (``pack_select``). No sort: the
   ``n``-th largest score of a query is found by bisection on the float's
-  bits (two bits a pass over the chunk's scores, a count a pass), then the
-  ties at that score by the same search over key positions.
+  bits (a count of the scores at or above a candidate a pass), then the
+  ties at that score by the same search over key positions. ``"fast"``:
+  the kernel ``apex_idx_search``, which reads a block of queries' scores
+  once, counts in VMEM a bit a pass over the keys the block can see, and
+  writes the packed words; ``"reference"``: :func:`topk_mask`, two bits
+  a pass over the chunk's scores in ``jax.numpy``, and ``pack_select``.
 - :func:`index_loss`: with ``p[t, s]`` the head-mean of the main
   attention's probabilities over ``S_t`` (made again from its queries,
   keys and saved log-sum-exp, never as ``[heads, T, T]``) and ``qi[t, :] =
@@ -30,8 +34,9 @@ queries' head weights ``w [B, T, H]`` (float32, every scale folded in):
 Both work a chunk of queries at a time against all keys: ``[chunk, T]``
 float32 arrays are the largest that stand in memory. ``impl="fast"``: the
 sums over heads are the Pallas kernels ``apex_idx_scores``,
-``apex_idx_probs`` and ``apex_idx_grad`` (``ops/pallas/sparse_index.py``;
-interpreted off the TPU); ``"reference"``: ``jax.numpy``, their oracle.
+``apex_idx_probs`` and ``apex_idx_grad`` and the search is
+``apex_idx_search`` (``ops/pallas/sparse_index.py``; interpreted off the
+TPU); ``"reference"``: ``jax.numpy``, their oracle.
 A ``jax.checkpoint`` whose policy saves ``SAVED_NAMES`` (the packed set,
 33.5 MB a row of 16,384, and the indexer's gradient) runs neither the
 search nor the loss again in its recomputed pass.
@@ -56,7 +61,7 @@ SAVED_NAMES = ("apex_idx_select", "apex_idx_grads")
 CHUNK = 1024            # queries a pass: five [CHUNK, T] float32 arrays
 _F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
-_RADIX = 2              # bits of the answer a counting pass settles
+_RADIX = 2              # bits of the answer a pass of topk_mask settles
 
 
 def _chunk(t: int, chunk) -> int:
@@ -189,6 +194,15 @@ def topk_mask(scores, n):
     return above | (ties & (key >= _kth_largest(key, left, bits)[..., None]))
 
 
+def _search(i, start, topk: int, impl: str):
+    """The packed sets of a chunk's scores ``i [B, c, T]``, query ``r`` at
+    position ``start + r``: its ``min(start + r + 1, topk)`` largest."""
+    if impl == "fast":
+        return _kernels.search(i, start, topk)
+    n = jnp.minimum(start + jnp.arange(i.shape[1]) + 1, topk)
+    return pack_select(topk_mask(i, jnp.broadcast_to(n, i.shape[:2])))
+
+
 def select_keys(qi, ki, w, topk: int, *, chunk=None, impl: str = "fast"):
     """The packed key sets ``int32 [B, T, 128 * ceil(T / 4096)]`` (a bit a
     key, ``key_set.pack_select``): query ``t``'s ``min(t + 1,
@@ -200,10 +214,9 @@ def select_keys(qi, ki, w, topk: int, *, chunk=None, impl: str = "fast"):
     qh, kh, wl = _operands(qi, ki, w)
 
     def one(start):
-        i = _scores(_rows(qh, start, c, 2), kh, _rows(wl, start, c, 1),
-                    start, impl)
-        n = jnp.minimum(start + jnp.arange(c) + 1, topk)
-        return pack_select(topk_mask(i, jnp.broadcast_to(n, i.shape[:2])))
+        return _search(_scores(_rows(qh, start, c, 2), kh,
+                               _rows(wl, start, c, 1), start, impl),
+                       start, topk, impl)
     words = jax.lax.map(one, jnp.arange(0, t, c))       # [chunks, B, c, W]
     words = words.transpose(1, 0, 2, 3).reshape(qi.shape[0], t, -1)
     return checkpoint_name(words, SAVED_NAMES[0])

@@ -358,9 +358,11 @@ NEW_OPS = [
      ("apex_gdn_fwd", "apex_gdn_bwd")),
     # Keye-VL 2.0's lightning indexer at the published shapes (16 heads of
     # 64 over one key head, a row of 16,384, the 2,048 best keys a query):
-    # the search a chunk of queries at a time, never [S, S] float32 (1 GB)
+    # the search a chunk of queries at a time, never [S, S] float32 (1 GB):
+    # a chunk's scores (67 MB) are the program's one temporary, the search
+    # holds a block of them in VMEM and writes the packed words
     ("sparse_index_select-B1S16384H16D64", lambda: _select_keys,
-     _indexer_args(16384), 0.5, ("apex_idx_scores",)),
+     _indexer_args(16384), 0.1, ("apex_idx_scores", "apex_idx_search")),
     ("sparse_index_loss_fwd_bwd-B1S16384H16D64", lambda: _index_loss,
      _indexer_args(16384) + [((1, 32, 16384, 128), BF16),
                              ((1, 4, 16384, 128), BF16),

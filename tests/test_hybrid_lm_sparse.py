@@ -203,9 +203,10 @@ def test_remat_keeps_the_set_and_the_indexers_gradient_by_name():
         lm.init(jax.random.key(0)), _tokens()))
     count = lambda name: len(re.findall(rf"name={name}\b", text))
     assert count("apex_idx_scores") == 2
-    assert [count(n) for n in ("apex_idx_probs", "apex_idx_grad",
-                               "apex_flash_sel_fwd", "apex_flash_sel_bwd_dq",
-                               "apex_flash_sel_bwd_dkv")] == [1] * 5
+    assert [count(n) for n in ("apex_idx_search", "apex_idx_probs",
+                               "apex_idx_grad", "apex_flash_sel_fwd",
+                               "apex_flash_sel_bwd_dq",
+                               "apex_flash_sel_bwd_dkv")] == [1] * 6
     every = str(jax.make_jaxpr(jax.grad(dataclasses.replace(
         lm, index_topk=10 ** 6).loss))(lm.init(jax.random.key(0)),
                                        _tokens()))

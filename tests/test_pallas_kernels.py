@@ -465,6 +465,9 @@ def _kernel_sites() -> dict:
                                 *idx),
         "apex_idx_grad": _site(lambda *a: I.grad(*a, 0), *idx,
                                _f32(1, 64, 128)),
+        # the 16 best of 128 keys for each of 64 queries
+        "apex_idx_search": _site(lambda i: I.search(i, 0, 16),
+                                 _f32(1, 64, 128)),
         "apex_moe_gmm": _site(_moe_grad(), *moe),
         "apex_moe_tgmm": _site(_moe_grad(), *moe),
         # a block of tokens' rows on two experts, of a buffer of 256

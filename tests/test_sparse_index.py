@@ -1,8 +1,9 @@
 """``ops.sparse_index``: the exact top-k without a sort against
 ``jax.lax.top_k`` (lengths around ``topk``, planted ties, queries with
-fewer keys than ``topk``), the indexer's loss and its gradient against
-autodiff of the plain formula, the kernels against their ``jax.numpy``
-oracle, and the two ``stop_gradient``s that part the gradients."""
+fewer keys than ``topk``), the search's kernel against both to the bit of
+a packed word, the indexer's loss and its gradient against autodiff of the
+plain formula, the kernels against their ``jax.numpy`` oracle, and the two
+``stop_gradient``s that part the gradients."""
 
 import importlib
 import re
@@ -13,6 +14,7 @@ import numpy as np
 import pytest
 
 from apex_tpu.ops import key_set as KS, sparse_index as SI
+from apex_tpu.ops.pallas import sparse_index as K
 
 fa = importlib.import_module(
     "apex_tpu.contrib.multihead_attn.flash_attention")
@@ -53,8 +55,9 @@ def test_the_selection_is_top_ks_at_lengths_around_topk(t, topk, impl):
     assert not bool(jnp.any(mask & (scores == -jnp.inf)))
 
 
+@pytest.mark.parametrize("impl", ["fast", "reference"])
 @pytest.mark.parametrize("levels", [2, 0.5, 0.0])
-def test_planted_ties_go_to_the_lower_key(levels):
+def test_planted_ties_go_to_the_lower_key(levels, impl):
     """Scores rounded to a few levels (at 0.0: every score equal, signed
     zeros among them) tie by the hundred: the choice is still exact and
     ``top_k``'s."""
@@ -67,13 +70,85 @@ def test_planted_ties_go_to_the_lower_key(levels):
                            raw)
         scores = jnp.where(scores == 0.0, 0.0, scores)  # as the ops do
     n = jnp.broadcast_to(jnp.minimum(jnp.arange(t) + 1, topk), (2, t))
-    got = SI.topk_mask(scores, n)
+    got = KS.unpack_select(SI._search(scores, 0, topk, impl), t)
     want = _by_top_k(scores, topk)
     np.testing.assert_array_equal(got, want)
     np.testing.assert_array_equal(got.sum(-1), n)
     if levels == 0.0:       # all equal: the first n keys
         np.testing.assert_array_equal(
             got[0, 100], np.arange(t) < 50)
+
+
+def _chunk_scores(b, c, t, start, key, above=-jnp.inf):
+    """A chunk's ``[b, c, t]`` scores, query ``r`` at position ``start +
+    r``; ``above`` in the key slabs wholly above a block's last query (the
+    rest of what a query cannot see is ``-inf``, as the ops leave it)."""
+    x = jax.random.normal(jax.random.key(key), (b, c, t))
+    rows, keys = start + jnp.arange(c)[:, None], jnp.arange(t)[None, :]
+    bq, bk = K.search_blocks(c, t + (-t) % 128)
+    last = start + (jnp.arange(c)[:, None] // bq + 1) * bq - 1
+    dead = keys // bk > last // bk
+    return jnp.where(keys <= rows, x, jnp.where(dead, above, -jnp.inf))
+
+
+# (queries, keys, the chunk's first position, topk)
+SEARCHES = {
+    "three_blocks_one_slab_of_three": (48, 1536, 0, 20),
+    "a_chunk_that_starts_at_1000": (48, 1536, 1000, 300),
+    "a_second_span_of_one_tile": (16, 4224, 4100, 2048),
+    "an_odd_chunk_keys_padded_fewer_than_topk": (65, 200, 100, 1000),
+    "one_key_a_query": (8, 256, 0, 1),
+}
+
+
+@pytest.mark.parametrize("c, t, start, topk", list(SEARCHES.values()),
+                         ids=list(SEARCHES))
+def test_the_search_kernel_packs_the_reference_choice(c, t, start, topk):
+    """``apex_idx_search`` gives ``pack_select(topk_mask(...))`` to the
+    bit, and ``jax.lax.top_k``'s keys; a key tile wholly above a block's
+    last query is not visited (whatever stands there, its words are
+    zeros)."""
+    scores = _chunk_scores(2, c, t, start, key=c + t)
+    want = SI._search(scores, start, topk, "reference")
+    got = K.search(_chunk_scores(2, c, t, start, key=c + t, above=1e9),
+                   start, topk)
+    assert got.shape == want.shape and got.dtype == jnp.int32
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(KS.unpack_select(got, t),
+                                  _by_top_k(scores, topk))
+
+
+@pytest.mark.parametrize("tied", [False, True])
+def test_a_block_with_no_tie_and_one_with_a_tie_in_one_row(tied):
+    """No two of a row's float32 scores at its threshold are equal: the
+    block takes every key at the threshold and searches no further. One row
+    with a second key at its threshold's score, at a lower position, takes
+    that one and leaves the other."""
+    c, t, start, topk = 16, 512, 300, 64
+    scores = _chunk_scores(1, c, t, start, key=11)
+    row = scores[0, 5]
+    at = int(jnp.argsort(-row)[topk - 1])           # the 64th largest
+    assert at > 0 and int(jnp.sum(row == row[at])) == 1
+    if tied:
+        lower = int(jnp.argmin(row[:at]))           # unchosen, visible
+        scores = scores.at[0, 5, lower].set(row[at])
+    words = K.search(scores, start, topk)
+    np.testing.assert_array_equal(
+        words, SI._search(scores, start, topk, "reference"))
+    got = KS.unpack_select(words, t)
+    np.testing.assert_array_equal(got, _by_top_k(scores, topk))
+    if tied:
+        assert bool(got[0, 5, lower]) and not bool(got[0, 5, at])
+
+
+def test_the_searchs_blocks_follow_the_shapes():
+    """128 queries at 16,384 keys (8 MB of float32 scores), fewer at a
+    longer row, a divisor of the chunk, an odd chunk whole."""
+    assert K.search_blocks(1024, 16384) == (128, 512)
+    assert K.search_blocks(1024, 32768) == (64, 512)
+    assert K.search_blocks(48, 1536) == (16, 512)
+    assert K.search_blocks(65, 256) == (65, 256)
+    assert K.search_blocks(16, 4224) == (16, 128)
 
 
 def test_the_kth_largest_by_bisection_on_the_bits():
