@@ -20,6 +20,11 @@ softmax_xentropy.py:9,26: masked_fill on labels==padding_idx). The
 reference defaults padding_idx=0, which silently drops class-0 rows —
 kept here for drop-in parity, but pass ``padding_idx=None`` (our
 extension) to disable masking.
+
+XLA runs this on every platform: on the v5e its fusion of the logsumexp and
+the recompute ran forward + backward ~1.2x faster than blocked kernels at 32k
+and 256k classes (docs/PERF.md r03; the saved logsumexp already gives the
+memory saving, a kernel added boundary cost and no fusion).
 """
 
 from __future__ import annotations
@@ -28,20 +33,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-
-def _use_pallas_xent(logits) -> bool:
-    # Measured on v5e (docs/PERF.md r03): XLA's fused logsumexp+recompute path
-    # runs the fwd+bwd ~1.2x faster than the blocked Pallas kernels at
-    # both 32k and 256k vocab (the lse-recompute custom_vjp already gives
-    # the memory saving; the kernel adds boundary cost, not fusion).
-    # Default to XLA; the kernels stay behind an explicit backend=pallas.
-    from apex_tpu.ops import dispatch
-    from apex_tpu.ops.pallas import xentropy as P
-    if dispatch.get_backend() != "pallas":
-        return False
-    v = logits.shape[-1]
-    return P.supported(logits.size // v, v)
 
 
 def select_label_logits(logits: jax.Array, labels: jax.Array) -> jax.Array:
@@ -59,12 +50,6 @@ def select_label_logits(logits: jax.Array, labels: jax.Array) -> jax.Array:
 
 
 def _fwd_math(logits, labels, smoothing):
-    if _use_pallas_xent(logits):
-        from apex_tpu.ops.pallas import xentropy as P
-        v = logits.shape[-1]
-        losses, lse = P.xent_fwd(logits.reshape(-1, v),
-                                 labels.reshape(-1), smoothing)
-        return (losses.reshape(labels.shape), lse.reshape(labels.shape))
     lf = logits.astype(jnp.float32)
     lse = jax.nn.logsumexp(lf, axis=-1)
     target = select_label_logits(lf, labels)
@@ -98,11 +83,6 @@ def _xent_bwd(smoothing, padding_idx, res, grad_loss):
     g = grad_loss.astype(jnp.float32)
     if padding_idx is not None:
         g = jnp.where(labels == padding_idx, 0.0, g)
-    if _use_pallas_xent(logits):
-        from apex_tpu.ops.pallas import xentropy as P
-        dx = P.xent_bwd(logits.reshape(-1, classes), labels.reshape(-1),
-                        lse.reshape(-1), g.reshape(-1), smoothing)
-        return dx.reshape(logits.shape), None
     # recompute softmax from saved logsumexp (the bprop epilogue,
     # xentropy_kernel.cu:445-493)
     probs = jnp.exp(logits.astype(jnp.float32) - lse[..., None])

@@ -17,14 +17,13 @@ the gamma/beta grads. Here the same structure is expressed as a
   the reference's hand-rolled warp-shuffle + shared-memory two-stage kernels
   (layer_norm_cuda_kernel.cu:403-637).
 
-The ``(n1, n2)`` flattening of ``normalized_shape`` follows
-layer_norm_cuda.cpp:7-27: the trailing ``len(normalized_shape)`` dims are
-the normalized axis; everything before is batch.
+As in layer_norm_cuda.cpp:7-27 the trailing ``len(normalized_shape)`` dims
+are the normalized axis; everything before is batch.
 
-A Pallas row-parallel kernel (``apex_tpu.ops.pallas``) can be swapped in
-through the dispatch layer; this jnp path is the numerics contract and the
-CPU fallback (the reference, by contrast, hard-requires the CUDA extension —
-fused_layer_norm.py:17-20 raises on import failure).
+There is one implementation, this one, on every platform: on the v5e XLA's
+fusion of it matched hand-written row kernels at F in {8192, 32768}
+(0.96-0.98x) and won 7x at F=1024 x 8192 rows (docs/PERF.md r03), so no
+kernel is kept beside it.
 """
 
 from __future__ import annotations
@@ -54,47 +53,8 @@ def _canon_shape(normalized_shape) -> tuple[int, ...]:
     return tuple(int(d) for d in normalized_shape)
 
 
-def _n1_n2(x_shape, normalized_shape):
-    """(n1, n2) flattening (reference layer_norm_cuda.cpp:7-27)."""
-    k = len(normalized_shape)
-    n2 = 1
-    for d in x_shape[len(x_shape) - k:]:
-        n2 *= d
-    n1 = 1
-    for d in x_shape[:len(x_shape) - k]:
-        n1 *= d
-    return n1, n2
-
-
-def _keepdims_shape(x_shape, normalized_shape):
-    k = len(normalized_shape)
-    return tuple(x_shape[:len(x_shape) - k]) + (1,) * k
-
-
-def _use_pallas_ln(x, normalized_shape) -> bool:
-    # Measured on v5e (docs/PERF.md r03): XLA's fused LN matches the Pallas
-    # kernels at F in {8192, 32768} (0.96-0.98x) and wins 7x at
-    # F=1024 x 8192 rows, so "auto" takes the XLA path; the kernels stay
-    # parity-tested behind an explicit backend="pallas".
-    from apex_tpu.ops import dispatch
-    from apex_tpu.ops.pallas import layer_norm as P
-    if dispatch.get_backend() != "pallas":
-        return False
-    n1, n2 = _n1_n2(x.shape, normalized_shape)
-    return P.supported(n1, n2)
-
-
 def _ln_fwd_math(x, weight, bias, normalized_shape, eps):
     axes = _norm_axes(x.shape, normalized_shape)
-    if _use_pallas_ln(x, normalized_shape):
-        from apex_tpu.ops.pallas import layer_norm as P
-        n1, n2 = _n1_n2(x.shape, normalized_shape)
-        y, mean, invvar = P.ln_fwd(
-            x.reshape(n1, n2),
-            None if weight is None else weight.astype(jnp.float32),
-            None if bias is None else bias.astype(jnp.float32), eps)
-        ks = _keepdims_shape(x.shape, normalized_shape)
-        return (y.reshape(x.shape), mean.reshape(ks), invvar.reshape(ks))
     xf = x.astype(jnp.float32)
     mean = jnp.mean(xf, axis=axes, keepdims=True)
     var = jnp.mean(jnp.square(xf - mean), axis=axes, keepdims=True)
@@ -127,17 +87,6 @@ def _ln_affine_bwd(normalized_shape, eps, res, dy):
     bias_dtype = bias.dtype
     axes = _norm_axes(x.shape, normalized_shape)
     batch_axes = tuple(range(len(x.shape) - len(normalized_shape)))
-
-    if _use_pallas_ln(x, normalized_shape):
-        from apex_tpu.ops.pallas import layer_norm as P
-        n1, n2 = _n1_n2(x.shape, normalized_shape)
-        dx, gw, gb = P.ln_bwd(
-            dy.reshape(n1, n2), x.reshape(n1, n2),
-            weight.astype(jnp.float32),
-            mean.reshape(n1), invvar.reshape(n1))
-        return (dx.reshape(x.shape),
-                gw.reshape(weight.shape).astype(weight.dtype),
-                gb.reshape(bias.shape).astype(bias_dtype))
 
     xf = x.astype(jnp.float32)
     dyf = dy.astype(jnp.float32)
@@ -176,12 +125,6 @@ def _ln_plain_fwd(x, normalized_shape, eps):
 def _ln_plain_bwd(normalized_shape, eps, res, dy):
     x, mean, invvar = res
     axes = _norm_axes(x.shape, normalized_shape)
-    if _use_pallas_ln(x, normalized_shape):
-        from apex_tpu.ops.pallas import layer_norm as P
-        n1, n2 = _n1_n2(x.shape, normalized_shape)
-        (dx,) = P.ln_bwd(dy.reshape(n1, n2), x.reshape(n1, n2), None,
-                         mean.reshape(n1), invvar.reshape(n1))
-        return (dx.reshape(x.shape),)
     xf = x.astype(jnp.float32)
     dyf = dy.astype(jnp.float32)
     xhat = (xf - mean) * invvar

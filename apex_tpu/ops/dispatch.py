@@ -1,12 +1,19 @@
-"""Backend dispatch — the single chokepoint for batched flat-buffer ops.
+"""Backend dispatch — the one rule for which implementation of an op runs.
 
 Plays the role of ``multi_tensor_applier`` in the reference
 (apex/multi_tensor_apply/multi_tensor_apply.py:3-34): every optimizer and the
 AMP scaler route their heavy ops through here. Instead of raising when the
-native extension is missing (reference: multi_tensor_apply.py:20-22), this
-layer selects between the Pallas kernels (TPU) and the pure-jnp reference
-implementations (CPU / interpret / cross-check), keeping both paths
-numerically interchangeable.
+native extension is missing (reference: multi_tensor_apply.py:20-22), an op
+with two sides takes its Pallas kernel where :func:`use_pallas` holds (the
+platform is a TPU) AND the kernel's own module accepts the shapes
+(``supported(...)`` / ``takes(...)``), and the pure-jnp implementation
+everywhere else; the two stay numerically interchangeable. An op whose kernel
+lost to XLA on the chip has one side, XLA's, and no switch reaches another.
+
+The backend is a seam, not a feature: ``"pallas"`` runs the kernels off the
+TPU (interpreted; the tests' way to reach them on the CPU), ``"reference"``
+takes every kernel out (the oracle ``chip_smoke.py`` compares against on the
+chip, and an operator's way to rule the kernels out of a fault).
 """
 
 from __future__ import annotations
@@ -19,7 +26,7 @@ import jax
 
 _VALID = ("auto", "reference", "pallas")
 
-# "auto": pallas on TPU, reference elsewhere. Overridable for tests/benchmarks.
+# "auto": the kernels on a TPU, jnp elsewhere.
 _backend = os.environ.get("APEX_TPU_BACKEND", "auto")
 if _backend not in _VALID:
     raise ValueError(
@@ -61,19 +68,13 @@ def use_pallas() -> bool:
     return _default_platform() == "tpu"
 
 
-def resolve(reference_fn, pallas_fn):
-    """Return the active implementation for an op pair."""
-    if pallas_fn is not None and use_pallas():
-        return pallas_fn
-    return reference_fn
-
-
 def resolve_crossover(reference_fn, pallas_fn, size: int, min_size: int):
-    """:func:`resolve` with a measured crossover gate: route to the
-    Pallas kernel only past ``min_size`` (flash_attention's
-    ``S >= flash_min_s`` rule generalized — below the crossover XLA's
-    composed program is the faster one even on TPU, docs/PERF.md r04).
-    ``size`` is whatever dimension the kernel's win scales with."""
+    """The active implementation of an op pair behind a measured crossover:
+    the Pallas kernel only where :func:`use_pallas` holds and ``size`` is
+    past ``min_size`` (flash_attention's ``S >= flash_min_s`` rule
+    generalized — below the crossover XLA's composed program is the faster
+    one even on TPU, docs/PERF.md r04). ``size`` is whatever dimension the
+    kernel's win scales with."""
     if pallas_fn is not None and use_pallas() and size >= min_size:
         return pallas_fn
     return reference_fn

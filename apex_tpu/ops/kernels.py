@@ -103,13 +103,9 @@ def novograd_step(g, p, m, v_norms, segment_ids, *,
 
 def lamb_step(g, p, m, v, segment_ids, num_segments, *,
               aligned_segments: bool = False, **kw):
-    # Measured on v5e (docs/PERF.md r03): XLA fuses the whole two-phase LAMB
-    # into ~2 sweeps (4.3 ms for 25.6M params) while the Pallas composition
-    # pays per-kernel boundaries and skinny per-row norm outputs (7.5-21
-    # ms). "auto" therefore takes the aligned XLA path; the Pallas kernel
-    # remains behind an explicit backend="pallas" (parity-tested).
-    if aligned_segments and dispatch.get_backend() == "pallas" \
-            and P.supported(g, p, m, v):
-        return P.lamb_step(g, p, m, v, segment_ids, num_segments, **kw)
+    # LAMB has one side, XLA's, on every platform: on the v5e XLA fuses the
+    # two phases into ~2 sweeps (4.3 ms for 25.6M params) where a two-kernel
+    # composition paid per-kernel boundaries and skinny per-row norm outputs
+    # (7.5-21 ms, docs/PERF.md r03; 0.67x, r05).
     return R.lamb_step(g, p, m, v, segment_ids, num_segments,
                        aligned=aligned_segments, **kw)

@@ -26,24 +26,3 @@ def vma(*arrays) -> frozenset:
 
 def round_up(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
-
-
-# Total VMEM working set across the streamed (rows, F) operands of a kernel;
-# x2 for double-buffering stays under the ~16 MiB/core budget.
-BLOCK_BUDGET_BYTES = 6 << 20
-
-
-def block_rows(n: int, row_elems: int, streams: int,
-               max_rows: int = 256) -> int:
-    """Rows per grid block so that ``streams`` fp32 (rows, row_elems)
-    operands together fit BLOCK_BUDGET_BYTES (multiple of 8 sublanes)."""
-    budget = max(8, (BLOCK_BUDGET_BYTES // 4) // row_elems // streams
-                 // 8 * 8)
-    return min(max_rows, budget, round_up(n, 8))
-
-
-def pad2d(a, rpad: int, fpad: int):
-    import jax.numpy as jnp
-    if rpad or fpad:
-        return jnp.pad(a, ((0, rpad), (0, fpad)))
-    return a

@@ -515,112 +515,3 @@ def novograd_step(g, p, m, v_norms, segment_ids, *, lr, beta1, beta2, eps,
     from apex_tpu.ops.reference import keep_old
     return (po.reshape(p.shape), mo.reshape(m.shape),
             keep_old(skip, v_norms, v_new))
-
-
-def _lamb_phase1_kernel(mode, grad_averaging, skip_at, s_ref, g_ref, p_ref,
-                        m_ref, v_ref, uo_ref, mo_ref, vo_ref, prow_ref,
-                        urow_ref):
-    # omb1/omb2 precomputed host-side in float64 (see _adam_kernel)
-    b1, b2, eps, bc1, bc2, wd, clip, omb1, omb2 = (
-        s_ref[0, k] for k in range(9))
-    skip = _skip_flag(s_ref, skip_at)
-    gf = g_ref[...].astype(jnp.float32) / clip
-    pf = p_ref[...].astype(jnp.float32)
-    mf = m_ref[...].astype(jnp.float32)
-    vf = v_ref[...].astype(jnp.float32)
-    beta3 = omb1 if grad_averaging else 1.0
-    if mode == 0:
-        gf = gf + wd * pf
-    mf = b1 * mf + beta3 * gf
-    vf = b2 * vf + omb2 * gf * gf
-    update = (mf / bc1) / (jnp.sqrt(vf / bc2) + eps)
-    if mode == 1:
-        update = update + wd * pf
-    uo_ref[...] = update
-    _store(mo_ref, m_ref, mf, skip)
-    _store(vo_ref, v_ref, vf, skip)
-    # per-row sumsq of p and u ride along (p and u are already in VMEM) so
-    # the per-tensor norms cost no extra sweep over HBM — the reference
-    # pays two more multi_tensor_l2norm launches here
-    # (multi_tensor_lamb.cu:370,394)
-    prow_ref[...] = jnp.sum(pf * pf, axis=1, keepdims=True)
-    urow_ref[...] = jnp.sum(update * update, axis=1, keepdims=True)
-
-
-def _lamb_phase2_kernel(*refs):
-    *s_ref, r_ref, p_ref, u_ref, po_ref = refs  # s_ref: the skip flag, if any
-    pf = p_ref[...].astype(jnp.float32)
-    _store(po_ref, p_ref, pf - r_ref[...] * u_ref[...],
-           _skip_flag(s_ref[0], 0) if s_ref else None)
-
-
-def lamb_step(g, p, m, v, segment_ids, num_segments, *, lr, beta1, beta2,
-              eps, step, bias_correction=True, weight_decay=0.0,
-              grad_averaging=True, mode=0, global_grad_norm,
-              max_grad_norm=0.0, use_nvlamb=False, skip=None):
-    """Two-phase LAMB (reference: multi_tensor_lamb.cu:40-413): phase 1
-    writes the Adam-style update term (the reference overwrites the grad
-    buffer, :332-391; here it is a temporary of its own, since the caller's
-    gradient need be neither fp32 nor dead) and m, v in place; per-tensor
-    param/update norms are row passes + segment sums (:370,394); phase 2
-    applies the trust ratio to p in place (:234-329)."""
-    stepf = _f32(step)
-    if bias_correction:
-        bc1 = 1.0 - jnp.power(_f32(beta1), stepf)
-        bc2 = 1.0 - jnp.power(_f32(beta2), stepf)
-    else:
-        bc1 = bc2 = _f32(1.0)
-    gg = _f32(global_grad_norm)
-    if max_grad_norm > 0:
-        clip = jnp.where(gg > max_grad_norm, gg / max_grad_norm, 1.0)
-    else:
-        clip = _f32(1.0)
-
-    g2, p2, m2, v2 = _rows(g), _rows(p), _rows(m), _rows(v)
-    nrows = p2.shape[0]
-    scalars, s_spec, skip_at = _step_scalars(
-        beta1, beta2, eps, bc1, bc2, weight_decay, clip, 1.0 - beta1,
-        1.0 - beta2, skip=skip)
-    u2, mo, vo, prow, urow = pl.pallas_call(
-        functools.partial(_lamb_phase1_kernel, mode, bool(grad_averaging),
-                          skip_at),
-        grid=_grid(nrows),
-        in_specs=[s_spec] + [_row_spec()] * 4,
-        out_specs=[_row_spec()] * 3 + [_col_spec()] * 2,
-        out_shape=[jax.ShapeDtypeStruct(p2.shape, jnp.float32),
-                   jax.ShapeDtypeStruct(m2.shape, m.dtype),
-                   jax.ShapeDtypeStruct(v2.shape, v.dtype),
-                   jax.ShapeDtypeStruct((nrows, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((nrows, 1), jnp.float32)],
-        input_output_aliases={3: 1, 4: 2},
-        interpret=interpret_mode(),
-        name="apex_mt_lamb_stage1",
-    )(scalars, g2, p2, m2, v2)
-
-    row_ids = row_segment_ids(segment_ids)
-    from apex_tpu.ops.reference import segment_sum_dense
-    param_norms = jnp.sqrt(segment_sum_dense(prow[:, 0], row_ids,
-                                             num_segments))
-    update_norms = jnp.sqrt(segment_sum_dense(urow[:, 0], row_ids,
-                                              num_segments))
-    lrf = _f32(lr)
-    if use_nvlamb or weight_decay != 0.0:
-        ratio = jnp.where((update_norms != 0.0) & (param_norms != 0.0),
-                          lrf * (param_norms / update_norms), lrf)
-    else:
-        ratio = jnp.full((num_segments,), lrf, jnp.float32)
-    row_ratio = ratio[row_ids][:, None]
-
-    flag = () if skip is None else (_scalars(skip),)
-    po = pl.pallas_call(
-        _lamb_phase2_kernel,
-        grid=_grid(nrows),
-        in_specs=[_smem_spec(1)] * len(flag)
-        + [_col_spec(), _row_spec(), _row_spec()],
-        out_specs=_row_spec(),
-        out_shape=jax.ShapeDtypeStruct(p2.shape, p.dtype),
-        input_output_aliases={len(flag) + 1: 0},
-        interpret=interpret_mode(),
-        name="apex_mt_lamb_stage2",
-    )(*flag, row_ratio, p2, u2)
-    return po.reshape(p.shape), mo.reshape(m.shape), vo.reshape(v.shape)

@@ -34,6 +34,12 @@ through the same custom_vjp.
 
 ``axis_name=None`` degrades to plain (single-replica) BatchNorm, the
 equivalent of running the reference module outside DDP.
+
+XLA runs the reductions on every platform: on the v5e RN50's 53 BNs cost
+~16 ms a step this way against ~150 ms through hand-written welford kernels
+(docs/PERF.md r03: a kernel boundary sends the activation through HBM once a
+call and pays grid overhead 53 times, where XLA folds the reductions into
+the adjacent convolutions' epilogues), so no kernel is kept beside them.
 """
 
 from __future__ import annotations
@@ -51,22 +57,14 @@ from apex_tpu.parallel.collectives import (grouped_psum as _psum,
 
 def _sum_pair(a, b, axes):
     """Sum two same-shape fp32 operands over ``axes`` as two plain
-    jnp.sums. A single variadic lax.reduce looked better in the CPU
-    compile audit (one fused input chain, no materialized fp32 upcast)
-    but LOST 14% whole-step on chip: 1868 vs 2169 img/s at batch 384
-    (BENCH_r05_builder.json vs BENCH_r05_bn_split.json) — the TPU
-    emitter handles a pair of fused reductions better than one variadic
-    reduce. Same measured-demotion story as welford. The variadic shape
-    stays available under APEX_BN_VARIADIC_REDUCE=1 for future re-A/B;
-    any other value (including "0") selects split."""
-    import os
-    if os.environ.get("APEX_BN_VARIADIC_REDUCE") == "1":
-        zero = jnp.asarray(0.0, jnp.float32)
-
-        def comp(acc, val):
-            return (acc[0] + val[0], acc[1] + val[1])
-
-        return jax.lax.reduce((a, b), (zero, zero), comp, tuple(axes))
+    jnp.sums. Two shapes that read better in a compile audit were
+    measured on the chip at RN50 batch 384 and lost whole-step: one
+    variadic ``lax.reduce`` over the pair (one fused input chain, no
+    materialized fp32 upcast) 1868 img/s against 2172
+    (BENCH_r05_builder.json vs BENCH_r05_bn_split.json), and moments from
+    the raw storage dtype with ``sum(x*x)`` as an MXU self-contraction
+    1749 (r05). The TPU emitter wants the pair of fused reductions
+    (docs/PERF.md keeps the readings)."""
     return jnp.sum(a, axis=tuple(axes)), jnp.sum(b, axis=tuple(axes))
 
 
@@ -85,47 +83,11 @@ def _folded_upcast() -> bool:
     materializing it (the r05b trace still carries 60 ms/capture of
     standalone jvp converts; prof.gaps attributes the seams). Numerics:
     identical for fp32 inputs; for bf16 the x^2 rounds to bf16 before
-    accumulation (relative 2^-8 per element — same tolerance class as
-    the MXU-moments rewrite, pinned by the parity test). UNMEASURED on
-    chip: stays opt-in until a window A/B decides it (docs/PERF.md r06 has
-    the arm commands)."""
+    accumulation (relative 2^-8 per element, pinned by the parity
+    test). UNMEASURED on chip: stays opt-in until a window A/B decides
+    it (docs/PERF.md r06 has the arm commands)."""
     import os
     return os.environ.get("APEX_BN_FOLDED_UPCAST") == "1"
-
-
-def _mxu_moments() -> bool:
-    """Opt-in no-materialized-upcast moments shape (on-chip A/B knob).
-
-    The split-sums default upcasts x to fp32 with TWO consumers (sum,
-    x*x), and XLA materializes the fp32 copy of every activation as a
-    standalone convert pass (r4 trace: 12.7 ms/step across the 53 BNs).
-    Under APEX_BN_MXU_MOMENTS=1 the moments read RAW storage-dtype x:
-    sum(x) as a reduce with fp32 accumulator, sum(x^2) as an
-    x-contract-x einsum riding the MXU — bf16*bf16 products are exact
-    in fp32, so numerics match the upcast shape to reduction order
-    (pinned in tests/test_parallel.py). MEASURED AND DEMOTED: 1749
-    img/s vs split-sums' 2172 at RN50 batch 384 (-19%, 09:53 UTC r5) —
-    the batched vector-dot contraction lowers worse than the convert
-    pass it removes. Third data point that the TPU emitter wants the
-    plain two-reduction shape: split 2172 > variadic 1868 > MXU 1749.
-    Kept as the documented dead end so nobody re-derives it."""
-    import os
-    return os.environ.get("APEX_BN_MXU_MOMENTS") == "1"
-
-
-def _mxu_contract(a, b, ndim, ca):
-    """sum over all axes but ``ca`` of a*b as one dot, fp32 accumulate.
-    precision=HIGHEST: fp32 operands must not be truncated to bf16 on
-    the MXU (the default TPU precision would break the documented
-    parity with the split-sums path for fp32 activations; bf16 inputs
-    are unaffected — their products are exact in fp32 at any setting).
-    ndim <= 7 covers every BN layout (the letter pool guards it)."""
-    letters = "abcdefg"
-    if ndim > len(letters):
-        raise ValueError(f"BN input rank {ndim} > {len(letters)}")
-    spec = f"{letters[:ndim]},{letters[:ndim]}->{letters[ca]}"
-    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32,
-                      precision=jax.lax.Precision.HIGHEST)
 
 
 def _reduce_axes(ndim: int, channel_axis: int) -> tuple[int, ...]:
@@ -140,27 +102,6 @@ def _bcast_shape(ndim: int, channel_axis: int, c: int) -> tuple[int, ...]:
 
 # -- training-mode core with hand-written VJP --------------------------------
 
-def _use_pallas_bn(x, channel_axis) -> bool:
-    from apex_tpu.ops import dispatch
-    if dispatch.get_backend() != "pallas":
-        # "auto" lets XLA fuse the BN reductions. Measured head-to-head on
-        # a v5e chip (docs/PERF.md r03): RN50's 53 BNs cost ~16 ms/step this way
-        # vs ~150 ms through the Pallas welford kernels — the kernel
-        # boundary forces the activation through HBM per call and pays
-        # per-grid-step overhead 53x, while XLA folds the reductions into
-        # the adjacent convolution epilogues. The kernels stay available
-        # behind an explicit dispatch backend="pallas" (the same opt-in as
-        # LN/xentropy/LAMB) as the welford.cu study path; "demoted to the
-        # jnp path by default — honesty over pride".
-        return False
-    from apex_tpu.ops.pallas import welford as P
-    ndim = x.ndim
-    if channel_axis % ndim != ndim - 1:  # kernels are channels-last
-        return False
-    c = x.shape[-1]
-    return P.supported(x.size // c, c)
-
-
 def _bn_train_fwd_math(x, z, weight, bias, eps, axis_name, groups,
                        fuse_relu, channel_axis):
     ndim = x.ndim
@@ -172,17 +113,7 @@ def _bn_train_fwd_math(x, z, weight, bias, eps, axis_name, groups,
     local_count = jnp.asarray(
         jnp.prod(jnp.asarray([x.shape[i] for i in axes])), jnp.float32)
     count = _psum(local_count, axis_name, groups)
-    if _use_pallas_bn(x, channel_axis):
-        # Pallas welford moments (welford.cu:885's local pass); cross-chip
-        # merge stays a psum of raw moments.
-        from apex_tpu.ops.pallas import welford as P
-        lsum, lsq = P.bn_moments(x.reshape(-1, c))
-    elif _mxu_moments():
-        # no-materialized-upcast shape: raw x feeds an fp32-accumulated
-        # reduce and an MXU self-contraction (see _mxu_moments)
-        lsum = jnp.sum(x, axis=axes, dtype=jnp.float32)
-        lsq = _mxu_contract(x, x, ndim, ca)
-    elif _folded_upcast():
+    if _folded_upcast():
         # per-reduction single-consumer upcasts (see _folded_upcast):
         # the square happens in storage dtype so each reduce owns its
         # whole input chain — no shared fp32 activation copy to
@@ -190,10 +121,9 @@ def _bn_train_fwd_math(x, z, weight, bias, eps, axis_name, groups,
         lsum = jnp.sum(x, axis=axes, dtype=jnp.float32)
         lsq = jnp.sum(jnp.square(x), axis=axes, dtype=jnp.float32)
     else:
-        # (sum, sum-of-squares) via _sum_pair — two plain fused
-        # reductions by default; the variadic-reduce alternative lost
-        # 14% whole-step on chip (see _sum_pair's measured-demotion
-        # note before "re-fixing" the shared-upcast shape here).
+        # (sum, sum-of-squares) via _sum_pair: two plain fused
+        # reductions (see its note on the measured losers before
+        # "re-fixing" the shared-upcast shape here).
         lsum, lsq = _sum2(x.astype(jnp.float32), axes)
     mean = _psum(lsum, axis_name, groups) / count
     mean_sq = _psum(lsq, axis_name, groups) / count
@@ -261,51 +191,17 @@ def _bn_train_bwd_out(eps, axis_name, groups, fuse_relu, channel_axis, res,
     ca = channel_axis % ndim
     axes = _reduce_axes(ndim, ca)
     bshape = _bcast_shape(ndim, ca, x.shape[ca])
-    use_pallas = _use_pallas_bn(x, channel_axis)
 
     # reduce_bn partial sums (welford.cu:325: per-channel sum_dy,
     # sum_dy_xmu -> grad_weight, grad_bias) + the two allreduces
     # (kernel.py:95-101), then the batchnorm_backward elementwise dx
-    # (welford.cu:387). The Pallas path streams x/dy in their storage
-    # dtype and recomputes xhat in-kernel — materializing fp32 xhat/masked
-    # dy around a kernel boundary was the dominant cost of the whole RN50
-    # step (~150 ms/step at batch 256; see docs/PERF.md r03).
-    if use_pallas:
-        from apex_tpu.ops.pallas import welford as P
-        c = x.shape[ca]
-        dy2, x2 = dy.reshape(-1, c), x.reshape(-1, c)
-        out2 = out.reshape(-1, c) if fuse_relu else None
-        sum_dy_local, sum_dy_xhat_local = P.bn_backward_fused_reduce(
-            dy2, x2, mean, invvar, out2)
-    elif _mxu_moments():
-        # no-materialized-upcast shape (see _mxu_moments): raw-dtype
-        # dy/x feed the reductions — sum(dy) with an fp32 accumulator,
-        # sum(dy*x) as an MXU contraction — and sum(dy*xhat) follows
-        # algebraically: (sum(dy*x) - mean*sum(dy)) * invvar. bf16*bf16
-        # products are exact in fp32; the subtraction is conditioned
-        # like the fwd's E[x^2]-E[x]^2 variance (same mean-offset
-        # cancellation class, pinned by the parity test).
-        dym = dy
-        if fuse_relu:
-            dym = jnp.where(out > 0, dym, jnp.zeros((), dym.dtype))
-        sum_dy_local = jnp.sum(dym, axis=axes, dtype=jnp.float32)
-        sum_dy_x = _mxu_contract(dym, x, ndim, ca)
-        sum_dy_xhat_local = (sum_dy_x - mean * sum_dy_local) * invvar
-        # the dx chain below reads these; each upcast is single-consumer
-        # elementwise there, so it fuses instead of materializing
-        dyf = dym.astype(jnp.float32)
-        xhat = ((x.astype(jnp.float32) - mean.reshape(bshape))
-                * invvar.reshape(bshape))
-    else:
-        dyf = dy.astype(jnp.float32)
-        if fuse_relu:
-            dyf = jnp.where(out > 0, dyf, 0.0)
-        xf = x.astype(jnp.float32)
-        xhat = (xf - mean.reshape(bshape)) * invvar.reshape(bshape)
-        # (sum_dy, sum_dy_xhat) via _sum_pair — split-sums default; see
-        # _sum_pair's measured-demotion note for why not one variadic
-        # reduce
-        sum_dy_local, sum_dy_xhat_local = _sum_pair(dyf, dyf * xhat, axes)
+    # (welford.cu:387).
+    dyf = dy.astype(jnp.float32)
+    if fuse_relu:
+        dyf = jnp.where(out > 0, dyf, 0.0)
+    xf = x.astype(jnp.float32)
+    xhat = (xf - mean.reshape(bshape)) * invvar.reshape(bshape)
+    sum_dy_local, sum_dy_xhat_local = _sum_pair(dyf, dyf * xhat, axes)
     # Param cotangents must match the primal's device-variance (jax vma
     # rules): a replicated weight gets globally-summed grads, so the psum
     # the reference leaves to DDP happens here, inside the vjp.
@@ -336,18 +232,10 @@ def _bn_train_bwd_out(eps, axis_name, groups, fuse_relu, channel_axis, res,
 
     wvec = (weight.astype(jnp.float32) if weight is not None
             else jnp.ones_like(invvar))
-    if use_pallas:
-        from apex_tpu.ops.pallas import welford as P
-        dx2, dz2 = P.bn_backward_dx(
-            dy2, x2, mean, invvar, invvar * wvec, mean_dy, mean_dy_xhat,
-            out2, emit_dz=has_z)
-        dx = dx2.reshape(x.shape)
-        dz = dz2.reshape(x.shape) if has_z else None
-    else:
-        dz = dyf.astype(x.dtype) if has_z else None
-        dx = ((invvar * wvec).reshape(bshape) *
-              (dyf - mean_dy.reshape(bshape)
-               - xhat * mean_dy_xhat.reshape(bshape))).astype(x.dtype)
+    dz = dyf.astype(x.dtype) if has_z else None
+    dx = ((invvar * wvec).reshape(bshape) *
+          (dyf - mean_dy.reshape(bshape)
+           - xhat * mean_dy_xhat.reshape(bshape))).astype(x.dtype)
     return dx, dz, grad_weight, grad_bias
 
 
@@ -467,9 +355,7 @@ class SyncBatchNorm:
             return out, state
 
         # The group stats come out of the SAME custom_vjp call that
-        # normalized (no second moments pass — through round 2 this
-        # recomputed _bn_train_fwd_math and relied on XLA CSE, which cannot
-        # merge Pallas kernel calls, so every BN paid its stats twice).
+        # normalized (no second moments pass).
         # stop_gradient: running stats are buffers, never differentiated.
         # Unbiased var for running_var (kernel.py:47-50: var*count/(count-1)).
         mean = jax.lax.stop_gradient(mean)

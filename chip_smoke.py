@@ -59,11 +59,8 @@ FULL = dict(
                rate=16.0, system_prompt=256, prompt_dist="uniform:16,96",
                new_dist="uniform:32,64", compare=4, compare_tokens=32),
     # kernel-family shapes: what the phases above trace
-    kernels=dict(flat=128 * 1024 * 1024, bn_rows=384 * 28 * 28, bn_c=512,
-                 ln_rows=8 * 4096, ln_f=1024, ln_wide_rows=520,
-                 ln_wide_f=16384, flash=(1, 8, 4096, 128),
-                 xent_rows=4096, decode_slots=32, decode_len=2048,
-                 page=32),
+    kernels=dict(flat=128 * 1024 * 1024, flash=(1, 8, 4096, 128),
+                 head_rows=4096, decode_slots=32, decode_len=2048, page=32),
 )
 TINY = dict(
     vocab=512, dim=128, heads=4, layers=1, seq=128, batch=4,
@@ -72,10 +69,8 @@ TINY = dict(
     serve=dict(slots=2, max_len=64, page=8, chunk=8, requests=16,
                rate=64.0, system_prompt=16, prompt_dist="uniform:4,12",
                new_dist="uniform:8,16", compare=2, compare_tokens=8),
-    kernels=dict(flat=128 * 64, bn_rows=200, bn_c=128, ln_rows=64,
-                 ln_f=128, ln_wide_rows=16, ln_wide_f=16384,
-                 flash=(1, 2, 256, 64), xent_rows=16, decode_slots=2,
-                 decode_len=256, page=32),
+    kernels=dict(flat=128 * 64, flash=(1, 2, 256, 64), head_rows=16,
+                 decode_slots=2, decode_len=256, page=32),
 )
 
 
@@ -165,13 +160,12 @@ class Smoke:
 
     # -- kernels -----------------------------------------------------------
     def kernels(self):
-        """Every Pallas family, COMPILED (interpret off,
-        ``tpu_custom_call`` in the lowered text) and compared with its
-        jnp reference at the widths the later phases trace. The kernel
-        side runs under ``backend("pallas")``: "auto" takes the flash,
-        decode and multi-tensor kernels on the chip but keeps XLA's
-        fusion for LayerNorm and xentropy (r03, docs/PERF.md), and
-        those kernels must keep compiling too."""
+        """The multi-tensor, flash and decode kernels, COMPILED (interpret
+        off, ``tpu_custom_call`` in the lowered text) and compared with
+        their jnp reference at the widths the later phases trace. On the
+        chip the kernel side runs under "auto": the platform and the
+        shapes pick the kernel, as in every later phase. A rehearsal
+        forces it (``backend("pallas")``, interpreted)."""
         import jax
         import jax.numpy as jnp
         import numpy as np
@@ -182,15 +176,14 @@ class Smoke:
             reference_slot_decode_attention, slot_decode_attention)
         from apex_tpu.contrib.xentropy import (linear_cross_entropy,
                                                softmax_cross_entropy_loss)
-        from apex_tpu.normalization import fused_layer_norm_affine
         from apex_tpu.ops import dispatch, kernels as K
-        from apex_tpu.ops.pallas import welford
         from apex_tpu.ops.pallas._common import interpret_mode
 
         k = self.cfg["kernels"]
         if self.on_tpu and (interpret_mode() or not dispatch.use_pallas()):
             raise AssertionError("on the chip but the dispatch chose "
                                  "interpret mode / the jnp reference")
+        kernel_side = "auto" if self.on_tpu else "pallas"
         key = iter(jax.random.split(jax.random.key(self.args.seed), 64))
         checked = []
 
@@ -203,7 +196,7 @@ class Smoke:
         def case(name, kernel_fn, ref_fn, args, tol):
             """tol: max |kernel - reference| per output, relative to
             the reference's own max magnitude (floor 1)."""
-            lowered = jax.jit(under("pallas", kernel_fn)).lower(*args)
+            lowered = jax.jit(under(kernel_side, kernel_fn)).lower(*args)
             compiled_kernel = "tpu_custom_call" in lowered.as_text()
             if self.on_tpu and not compiled_kernel:
                 raise AssertionError(f"{name}: no tpu_custom_call in the "
@@ -240,39 +233,6 @@ class Smoke:
             g, p, jnp.zeros_like(p), jnp.zeros_like(p), lr=1e-3,
             beta1=0.9, beta2=0.999, eps=1e-8, step=1)), (g, x), 1e-5)
         del x, g
-
-        # BN welford moments + backward reduce (kernel vs plain sums)
-        xb = jax.random.normal(next(key), (k["bn_rows"], k["bn_c"]),
-                               jnp.bfloat16)
-        dy = jax.random.normal(next(key), (k["bn_rows"], k["bn_c"]),
-                               jnp.float32)
-        case("bn_moments", welford.bn_moments,
-             lambda x: (jnp.sum(x.astype(jnp.float32), 0),
-                        jnp.sum(x.astype(jnp.float32) ** 2, 0)),
-             (xb,), 1e-3)
-        case("bn_backward_reduce", welford.bn_backward_reduce,
-             lambda dy, x: (jnp.sum(dy, 0),
-                            jnp.sum(dy * x.astype(jnp.float32), 0)),
-             (dy, xb.astype(jnp.float32)), 1e-3)
-        del xb, dy
-
-        # LayerNorm fwd+bwd: the LM's F, and the wide two-stage path
-        # (520 rows: both backward grid dims > 1)
-        for tag, rows, f in (("ln", k["ln_rows"], k["ln_f"]),
-                             ("ln_wide", k["ln_wide_rows"],
-                              k["ln_wide_f"])):
-            xl = jax.random.normal(next(key), (rows, f), jnp.float32)
-            w = jnp.full((f,), 1.1, jnp.float32)
-            b = jnp.zeros((f,), jnp.float32)
-
-            def ln_loss(x, w, b, f=f):
-                return jnp.sum(fused_layer_norm_affine(x, (f,), w, b) ** 2)
-            case(f"{tag}_fwd_F{f}", *same(
-                lambda x, w, b, f=f: fused_layer_norm_affine(
-                    x, (f,), w, b)), (xl, w, b), 1e-4)
-            case(f"{tag}_bwd_F{f}", *same(jax.grad(
-                ln_loss, argnums=(0, 1, 2))), (xl, w, b), 1e-3)
-            del xl
 
         # flash attention fwd + bwd at the LM's head shape, then the
         # kv_bias and in-kernel dropout variants at short S
@@ -313,20 +273,10 @@ class Smoke:
              (qf, kf, vf), 0.05)
         del q, kk, v, qf, kf, vf
 
-        # fused xentropy fwd+bwd at the LM's vocab, then the chunked
-        # fused LM head against materialized logits
+        # the chunked fused LM head against materialized logits
         vocab = self.cfg["vocab"]
-        rows = k["xent_rows"]
-        logits = jax.random.normal(next(key), (rows, vocab), jnp.bfloat16)
+        rows = k["head_rows"]
         labels = jax.random.randint(next(key), (rows,), 0, vocab)
-
-        def xent(l):
-            return softmax_cross_entropy_loss(
-                l, labels, padding_idx=None, half_to_float=True)
-        case(f"xentropy_fwd_V{vocab}", *same(xent), (logits,), 1e-3)
-        case(f"xentropy_bwd_V{vocab}",
-             *same(jax.grad(lambda l: jnp.sum(xent(l)))), (logits,), 0.02)
-        del logits
         dim = self.cfg["dim"]
         hid = jax.random.normal(next(key), (rows, dim), jnp.bfloat16)
         wte = jax.random.normal(next(key), (vocab, dim),
@@ -342,7 +292,7 @@ class Smoke:
                 padding_idx=None))
         # a jnp scan on both sides (the head matmul rides the MXU, no
         # Pallas kernel) — compared, not asserted compiled
-        o = jax.jit(under("pallas", jax.value_and_grad(
+        o = jax.jit(under(kernel_side, jax.value_and_grad(
             head_fused, argnums=(0, 1))))(hid, wte)
         r = jax.jit(under("reference", jax.value_and_grad(
             head_plain, argnums=(0, 1))))(hid, wte)

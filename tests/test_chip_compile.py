@@ -103,44 +103,12 @@ def _paged_args(s, h, page, pages, hd):
             ((s, pages), I32)]
 
 
-def _forced_pallas(fn):
-    """LayerNorm and xentropy take their kernels only under an explicit
-    ``backend("pallas")`` ("auto" keeps XLA's fusion, which measured
-    faster on v5e in r03 — docs/PERF.md)."""
-    from apex_tpu.ops import dispatch
-
-    def traced(*args):
-        with dispatch.backend("pallas"):
-            return fn(*args)
-    return traced
-
-
-def _layer_norm(f):
-    from apex_tpu.normalization import fused_layer_norm_affine
-    return _forced_pallas(jax.value_and_grad(lambda x, w, b: jnp.sum(
-        fused_layer_norm_affine(x, (f,), w, b) ** 2), argnums=(0, 1, 2)))
-
-
-def _ln_args(rows, f):
-    return [((rows, f), F32), ((f,), F32), ((f,), F32)]
-
-
-def _xentropy():
-    from apex_tpu.contrib.xentropy import softmax_cross_entropy_loss
-    return _forced_pallas(lambda logits, labels: jax.value_and_grad(
-        lambda l: jnp.sum(softmax_cross_entropy_loss(
-            l, labels, padding_idx=None, half_to_float=True)))(logits))
-
-
 def _multi_tensor(name, **kw):
     from apex_tpu.ops.pallas import multi_tensor
     return functools.partial(getattr(multi_tensor, name), **kw)
 
 
 _ADAM = dict(lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-8, step=1)
-_LAMB = dict(lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-6, step=1,
-             weight_decay=0.01, global_grad_norm=1.0)
-_SEGS = 8                           # lamb's per-parameter trust ratios
 
 # (id, builder of the traced function, [(shape, dtype), ...],
 #  the kernels' names: the HLO instructions a device trace will show)
@@ -215,21 +183,6 @@ KERNELS = [
     ("flash_fwd_bwd-B24H16S2048D128", lambda: _flash(True),
      _qkv(24, 16, 2048, 128),
      ("apex_flash_fwd", "apex_flash_bwd_dq", "apex_flash_bwd_dkv")),
-    ("layer_norm_fwd_bwd-F1024", lambda: _layer_norm(1024),
-     _ln_args(8 * 4096, 1024),
-     ("apex_ln_fwd", "apex_ln_bwd")),
-    ("layer_norm_fwd_bwd-F4096", lambda: _layer_norm(4096),
-     _ln_args(4096, 4096),
-     ("apex_ln_fwd", "apex_ln_bwd")),
-    ("layer_norm_fwd_bwd-F16384-wide", lambda: _layer_norm(16384),
-     _ln_args(520, 16384),
-     ("apex_ln_wide_moments", "apex_ln_wide_apply", "apex_ln_wide_bwd_reduce", "apex_ln_wide_bwd_gwgb", "apex_ln_wide_bwd_dx")),
-    ("xentropy_fwd_bwd-V32768", _xentropy,
-     [((4096, 32768), BF16), ((4096,), I32)],
-     ("apex_xent_fwd", "apex_xent_bwd")),
-    ("xentropy_fwd_bwd-V50304", _xentropy,
-     [((4096, 50304), BF16), ((4096,), I32)],
-     ("apex_xent_fwd", "apex_xent_bwd")),
     ("scale-128M", lambda: _multi_tensor("scale", scale_factor=0.5),
      [((FLAT,), F32)],
      ("apex_mt_scale",)),
@@ -238,10 +191,6 @@ KERNELS = [
     ("adam_step-128M", lambda: _multi_tensor("adam_step", **_ADAM),
      [((FLAT,), F32)] * 4,
      ("apex_mt_adam",)),
-    ("lamb_step-128M", lambda: (lambda g, p, m, v, seg: _multi_tensor(
-        "lamb_step", **_LAMB)(g, p, m, v, seg, _SEGS)),
-     [((FLAT,), F32)] * 4 + [((FLAT,), I32)],
-     ("apex_mt_lamb_stage1", "apex_mt_lamb_stage2")),
 ]
 
 
@@ -483,14 +432,10 @@ def _step(name, skip, **kw):
 
 
 # (id, step, arguments after (g, p, m, v), kernels, state-sized temporaries
-#  allowed). LAMB's are its update term and the two per-row norm columns:
-#  the chip's (8, 128) tiling pads an f32[rows, 1] to a whole buffer.
+#  allowed)
 IN_PLACE = [
     ("adam_step-128M", "adam_step", _ADAM, [],
      ("apex_mt_adam",), 1),
-    ("lamb_step-128M", "lamb_step", dict(_LAMB, num_segments=_SEGS),
-     [((FLAT,), I32)],
-     ("apex_mt_lamb_stage1", "apex_mt_lamb_stage2"), 3),
 ]
 
 

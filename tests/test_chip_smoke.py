@@ -68,7 +68,7 @@ class TestRehearsal:
         assert ln["ok"], (ln.get("error"), r.stderr[-2000:])
         assert ln["seconds"] > 0 and "compile_seconds" in ln
         if name == "kernels":
-            assert len(ln["checked"]) >= 18
+            assert len(ln["checked"]) >= 12
         elif name == "train_lm":
             assert ln["losses"][-1] < ln["losses"][0]
             assert abs(ln["losses"][0] - ln["reference_loss"]) \

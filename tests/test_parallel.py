@@ -177,126 +177,6 @@ def test_syncbn_backward_matches_global_autodiff():
                                rtol=1e-4)
 
 
-def test_syncbn_variadic_reduce_opt_in_parity(monkeypatch):
-    """APEX_BN_VARIADIC_REDUCE=1 (the demoted single-lax.reduce moments
-    shape, kept for an on-chip re-A/B) must stay numerically equivalent
-    to the split-sums default in fwd AND bwd. Pinned on CPU so a
-    regression in the dead-by-default branch costs no chip time."""
-    mesh = make_mesh({"data": 8})
-    bn = SyncBatchNorm(4, axis_name="data", track_running_stats=False)
-    params, state = bn.init()
-    rs = np.random.RandomState(7)
-    x = jnp.asarray(rs.randn(8, 3, 4), jnp.float32)
-
-    def grads():
-        # fresh trace each time: _sum_pair reads the env at trace time
-        jax.clear_caches()
-
-        @partial(shard_map, mesh=mesh, in_specs=(P(), P("data")),
-                 out_specs=(P(), P(), P("data")))
-        def run(params, x):
-            def loss(p, xs):
-                y, _ = bn.apply(p, state, xs, training=True)
-                return jax.lax.psum(jnp.sum(jnp.sin(y)), "data")
-            l = loss(params, x)
-            gp, gx = jax.grad(loss, argnums=(0, 1))(params, x)
-            return l, gp, gx
-
-        return run(params, x)
-
-    l_def, gp_def, gx_def = grads()
-    monkeypatch.setenv("APEX_BN_VARIADIC_REDUCE", "1")
-    l_var, gp_var, gx_var = grads()
-    np.testing.assert_allclose(l_def, l_var, rtol=1e-6)
-    np.testing.assert_allclose(gx_def, gx_var, atol=1e-6)
-    np.testing.assert_allclose(gp_def["weight"], gp_var["weight"],
-                               atol=1e-5)
-    np.testing.assert_allclose(gp_def["bias"], gp_var["bias"], atol=1e-5)
-    # and the guard precedence, STRUCTURALLY (the old value-parity
-    # assertion was vacuous — both shapes agree numerically by design,
-    # so it could never fail): the variadic shape is the single
-    # multi-operand `reduce` primitive, split-sums is two `reduce_sum`s.
-    from apex_tpu.parallel.sync_batchnorm import _sum2
-
-    def has_variadic_reduce():
-        jax.clear_caches()   # _sum_pair reads the env at trace time
-        fn = lambda v: _sum2(v.astype(jnp.float32), (0,))
-        jaxpr = jax.make_jaxpr(fn)(x)
-        names = {e.primitive.name for e in jaxpr.jaxpr.eqns}
-        assert "reduce" in names or "reduce_sum" in names
-        variadic = "reduce" in names
-        # and in the LOWERED HLO: the variadic shape is ONE
-        # multi-operand stablehlo.reduce, split-sums is two — the jaxpr
-        # verdict must survive lowering, or the env knob selects
-        # nothing XLA can see
-        n_reduce = jax.jit(fn).lower(x).as_text().count(
-            "stablehlo.reduce")
-        assert n_reduce == (1 if variadic else 2), \
-            f"jaxpr says variadic={variadic} but lowered HLO has " \
-            f"{n_reduce} reduce ops"
-        return variadic
-
-    monkeypatch.delenv("APEX_BN_VARIADIC_REDUCE", raising=False)
-    monkeypatch.delenv("APEX_BN_SPLIT_SUMS", raising=False)
-    assert not has_variadic_reduce()          # split-sums default
-    monkeypatch.setenv("APEX_BN_VARIADIC_REDUCE", "1")
-    assert has_variadic_reduce()              # explicit opt-in
-    # the retired SPLIT_SUMS var must NOT veto an explicit variadic
-    # opt-in (bench.py may export it from legacy defaults)
-    monkeypatch.setenv("APEX_BN_SPLIT_SUMS", "1")
-    assert has_variadic_reduce()
-    # "0" must force split even when the defaults-driven export armed it
-    monkeypatch.setenv("APEX_BN_VARIADIC_REDUCE", "0")
-    assert not has_variadic_reduce()
-    # the retired var alone selects nothing
-    monkeypatch.delenv("APEX_BN_VARIADIC_REDUCE", raising=False)
-    assert not has_variadic_reduce()
-
-
-def test_syncbn_mxu_moments_opt_in_parity(monkeypatch):
-    """APEX_BN_MXU_MOMENTS=1 (raw-dtype reductions: fp32-accumulated
-    sum + MXU self-/cross-contractions, sum_dy_xhat via the raw-moment
-    algebra) must match the split-sums default in fwd AND bwd — in
-    fp32, and in bf16 with a mean-offset input (the conditioning case
-    the algebraic sum(dy*x) - mean*sum(dy) rewrite is exposed to)."""
-    mesh = make_mesh({"data": 8})
-    bn = SyncBatchNorm(4, axis_name="data", track_running_stats=False,
-                       fuse_relu=True)
-    params, state = bn.init()
-    rs = np.random.RandomState(11)
-
-    def grads(x):
-        jax.clear_caches()
-
-        @partial(shard_map, mesh=mesh, in_specs=(P(), P("data")),
-                 out_specs=(P(), P(), P("data")))
-        def run(params, x):
-            def loss(p, xs):
-                y, _ = bn.apply(p, state, xs, training=True)
-                return jax.lax.psum(jnp.sum(jnp.sin(y)), "data")
-            l = loss(params, x)
-            gp, gx = jax.grad(loss, argnums=(0, 1))(params, x)
-            return l, gp, gx
-
-        return run(params, x)
-
-    for dtype, off, tol in ((jnp.float32, 0.0, 1e-5),
-                            (jnp.bfloat16, 3.0, 2e-2)):
-        x = jnp.asarray(rs.randn(8, 5, 4) + off, dtype)
-        monkeypatch.delenv("APEX_BN_MXU_MOMENTS", raising=False)
-        l_def, gp_def, gx_def = grads(x)
-        monkeypatch.setenv("APEX_BN_MXU_MOMENTS", "1")
-        l_mxu, gp_mxu, gx_mxu = grads(x)
-        np.testing.assert_allclose(l_def, l_mxu, rtol=tol)
-        np.testing.assert_allclose(np.asarray(gx_def, np.float32),
-                                   np.asarray(gx_mxu, np.float32),
-                                   atol=tol, rtol=tol)
-        np.testing.assert_allclose(gp_def["weight"], gp_mxu["weight"],
-                                   atol=tol, rtol=tol)
-        np.testing.assert_allclose(gp_def["bias"], gp_mxu["bias"],
-                                   atol=tol, rtol=tol)
-
-
 def test_syncbn_folded_upcast_opt_in_parity(monkeypatch):
     """APEX_BN_FOLDED_UPCAST=1 (r06 convert-seam A/B arm: each moments
     reduction owns its single-consumer upcast, square in storage dtype)
@@ -414,70 +294,6 @@ def test_syncbn_channel_axis_nchw():
     np.testing.assert_allclose(y, want, atol=1e-5, rtol=1e-5)
 
 
-def test_syncbn_pallas_backend_agreement():
-    """Fused Pallas BN backward kernels vs the XLA-fused jnp path (the
-    kernel-vs-python axis; kernels: apex_tpu/ops/pallas/welford.py). The
-    jnp path is the *default* (docs/PERF.md r03: XLA wins end-to-end); the
-    kernels remain behind dispatch backend="pallas" and must agree —
-    including the fused-relu mask and the residual dz output."""
-    from apex_tpu.ops import dispatch
-    from apex_tpu.parallel import SyncBatchNorm
-
-    for fuse_relu, with_z in ((False, False), (True, False), (True, True)):
-        bn = SyncBatchNorm(128, axis_name=None, fuse_relu=fuse_relu)
-        p, st = bn.init()
-        x = jax.random.normal(jax.random.key(0), (4, 6, 6, 128))
-        z = (jax.random.normal(jax.random.key(1), x.shape)
-             if with_z else None)
-
-        def run(backend):
-            kw = {"z": z} if with_z else {}
-            with dispatch.backend(backend):
-                y, _ = bn.apply(p, st, x, training=True, **kw)
-
-                def loss(x, z):
-                    kw2 = {"z": z} if with_z else {}
-                    return jnp.sum(bn.apply(p, st, x, training=True,
-                                            **kw2)[0] ** 2)
-                grads = jax.grad(loss, argnums=(0, 1))(x, z if with_z
-                                                       else x)
-            return y, grads
-
-        y_ref, g_ref = run("reference")
-        y_pal, g_pal = run("pallas")
-        np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_ref),
-                                   rtol=2e-5, atol=2e-5)
-        for a, b in zip(g_pal, g_ref):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-4, atol=2e-4)
-
-
-def test_welford_kernels_multiblock_and_ragged():
-    """Exercise the cross-step accumulation and the ragged-final-block mask
-    of the Pallas welford kernels (block budget forces many grid steps)."""
-    from apex_tpu.ops.pallas import welford as W
-
-    n, c = 2603, 256  # > several blocks, n not a multiple of anything nice
-    x = jax.random.normal(jax.random.key(0), (n, c))
-    dy = jax.random.normal(jax.random.key(1), (n, c))
-    assert W._block_rows(n, c) < n  # really multi-block
-
-    s, sq = W.bn_moments(x)
-    np.testing.assert_allclose(np.asarray(s), np.asarray(jnp.sum(x, 0)),
-                               rtol=1e-5, atol=1e-3)
-    np.testing.assert_allclose(np.asarray(sq),
-                               np.asarray(jnp.sum(x * x, 0)),
-                               rtol=1e-5, atol=1e-3)
-
-    xhat = (x - jnp.mean(x, 0)) * jax.lax.rsqrt(jnp.var(x, 0) + 1e-5)
-    sdy, sdx = W.bn_backward_reduce(dy, xhat)
-    np.testing.assert_allclose(np.asarray(sdy), np.asarray(jnp.sum(dy, 0)),
-                               rtol=1e-5, atol=1e-3)
-    np.testing.assert_allclose(np.asarray(sdx),
-                               np.asarray(jnp.sum(dy * xhat, 0)),
-                               rtol=1e-5, atol=1e-3)
-
-
 @pytest.mark.slow
 def test_syncbn_ddp_parity_under_check_vma_false():
     """The classic-semantics contract (vma tracking OFF, as forced by any
@@ -488,10 +304,8 @@ def test_syncbn_ddp_parity_under_check_vma_false():
     average_gradients skip the psum entirely.
 
     Marked slow (r15 tier-1 runtime guard): ~26 s, while the same
-    SyncBN-vjp + average_gradients psum seam stays covered in-tier by
-    test_syncbn_variadic_reduce_opt_in_parity and
-    test_syncbn_folded_upcast_opt_in_parity (same ResNet/ddp harness,
-    different reduce arms)."""
+    SyncBN vjp's psum over a mesh stays covered in-tier by
+    test_syncbn_backward_matches_global_autodiff."""
     from jax import shard_map as new_shard_map  # check_vma kwarg
     from apex_tpu.models import ResNet
     from apex_tpu.ops import flat as F

@@ -3,8 +3,10 @@
 Answers the round-3 questions from VERDICT.md Weak #1/#7:
   1. How much of the measured step time is per-call dispatch overhead?
      (times the same compiled step per-call vs. inside one lax.fori_loop)
-  2. Does the Pallas welford BN path help or hurt vs. plain XLA reductions?
-     (--backend auto|reference ablation)
+  2. What do the step's Pallas kernels (the flat-buffer optimizer ops)
+     buy over their jnp sides? (--backend auto|reference ablation; the
+     welford BN kernels this was first asked of lost, 150 ms to 16 a
+     step, and are gone)
   3. What are the true analytic FLOPs per image (vs. XLA cost_analysis)?
 
 Usage (on the TPU host):
