@@ -9,9 +9,10 @@ short-convolution hybrids, with the layer pattern as data.
                                   or not), "window" (the same over a
                                   sliding window), "sparse" (the same
                                   over a learned per-query key set),
-                                  "latent" (latent attention, MLA) or
+                                  "latent" (latent attention, MLA),
                                   "conv" (the double-gated short
-                                  convolution)
+                                  convolution) or "kda" (Kimi Delta
+                                  Attention)
     x += ffn_i(norm(x))           ffn_i by ``ffn_types[i]``: "experts" (a
                                   chip's share of a many-expert layer) or
                                   "dense" (one SwiGLU of ``dense_ffn``)
@@ -80,6 +81,22 @@ gradients: ``index`` learns from ``L_I`` alone, every other leaf from the
 rest of the loss alone. It runs under two sibling scopes,
 ``sparse_attention`` (the mixer less its indexer) and ``sparse_index``.
 
+The **Kimi Delta Attention mixer** (``"kda"``; ``kda_heads`` heads of
+``kda_head_dim``, keys and values alike): ``q~ = silu(conv(h W_q))``,
+``k~``, ``v`` likewise, three projections each through a causal depthwise
+convolution of its own (``conv_kernel`` taps, no bias); ``q = unit(q~) *
+d^-1/2``, ``k = unit(k~)`` (L2 a head, in float32); **a decay a key
+channel**, ``g = -exp(A_log[head]) softplus((h W_f1) W_f2 + dt_bias)`` in
+``R^(heads x d)`` through a rank of ``d``, float32; ``beta = sigmoid(h
+W_b^T)`` a head (``w_b`` lies ``[heads, hidden]``: a leaf 32 wide would be
+a view of the flat master in rows of 32, which the TPU's tiling pads); the
+recurrence is ``ops.gated_delta_rule`` with ``g [B, H, T, d]``; its output
+is RMS-normalised a head (one weight of ``d``), gated by ``sigmoid((h
+W_g1) W_g2 + b_g)`` and projected out. It runs under ``kda_attention``
+around ``delta_rule`` (the op alone, as in the Gated DeltaNet mixer) and
+hands out ``kda_chunk_decay_nats``, how far its fastest channel decays
+inside one chunk (``ops.gated_delta_rule.chunk_decay_nats``).
+
 The **short-convolution mixer**: ``[B | C | u] = h W_in``, three streams
 ``hidden`` wide; ``z = conv(B * u)``, a causal depthwise convolution of
 ``conv_kernel`` taps a channel (no bias, zeros before the first token);
@@ -92,7 +109,9 @@ The **latent attention mixer** (``num_heads`` heads; queries and keys
 ``q = h W_q``; ``[c | kr] = h W_kva`` with ``c`` the ``kv_lora_rank``-wide
 latent and ``kr`` **one** rotary key head that all heads share; ``[kn |
 v] = norm(c) W_kvb`` a head; rotary positions turn ``q``'s last
-``qk_rope_dim`` and ``kr`` whole; ``k = [kn | kr]`` with ``kr``
+``qk_rope_dim`` and ``kr`` whole (with ``latent_rotary=False`` nothing
+is turned: ``kr`` stays as one shared key head without positions, what
+a model with no positions anywhere leaves of it); ``k = [kn | kr]`` with ``kr``
 **broadcast to the heads in front of the kernel** (its transpose sums the
 heads' ``dK_rope``). ``flash_attention`` takes one width for ``q``, ``k``
 and ``v``, so ``v`` is padded to the keys' width and the result sliced, as
@@ -108,8 +127,9 @@ step's own pairs an expert (``ExpertLayer.moved_bias``), as a ResNet's
 batch statistics travel through ``train_step.build_step``.
 
 Scopes (``prof.SCOPES``): ``embed``, ``linear_attention``,
-``delta_rule``, ``attention``, ``window_attention``, ``sparse_attention``,
-``sparse_index``, ``latent_attention``, ``short_conv``, ``mlp``, ``moe_route``,
+``delta_rule``, ``kda_attention``, ``attention``, ``window_attention``,
+``sparse_attention``, ``sparse_index``, ``latent_attention``,
+``short_conv``, ``mlp``, ``moe_route``,
 ``moe_experts``, ``head_loss``; siblings, never
 nested. Regions (``prof.REGIONS``), around the scopes: ``layer_stack``
 (a run's stacked leaves, the counters' ``concatenate``) and ``layer_scan``
@@ -122,8 +142,9 @@ attention kernel made: the block's ``jax.checkpoint`` saves the names
 ``flash_attention.SAVED_NAMES`` (the forward kernel's output and
 log-sum-exp, ``bf16[B * heads, T, head width padded to 128 lanes]`` and
 ``f32[B * heads, T]`` a flash layer), so ``apex_flash_fwd`` runs once a
-step, and nothing else. A block with no flash kernel (a Gated DeltaNet
-or conv layer, ``attn_impl="default"``) saves its input alone. A
+step, and nothing else. A block with no flash kernel (a Gated DeltaNet,
+Kimi Delta Attention or conv layer, ``attn_impl="default"``) saves its
+input alone. A
 ``"sparse"`` block also keeps ``ops.sparse_index.SAVED_NAMES``: its packed
 key sets (``int32[B, T, T / 32]``) and the indexer's gradient, so neither
 the search nor the indexer's loss runs again.
@@ -142,12 +163,13 @@ import jax.numpy as jnp
 
 from apex_tpu.contrib.moe.expert_layer import ExpertLayer
 from apex_tpu.contrib.multihead_attn.flash_attention import SAVED_NAMES
-from apex_tpu.ops.gated_delta_rule import gated_delta_rule
+from apex_tpu.ops.gated_delta_rule import (chunk_decay_nats,
+                                           gated_delta_rule)
 
 __all__ = ["HybridLM"]
 
 _F32 = jnp.float32
-MIXERS = ("linear", "full", "latent", "conv", "window", "sparse")
+MIXERS = ("linear", "full", "latent", "conv", "window", "sparse", "kda")
 FFNS = ("experts", "dense")
 
 
@@ -238,6 +260,10 @@ class HybridLM:
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+    latent_rotary: bool = True  # False: no positions (q's and kr's stay)
+    # Kimi Delta Attention (conv_kernel, delta_chunk)
+    kda_heads: int = 32
+    kda_head_dim: int = 128
     # Gated DeltaNet
     linear_k_heads: int = 16
     linear_v_heads: int = 32
@@ -347,6 +373,25 @@ class HybridLM:
                     "kv_norm": gain(r),
                     "w_kvb": w(r, h * (dn + self.v_head_dim)),
                     "w_o": w(h * self.v_head_dim, d)}
+            elif kind == "kda":
+                hh, hd = self.kda_heads, self.kda_head_dim
+                taps = self.conv_kernel
+                # the mixer's twelve matrices from one key of the layer's
+                # eight (the other kinds' draws stay what they were)
+                mine = iter(jax.random.split(next(keys), 12))
+
+                def wk(*shape):
+                    return jax.random.normal(next(mine), shape) * scale
+                lp["kda"] = {
+                    "w_q": wk(d, hh * hd), "w_k": wk(d, hh * hd),
+                    "w_v": wk(d, hh * hd), "conv_q": wk(taps, hh * hd),
+                    "conv_k": wk(taps, hh * hd), "conv_v": wk(taps, hh * hd),
+                    "w_f1": wk(d, hd), "w_f2": wk(hd, hh * hd),
+                    "A_log": jnp.zeros((hh,)),
+                    "dt_bias": jnp.ones((hh * hd,)), "w_b": wk(hh, d),
+                    "w_g1": wk(d, hd), "w_g2": wk(hd, hh * hd),
+                    "b_g": jnp.zeros((hh * hd,)), "norm": jnp.ones((hd,)),
+                    "w_out": wk(hh * hd, d)}
             elif kind == "conv":
                 lp["conv"] = {"w_in": w(d, 3 * d),
                               "taps": w(self.conv_kernel, d),
@@ -414,6 +459,45 @@ class HybridLM:
                                     + self.rms_eps) * p["norm"].astype(_F32)
             of = of * jax.nn.silu(z.reshape(b, t, hv, dv).astype(_F32))
             return x + of.reshape(b, t, vd).astype(x.dtype) @ p["w_out"]
+
+    def _kda_mixer(self, lp, x):
+        """``(x out, the mixer's aux)``: how far its fastest channel
+        decays inside one chunk, in nats."""
+        b, t, _ = x.shape
+        hh, hd = self.kda_heads, self.kda_head_dim
+        with jax.named_scope("kda_attention"):
+            h = self._norm(x, lp["norm1"])
+            p = lp["kda"]
+
+            def heads(a):       # [B, T, H d] -> [B, H, T, d]
+                return a.reshape(b, t, hh, hd).transpose(0, 2, 1, 3)
+
+            def unit(a):        # L2-normalise a head, in float32
+                af = a.astype(_F32)
+                return af * jax.lax.rsqrt(jnp.sum(af * af, -1, keepdims=True)
+                                          + 1e-6)
+            q, k, v = (heads(jax.nn.silu(_causal_conv(
+                h @ p["w_" + n], p["conv_" + n]))) for n in "qkv")
+            q = (unit(q) * hd ** -0.5).astype(x.dtype)
+            k = unit(k).astype(x.dtype)
+            a = ((h @ p["w_f1"]) @ p["w_f2"]).astype(_F32) \
+                + p["dt_bias"].astype(_F32)
+            g = heads(jax.nn.softplus(a)) * -jnp.exp(
+                p["A_log"].astype(_F32))[:, None, None]
+            beta = jax.nn.sigmoid((h @ p["w_b"].T).astype(_F32)) \
+                .transpose(0, 2, 1)
+            aux = {"kda_chunk_decay_nats": chunk_decay_nats(
+                g, self.delta_chunk)}
+        with jax.named_scope("delta_rule"):
+            o = gated_delta_rule(q, k, v, g, beta, chunk=self.delta_chunk)
+        with jax.named_scope("kda_attention"):
+            of = o.transpose(0, 2, 1, 3).astype(_F32)       # [B, T, H, d]
+            of = of * jax.lax.rsqrt(jnp.mean(of * of, -1, keepdims=True)
+                                    + self.rms_eps) * p["norm"].astype(_F32)
+            gate = ((h @ p["w_g1"]) @ p["w_g2"]).astype(_F32) \
+                + p["b_g"].astype(_F32)
+            of = of.reshape(b, t, hh * hd) * jax.nn.sigmoid(gate)
+            return x + of.astype(x.dtype) @ p["w_out"], aux
 
     def _qkv(self, p, hid, yarn=None):
         """The attention mixers' heads from the layer's normed input:
@@ -542,11 +626,13 @@ class HybridLM:
             kva = hid @ p["w_kva"]
             kv = (self._norm(kva[..., :r], p["kv_norm"])
                   @ p["w_kvb"]).reshape(b, t, h, dn + dv)
-            q = jnp.concatenate(
-                [q[..., :dn], _rotary(q[..., dn:], self.rope_theta, dr)], -1)
+            kr = kva[..., None, r:]
+            if self.latent_rotary:
+                q = jnp.concatenate([q[..., :dn], _rotary(
+                    q[..., dn:], self.rope_theta, dr)], -1)
+                kr = _rotary(kr, self.rope_theta, dr)
             # one rotary key head serves every head: broadcast in front of
             # the kernel (its transpose sums the heads' dK_rope)
-            kr = _rotary(kva[..., None, r:], self.rope_theta, dr)
             k = jnp.concatenate(
                 [kv[..., :dn], jnp.broadcast_to(kr, (b, t, h, dr))], -1)
             q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, kv[..., dn:]))
@@ -572,12 +658,13 @@ class HybridLM:
 
     def _block(self, kind: str, lp, x, ffn: str = "experts", bias=None):
         """One layer: ``(x, the expert layer's aux | None)``, and for a
-        "sparse" layer ``(x, (that, the indexer's aux))``. ``bias``: the
-        sigmoid router's selection bias of this layer."""
-        if kind == "sparse":
-            x, index_aux = self._sparse_mixer(lp, x)
+        "sparse" or a "kda" layer ``(x, (that, the mixer's aux))``.
+        ``bias``: the sigmoid router's selection bias of this layer."""
+        if kind in ("sparse", "kda"):
+            x, mixer_aux = (self._sparse_mixer if kind == "sparse"
+                            else self._kda_mixer)(lp, x)
             x, aux = self._ffn(lp, x, ffn, bias)
-            return x, (aux, index_aux)
+            return x, (aux, mixer_aux)
         x = {"linear": self._linear_mixer, "full": self._full_mixer,
              "window": functools.partial(self._full_mixer,
                                          window=self.window),
@@ -608,14 +695,16 @@ class HybridLM:
         the sigmoid router, each expert layer's pairs an expert
         (``expert_pairs [expert layers, num_experts]``); with "sparse"
         layers, their summed indexer loss, the pairs they selected and the
-        fullest layer's share of 512 x 512 tiles that hold one.
+        fullest layer's share of 512 x 512 tiles that hold one; with "kda"
+        layers, ``kda_chunk_decay_nats_max``, how far the fastest channel
+        of any of them decays inside one chunk.
         ``router_bias``:
         that router's selection biases, a row an expert layer."""
         with jax.named_scope("embed"):
             x = params["embed"][tokens]
         # a run of like layers is one scanned body over the run's stacked
         # parameters: three Gated DeltaNet layers compile once
-        auxes, indexers, first, row = [], [], 0, 0
+        auxes, indexers, kdas, first, row = [], [], [], 0, 0
         saved = SAVED_NAMES
         if "sparse" in self.layer_types:
             from apex_tpu.ops import sparse_index
@@ -641,16 +730,17 @@ class HybridLM:
                     xs, row = (xs, router_bias[row:row + n]), row + n
             with jax.named_scope("layer_scan"):
                 x, aux = jax.lax.scan(block, x, xs)
-            if kind == "sparse":
-                aux, index_aux = aux
-                indexers.append(index_aux)
+            if kind in ("sparse", "kda"):
+                aux, mixer_aux = aux
+                (indexers if kind == "sparse" else kdas).append(mixer_aux)
             if aux is not None:
                 auxes.append(aux)
             first += n
         with jax.named_scope("layer_stack"):    # the runs' counters joined
             aux = jax.tree.map(lambda *a: jnp.concatenate(a), *auxes)
-            index_aux = jax.tree.map(lambda *a: jnp.concatenate(a),
-                                     *indexers) if indexers else {}
+            index_aux, kda_aux = (
+                jax.tree.map(lambda *a: jnp.concatenate(a), *x) if x else {}
+                for x in (indexers, kdas))
         with jax.named_scope("head_loss"):
             x = self._norm(x, params["norm_f"])
         counters = {"load_balance_loss": jnp.sum(aux["load_balance_loss"]),
@@ -667,6 +757,9 @@ class HybridLM:
                 select_pairs=jnp.sum(index_aux["select_pairs"]),
                 select_live_tile_pct=jnp.max(
                     index_aux["select_live_tile_pct"]))
+        if kda_aux:
+            counters["kda_chunk_decay_nats_max"] = jnp.max(
+                kda_aux["kda_chunk_decay_nats"])
         return x, counters
 
     def apply(self, params: dict, tokens, router_bias=None):
@@ -683,7 +776,8 @@ class HybridLM:
         counters (``moe_overflow_pairs``, ``moe_held_pairs_max``,
         ``moe_live_tiles_max``, ``expert_load_max_over_mean``; the sigmoid
         router's ``expert_pairs``; the sparse layers' ``index_loss``,
-        ``select_pairs``, ``select_live_tile_pct``)."""
+        ``select_pairs``, ``select_live_tile_pct``; the "kda" layers'
+        ``kda_chunk_decay_nats_max``)."""
         from apex_tpu.contrib.xentropy import linear_cross_entropy
         x, c = self.hidden_states(params, tokens[:, :-1], router_bias)
         with jax.named_scope("head_loss"):

@@ -30,8 +30,40 @@ the state and those three never leave VMEM), and anywhere else
 ``jax.numpy`` with a ``lax.scan``, the kernels' oracle. Either way the
 backward keeps one state a *chunk*, never one a token.
 
-Shapes: ``q, k [B, H, L, dk]``, ``v [B, H, L, dv]``, ``g, beta [B, H,
-L]``; the result is ``[B, H, L, dv]`` in ``v``'s type. ``q`` and ``k``
+**A decay a channel** (Kimi Delta Attention): ``g [B, H, L, dk]`` in the
+place of ``g [B, H, L]`` makes the first line ``S = Diag(exp(g_t)) S``,
+row ``c`` of ``S`` times ``exp(g_t[c])``; with ``g_t`` the same in all
+``dk`` channels it is the recurrence above. Its chunked form is
+:func:`_chunked_vector`: with ``G [C, dk]`` the running sum inside a
+chunk the decay sits **inside** the contraction, ``A[t, s] = beta_t sum_c
+k_t[c] k_s[c] exp(G_t[c] - G_s[c])`` (and ``M`` the same with ``q_t``),
+so the operands are decayed before the product and ``exp(-G_s)`` over a
+whole chunk overflows. The products are taken level by level against
+reference tokens (:func:`_local_products`): the chunk halved and halved
+again down to sub-blocks of ``SUB`` = 16 tokens, each off-diagonal block
+against the first token ``r`` of its lower half (rows ``x_t exp(G_t -
+G_r)``, columns ``k_s exp(G_r - G_s)``: every exponent <= 0), each
+diagonal sub-block against its own first token (columns ``k_s exp(G_r -
+G_s)`` with exponents >= 0 over at most 15 tokens). Then ``T = (I +
+A)^-1``, ``U = T (beta V)``, ``W = T (beta exp(G) K)`` as above and, a
+chunk at a time, ``D = U - W S0``, ``O = (exp(G) Q) S0 + M D``, ``S' =
+Diag(exp(G_last)) S0 + (exp(G_last - G) K)^T D``: on the TPU at the
+kernels' shapes the Pallas pair ``apex_kda_fwd`` / ``apex_kda_bwd``
+(``ops/pallas/kda_delta_rule.py``, imported by this arm alone: the state
+stays in VMEM, transposed, so that a channel's decay is a lane's), the
+same ``lax.scan`` anywhere else. **Range:** the vector form equals the
+recurrence while no channel decays by more than float32's largest
+exponent (88.7 nats) over the 15 tokens of a sub-block, ``g >= -5.9`` a
+token a channel held throughout; past that a diagonal column overflows
+and the result is not finite. Over a whole chunk any decay is exact
+(320 nats at ``g = -5`` and a chunk of 64; :func:`chunk_decay_nats` is
+the number a model reports). ``g``'s cotangent comes out in float32
+``[B, H, L, dk]``; nothing with two token axes and a channel axis is
+formed anywhere.
+
+Shapes: ``q, k [B, H, L, dk]``, ``v [B, H, L, dv]``, ``beta [B, H, L]``,
+``g [B, H, L]`` or ``[B, H, L, dk]``; the result is ``[B, H, L, dv]`` in
+``v``'s type. ``q`` and ``k``
 come normalised and ``q`` scaled, as the caller's model has them. Any
 ``L``: the chunked form pads to a whole chunk with tokens that write
 nothing (``beta`` 0, ``g`` 0).
@@ -47,22 +79,26 @@ import jax.numpy as jnp
 from apex_tpu.ops import dispatch
 from apex_tpu.ops.pallas import gated_delta_rule as _kernels
 
-__all__ = ["gated_delta_rule", "gated_delta_rule_chunked",
-           "gated_delta_rule_recurrent"]
+__all__ = ["chunk_decay_nats", "gated_delta_rule",
+           "gated_delta_rule_chunked", "gated_delta_rule_recurrent"]
 
 _HI = jax.lax.Precision.HIGHEST
 _F32 = jnp.float32
+SUB = 16                # tokens a diagonal sub-block of a vector gate's chunk
 
 
 def gated_delta_rule_recurrent(q, k, v, g, beta):
-    """The recurrence as written, one token at a time, in float32."""
+    """The recurrence as written, one token at a time, in float32; ``g``
+    a number or ``dk`` numbers a token."""
     out_dtype = v.dtype
     q, k, v, g, beta = (x.astype(_F32) for x in (q, k, v, g, beta))
     b, h, _, dk = q.shape
+    if g.ndim == 3:     # one decay for every channel
+        g = g[..., None]
 
     def step(s, x):
         q_t, k_t, v_t, g_t, b_t = x
-        s = s * jnp.exp(g_t)[..., None, None]
+        s = s * jnp.exp(g_t)[..., None]
         d = b_t[..., None] * (v_t - jnp.einsum(
             "bhkv,bhk->bhv", s, k_t, precision=_HI))
         s = s + k_t[..., :, None] * d[..., None, :]
@@ -134,6 +170,8 @@ def gated_delta_rule_chunked(q, k, v, g, beta, *, chunk: int = 64):
     float32; the inverse ``T``, the decays and the state are float32."""
     if chunk not in (16, 32, 64, 128):
         raise ValueError(f"chunk must be 16, 32, 64 or 128, got {chunk}")
+    if g.ndim == 4:
+        return _chunked_vector(q, k, v, g, beta, chunk)
     dt = v.dtype
     b, h, length, dk = q.shape
     pad = (-length) % chunk
@@ -174,13 +212,115 @@ def gated_delta_rule_chunked(q, k, v, g, beta, *, chunk: int = 64):
     return o.reshape(b, h, n * chunk, v.shape[-1])[:, :, :length]
 
 
+def _levels(chunk: int):
+    """The vector gate's products inside a chunk, level by level: ``(block,
+    lower)`` with ``block`` the tokens of a block and ``lower`` where its
+    lower half starts: the chunk's halves, their halves, ... down to
+    ``SUB``, then the diagonal sub-blocks themselves (``lower`` 0)."""
+    sub = min(SUB, chunk)
+    out, block = [], chunk
+    while block > sub:
+        out.append((block, block // 2))
+        block //= 2
+    return out + [(sub, 0)]
+
+
+@jax.checkpoint
+def _local_products(q, k, gsum):
+    """``(qk, kk) [..., C, C]`` float32 from a chunk's ``q, k [..., C, dk]``
+    and its running log-decay ``gsum [..., C, dk]``: ``qk[t, s] = sum_c
+    q_t[c] k_s[c] exp(G_t[c] - G_s[c])`` for ``s <= t``, ``kk`` the same
+    with ``k_t`` for ``s < t``, 0 elsewhere. A level (``_levels``) is one
+    product of decayed rows with decayed columns, both in ``q``'s type: a
+    block's lower half as rows against its upper half as columns, both
+    decayed to the lower half's first token ``r`` (``exp(G_t - G_r)``,
+    ``exp(G_r - G_s)``, exponents <= 0); a diagonal sub-block against its
+    own first token (the columns' exponents >= 0 over ``SUB - 1`` tokens).
+    Tokens with no part in a level enter it as zeros (masked before the
+    exp). Recomputed in the backward: only ``q``, ``k``, ``gsum`` are kept."""
+    dt = q.dtype
+    c, dk = k.shape[-2:]
+    lead = k.shape[:-2]
+    x = jnp.stack([q, k], axis=-3).astype(_F32)         # [..., 2, C, dk]
+    kf = k.astype(_F32)
+    idx = jnp.arange(c)
+    same = idx[:, None] >= idx[None, :]
+    total = 0.0
+    for block, lower in _levels(c):
+        gb = gsum.reshape(*lead, c // block, block, dk)
+        ref = jnp.broadcast_to(gb[..., lower:lower + 1, :],
+                               gb.shape).reshape(gsum.shape)
+        pos = idx % block
+        row = (pos >= lower)[:, None]
+        col = row if lower == 0 else ~row
+        rows = x * jnp.exp(jnp.where(row, gsum - ref, -jnp.inf))[..., None,
+                                                                 :, :]
+        cols = kf * jnp.exp(jnp.where(col, ref - gsum, -jnp.inf))
+        prod = jnp.einsum("...xck,...sk->...xcs", rows.astype(dt),
+                          cols.astype(dt), preferred_element_type=_F32)
+        here = same & (idx[:, None] // block == idx[None, :] // block)
+        total = total + jnp.where(here, prod, 0.0)
+    strict = idx[:, None] > idx[None, :]
+    return total[..., 0, :, :], jnp.where(strict, total[..., 1, :, :], 0.0)
+
+
+def chunk_decay_nats(g, chunk: int):
+    """How far a channel decays inside one chunk, at the most: the largest
+    ``-G_last[c]`` over everything of ``g [B, H, L, dk]`` (a float32
+    scalar, no gradient). Past 88.7 a form that divides by ``exp(G)`` over
+    a whole chunk is wrong; this one is not (the module's text)."""
+    b, h, length, dk = g.shape
+    g = jnp.pad(g.astype(_F32), ((0, 0), (0, 0), (0, (-length) % chunk),
+                                 (0, 0)))
+    return jax.lax.stop_gradient(-jnp.min(jnp.sum(
+        g.reshape(b, h, -1, chunk, dk), axis=3)))
+
+
+def _chunked_vector(q, k, v, g, beta, chunk: int):
+    """The chunked form under a decay a channel, ``g [B, H, L, dk]`` (the
+    module's text)."""
+    dt = v.dtype
+    b, h, length, dk = q.shape
+    pad = (-length) % chunk
+    if pad:
+        q, k, v, g = (jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                      for x in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, 0), (0, 0), (0, pad)))
+    n = (length + pad) // chunk
+
+    def chunks(x):
+        return x.reshape(b, h, n, chunk, *x.shape[3:])
+
+    q, k, v = chunks(q.astype(dt)), chunks(k.astype(dt)), chunks(v)
+    beta = chunks(beta.astype(_F32))[..., None]
+    gsum = jnp.cumsum(chunks(g.astype(_F32)), axis=-2)      # G [C, dk]
+    qk, kk = _local_products(q, k, gsum)
+    t_inv = _inv_unit_lower(beta * kk)
+    u = jnp.matmul(t_inv, beta * v.astype(_F32), precision=_HI)
+    w = jnp.matmul(t_inv, beta * jnp.exp(gsum) * k.astype(_F32),
+                   precision=_HI)
+    last = gsum[..., -1:, :]
+    q_in = q.astype(_F32) * jnp.exp(gsum)
+    k_out = k.astype(_F32) * jnp.exp(last - gsum)
+    through = jnp.exp(last[..., 0, :])                      # [B, H, n, dk]
+    kernels = None
+    if dispatch.use_pallas():       # this arm's alone: imported here
+        from apex_tpu.ops.pallas import kda_delta_rule as kernels
+    if kernels is not None and kernels.takes(dk, v.shape[-1], chunk):
+        o = kernels.chunk_scan(dt, q_in, k_out, w, u, qk, through)
+    else:
+        o = _chunk_scan(*(x.astype(dt) for x in (w, u, q_in, k_out, qk)),
+                        through)
+    return o.reshape(b, h, n * chunk, v.shape[-1])[:, :, :length]
+
+
 def _chunk_scan(w, u, q, k, qk, decay):
     """The loop over chunks as a ``lax.scan``: what runs off the TPU and at
     shapes the kernels do not take, and what they are tested against: from
     the products' operands ``w, q, k [B, H, n, C, dk]``, ``u [B, H, n, C,
     dv]``, ``qk [B, H, n, C, C]`` (``q`` and ``k`` decayed, ``qk`` masked)
-    and the chunks' decays ``[B, H, n, 1]``, the outputs ``[B, H, n, C,
-    dv]``."""
+    and the chunks' decays ``[B, H, n, 1]`` (a vector gate's: ``[B, H, n,
+    dk]``, a row of the state each), the outputs ``[B, H, n, C, dv]``."""
     dt = u.dtype
     mm = functools.partial(_mm, dt)
 

@@ -36,6 +36,15 @@ def _gdn(head_dim=128):
                   head, head, head, gate, gate)
 
 
+def _kda(head_dim=128, chunk=64):
+    """The delta rule under a decay a channel (``g [B, H, L, dk]``)."""
+    from apex_tpu.ops.gated_delta_rule import gated_delta_rule
+    head, beta = _f32(1, 2, 128, head_dim), _f32(1, 2, 128)
+    return _trace(jax.grad(lambda *a: gated_delta_rule(*a, chunk=chunk).sum(),
+                           argnums=(0, 1, 2, 3, 4)),
+                  head, head, head, head, beta)
+
+
 def _experts(hidden=128):
     def trace():
         from apex_tpu.contrib.moe.expert_layer import ExpertLayer
@@ -96,6 +105,8 @@ FAMILIES = {
             **_HP), *[_BUF] * 4, _ROW_IDS),
         set()),
     "gated_delta_rule": (_gdn(), {"apex_gdn_fwd", "apex_gdn_bwd"}),
+    "gated_delta_rule_vector_gate": (_kda(), {"apex_kda_fwd",
+                                              "apex_kda_bwd"}),
     "expert_layer": (_experts(), {"apex_moe_gmm", "apex_moe_tgmm",
                                   "apex_moe_rowsum"}),
     "decode_dense": (_decode(paged=False), {"apex_decode_dense"}),
@@ -125,6 +136,8 @@ UNTAKEN = {
         lambda g, p, m, v, ids: K.novograd_step(g, p, m, v, ids, **_HP),
         _BUF, _BUF, _BUF, _f32(2), _ROW_IDS),
     "delta_rule_heads_of_64": _gdn(head_dim=64),
+    "vector_gate_delta_rule_heads_of_64": _kda(head_dim=64),
+    "vector_gate_delta_rule_chunks_of_32": _kda(chunk=32),
     "experts_of_half_a_lane_tile": _experts(hidden=64),
     "decode_dense_below_crossover": _decode(paged=False, below=16),
     "decode_paged_below_crossover": _decode(paged=True, below=16),

@@ -195,3 +195,197 @@ def test_shapes_the_kernels_do_not_take_fall_to_the_scan(dk, dv, chunk,
 def test_a_chunk_size_that_is_no_power_of_two_is_refused():
     with pytest.raises(ValueError):
         G.gated_delta_rule_chunked(*_inputs(48), chunk=48)
+
+
+# -- a decay a channel (Kimi Delta Attention): g [B, H, L, dk] ---------------
+
+def _vector(length, seed=0, bias=-1.0, **sizes):
+    """``_inputs`` with a gate a key channel, decays of every speed."""
+    q, k, v, g, beta = _inputs(length, seed=seed, **sizes)
+    g = -jax.nn.softplus(jax.random.normal(
+        jax.random.key(seed + 100), g.shape + (q.shape[-1],)) + bias)
+    return q, k, v, g, beta
+
+
+def _both(fn):
+    """``fn``'s result and its five gradients as one compiled program (a
+    new one each call: the dispatch's side is no part of a trace's key)."""
+    return jax.jit(lambda *a: (fn(*a),) + _grads(fn, a))
+
+
+def _close(got, want, names, tolerance=2e-6):
+    for name, a, b in zip(names.split(), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert bool(jnp.isfinite(a).all()), name
+        np.testing.assert_allclose(a, b, atol=tolerance * float(
+            jnp.abs(b).max()) + 1e-7, err_msg=name)
+
+
+@pytest.mark.parametrize("length, chunk", [
+    (64, 16), (96, 32), (128, 64), (100, 64), (130, 128), (7, 16)])
+def test_vector_gate_chunked_is_the_recurrence(length, chunk):
+    """Forward and all five gradients, ``g``'s in float32 ``[B, H, L,
+    dk]``, at whole chunks and at a length that is none; a chunk of 16 is
+    one level, 32 two, 64 three, 128 four (``_levels``)."""
+    args = _vector(length, seed=length, b=1, h=2, **SCAN)
+    fn = lambda *a: G.gated_delta_rule_chunked(*a, chunk=chunk)
+    assert len(G._levels(chunk)) == {16: 1, 32: 2, 64: 3, 128: 4}[chunk]
+    want = _both(G.gated_delta_rule_recurrent)(*args)
+    got = _both(fn)(*args)
+    assert got[4].shape == args[3].shape and got[4].dtype == jnp.float32
+    _close(got, want, "o q k v g beta", 3e-6)
+
+
+@pytest.mark.parametrize("chunk", [32, 64])
+def test_vector_gate_far_past_float32s_exp_range_over_a_chunk(chunk):
+    """A gate of -5 a token a channel is 320 nats over a chunk of 64,
+    where ``exp(-G)`` has long overflowed (88.7), beside channels that do
+    not decay at all: finite and the recurrence's, result and gradients."""
+    q, k, v, g, beta = _vector(2 * chunk, seed=7, b=1, h=2, **SCAN)
+    g = jnp.full_like(g, -5.0).at[..., ::3].set(0.0)
+    g = g.at[:, 0, :, 1].set(-0.3)
+    assert float(G.chunk_decay_nats(g, chunk)) == pytest.approx(5.0 * chunk)
+    assert 5.0 * chunk > 88.7
+    args = (q, k, v, g, beta)
+    fn = lambda *a: G.gated_delta_rule_chunked(*a, chunk=chunk)
+    _close(_both(fn)(*args), _both(G.gated_delta_rule_recurrent)(*args),
+           "o q k v g beta", 3e-6)
+
+
+def test_vector_gate_equal_in_all_channels_is_the_scalar_path():
+    """``g_t`` the same in all ``dk`` channels: the recurrence is the
+    scalar one's to the bit, the chunked forms agree to rounding, and the
+    vector gate's cotangent summed over the channels is the scalar's."""
+    q, k, v, g, beta = _inputs(128, seed=8, b=1, h=2, **SCAN)
+    wide = jnp.broadcast_to(g[..., None], g.shape + (q.shape[-1],))
+    np.testing.assert_array_equal(
+        G.gated_delta_rule_recurrent(q, k, v, wide, beta),
+        G.gated_delta_rule_recurrent(q, k, v, g, beta))
+    fn = lambda *a: G.gated_delta_rule_chunked(*a, chunk=64)
+    scalar = _both(fn)(q, k, v, g, beta)
+    vector = _both(fn)(q, k, v, wide, beta)
+    vector = vector[:4] + (vector[4].sum(-1),) + vector[5:]
+    _close(vector, scalar, "o q k v g beta", 3e-6)
+
+
+def _parents_chunked(q, k, v, g, beta, *, chunk):
+    """The parent commit's ``gated_delta_rule_chunked`` (scalar gate, the
+    ``lax.scan`` side), frozen here: the path a vector gate must leave
+    alone."""
+    dt, f32, hi = v.dtype, jnp.float32, jax.lax.Precision.HIGHEST
+    b, h, length, dk = q.shape
+    pad = (-length) % chunk
+    if pad:
+        q, k, v = (jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                   for x in (q, k, v))
+        g, beta = (jnp.pad(x, ((0, 0), (0, 0), (0, pad))) for x in (g, beta))
+    n = (length + pad) // chunk
+
+    def chunks(x):
+        return x.reshape(b, h, n, chunk, *x.shape[3:])
+    mm = lambda eq, x, y: jnp.einsum(eq, x.astype(dt), y.astype(dt),
+                                     preferred_element_type=f32)
+    q, k, v = chunks(q), chunks(k), chunks(v)
+    beta = chunks(beta.astype(f32))[..., None]
+    gsum = jnp.cumsum(chunks(g.astype(f32)), axis=-1)
+    idx = jnp.arange(chunk)
+    lower = idx[:, None] >= idx[None, :]
+    decay = jnp.exp(jnp.where(lower, gsum[..., :, None] - gsum[..., None, :],
+                              -jnp.inf))
+    kk = mm("bhnck,bhnsk->bhncs", k, k)
+    t_inv = G._inv_unit_lower(jnp.where(idx[:, None] > idx[None, :],
+                                        beta * kk * decay, 0.0))
+    u = jnp.matmul(t_inv, beta * v.astype(f32), precision=hi)
+    w = jnp.matmul(t_inv, beta * jnp.exp(gsum)[..., None] * k.astype(f32),
+                   precision=hi)
+    qk = mm("bhnck,bhnsk->bhncs", q, k) * decay
+    q_in = q.astype(f32) * jnp.exp(gsum)[..., None]
+    last = gsum[..., -1:]
+    k_out = k.astype(f32) * jnp.exp(last - gsum)[..., None]
+    o = G._chunk_scan(*(x.astype(dt) for x in (w, u, q_in, k_out, qk)),
+                      jnp.exp(last))
+    return o.reshape(b, h, n * chunk, v.shape[-1])[:, :, :length]
+
+
+@pytest.mark.parametrize("length, chunk", [(128, 64), (100, 32)])
+def test_the_scalar_path_is_the_parents_to_the_bit(length, chunk):
+    args = _inputs(length, seed=9, b=1, h=2, **SCAN)
+    ours = lambda *a: G.gated_delta_rule_chunked(*a, chunk=chunk)
+    theirs = lambda *a: _parents_chunked(*a, chunk=chunk)
+    for a, b in zip(_both(ours)(*args), _both(theirs)(*args)):
+        np.testing.assert_array_equal(a, b)
+    # and the same equations, one by one
+    eqns = lambda f: [(e.primitive.name, [v.aval for v in e.outvars])
+                      for e in jax.make_jaxpr(f)(*args).eqns]
+    assert eqns(ours) == eqns(theirs)
+
+
+@pytest.mark.parametrize("length, chunk, heads, dtype, tolerance", [
+    (128, 64, (2, 8), jnp.float32, 1e-6),
+    (128, 64, (1, 8), jnp.bfloat16, 2e-2),
+    (200, 128, (1, 3), jnp.float32, 1e-6)])
+def test_the_vector_gates_kernels_are_the_scan(length, chunk, heads, dtype,
+                                               tolerance):
+    """``apex_kda_fwd`` / ``apex_kda_bwd`` in interpret mode against the
+    ``lax.scan`` form on the same inputs, result and every gradient, with
+    (batch, heads) that do and do not fill the kernels' 8 heads a step."""
+    args = _vector(length, seed=11, dtype=dtype, **kernels(*heads))
+    fn = lambda *a: G.gated_delta_rule_chunked(*a, chunk=chunk)
+    assert not _runs_kernels(fn, *args)
+    want = _both(fn)(*args)
+    with dispatch.backend("pallas"):
+        text = str(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(
+            fn(*a).astype(jnp.float32))))(*args))
+        assert "apex_kda_fwd" in text and "apex_kda_bwd" in text \
+            and "apex_gdn" not in text
+        got = _both(fn)(*args)
+    for name, a, b in zip("o q k v g beta".split(), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        assert float(jnp.abs(a - b).max()) <= tolerance * float(
+            jnp.abs(b).max()), name
+
+
+def test_the_vector_gates_kernels_reach_across_chunks_and_meet_the_recurrence():
+    q, k, v, g, beta = _vector(192, seed=12, bias=-3.0, **kernels(1, 3))
+    with dispatch.backend("pallas"):
+        fn = jax.jit(lambda *a: G.gated_delta_rule_chunked(*a, chunk=64))
+        whole = fn(q, k, v, g, beta)
+        cut = fn(q, k, v.at[:, :, :64].set(0.0), g, beta)
+    assert float(jnp.abs(whole[:, :, 64:] - cut[:, :, 64:]).max()) > 1e-2
+    np.testing.assert_allclose(
+        whole, jax.jit(G.gated_delta_rule_recurrent)(q, k, v, g, beta),
+        atol=2e-6)
+
+
+@pytest.mark.parametrize("dk, dv, chunk, backend, kernel", [
+    (128, 128, 64, "pallas", True), (128, 128, 32, "pallas", False),
+    (64, 128, 64, "pallas", False), (128, 128, 64, "auto", False)])
+def test_vector_gate_shapes_the_kernels_do_not_take_fall_to_the_scan(
+        dk, dv, chunk, backend, kernel):
+    args = _vector(2 * chunk, seed=13, b=1, h=2, dk=dk, dv=dv)
+    fn = lambda *a: G.gated_delta_rule_chunked(*a, chunk=chunk)
+    with dispatch.backend(backend):
+        assert _runs_kernels(fn, *args) == kernel
+        got = jax.jit(lambda *a: fn(*a))(*args)
+    np.testing.assert_allclose(
+        got, jax.jit(G.gated_delta_rule_recurrent)(*args), atol=2e-6)
+
+
+def test_the_scalar_arm_never_imports_the_vector_gates_kernels():
+    """The new bodies' module is the vector arm's alone: a program with a
+    scalar gate (``qnext_train_s8192``'s) neither imports nor traces it."""
+    import subprocess
+    import sys
+    code = ("import sys, jax, jax.numpy as jnp\n"
+            "from apex_tpu.ops import dispatch\n"
+            "from apex_tpu.ops.gated_delta_rule import gated_delta_rule\n"
+            "import apex_tpu.models.hybrid_lm\n"
+            "x = jnp.ones((1, 1, 64, 128))\n"
+            "with dispatch.backend('pallas'):\n"
+            "    jax.make_jaxpr(lambda *a: gated_delta_rule(*a))(\n"
+            "        x, x, x, -x[..., 0], x[..., 0])\n"
+            "assert 'apex_tpu.ops.pallas.gated_delta_rule' in sys.modules\n"
+            "assert 'apex_tpu.ops.pallas.kda_delta_rule' not in sys.modules\n")
+    subprocess.run([sys.executable, "-c", code], check=True,
+                   env={**__import__("os").environ, "JAX_PLATFORMS": "cpu"})

@@ -42,7 +42,7 @@ def _params(lm, key=0):
 
 
 def test_the_sparse_kind_is_data_and_a_run_is_one_scanned_body():
-    assert MIXERS[-1] == "sparse" and len(MIXERS) == 6
+    assert MIXERS[5] == "sparse" and len(MIXERS) == 7
     lm = _sparse()
     p = lm.init(jax.random.key(0))
     assert all(set(p[f"layer_{i}"]) == {"norm1", "norm2", "attn", "index",

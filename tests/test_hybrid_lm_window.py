@@ -27,7 +27,7 @@ def test_a_window_window_window_full_pattern_is_scans_of_three_and_one():
     under ``remat`` each run's body holds its forward kernel once, the
     window run's under its own name."""
     assert MIXERS == ("linear", "full", "latent", "conv", "window",
-                      "sparse")
+                      "sparse", "kda")
     lm = _window()
     p = lm.init(jax.random.key(0))
     assert all(set(p[f"layer_{i}"]) == {"norm1", "norm2", "attn", "moe"}

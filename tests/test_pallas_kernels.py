@@ -399,6 +399,12 @@ def _gdn_grad():
                     argnums=(0, 1, 2, 3, 4))
 
 
+def _kda_grad():
+    from apex_tpu.ops.pallas import kda_delta_rule as K
+    return jax.grad(lambda *a: K.chunk_scan(jnp.float32, *a).sum(),
+                    argnums=(0, 1, 2, 3, 4, 5))
+
+
 def _moe_grad():
     from apex_tpu.ops.pallas import grouped_matmul as G
     return jax.grad(lambda lhs, w, tile_e, live: G.grouped_matmul(
@@ -419,6 +425,10 @@ def _kernel_sites() -> dict:
     qkv = _f32(2, 256, 128)
     # two chunks of 64 tokens of two heads of 128
     gdn = [_f32(1, 2, 2, 64, 128)] * 4 + [_f32(1, 2, 2, 64)]
+    # the same under a decay a channel: the masked products come as an
+    # operand, the chunks' decays a row of 128
+    kda = [_f32(1, 2, 2, 64, 128)] * 4 + [_f32(1, 2, 2, 64, 64),
+                                          _f32(1, 2, 2, 128)]
     # two tiles of 128 rows over two experts' [128, 256]
     moe = [_f32(256, 128), _f32(2, 128, 256), _i32(2), _i32()]
     # the indexer's kernels: 64 queries of 2 heads against 128 keys; the
@@ -439,6 +449,8 @@ def _kernel_sites() -> dict:
                                  _i32(R.BLOCK, 2)),
         "apex_gdn_fwd": _site(_gdn_grad(), *gdn),
         "apex_gdn_bwd": _site(_gdn_grad(), *gdn),
+        "apex_kda_fwd": _site(_kda_grad(), *kda),
+        "apex_kda_bwd": _site(_kda_grad(), *kda),
         "apex_mt_scale": _site(P.scale, buf, scale_factor=2.0),
         "apex_mt_axpby": _site(lambda x, y: P.axpby(1.0, x, 2.0, y), buf, buf),
         "apex_mt_l2norm": _site(P.l2norm, buf),
