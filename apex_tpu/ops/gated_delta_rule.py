@@ -39,7 +39,11 @@ chunk the decay sits **inside** the contraction, ``A[t, s] = beta_t sum_c
 k_t[c] k_s[c] exp(G_t[c] - G_s[c])`` (and ``M`` the same with ``q_t``),
 so the operands are decayed before the product and ``exp(-G_s)`` over a
 whole chunk overflows. The products are taken level by level against
-reference tokens (:func:`_local_products`): the chunk halved and halved
+reference tokens (:func:`_local_products`; on the TPU at the kernels'
+shapes the Pallas pair ``apex_kda_local_fwd`` / ``apex_kda_local_bwd``,
+which read :func:`_levels` too and make a level's decayed rows and
+columns in VMEM from ``q``, ``k``, ``G``: only the ``[C, C]`` products
+and the ``[C, dk]`` cotangents leave it): the chunk halved and halved
 again down to sub-blocks of ``SUB`` = 16 tokens, each off-diagonal block
 against the first token ``r`` of its lower half (rows ``x_t exp(G_t -
 G_r)``, columns ``k_s exp(G_r - G_s)``: every exponent <= 0), each
@@ -51,7 +55,9 @@ Diag(exp(G_last)) S0 + (exp(G_last - G) K)^T D``: on the TPU at the
 kernels' shapes the Pallas pair ``apex_kda_fwd`` / ``apex_kda_bwd``
 (``ops/pallas/kda_delta_rule.py``, imported by this arm alone: the state
 stays in VMEM, transposed, so that a channel's decay is a lane's), the
-same ``lax.scan`` anywhere else. **Range:** the vector form equals the
+same ``lax.scan`` anywhere else. The inverse, ``U``, ``W``, the running
+sum ``G`` and the decayed ``exp(G) Q``, ``exp(G_last - G) K`` are
+``jax.numpy`` on every platform. **Range:** the vector form equals the
 recurrence while no channel decays by more than float32's largest
 exponent (88.7 nats) over the 15 tokens of a sub-block, ``g >= -5.9`` a
 token a channel held throughout; past that a diagonal column overflows
@@ -218,11 +224,11 @@ def _levels(chunk: int):
     lower half starts: the chunk's halves, their halves, ... down to
     ``SUB``, then the diagonal sub-blocks themselves (``lower`` 0)."""
     sub = min(SUB, chunk)
-    out, block = [], chunk
+    out, block = (), chunk
     while block > sub:
-        out.append((block, block // 2))
+        out += ((block, block // 2),)
         block //= 2
-    return out + [(sub, 0)]
+    return out + ((sub, 0),)
 
 
 @jax.checkpoint
@@ -294,7 +300,15 @@ def _chunked_vector(q, k, v, g, beta, chunk: int):
     q, k, v = chunks(q.astype(dt)), chunks(k.astype(dt)), chunks(v)
     beta = chunks(beta.astype(_F32))[..., None]
     gsum = jnp.cumsum(chunks(g.astype(_F32)), axis=-2)      # G [C, dk]
-    qk, kk = _local_products(q, k, gsum)
+    kernels = None
+    if dispatch.use_pallas():       # this arm's alone: imported here
+        from apex_tpu.ops.pallas import kda_delta_rule
+        if kda_delta_rule.takes(dk, v.shape[-1], chunk):
+            kernels = kda_delta_rule
+    if kernels is not None:
+        qk, kk = kernels.local_products(_levels(chunk), q, k, gsum)
+    else:
+        qk, kk = _local_products(q, k, gsum)
     t_inv = _inv_unit_lower(beta * kk)
     u = jnp.matmul(t_inv, beta * v.astype(_F32), precision=_HI)
     w = jnp.matmul(t_inv, beta * jnp.exp(gsum) * k.astype(_F32),
@@ -303,10 +317,7 @@ def _chunked_vector(q, k, v, g, beta, chunk: int):
     q_in = q.astype(_F32) * jnp.exp(gsum)
     k_out = k.astype(_F32) * jnp.exp(last - gsum)
     through = jnp.exp(last[..., 0, :])                      # [B, H, n, dk]
-    kernels = None
-    if dispatch.use_pallas():       # this arm's alone: imported here
-        from apex_tpu.ops.pallas import kda_delta_rule as kernels
-    if kernels is not None and kernels.takes(dk, v.shape[-1], chunk):
+    if kernels is not None:
         o = kernels.chunk_scan(dt, q_in, k_out, w, u, qk, through)
     else:
         o = _chunk_scan(*(x.astype(dt) for x in (w, u, q_in, k_out, qk)),

@@ -1,12 +1,29 @@
-"""The delta rule's loop over chunks under a decay a channel (Kimi Delta
-Attention) as two Pallas kernels.
+"""The delta rule under a decay a channel (Kimi Delta Attention): its
+products inside a chunk and its loop over chunks, a Pallas pair each.
 
-``ops/gated_delta_rule.py`` ``_chunked_vector`` makes, chunk by chunk and
-in XLA, everything that holds a decay: the decayed queries ``Q = exp(G)
-q`` and keys ``K = exp(G_last - G) k``, the masked ``M[t, s] = sum_c
-q_t[c] k_s[c] exp(G_t[c] - G_s[c])`` (level by level, against reference
-tokens), ``W``, ``U`` and the chunk's decay ``e = exp(G_last) [dk]``.
-What is left is a recurrence over the chunks of a head,
+**Inside a chunk** (:func:`local_products`: ``apex_kda_local_fwd`` /
+``apex_kda_local_bwd``): the masked ``M[t, s] = sum_c q_t[c] k_s[c]
+exp(G_t[c] - G_s[c])`` and the same with ``k_t``, level by level against
+reference tokens, as ``gated_delta_rule._local_products`` takes them in
+``jax.numpy`` (the kernels' oracle, and its text the levels': the caller
+hands ``gated_delta_rule._levels(C)`` in). A grid step holds ``CHUNKS``
+chunks' ``q``, ``k``, ``G``, all chunks independent; a chunk makes each
+level's decayed rows ``[2 C, dk]`` (``q``'s above ``k``'s) and columns
+``[C, dk]`` in VMEM, masked before the ``exp``, rounds them to the
+products' type, takes the whole-chunk product on the MXU into float32 and
+keeps the level's blocks of it. The backward makes the levels again from
+the same three residuals and hands out ``dq``, ``dk`` (the operands' type)
+and ``dG`` (float32), a reference token's share of ``dG`` (its block's sum,
+minus) placed on its row; the masked cotangent of a product is rounded to
+the products' type where it enters a product, as JAX's transpose rounds
+it, and nothing else is. No decayed operand and nothing with two token
+axes but the two results and their cotangents is ever in HBM.
+
+**Over the chunks.** ``ops/gated_delta_rule.py`` ``_chunked_vector``
+makes in XLA what else holds a decay: the decayed queries ``Q = exp(G)
+q`` and keys ``K = exp(G_last - G) k``, ``W``, ``U`` (behind the inverse)
+and the chunk's decay ``e = exp(G_last) [dk]``. What is left is a
+recurrence over the chunks of a head,
 
     D  = U - W S
     O  = Q S + M D
@@ -43,9 +60,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops.pallas._common import interpret_mode, round_up, vma
 
-__all__ = ["chunk_scan", "takes"]
+__all__ = ["chunk_scan", "local_products", "takes"]
 
-HEADS = 8               # (batch x head) pairs a grid step
+HEADS = 8               # (batch x head) pairs a grid step of the scan pair
+CHUNKS = 8              # chunks a grid step of the local pair
 _F32 = jnp.float32
 
 
@@ -198,3 +216,158 @@ def _chunk_scan_bwd(dt, residuals, do):
 
 
 chunk_scan.defvjp(_chunk_scan_fwd, _chunk_scan_bwd)
+
+
+# -- the products inside a chunk: apex_kda_local_fwd / apex_kda_local_bwd ----
+
+def _decays(g, block, lower):
+    """A level's decays ``[C, dk]`` from a chunk's ``G``, the rows' and the
+    columns': every block of ``block`` tokens against its token ``lower``,
+    masked before the ``exp`` (``gated_delta_rule._local_products``)."""
+    c, dk = g.shape
+    ref = jnp.concatenate([jnp.broadcast_to(g[r:r + 1], (block, dk))
+                           for r in range(lower, c, block)], axis=0)
+    if lower == 0:      # a diagonal sub-block: every token both ways
+        return jnp.exp(g - ref), jnp.exp(ref - g)
+    row = jax.lax.broadcasted_iota(jnp.int32, g.shape, 0) % block >= lower
+    return (jnp.exp(jnp.where(row, g - ref, -jnp.inf)),
+            jnp.exp(jnp.where(row, -jnp.inf, ref - g)))
+
+
+def _kept(c, block):
+    """Where a level's product ``[2 C, C]`` (``q``'s rows above ``k``'s) is
+    kept: inside a block, ``s <= t`` under ``q`` and ``s < t`` under ``k``."""
+    t = jax.lax.broadcasted_iota(jnp.int32, (2 * c, c), 0)
+    s = jax.lax.broadcasted_iota(jnp.int32, (2 * c, c), 1)
+    of_k = (t >= c).astype(jnp.int32)
+    t = t % c
+    return ((t ^ s) < block) & (t - s >= of_k)
+
+
+def _operands(q_ref, k_ref, g_ref, i):
+    """Chunk ``i`` of a grid step: ``q`` above ``k`` ``[2 C, dk]``, ``k``
+    and ``G`` ``[C, dk]``, all float32."""
+    k = k_ref[i].astype(_F32)
+    return jnp.concatenate([q_ref[i].astype(_F32), k], axis=0), k, g_ref[i]
+
+
+def _local_fwd_kernel(levels, q_ref, k_ref, g_ref, qk_ref, kk_ref):
+    dt = q_ref.dtype
+    c = q_ref.shape[1]
+
+    def chunk(i, carry):
+        x, k, g = _operands(q_ref, k_ref, g_ref, i)
+        total = jnp.zeros((2 * c, c), _F32)
+        for block, lower in levels:
+            er, ec = _decays(g, block, lower)
+            rows = x * jnp.concatenate([er, er], axis=0)
+            total += jnp.where(_kept(c, block), _dot(
+                rows.astype(dt), (k * ec).astype(dt), _NT), 0.0)
+        qk_ref[i] = total[:c]
+        kk_ref[i] = total[c:]
+        return carry
+    jax.lax.fori_loop(0, q_ref.shape[0], chunk, 0)
+
+
+def _on_reference(e, block, lower):
+    """Every block's sum of ``e [C, dk]`` on its token ``lower``, zeros on
+    the others: what the reference tokens collect."""
+    dk = e.shape[1]
+    at = jax.lax.broadcasted_iota(jnp.int32, (block, dk), 0) == lower
+    return jnp.concatenate([
+        jnp.where(at, jnp.sum(e[r:r + block], axis=0, keepdims=True), 0.0)
+        for r in range(0, e.shape[0], block)], axis=0)
+
+
+def _local_bwd_kernel(levels, q_ref, k_ref, g_ref, dqk_ref, dkk_ref,
+                      dq_ref, dk_ref, dg_ref):
+    dt = q_ref.dtype
+    c = q_ref.shape[1]
+
+    def chunk(i, carry):
+        x, k, g = _operands(q_ref, k_ref, g_ref, i)
+        dp = jnp.concatenate([dqk_ref[i], dkk_ref[i]], axis=0)
+        dx, dcol, dg = jnp.zeros_like(x), jnp.zeros_like(k), jnp.zeros_like(g)
+        for block, lower in levels:
+            er, ec = _decays(g, block, lower)
+            er = jnp.concatenate([er, er], axis=0)
+            rows, cols = x * er, k * ec
+            dp_here = jnp.where(_kept(c, block), dp, 0.0).astype(dt)
+            drows = _dot(dp_here, cols.astype(dt))
+            dcols = _dot(dp_here, rows.astype(dt), _TN)
+            dx += drows * er
+            dcol += dcols * ec
+            # d(G_t - G_r) less d(G_r - G_s): a token's own, and the
+            # block's sum, minus, on its reference token
+            e = drows * rows
+            e = e[:c] + e[c:] - dcols * cols
+            dg += e - _on_reference(e, block, lower)
+        dq_ref[i] = dx[:c].astype(dq_ref.dtype)
+        dk_ref[i] = (dx[c:] + dcol).astype(dk_ref.dtype)
+        dg_ref[i] = dg
+        return carry
+    jax.lax.fori_loop(0, q_ref.shape[0], chunk, 0)
+
+
+def _chunks(x):
+    """``[B, H, n, ...]`` as ``[B H n (+ pad), ...]``, a whole number of
+    grid steps; the chunks added are zeros."""
+    x = x.reshape((-1,) + x.shape[3:])
+    pad = round_up(x.shape[0], CHUNKS) - x.shape[0]
+    return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1)) if pad else x
+
+
+def _blocks(operands, outs):
+    """What a ``pallas_call`` over blocks of ``CHUNKS`` chunks, in any
+    order, takes beside its body and its name: ``operands`` and the
+    results ``outs`` (``(tail, dtype)``) are all ``[chunks, *tail]``."""
+    n = operands[0].shape[0]
+    spec = lambda tail: pl.BlockSpec((CHUNKS,) + tail, lambda i: (i, 0, 0))
+    sds = functools.partial(jax.ShapeDtypeStruct, vma=vma(*operands))
+    return dict(
+        grid=(n // CHUNKS,),
+        in_specs=[spec(x.shape[1:]) for x in operands],
+        out_specs=[spec(tail) for tail, _ in outs],
+        out_shape=[sds((n,) + tail, dtype) for tail, dtype in outs],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret_mode())
+
+
+def _unchunks(xs, lead):
+    """``_chunks`` undone: ``[B, H, n, ...]`` again, the padding off."""
+    return tuple(x[:lead[0] * lead[1] * lead[2]].reshape(lead + x.shape[1:])
+                 for x in xs)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def local_products(levels, q, k, g):
+    """``gated_delta_rule._local_products``, whose text this is: ``(qk, kk)
+    [B, H, n, C, C]`` float32 from ``q, k [B, H, n, C, dk]`` in the
+    products' type and float32 ``g`` (``G``, the running log-decay), level
+    by level (``levels``: ``gated_delta_rule._levels(C)``). The backward
+    keeps ``q``, ``k``, ``g`` and nothing else."""
+    return _local_products_fwd(levels, q, k, g)[0]
+
+
+def _local_products_fwd(levels, q, k, g):
+    c = q.shape[-2]
+    operands = [_chunks(x) for x in (q, k, g)]
+    products = pl.pallas_call(
+        functools.partial(_local_fwd_kernel, levels),
+        name="apex_kda_local_fwd",
+        **_blocks(operands, [((c, c), _F32)] * 2))(*operands)
+    return _unchunks(products, q.shape[:3]), (q, k, g)
+
+
+def _local_products_bwd(levels, residuals, cotangents):
+    operands = [_chunks(x) for x in residuals + tuple(cotangents)]
+    grads = pl.pallas_call(
+        functools.partial(_local_bwd_kernel, levels),
+        name="apex_kda_local_bwd",
+        **_blocks(operands, [(x.shape[-2:], x.dtype) for x in residuals]))(
+            *operands)
+    return _unchunks(grads, residuals[0].shape[:3])
+
+
+local_products.defvjp(_local_products_fwd, _local_products_bwd)

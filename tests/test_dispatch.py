@@ -105,8 +105,9 @@ FAMILIES = {
             **_HP), *[_BUF] * 4, _ROW_IDS),
         set()),
     "gated_delta_rule": (_gdn(), {"apex_gdn_fwd", "apex_gdn_bwd"}),
-    "gated_delta_rule_vector_gate": (_kda(), {"apex_kda_fwd",
-                                              "apex_kda_bwd"}),
+    "gated_delta_rule_vector_gate": (_kda(), {
+        "apex_kda_local_fwd", "apex_kda_local_bwd", "apex_kda_fwd",
+        "apex_kda_bwd"}),
     "expert_layer": (_experts(), {"apex_moe_gmm", "apex_moe_tgmm",
                                   "apex_moe_rowsum"}),
     "decode_dense": (_decode(paged=False), {"apex_decode_dense"}),

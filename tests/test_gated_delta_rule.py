@@ -236,19 +236,25 @@ def test_vector_gate_chunked_is_the_recurrence(length, chunk):
     _close(got, want, "o q k v g beta", 3e-6)
 
 
-@pytest.mark.parametrize("chunk", [32, 64])
-def test_vector_gate_far_past_float32s_exp_range_over_a_chunk(chunk):
+@pytest.mark.parametrize("chunk, sizes", [
+    (32, SCAN), (64, SCAN), pytest.param(64, dict(dk=128, dv=128),
+                                         id="64-kernels")])
+def test_vector_gate_far_past_float32s_exp_range_over_a_chunk(chunk, sizes):
     """A gate of -5 a token a channel is 320 nats over a chunk of 64,
     where ``exp(-G)`` has long overflowed (88.7), beside channels that do
-    not decay at all: finite and the recurrence's, result and gradients."""
-    q, k, v, g, beta = _vector(2 * chunk, seed=7, b=1, h=2, **SCAN)
+    not decay at all: finite and the recurrence's, result and gradients,
+    in ``jax.numpy`` and through the kernels (the levels made in VMEM)."""
+    q, k, v, g, beta = _vector(2 * chunk, seed=7, b=1, h=2, **sizes)
     g = jnp.full_like(g, -5.0).at[..., ::3].set(0.0)
     g = g.at[:, 0, :, 1].set(-0.3)
     assert float(G.chunk_decay_nats(g, chunk)) == pytest.approx(5.0 * chunk)
     assert 5.0 * chunk > 88.7
     args = (q, k, v, g, beta)
     fn = lambda *a: G.gated_delta_rule_chunked(*a, chunk=chunk)
-    _close(_both(fn)(*args), _both(G.gated_delta_rule_recurrent)(*args),
+    with loop(sizes):
+        assert _runs_kernels(fn, *args) == (sizes is not SCAN)
+        got = _both(fn)(*args)
+    _close(got, _both(G.gated_delta_rule_recurrent)(*args),
            "o q k v g beta", 3e-6)
 
 
@@ -336,7 +342,8 @@ def test_the_vector_gates_kernels_are_the_scan(length, chunk, heads, dtype,
     with dispatch.backend("pallas"):
         text = str(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(
             fn(*a).astype(jnp.float32))))(*args))
-        assert "apex_kda_fwd" in text and "apex_kda_bwd" in text \
+        assert all(f"apex_kda_{name}" in text for name in (
+            "fwd", "bwd", "local_fwd", "local_bwd")) \
             and "apex_gdn" not in text
         got = _both(fn)(*args)
     for name, a, b in zip("o q k v g beta".split(), got, want):
@@ -344,6 +351,75 @@ def test_the_vector_gates_kernels_are_the_scan(length, chunk, heads, dtype,
         a, b = a.astype(jnp.float32), b.astype(jnp.float32)
         assert float(jnp.abs(a - b).max()) <= tolerance * float(
             jnp.abs(b).max()), name
+
+
+def _chunks_of(chunk, dtype, seed=14, heads=(1, 2)):
+    """``q, k`` in ``dtype`` and ``G`` ``[B, H, 2, C, 128]`` (two chunks
+    of ``[1, 2, 2 C, 128]``) and a weight for each of the two products."""
+    q, k, _, g, _ = _vector(2 * chunk, seed=seed, dtype=dtype,
+                            **kernels(*heads))
+    split = lambda x: x.reshape(x.shape[:2] + (2, chunk) + x.shape[3:])
+    w = jax.random.normal(jax.random.key(seed), (2,) + q.shape[:2]
+                          + (2, chunk, chunk))
+    return split(q), split(k), jnp.cumsum(split(g), axis=-2), w
+
+
+def _local_both(fn, w):
+    """``fn``'s two products and the gradients of their weighted sum in
+    ``q``, ``k`` and ``G``, one compiled program."""
+    def loss(*a):
+        qk, kk = fn(*a)
+        return jnp.sum(qk * w[0]) + jnp.sum(kk * w[1])
+    return jax.jit(lambda *a: fn(*a) + jax.grad(loss, argnums=(0, 1, 2))(*a))
+
+
+@pytest.mark.parametrize("dtype, tolerance", [(jnp.float32, 1e-6),
+                                              (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("chunk, heads", [(64, (1, 2)), (128, (1, 2)),
+                                          (64, (1, 5))])
+def test_the_local_pair_is_jax_numpys_local_products(chunk, heads, dtype,
+                                                     tolerance):
+    """``apex_kda_local_fwd`` / ``apex_kda_local_bwd`` in interpret mode
+    against ``_local_products``: the products to the bit (the same
+    operands in the same type, one float32 accumulation), the three
+    gradients to rounding (the kernel keeps in float32 what JAX's
+    transposes round to the products' type), with chunks that do (4) and
+    do not (10) fill the kernels' 8 a step."""
+    from apex_tpu.ops.pallas import kda_delta_rule as K
+    q, k, gsum, w = _chunks_of(chunk, dtype, heads=heads)
+    pair = lambda *a: K.local_products(G._levels(chunk), *a)
+    text = str(jax.make_jaxpr(_local_both(pair, w))(q, k, gsum))
+    assert "apex_kda_local_fwd" in text and "apex_kda_local_bwd" in text
+    got = _local_both(pair, w)(q, k, gsum)
+    want = _local_both(G._local_products, w)(q, k, gsum)
+    for a, b in zip(got[:2], want[:2]):
+        np.testing.assert_array_equal(a, b)
+    assert float(jnp.abs(want[1]).max()) > 0.1
+    for name, a, b in zip("q k g".split(), got[2:], want[2:]):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        assert float(jnp.abs(a - b).max()) <= tolerance * float(
+            jnp.abs(b).max()), name
+
+
+@pytest.mark.parametrize("token", [0, 16, 32, 48, 37])
+def test_a_reference_tokens_share_of_the_gates_gradient(token):
+    """A level's reference token collects, minus, what every row and
+    column decayed to it hands ``G``: the kernel's ``dG`` on tokens 0,
+    16, 32, 48 of a chunk of 64 (and on one that is no reference) against
+    central differences of the ``jax.numpy`` form, along a direction
+    over the channels."""
+    from apex_tpu.ops.pallas import kda_delta_rule as K
+    q, k, gsum, w = _chunks_of(64, jnp.float32, seed=15)
+    way = jnp.sign(jax.random.normal(jax.random.key(token), (128,)))
+    step = jnp.zeros_like(gsum).at[0, 1, 1, token].set(1e-2 * way)
+    loss = jax.jit(lambda g: sum(jnp.sum(p * x) for p, x in zip(
+        G._local_products(q, k, g), w)))
+    want = float(loss(gsum + step) - loss(gsum - step)) / 2e-2
+    dg = _local_both(lambda *a: K.local_products(G._levels(64), *a), w)(
+        q, k, gsum)[4]
+    got = float(jnp.sum(dg[0, 1, 1, token] * way))
+    assert abs(want) > 1e-2 and got == pytest.approx(want, rel=2e-2)
 
 
 def test_the_vector_gates_kernels_reach_across_chunks_and_meet_the_recurrence():
@@ -367,6 +443,12 @@ def test_vector_gate_shapes_the_kernels_do_not_take_fall_to_the_scan(
     fn = lambda *a: G.gated_delta_rule_chunked(*a, chunk=chunk)
     with dispatch.backend(backend):
         assert _runs_kernels(fn, *args) == kernel
+        # the local pair and the scan pair go together: both or neither
+        text = str(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(fn(*a))))(
+            *args))
+        assert [name in text for name in (
+            "apex_kda_local_fwd", "apex_kda_local_bwd", "apex_kda_fwd",
+            "apex_kda_bwd")] == [kernel] * 4
         got = jax.jit(lambda *a: fn(*a))(*args)
     np.testing.assert_allclose(
         got, jax.jit(G.gated_delta_rule_recurrent)(*args), atol=2e-6)

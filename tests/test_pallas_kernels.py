@@ -405,6 +405,13 @@ def _kda_grad():
                     argnums=(0, 1, 2, 3, 4, 5))
 
 
+def _kda_local_grad():
+    from apex_tpu.ops.gated_delta_rule import _levels
+    from apex_tpu.ops.pallas import kda_delta_rule as K
+    return jax.grad(lambda *a: sum(x.sum() for x in K.local_products(
+        _levels(64), *a)), argnums=(0, 1, 2))
+
+
 def _moe_grad():
     from apex_tpu.ops.pallas import grouped_matmul as G
     return jax.grad(lambda lhs, w, tile_e, live: G.grouped_matmul(
@@ -451,6 +458,9 @@ def _kernel_sites() -> dict:
         "apex_gdn_bwd": _site(_gdn_grad(), *gdn),
         "apex_kda_fwd": _site(_kda_grad(), *kda),
         "apex_kda_bwd": _site(_kda_grad(), *kda),
+        # the products inside a chunk, from q, k and the running decay
+        "apex_kda_local_fwd": _site(_kda_local_grad(), *kda[:3]),
+        "apex_kda_local_bwd": _site(_kda_local_grad(), *kda[:3]),
         "apex_mt_scale": _site(P.scale, buf, scale_factor=2.0),
         "apex_mt_axpby": _site(lambda x, y: P.axpby(1.0, x, 2.0, y), buf, buf),
         "apex_mt_l2norm": _site(P.l2norm, buf),
