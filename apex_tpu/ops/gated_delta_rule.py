@@ -54,9 +54,11 @@ chunk at a time, ``D = U - W S0``, ``O = (exp(G) Q) S0 + M D``, ``S' =
 Diag(exp(G_last)) S0 + (exp(G_last - G) K)^T D``: on the TPU at the
 kernels' shapes the Pallas pair ``apex_kda_fwd`` / ``apex_kda_bwd``
 (``ops/pallas/kda_delta_rule.py``, imported by this arm alone: the state
-stays in VMEM, transposed, so that a channel's decay is a lane's), the
-same ``lax.scan`` anywhere else. The inverse, ``U``, ``W``, the running
-sum ``G`` and the decayed ``exp(G) Q``, ``exp(G_last - G) K`` are
+stays in VMEM, transposed, so that a channel's decay is a lane's, and the
+decayed ``exp(G) Q``, ``exp(G_last - G) K`` and ``exp(G_last)`` are made
+there from ``q``, ``k``, ``G``, the backward handing out ``dq``, ``dk`` and
+one ``dG``), the same ``lax.scan`` over ``jax.numpy``'s decayed operands
+anywhere else. The inverse, ``U``, ``W`` and the running sum ``G`` are
 ``jax.numpy`` on every platform. **Range:** the vector form equals the
 recurrence while no channel decays by more than float32's largest
 exponent (88.7 nats) over the 15 tokens of a sub-block, ``g >= -5.9`` a
@@ -284,7 +286,9 @@ def chunk_decay_nats(g, chunk: int):
 
 def _chunked_vector(q, k, v, g, beta, chunk: int):
     """The chunked form under a decay a channel, ``g [B, H, L, dk]`` (the
-    module's text)."""
+    module's text). The two Pallas pairs read the same ``q``, ``k`` (in the
+    products' type) and ``G``; only the ``jax.numpy`` arm makes the scan's
+    decayed operands."""
     dt = v.dtype
     b, h, length, dk = q.shape
     pad = (-length) % chunk
@@ -313,13 +317,13 @@ def _chunked_vector(q, k, v, g, beta, chunk: int):
     u = jnp.matmul(t_inv, beta * v.astype(_F32), precision=_HI)
     w = jnp.matmul(t_inv, beta * jnp.exp(gsum) * k.astype(_F32),
                    precision=_HI)
-    last = gsum[..., -1:, :]
-    q_in = q.astype(_F32) * jnp.exp(gsum)
-    k_out = k.astype(_F32) * jnp.exp(last - gsum)
-    through = jnp.exp(last[..., 0, :])                      # [B, H, n, dk]
-    if kernels is not None:
-        o = kernels.chunk_scan(dt, q_in, k_out, w, u, qk, through)
+    if kernels is not None:     # the decayed operands are made in VMEM
+        o = kernels.chunk_scan(q, k, gsum, w, u, qk)
     else:
+        last = gsum[..., -1:, :]
+        q_in = q.astype(_F32) * jnp.exp(gsum)
+        k_out = k.astype(_F32) * jnp.exp(last - gsum)
+        through = jnp.exp(last[..., 0, :])                  # [B, H, n, dk]
         o = _chunk_scan(*(x.astype(dt) for x in (w, u, q_in, k_out, qk)),
                         through)
     return o.reshape(b, h, n * chunk, v.shape[-1])[:, :, :length]

@@ -20,33 +20,43 @@ it, and nothing else is. No decayed operand and nothing with two token
 axes but the two results and their cotangents is ever in HBM.
 
 **Over the chunks.** ``ops/gated_delta_rule.py`` ``_chunked_vector``
-makes in XLA what else holds a decay: the decayed queries ``Q = exp(G)
-q`` and keys ``K = exp(G_last - G) k``, ``W``, ``U`` (behind the inverse)
-and the chunk's decay ``e = exp(G_last) [dk]``. What is left is a
-recurrence over the chunks of a head,
+makes in XLA the masked ``M`` (the local pair's ``qk``), ``U`` and ``W = T
+(beta exp(G) k)`` (behind the inverse). What is left is, from the chunk's
+``q``, ``k`` and running log-decay ``G [C, dk]``, the decayed queries ``Q
+= exp(G) q`` and keys ``K = exp(G_last - G) k``, the chunk's decay ``e =
+exp(G_last) [dk]`` (every exponent <= 0: ``G`` is a running sum of ``g <=
+0``, so nothing overflows at any decay), and a recurrence over the chunks
+of a head,
 
     D  = U - W S
     O  = Q S + M D
     S' = Diag(e) S + K^T D
 
-which holds no exponential at all. :func:`chunk_scan` is that loop
-(``apex_kda_fwd``) with the chunk axis the sequential axis of a grid and
-the state of ``HEADS`` heads in VMEM scratch from a head's first chunk to
-its last, **transposed** (``S^T [dv, dk]``): the decay of a key channel
-is then one number a lane, a row ``[1, dk]`` broadcast down the
-sublanes, where ``S [dk, dv]`` would want it as a column. Its
-``custom_vjp`` is the same loop backwards (``apex_kda_bwd``) with the
-state's cotangent in scratch, from the state each chunk came in with
-(the forward's one residual of its own), and hands every operand's
-cotangent out in float32: ``Q``'s, ``K``'s and ``e``'s are what ``g``'s
-cotangent is summed from in XLA, as differences. Several heads a grid
-step because one head's products are a dependent chain of 64-row
-matmuls; the heads' chains are independent.
+:func:`chunk_scan` is that loop (``apex_kda_fwd``) with the chunk axis the
+sequential axis of a grid, ``Q``, ``K`` and ``e`` made in VMEM from the
+three arrays the local pair reads (the same float32 product and the one
+rounding to the products' type that ``jax.numpy`` gives them: no decayed
+operand is ever in HBM) and the state of ``HEADS`` heads in VMEM scratch
+from a head's first chunk to its last, **transposed** (``S^T [dv, dk]``):
+the decay of a key channel is then one number a lane, a row ``[1, dk]``
+broadcast down the sublanes, where ``S [dk, dv]`` would want it as a
+column. Its ``custom_vjp`` is the same loop backwards (``apex_kda_bwd``)
+with the state's cotangent in scratch, from ``q``, ``k``, ``G`` again and
+the state each chunk came in with (the forward's one residual of its own;
+``q``, ``k``, ``G`` are the local pair's residuals too, ``w``, ``u``, ``m``
+are kept in the products' type). It takes ``Q``'s, ``K``'s and ``e``'s
+cotangents the rest of the way in VMEM and hands out ``dq``, ``dk`` in the
+operands' type and **one** float32 ``dG [C, dk]``: ``dQ Q - dK K`` a
+token, and on the chunk's last token also the column sums of ``dK K`` and
+``e sum_v(S^T dS'^T)``; ``dw``, ``du``, ``dm`` leave in float32. Several
+heads a grid step because one head's products are a dependent chain of
+64-row matmuls; the heads' chains are independent.
 
-The arithmetic is the ``lax.scan``'s (``gated_delta_rule._chunk_scan``):
-every product takes both operands in the products' type and accumulates
-in float32; the state, its cotangent and the decay are float32 and are
-rounded only where they enter a product.
+The arithmetic is the ``lax.scan``'s (``gated_delta_rule._chunk_scan``
+over ``jax.numpy``'s decayed operands): every product takes both operands
+in the products' type and accumulates in float32; ``G``, every ``exp``,
+the state, its cotangent and ``dG`` are float32, rounded only where they
+enter a product.
 """
 
 from __future__ import annotations
@@ -83,7 +93,18 @@ _TN = ((0,), (0,))      # x^T y
 _NT = ((1,), (1,))      # x y^T
 
 
-def _fwd_kernel(q_ref, k_ref, w_ref, u_ref, m_ref, e_ref, o_ref, s0_ref,
+def _decayed(q_ref, k_ref, g_ref, h):
+    """Head ``h``'s chunk from ``q``, ``k``, ``G``: ``Q = exp(G) q`` and ``K
+    = exp(G_last - G) k`` in float32 ``[C, dk]``, the two decays, and ``e =
+    exp(G_last) [1, dk]``. Every exponent is <= 0."""
+    g = g_ref[h]
+    last = g[-1:]
+    into, out = jnp.exp(g), jnp.exp(last - g)
+    return (q_ref[h].astype(_F32) * into, k_ref[h].astype(_F32) * out,
+            into, out, jnp.exp(last))
+
+
+def _fwd_kernel(q_ref, k_ref, g_ref, w_ref, u_ref, m_ref, o_ref, s0_ref,
                 s_ref):
     @pl.when(pl.program_id(1) == 0)
     def _():
@@ -91,18 +112,19 @@ def _fwd_kernel(q_ref, k_ref, w_ref, u_ref, m_ref, e_ref, o_ref, s0_ref,
     dt = q_ref.dtype
 
     def head(h, carry):
+        q, k, _, _, e = _decayed(q_ref, k_ref, g_ref, h)
         st = s0_ref[h] = s_ref[h]                       # S^T [dv, dk]
         st_in = st.astype(dt)
         d = (u_ref[h].astype(_F32) - _dot(w_ref[h], st_in, _NT)).astype(dt)
-        o_ref[h] = (_dot(q_ref[h], st_in, _NT)
+        o_ref[h] = (_dot(q.astype(dt), st_in, _NT)
                     + _dot(m_ref[h], d)).astype(o_ref.dtype)
-        s_ref[h] = st * e_ref[h] + _dot(d, k_ref[h], _TN)
+        s_ref[h] = st * e + _dot(d, k.astype(dt), _TN)
         return carry
     jax.lax.fori_loop(0, HEADS, head, 0)
 
 
-def _bwd_kernel(q_ref, k_ref, w_ref, u_ref, m_ref, e_ref, s0_ref, do_ref,
-                dq_ref, dk_ref, dw_ref, du_ref, dm_ref, de_ref, ds_ref):
+def _bwd_kernel(q_ref, k_ref, g_ref, w_ref, u_ref, m_ref, s0_ref, do_ref,
+                dq_ref, dk_ref, dg_ref, dw_ref, du_ref, dm_ref, ds_ref):
     @pl.when(pl.program_id(1) == 0)         # the chunks run backwards
     def _():
         ds_ref[...] = jnp.zeros_like(ds_ref)
@@ -110,19 +132,29 @@ def _bwd_kernel(q_ref, k_ref, w_ref, u_ref, m_ref, e_ref, s0_ref, do_ref,
 
     def head(h, carry):
         w, do = w_ref[h], do_ref[h]
+        q, k, into, out, e = _decayed(q_ref, k_ref, g_ref, h)
         st, dst = s0_ref[h], ds_ref[h]                  # [dv, dk] both
         st_in, dst_in = st.astype(dt), dst.astype(dt)
         d = (u_ref[h].astype(_F32) - _dot(w, st_in, _NT)).astype(dt)
-        dd = _dot(m_ref[h], do, _TN) + _dot(k_ref[h], dst_in, _NT)
+        # the recurrence's transposes
+        dd = _dot(m_ref[h], do, _TN) + _dot(k.astype(dt), dst_in, _NT)
         dd_in = dd.astype(dt)
         du_ref[h] = dd
         dw_ref[h] = -_dot(dd_in, st_in)
-        dq_ref[h] = _dot(do, st_in)
-        dk_ref[h] = _dot(d, dst_in)
         dm_ref[h] = _dot(do, d, _NT)
-        de_ref[h] = jnp.sum(st * dst, axis=0, keepdims=True)
-        ds_ref[h] = (dst * e_ref[h] + _dot(do, q_ref[h], _TN)
+        dq_in, dk_out = _dot(do, st_in), _dot(d, dst_in)
+        ds_ref[h] = (dst * e + _dot(do, q.astype(dt), _TN)
                      - _dot(dd_in, w, _TN))
+        # through Q, K and e to q, k and G
+        dq_ref[h] = (dq_in * into).astype(dq_ref.dtype)
+        dk_ref[h] = (dk_out * out).astype(dk_ref.dtype)
+        from_k = dk_out * k
+        # G_last: K's exp(G_last - G) and the state's decay, sum_v(S0 dS')
+        at_last = jnp.sum(from_k, axis=0, keepdims=True) + e * jnp.sum(
+            st * dst, axis=0, keepdims=True)
+        rows = jax.lax.broadcasted_iota(jnp.int32, from_k.shape, 0)
+        dg_ref[h] = dq_in * q - from_k + jnp.where(
+            rows == from_k.shape[0] - 1, at_last, 0.0)
         return carry
     jax.lax.fori_loop(0, HEADS, head, 0)
 
@@ -138,46 +170,46 @@ _SEQUENTIAL = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"))
 
 
-def _forward(q, k, w, u, m, e):
+def _forward(q, k, g, w, u, m):
     bh, n, c, dk = q.shape
     dv = u.shape[-1]
     spec = functools.partial(_spec, lambda j: j)
-    sds = functools.partial(jax.ShapeDtypeStruct, vma=vma(q, k, w, u, m, e))
+    sds = functools.partial(jax.ShapeDtypeStruct, vma=vma(q, k, g, w, u, m))
     return pl.pallas_call(
         _fwd_kernel,
         grid=(bh // HEADS, n),
-        in_specs=[spec(c, dk), spec(c, dk), spec(c, dk), spec(c, dv),
-                  spec(c, c), spec(1, dk)],
+        in_specs=[spec(c, dk), spec(c, dk), spec(c, dk), spec(c, dk),
+                  spec(c, dv), spec(c, c)],
         out_specs=[spec(c, dv), spec(dv, dk)],
         out_shape=[sds((bh, n, c, dv), u.dtype), sds((bh, n, dv, dk), _F32)],
         scratch_shapes=[pltpu.VMEM((HEADS, dv, dk), _F32)],
         compiler_params=_SEQUENTIAL,
         interpret=interpret_mode(),
         name="apex_kda_fwd",
-    )(q, k, w, u, m, e)
+    )(q, k, g, w, u, m)
 
 
-def _backward(q, k, w, u, m, e, s0, do):
+def _backward(q, k, g, w, u, m, s0, do):
     bh, n, c, dk = q.shape
     dv = u.shape[-1]
     spec = functools.partial(_spec, lambda j: n - 1 - j)
     sds = functools.partial(jax.ShapeDtypeStruct,
-                            vma=vma(q, k, w, u, m, e, s0, do))
+                            vma=vma(q, k, g, w, u, m, s0, do))
     return pl.pallas_call(
         _bwd_kernel,
         grid=(bh // HEADS, n),
-        in_specs=[spec(c, dk), spec(c, dk), spec(c, dk), spec(c, dv),
-                  spec(c, c), spec(1, dk), spec(dv, dk), spec(c, dv)],
-        out_specs=[spec(c, dk), spec(c, dk), spec(c, dk), spec(c, dv),
-                   spec(c, c), spec(1, dk)],
-        out_shape=[sds(q.shape, _F32), sds(k.shape, _F32),
-                   sds(w.shape, _F32), sds(u.shape, _F32),
-                   sds(m.shape, _F32), sds(e.shape, _F32)],
+        in_specs=[spec(c, dk), spec(c, dk), spec(c, dk), spec(c, dk),
+                  spec(c, dv), spec(c, c), spec(dv, dk), spec(c, dv)],
+        out_specs=[spec(c, dk), spec(c, dk), spec(c, dk), spec(c, dk),
+                   spec(c, dv), spec(c, c)],
+        out_shape=[sds(q.shape, q.dtype), sds(k.shape, k.dtype),
+                   sds(g.shape, _F32), sds(w.shape, _F32),
+                   sds(u.shape, _F32), sds(m.shape, _F32)],
         scratch_shapes=[pltpu.VMEM((HEADS, dv, dk), _F32)],
         compiler_params=_SEQUENTIAL,
         interpret=interpret_mode(),
         name="apex_kda_bwd",
-    )(q, k, w, u, m, e, s0, do)
+    )(q, k, g, w, u, m, s0, do)
 
 
 def _heads(x, pad):
@@ -187,32 +219,33 @@ def _heads(x, pad):
     return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1)) if pad else x
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def chunk_scan(dt, q, k, w, u, m, e):
-    """The chunks' outputs ``[B, H, n, C, dv]`` in ``dt``, the products'
-    type, from float32 ``q, k, w [B, H, n, C, dk]`` (``q`` and ``k``
-    decayed), ``u [B, H, n, C, dv]``, ``m [B, H, n, C, C]`` (masked) and
-    the chunks' decays ``e [B, H, n, dk]``, with the state zero in front of
-    a head's first chunk. The operands are cast here, so that their
-    cotangents leave the backward kernel in float32, rounded nowhere."""
-    return _chunk_scan_fwd(dt, q, k, w, u, m, e)[0]
+@jax.custom_vjp
+def chunk_scan(q, k, g, w, u, m):
+    """The chunks' outputs ``[B, H, n, C, dv]`` in ``q``'s type, the
+    products', from the three arrays :func:`local_products` takes (``q, k
+    [B, H, n, C, dk]`` and float32 ``g``, the running log-decay ``G``
+    inside each chunk) and float32 ``w [B, H, n, C, dk]``, ``u [B, H, n, C,
+    dv]``, ``m [B, H, n, C, C]`` (masked), with the state zero in front of
+    a head's first chunk. ``w``, ``u``, ``m`` are cast here, so that their
+    cotangents leave the backward kernel in float32, rounded nowhere;
+    ``g``'s is float32 and ``q``'s, ``k``'s come in their own type."""
+    return _chunk_scan_fwd(q, k, g, w, u, m)[0]
 
 
-def _chunk_scan_fwd(dt, q, k, w, u, m, e):
+def _chunk_scan_fwd(q, k, g, w, u, m):
     b, h = q.shape[:2]
     pad = round_up(b * h, HEADS) - b * h
-    operands = tuple(_heads(x.astype(dt), pad) for x in (q, k, w, u, m)) \
-        + (_heads(e[..., None, :], pad),)
-    o, s0 = _forward(*operands)
-    return o[:b * h].reshape(u.shape), operands + (s0,)
+    kept = (q, k, g) + tuple(x.astype(q.dtype) for x in (w, u, m))
+    o, s0 = _forward(*(_heads(x, pad) for x in kept))
+    return o[:b * h].reshape(u.shape), kept + (s0,)
 
 
-def _chunk_scan_bwd(dt, residuals, do):
+def _chunk_scan_bwd(residuals, do):
     b, h = do.shape[:2]
-    grads = _backward(*residuals, _heads(do, residuals[0].shape[0] - b * h))
-    dq, dk, dw, du, dm, de = (x[:b * h].reshape((b, h) + x.shape[1:])
-                              for x in grads)
-    return dq, dk, dw, du, dm, de[..., 0, :]
+    *kept, s0 = residuals
+    pad = s0.shape[0] - b * h
+    grads = _backward(*(_heads(x, pad) for x in kept), s0, _heads(do, pad))
+    return tuple(x[:b * h].reshape((b, h) + x.shape[1:]) for x in grads)
 
 
 chunk_scan.defvjp(_chunk_scan_fwd, _chunk_scan_bwd)

@@ -274,6 +274,10 @@ def _grouped_args(rows, held, depth, width):
             ((rows // 128,), I32), ((), I32)]
 
 
+_KDA_ARGS = [((2, 32, 8192, 128), BF16)] * 3 + [((2, 32, 8192, 128), F32),
+                                                ((2, 32, 8192), F32)]
+
+
 # (id, builder, arguments (trees of (shape, dtype)), temporaries allowed in
 #  GB, the kernels the program has to hold): the hybrid LM's new ops at the
 #  published widths and the benchmark cells' 2 x 8192 tokens. The delta
@@ -307,13 +311,13 @@ NEW_OPS = [
      ("apex_gdn_fwd", "apex_gdn_bwd")),
     # Kimi Delta Attention's rule at the published shape ([64, 8192, 128],
     # chunk 64): a decay a key channel, the chunk-local products level by
-    # level in one Pallas pair (3.90 GB of temporaries; 4.86 with the
-    # levels' operands made in jax.numpy), the loop over chunks in the
-    # other with the state transposed; g's cotangent float32
-    # [2, 32, 8192, 128]
+    # level in one Pallas pair, the loop over chunks in the other with the
+    # state transposed and its decayed operands made in VMEM (3.36 GB of
+    # temporaries, and 5% of room; 3.90 with exp(G) q, exp(G_last - G) k
+    # made in jax.numpy, 4.86 with the levels' operands too); g's
+    # cotangent float32 [2, 32, 8192, 128]
     ("kda_delta_rule_fwd_bwd-B2H32S8192D128", _delta_rule,
-     [((2, 32, 8192, 128), BF16)] * 3 + [((2, 32, 8192, 128), F32),
-                                         ((2, 32, 8192), F32)], 4.5,
+     _KDA_ARGS, 3.53,
      ("apex_kda_local_fwd", "apex_kda_local_bwd", "apex_kda_fwd",
       "apex_kda_bwd")),
     # Keye-VL 2.0's lightning indexer at the published shapes (16 heads of
@@ -360,6 +364,14 @@ def test_jnp_op_compiles_and_fits_for_v5e(chip, for_chip, make_fn, args,
                       text) == []
 
 
+@functools.cache
+def _kda_gradient_text(chip) -> str:
+    """The compiled gradient of the vector gate's rule at the published
+    shape, for the two tests that read it."""
+    return jax.jit(_delta_rule()).lower(*_specs(_KDA_ARGS, chip)).compile() \
+        .as_text()
+
+
 def test_the_vector_gates_rule_forms_no_array_of_two_token_axes_and_a_channel_axis(
         chip, for_chip):
     """At the published shape no buffer of the compiled gradient has two
@@ -369,16 +381,31 @@ def test_the_vector_gates_rule_forms_no_array_of_two_token_axes_and_a_channel_ax
     ``[C, C, dk]`` mask. And no level's operands or product (``q``'s rows
     beside ``k``'s, ``[.., 2, 64, 128]`` and ``[.., 2, 64, 64]``) is a
     buffer at all: they are made and used in VMEM."""
-    args = [((2, 32, 8192, 128), BF16)] * 3 + [((2, 32, 8192, 128), F32),
-                                               ((2, 32, 8192), F32)]
-    text = jax.jit(_delta_rule()).lower(*_specs(args, chip)).compile() \
-        .as_text()
+    text = _kda_gradient_text(chip)
     shapes = set(re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
     assert any(s.endswith("64,128") for s in shapes)
     assert not [s for s in shapes if re.search(
         r"(^|,)(64,64,128|16,16,128|8192,8192)(,|$)|,2,64,(128|64)$", s)]
     # g's cotangent: float32, a number a key channel
     assert re.search(r"ENTRY[^\n]*->[^\n]*f32\[2,32,8192,128\]", text)
+
+
+def test_the_vector_gates_scan_decays_its_operands_in_vmem_for_v5e(
+        chip, for_chip):
+    """XLA makes no decayed operand of the loop over chunks: of the
+    exponentials the compiled gradient holds outside its kernels (a
+    kernel's own are in its serialized body, not in the text) every one is
+    ``exp(G)`` over ``[2, 32, 128, 64, 128]``, the operand of ``W = T (beta
+    exp(G) k)`` and its transposes (4; 9 with ``exp(G) q`` and ``exp(G_last
+    - G) k`` in ``jax.numpy``): none of a difference (``G_last - G``), none
+    of a slice (``exp(G_last) [.., 1, 128]``), none over the scan pair's
+    ``[64, 128, 64, 128]``."""
+    text = _kda_gradient_text(chip)
+    over = re.findall(r" = f32\[([0-9,]+)\][^ ]* exponential\(%([a-z_]+)",
+                      text)
+    assert over and {shape for shape, _ in over} == {"2,32,128,64,128"}
+    assert len(over) <= 4
+    assert not [of for _, of in over if of.startswith(("sub", "slice"))]
 
 
 def test_the_combine_and_the_dead_rows_cost_no_pass_for_v5e(chip, for_chip):

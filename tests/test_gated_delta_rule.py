@@ -329,12 +329,18 @@ def test_the_scalar_path_is_the_parents_to_the_bit(length, chunk):
 @pytest.mark.parametrize("length, chunk, heads, dtype, tolerance", [
     (128, 64, (2, 8), jnp.float32, 1e-6),
     (128, 64, (1, 8), jnp.bfloat16, 2e-2),
-    (200, 128, (1, 3), jnp.float32, 1e-6)])
+    (200, 128, (1, 3), jnp.float32, 1e-6),
+    (192, 64, (1, 9), jnp.bfloat16, 2e-2),
+    (100, 64, (1, 1), jnp.float32, 1e-6)])
 def test_the_vector_gates_kernels_are_the_scan(length, chunk, heads, dtype,
                                                tolerance):
-    """``apex_kda_fwd`` / ``apex_kda_bwd`` in interpret mode against the
-    ``lax.scan`` form on the same inputs, result and every gradient, with
-    (batch, heads) that do and do not fill the kernels' 8 heads a step."""
+    """The op through its two Pallas pairs in interpret mode against the
+    ``lax.scan`` form over ``jax.numpy``'s decayed operands (the parent's
+    arithmetic) on the same inputs: **the result to the bit** (the local
+    pair's products, and ``exp(G) q``, ``exp(G_last - G) k`` made in VMEM
+    by the same float32 product and the one rounding), every gradient to
+    rounding, with (batch, heads) that do and do not fill the kernels' 8
+    heads a step."""
     args = _vector(length, seed=11, dtype=dtype, **kernels(*heads))
     fn = lambda *a: G.gated_delta_rule_chunked(*a, chunk=chunk)
     assert not _runs_kernels(fn, *args)
@@ -346,11 +352,120 @@ def test_the_vector_gates_kernels_are_the_scan(length, chunk, heads, dtype,
             "fwd", "bwd", "local_fwd", "local_bwd")) \
             and "apex_gdn" not in text
         got = _both(fn)(*args)
+    np.testing.assert_array_equal(got[0], want[0])
     for name, a, b in zip("o q k v g beta".split(), got, want):
         assert a.dtype == b.dtype and a.shape == b.shape
         a, b = a.astype(jnp.float32), b.astype(jnp.float32)
         assert float(jnp.abs(a - b).max()) <= tolerance * float(
             jnp.abs(b).max()), name
+
+
+def _scan_operands(chunk, dtype, heads, case="random", n=2, seed=16,
+                   bias=-1.0):
+    """What ``apex_kda_fwd`` takes, ``n`` chunks of ``heads``: ``q, k`` in
+    ``dtype``, ``G`` and float32 ``w, u, m`` (``m`` masked), and a weight
+    for the outputs. ``case``: ``steep`` decays 5 nats a token in two
+    channels of three (320 a chunk of 64); ``late`` weighs the last chunk's
+    outputs alone, so that the first chunk's gradients come through the
+    state's cotangent."""
+    q, k, v, g, _ = _vector(n * chunk, seed=seed, dtype=dtype, bias=bias,
+                            **kernels(*heads))
+    if case == "steep":
+        g = jnp.full_like(g, -5.0).at[..., ::3].set(-0.05)
+    split = lambda x: x.reshape(x.shape[:2] + (n, chunk) + x.shape[3:])
+    ks = jax.random.split(jax.random.key(seed + 1), 4)
+    w = 0.1 * jax.random.normal(ks[0], split(q).shape)
+    u = jax.random.normal(ks[1], split(v).shape)
+    m = 0.2 * jnp.tril(jax.random.normal(ks[2], w.shape[:-1] + (chunk,)))
+    weight = jax.random.normal(ks[3], u.shape)
+    if case == "late":
+        weight = weight.at[:, :, :-1].set(0.0)
+    return (split(q), split(k), jnp.cumsum(split(g), axis=-2), w, u, m), \
+        weight
+
+
+def _scan_oracle(q, k, g, w, u, m):
+    """``_chunk_scan`` over ``jax.numpy``'s decayed operands, as
+    ``_chunked_vector`` calls it off the TPU."""
+    dt, f32 = q.dtype, jnp.float32
+    last = g[..., -1:, :]
+    q_in = q.astype(f32) * jnp.exp(g)
+    k_out = k.astype(f32) * jnp.exp(last - g)
+    return G._chunk_scan(*(x.astype(dt) for x in (w, u, q_in, k_out, m)),
+                         jnp.exp(last[..., 0, :]))
+
+
+def _scan_both(fn, weight):
+    """``fn``'s outputs and the gradients of their weighted sum in all six
+    operands, one compiled program."""
+    loss = lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * weight)
+    return jax.jit(lambda *a: (fn(*a),) + jax.grad(
+        loss, argnums=tuple(range(6)))(*a))
+
+
+@pytest.mark.parametrize("chunk, heads, dtype, tolerance, case", [
+    (64, (1, 1), jnp.float32, 5e-7, "random"),
+    (64, (1, 3), jnp.float32, 5e-7, "random"),
+    (128, (1, 9), jnp.float32, 5e-7, "random"),
+    (64, (2, 8), jnp.bfloat16, 2e-2, "random"),
+    (128, (1, 3), jnp.bfloat16, 2e-2, "random"),
+    (64, (1, 3), jnp.float32, 5e-7, "steep"),
+    (64, (1, 9), jnp.bfloat16, 2e-2, "steep"),
+    (64, (1, 3), jnp.float32, 5e-7, "late"),
+    (64, (1, 1), jnp.bfloat16, 2e-2, "late")])
+def test_the_scan_pair_is_the_scan_over_jax_numpys_decayed_operands(
+        chunk, heads, dtype, tolerance, case):
+    """``apex_kda_fwd`` / ``apex_kda_bwd`` in interpret mode on ``q``,
+    ``k``, ``G`` against ``_chunk_scan`` on the operands ``jax.numpy``
+    decays: the outputs to the bit (the same float32 product, the one
+    rounding to the products' type), ``dq``, ``dk``, ``dw``, ``du``, ``dm``
+    and the one ``dG`` to rounding against JAX's differentiation of that
+    form, with heads that do and do not fill the kernels' 8 a step; at 320
+    nats a chunk every number finite; with the last chunk's outputs alone
+    weighed, the first chunk's ``dG`` nonzero on its last token (the
+    state's cotangent, through ``e`` and ``K``)."""
+    from apex_tpu.ops.pallas import kda_delta_rule as K
+    args, weight = _scan_operands(chunk, dtype, heads, case)
+    text = str(jax.make_jaxpr(_scan_both(K.chunk_scan, weight))(*args))
+    assert "apex_kda_fwd" in text and "apex_kda_bwd" in text
+    got = _scan_both(K.chunk_scan, weight)(*args)
+    want = _scan_both(_scan_oracle, weight)(*args)
+    np.testing.assert_array_equal(got[0], want[0])
+    assert got[0].dtype == dtype and got[3].dtype == jnp.float32
+    if case == "late":
+        assert float(jnp.abs(got[3][:, :, 0, -1]).max()) > 1e-3
+    for name, a, b in zip("q k g w u m".split(), got[1:], want[1:]):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert bool(jnp.isfinite(a).all()), name
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        assert float(jnp.abs(a - b).max()) <= tolerance * float(
+            jnp.abs(b).max()), name
+
+
+@pytest.mark.parametrize("chunk_index, token", [(0, 63), (1, 63), (1, 20),
+                                                (2, 3)])
+def test_a_chunks_last_tokens_share_of_the_scan_pairs_gates_gradient(
+        chunk_index, token):
+    """The scan pair's ``dG`` on a chunk's last token holds, beside the
+    token's own ``dQ Q - dK K``, the column sums of ``dK K`` and the
+    state's decay ``e sum_v(S^T dS'^T)``: on token 63 of the first and of
+    the second chunk of three, and on tokens that are not last (one in the
+    last chunk, with no state after it), against central differences of
+    the ``jax.numpy`` form in float32, along a direction over the
+    channels (slow decays and the loss summed in float64, so that no
+    path's share is lost in the sum's rounding)."""
+    from apex_tpu.ops.pallas import kda_delta_rule as K
+    (q, k, g, w, u, m), weight = _scan_operands(64, jnp.float32, (1, 3),
+                                                n=3, seed=17, bias=-3.0)
+    way = jnp.sign(jax.random.normal(jax.random.key(token), (128,)))
+    step = jnp.zeros_like(g).at[0, 1, chunk_index, token].set(1e-2 * way)
+    outs = jax.jit(lambda g: _scan_oracle(q, k, g, w, u, m))
+    loss = lambda g: float(np.sum(np.asarray(outs(g), np.float64)
+                                  * np.asarray(weight, np.float64)))
+    want = (loss(g + step) - loss(g - step)) / 2e-2
+    dg = _scan_both(K.chunk_scan, weight)(q, k, g, w, u, m)[3]
+    got = float(jnp.sum(dg[0, 1, chunk_index, token] * way))
+    assert abs(want) > 3e-3 and got == pytest.approx(want, rel=2e-2)
 
 
 def _chunks_of(chunk, dtype, seed=14, heads=(1, 2)):

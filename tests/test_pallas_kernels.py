@@ -401,7 +401,7 @@ def _gdn_grad():
 
 def _kda_grad():
     from apex_tpu.ops.pallas import kda_delta_rule as K
-    return jax.grad(lambda *a: K.chunk_scan(jnp.float32, *a).sum(),
+    return jax.grad(lambda *a: K.chunk_scan(*a).sum(),
                     argnums=(0, 1, 2, 3, 4, 5))
 
 
@@ -432,10 +432,9 @@ def _kernel_sites() -> dict:
     qkv = _f32(2, 256, 128)
     # two chunks of 64 tokens of two heads of 128
     gdn = [_f32(1, 2, 2, 64, 128)] * 4 + [_f32(1, 2, 2, 64)]
-    # the same under a decay a channel: the masked products come as an
-    # operand, the chunks' decays a row of 128
-    kda = [_f32(1, 2, 2, 64, 128)] * 4 + [_f32(1, 2, 2, 64, 64),
-                                          _f32(1, 2, 2, 128)]
+    # the same under a decay a channel: q, k, the running decay a channel
+    # G, w, u, and the masked products as an operand
+    kda = [_f32(1, 2, 2, 64, 128)] * 5 + [_f32(1, 2, 2, 64, 64)]
     # two tiles of 128 rows over two experts' [128, 256]
     moe = [_f32(256, 128), _f32(2, 128, 256), _i32(2), _i32()]
     # the indexer's kernels: 64 queries of 2 heads against 128 keys; the
