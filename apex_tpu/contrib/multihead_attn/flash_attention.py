@@ -20,12 +20,15 @@ Design notes:
   (initialized on its first step, finalized on its last).
 - a block's kind is known from the offsets alone (``_block_kind``) before
   any body runs: a dead block (wholly above the causal diagonal, past the
-  k length, or wholly farther back than a sliding ``window`` reaches) gets
+  k length, wholly farther back than a sliding ``window`` reaches, or
+  holding no pair the ``block_diffusion`` mask shows) gets
   no step at all, so it is neither fetched nor computed; ``block_census``
   counts the dead, the interior (no element masked) and the edge blocks
   (the diagonal, the window's trailing edge, the k length's last block).
   With a window the grid is the band: a row's sweep is as long as the
-  window is wide, whatever the sequence's length.
+  window is wide, whatever the sequence's length. Under ``block_diffusion``
+  (``BlockDiffusion``: a sequence's noised copy beside its clean one) the
+  grid is two block-causal triangles and the noised copy's own diagonal.
 - softmax statistics are carried as (block_q, 128) lane-replicated tiles
   (the VPU-friendly layout); ``lse`` is emitted lane-replicated and sliced
   by the wrapper.
@@ -58,7 +61,7 @@ numerics oracle and the ``APEX_TPU_FLASH_BWD=chunked`` fallback.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -184,11 +187,72 @@ def _selected(s, sel, kb):
     return jnp.where(keep != 0, s, NEG_INF)
 
 
+class BlockDiffusion(NamedTuple):
+    """The block-diffusion mask over ``2 * length`` rows, queries and keys
+    alike: rows ``[0, length)`` are a sequence's noised copy, rows
+    ``[length, 2 * length)`` its clean one, both cut into blocks of
+    ``block`` positions. A noised row sees its own noised block (both
+    ways) and the clean blocks before it; a clean row sees the clean blocks
+    up to its own, its own whole; no clean row sees a noised one. Plain
+    integers: the mask is structure, and the grids are built from it. It
+    rides where a ``window`` does (the kernels' static ``window``), under
+    ``causal=False``."""
+    block: int
+    length: int
+
+    def visible(self, a, b):
+        """Whether query row ``a`` sees key row ``b`` (integer arrays
+        that broadcast; numpy or jax.numpy)."""
+        block, length = self
+        noised, k_noised = a < length, b < length
+        # a key's position among the noised keys and among the clean ones,
+        # out of every query's reach where it is of the other kind (so is
+        # a key past the 2 * length rows); what varies with the query
+        # alone or the key alone stays a column or a row
+        own = b + 2 * length * ~k_noised
+        past = b - length + 2 * length * k_noised
+        lo = (a - length * ~noised) // block * block    # the query's block
+        return ((own >= lo) & (own < (lo + block) * noised)) \
+            | (past < lo + block * ~noised)
+
+    def tile_kind(self, k_len, q0, k0, bq: int, bk: int):
+        """``(live, interior)`` of the tiles of ``bq`` rows from ``q0`` and
+        ``bk`` keys from ``k0`` (numpy, broadcasting): some pair visible,
+        every pair visible. A tile is split at ``length`` into its noised
+        and its clean rows and keys; each of the three quadrants that show
+        anything is a comparison of first and last blocks."""
+        block, length = self
+        q1 = np.minimum(q0 + bq, 2 * length) - 1    # the last real row
+        k1 = np.minimum(k0 + bk, k_len) - 1
+
+        def blk(i):
+            return i // block
+        qn, qc, kn, kc = q0 < length, q1 >= length, k0 < length, k1 >= length
+        qn1, kn1 = np.minimum(q1, length - 1), np.minimum(k1, length - 1)
+        qc0, kc0 = (np.maximum(x, length) - length for x in (q0, k0))
+        qc1, kc1 = q1 - length, k1 - length
+        live = (k0 < k_len) & (
+            (qn & kn & (blk(q0) <= blk(kn1)) & (blk(k0) <= blk(qn1)))
+            | (qn & kc & (blk(kc0) < blk(qn1)))
+            | (qc & kc & (blk(kc0) <= blk(qc1))))
+        interior = live & ~(qc & kn) & (k0 + bk <= k_len) \
+            & (~(qn & kn) | ((blk(q0) == blk(qn1)) & (blk(k0) == blk(kn1))
+                             & (blk(q0) == blk(k0)))) \
+            & (~(qn & kc) | (blk(kc1) < blk(q0))) \
+            & (~(qc & kc) | (blk(kc1) <= blk(qc0)))
+        return live, interior
+
+
 def _masked_scores(s, off_ref, qb, kb, causal, window=None):
     """Apply causal (global positions from SMEM offsets), sliding-window
-    (``window`` keys back from the query, itself included) and k-length
+    (``window`` keys back from the query, itself included; a
+    ``BlockDiffusion`` in its place: that mask, rows from 0) and k-length
     (local padding, offs[2]) masks to a [bq, bk] score block."""
     bq, bk = s.shape
+    if isinstance(window, BlockDiffusion):      # it shows no padded key
+        rows = qb * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+        keys = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        return jnp.where(window.visible(rows, keys), s, NEG_INF)
     k_local = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     s = jnp.where(k_local < off_ref[2], s, NEG_INF)
     if causal:
@@ -205,15 +269,19 @@ def _masked_scores(s, off_ref, qb, kb, causal, window=None):
 def _block_kind(offs, qb, kb, bq, bk, causal, window=None):
     """``(live, interior)`` of score block (qb, kb), from the offsets
     alone. Not live (dead): wholly above the causal diagonal, past the
-    k length or farther back than ``window - 1`` keys, nothing to compute,
-    and no grid step. Interior: no element masked. Live and not interior
-    (edge): the diagonal, the window's trailing edge and the k length's
-    last block. The kernels act on live alone: a body without masks for
+    k length, farther back than ``window - 1`` keys or with no pair a
+    ``BlockDiffusion`` shows, nothing to compute, and no grid step.
+    Interior: no element masked. Live and not interior (edge): the
+    diagonal, the window's trailing edge and the k length's last block.
+    The kernels act on live alone: a body without masks for
     the interior blocks measured nothing on the chip (PERF.md, PR 35);
     ``block_census`` counts all three. ``offs`` indexes as (q_start, k_start,
     k_len, ...): the kernels' SMEM ref, or plain integers in
-    ``block_census``."""
+    ``block_census``. ``window``: the band's width, or a ``BlockDiffusion``
+    (plain offsets alone: ``BlockDiffusion.tile_kind``)."""
     k_lo = kb * bk
+    if isinstance(window, BlockDiffusion):
+        return window.tile_kind(offs[2], qb * bq, k_lo, bq, bk)
     live = k_lo < offs[2]
     interior = k_lo + bk <= offs[2]
     if causal:
@@ -316,19 +384,21 @@ def _run_step(code, init, body, finalize):
 def block_census(sq: int, sk: int, block_q: int, block_k: int, causal: bool,
                  q_start: int = 0, k_start: int = 0,
                  k_len: Optional[int] = None,
-                 window: Optional[int] = None) -> dict:
+                 window: Optional[int] = None,
+                 block_diffusion: Optional[tuple] = None) -> dict:
     """Grid steps by kind for one batch-head, ``{"dead", "interior",
     "edge"}``, from the kernels' own predicate: of a kernel over ``sq`` x
     ``sk`` scores in ``block_q`` x ``block_k`` blocks, the blocks that get
     no step (with traced offsets an empty one), and of those that get one
     the blocks no mask touches and the blocks one does. ``sq``, ``sk``: the lengths the grid tiles
     (the backward's are the forward's padded ones); ``k_len``: the
-    unpadded key length, ``sk`` by default; ``window``: as
-    ``flash_attention``'s (the band's blocks are live, the rest dead)."""
+    unpadded key length, ``sk`` by default; ``window``,
+    ``block_diffusion``: as ``flash_attention``'s (the band's blocks, the
+    blocks that hold a visible pair, are live, the rest dead)."""
     _, _, live, interior = _grid_kinds(
         np, (q_start, k_start, sk if k_len is None else k_len),
         -(-sq // block_q), -(-sk // block_k), block_q, block_k, causal,
-        window)
+        _structure(window, block_diffusion))
     return {"dead": int((~live).sum()), "interior": int(interior.sum()),
             "edge": int((live & ~interior).sum())}
 
@@ -447,10 +517,13 @@ def _heads(sel, bh: int) -> int:
 
 
 def _family(window, sel) -> str:
-    """What ``flash_`` reads in a call's name: a windowed call and one over
-    a selected key set have names of their own (``apex_flash_win_fwd``,
-    ``apex_flash_sel_fwd``), so a trace tells a layer kind's kernels
+    """What ``flash_`` reads in a call's name: a windowed call, one over
+    a selected key set and one under the block-diffusion mask have names
+    of their own (``apex_flash_win_fwd``, ``apex_flash_sel_fwd``,
+    ``apex_flash_bd_fwd``), so a trace tells a layer kind's kernels
     apart."""
+    if isinstance(window, BlockDiffusion):
+        return "flash_bd_"
     return "flash_sel_" if sel is not None else \
         "flash_" if window is None else "flash_win_"
 
@@ -780,18 +853,21 @@ def reference_attention(q, k, v, bias=None, *, kv_bias=None,
                         causal=False, scale=None,
                         q_start=0, k_start=0, return_lse=False,
                         dropout_rate=0.0, dropout_seed=0, window=None,
-                        select=None):
+                        select=None, block_diffusion=None):
     """Unfused jnp attention with the same (out, lse) contract — the
     impl='default' path (reference: the torch-composed SelfAttnFunc,
     apex/contrib/multihead_attn/self_multihead_attn_func.py:4) and the
     numerics oracle for the kernel tests. ``dropout_rate`` applies
     dropout to the softmax probabilities with the SAME coordinate-hash
     mask as the flash kernel, so the two impls agree bit-for-bit on which
-    weights are dropped. ``window``, ``select``: as ``flash_attention``'s."""
+    weights are dropped. ``window``, ``select``, ``block_diffusion``: as
+    ``flash_attention``'s."""
     import math
     _check_window(window, causal)
     sq, d = q.shape[-2], q.shape[-1]
     sk = k.shape[-2]
+    _check_block_diffusion(block_diffusion, sq, sk, causal, window, select,
+                           bias, q_start, k_start)
     if scale is None:
         scale = 1.0 / float(d) ** 0.5
     s = jnp.einsum("...qd,...kd->...qk", q.astype(jnp.float32),
@@ -811,6 +887,9 @@ def reference_attention(q, k, v, bias=None, *, kv_bias=None,
         keep = keep[:, None] if q.ndim == 4 else jnp.repeat(
             keep, q.shape[0] // keep.shape[0], axis=0)
         s = jnp.where(keep, s, NEG_INF)
+    if block_diffusion is not None:
+        s = jnp.where(_structure(None, block_diffusion).visible(
+            jnp.arange(sq)[:, None], jnp.arange(sk)[None, :]), s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     m = jnp.maximum(m, NEG_INF)
     p = jnp.where(s > NEG_INF * 0.5, jnp.exp(s - m), 0.0)
@@ -838,7 +917,8 @@ def _bwd_chunked(res, do, dlse, *, causal, scale, block_k, bias_grad=True,
     """Flash backward: recompute p per K/V block from (q, k, v, lse), scan
     over blocks accumulating dq and emitting (dk, dv) — O(S·block) memory
     (the flash backward recurrence; replaces saving the S×S softmax the way
-    the reference kernels recompute from saved softmax results)."""
+    the reference kernels recompute from saved softmax results).
+    ``window``: the band's width or a ``BlockDiffusion``, as the kernels'."""
     q, k, v, bias, kvb, offs, lse, o = res
     bh, sq, d = q.shape
     sk = k.shape[1]
@@ -890,6 +970,9 @@ def _bwd_chunked(res, do, dlse, *, causal, scale, block_k, bias_grad=True,
             s = jnp.where(ahead >= 0, s, NEG_INF)
             if window is not None:
                 s = jnp.where(ahead < window, s, NEG_INF)
+        elif isinstance(window, BlockDiffusion):
+            s = jnp.where(window.visible(jnp.arange(sq)[:, None],
+                                         k_local[None, :]), s, NEG_INF)
         p = jnp.where(s > NEG_INF * 0.5,
                       jnp.exp(s - lse[:, :, None]), 0.0)   # [bh, sq, bk]
         dp = jnp.einsum("bqd,bkd->bqk", do, vjf)
@@ -1048,6 +1131,36 @@ def _check_window(window, causal) -> None:
                          "from the query's own position")
 
 
+def _check_block_diffusion(bd, sq, sk, causal, window, select, bias,
+                           q_start, k_start) -> None:
+    if bd is None:
+        return
+    if causal or window is not None or select is not None \
+            or bias is not None:
+        raise ValueError("block_diffusion is a mask of its own: it goes "
+                         "with causal=False and with neither a window, a "
+                         "select nor a bias")
+    if len(bd) != 2 or not all(isinstance(x, (int, np.integer)) and x >= 1
+                               for x in bd) or bd[1] % bd[0]:
+        raise ValueError(f"block_diffusion must be (block, length), plain "
+                         f"positive integers (they shape the grids) with "
+                         f"block dividing length, got {bd!r}")
+    if sq != 2 * bd[1] or sk != sq or not all(
+            isinstance(x, (int, np.integer)) and x == 0
+            for x in (q_start, k_start)):
+        raise ValueError(f"block_diffusion=(.., {bd[1]}) masks {2 * bd[1]} "
+                         f"rows against themselves from position 0 (the "
+                         f"noised copy, then the clean one), got {sq} x "
+                         f"{sk} at ({q_start!r}, {k_start!r})")
+
+
+def _structure(window, block_diffusion):
+    """The kernels' static ``window``: the band's width, or the
+    ``BlockDiffusion`` that rides in its place."""
+    return window if block_diffusion is None \
+        else BlockDiffusion(*block_diffusion)
+
+
 def _capped(block: int, padded: int, most: int) -> int:
     """``block``, or where it is wider than ``most`` the largest of {512,
     384, 256, 192, 128} up to ``most`` that divides the padded length (a
@@ -1133,7 +1246,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     dropout_rate: float = 0.0,
                     dropout_seed=0,
                     window: Optional[int] = None,
-                    select: Optional[jax.Array] = None):
+                    select: Optional[jax.Array] = None,
+                    block_diffusion: Optional[tuple] = None):
     """Fused attention over [B, H, S, D] (or [BH, S, D]) inputs.
 
     bias: optional additive [1|BH, Sq, Sk] (or [B, H, Sq, Sk]) score bias —
@@ -1181,8 +1295,21 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     wide (whole bits of a packed word), and the three calls are named
     ``apex_flash_sel_fwd`` / ``_sel_bwd_dq`` / ``_sel_bwd_dkv``. Neither
     a ``bias`` nor a ``window`` goes with it. ``None``: today's call.
+    ``block_diffusion``: ``(block, length)``, plain integers, under
+    ``causal=False``: the ``2 * length`` rows are a sequence's noised copy
+    followed by its clean one, in blocks of ``block`` positions, and row
+    ``a`` sees row ``b`` iff both are noised and of one block, or ``b`` is
+    clean and its block lies before ``a``'s (``a`` noised) or up to
+    ``a``'s (``a`` clean): ``BlockDiffusion``. The three grids hold exactly
+    the tiles with a visible pair (``block_census(block_diffusion=)``),
+    the blocks are the causal defaults, and the calls are named
+    ``apex_flash_bd_fwd`` / ``_bd_bwd_dq`` / ``_bd_bwd_dkv``. Neither a
+    ``window``, a ``select`` nor a ``bias`` goes with it, and the rows
+    start at position 0. ``None``: today's call.
     """
     _check_window(window, causal)
+    _check_block_diffusion(block_diffusion, q.shape[-2], k.shape[-2], causal,
+                           window, select, bias, q_start, k_start)
     squeeze = q.ndim == 4
     if squeeze:
         b, h, _, _ = q.shape
@@ -1252,7 +1379,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         isinstance(x, (int, np.integer)) for x in (q_start, k_start)) else None
     if select is not None and qpad:
         select = jnp.pad(select, ((0, 0), (0, qpad), (0, 0)))
-    out, lse = _flash_core(qq, kk, vv, bb, kvb, select, causal, window,
+    out, lse = _flash_core(qq, kk, vv, bb, kvb, select, causal,
+                           _structure(window, block_diffusion),
                            float(scale), block_q, block_k, bwd_block_q,
                            bwd_block_k, bool(bias_grad), float(dropout_rate),
                            known, offs)

@@ -19,6 +19,8 @@ short-convolution hybrids, with the layer pattern as data.
     loss = xent(norm(x) @ head^T) + aux_coef * sum of the routers'
            load-balancing terms + index_coef * sum of the "sparse"
            layers' indexer losses
+           next-token (row t predicts token t + 1, every row weighs 1)
+           or, with ``block_diffusion``, block diffusion's (below)
 
 ``norm(x; w) = x * rsqrt(mean(x^2) + eps) * (1 + w)`` is the zero-centred
 RMSNorm (weight zero at init) or, with ``zero_centred_norm=False``, the
@@ -60,6 +62,25 @@ its heads with the plain table at ``rope_theta``, a full layer with
 ``factor`` below the ramp over ``original positions``, extrapolated above
 it, blended between ``beta_fast`` and ``beta_slow`` rotations) and its
 ``attention_factor`` on ``cos`` and ``sin``.
+
+**Block diffusion** (``block_diffusion = B`` > 0, over "full" layers
+without a gate; SDAR's training form): a sequence of ``L`` tokens runs as
+``2 L`` rows, its noised copy (a masked position holds the mask token, id
+``vocab_size - 1``) followed by its clean one, both at rotary positions
+``0 .. L - 1``; the attention mixer's mask is
+``flash_attention(block_diffusion=(B, L))``'s under ``causal=False`` (a
+noised block sees itself and the clean blocks before it, a clean row the
+clean blocks up to its own, no clean row a noised one), under a scope of
+its own; the head runs over the noised half alone and a masked position
+predicts its own token (no shift) with weight ``1 / p``, ``p`` the
+probability its sequence was masked with
+(:meth:`HybridLM.diffusion_loss_with_counters`; ``loss_with_counters``
+takes the triple ``(tokens, masked, p)`` in the tokens' place). Such a
+mixer also hands out what layer 0's heads made for the first
+``PROBE_ROWS`` noised rows (the counter ``diffusion_probe``): every masked
+row enters layer 0 as one embedding row and the routers read them alike,
+so a forward reading made before any router is what a comparison with a
+reference can rest on.
 
 The **sparse mixer** (``"sparse"``: DeepSeek-V3.2's lightning indexer
 over the ungated attention mixer, leaf for leaf, as Keye-VL-2.0 has it):
@@ -128,7 +149,8 @@ batch statistics travel through ``train_step.build_step``.
 
 Scopes (``prof.SCOPES``): ``embed``, ``linear_attention``,
 ``delta_rule``, ``kda_attention``, ``attention``, ``window_attention``,
-``sparse_attention``, ``sparse_index``, ``latent_attention``,
+``diffusion_attention``, ``sparse_attention``, ``sparse_index``,
+``latent_attention``,
 ``short_conv``, ``mlp``, ``moe_route``,
 ``moe_experts``, ``head_loss``; siblings, never
 nested. Regions (``prof.REGIONS``), around the scopes: ``layer_stack``
@@ -170,6 +192,7 @@ __all__ = ["HybridLM"]
 
 _F32 = jnp.float32
 MIXERS = ("linear", "full", "latent", "conv", "window", "sparse", "kda")
+PROBE_ROWS = 32     # rows of layer 0's attention a block-diffusion step shows
 FFNS = ("experts", "dense")
 
 
@@ -207,15 +230,19 @@ def _yarn(freq, theta: float, rot: int, yarn: Yarn):
     return freq / yarn.factor * ramp + freq * (1.0 - ramp)
 
 
-def _rotary(x, theta: float, rot: int, yarn: Optional[Yarn] = None):
+def _rotary(x, theta: float, rot: int, yarn: Optional[Yarn] = None,
+            positions=None):
     """Rotary positions on the first ``rot`` of the last axis of
     ``x [B, T, H, D]``, half-split pairing; ``yarn``: ``_yarn``'s
-    frequencies and its attention factor on ``cos`` and ``sin``."""
+    frequencies and its attention factor on ``cos`` and ``sin``;
+    ``positions [T]``: the rows' positions, ``arange(T)`` where not given."""
     half = rot // 2
     freq = theta ** (-jnp.arange(half, dtype=_F32) / half)
     if yarn:
         freq = _yarn(freq, theta, rot, yarn)
-    ang = jnp.arange(x.shape[1], dtype=_F32)[:, None] * freq    # [T, half]
+    if positions is None:
+        positions = jnp.arange(x.shape[1], dtype=_F32)
+    ang = positions.astype(_F32)[:, None] * freq                # [T, half]
     cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
     if yarn:
         cos, sin = (cos * yarn.attention_factor,
@@ -250,6 +277,8 @@ class HybridLM:
     window: int = 0             # keys a "window" layer's query sees
     rope_yarn: Optional[Yarn] = None    # the "full" layers' rotary
     #                             scaling; None = the plain table
+    block_diffusion: int = 0    # > 0: trained by block diffusion, in
+    #                             blocks of that many positions
     # the "sparse" layers' indexer (ops.sparse_index)
     index_heads: int = 16
     index_dim: int = 64
@@ -312,6 +341,16 @@ class HybridLM:
         if self.rope_yarn is not None \
                 and not isinstance(self.rope_yarn, Yarn):
             raise ValueError("rope_yarn: a hybrid_lm.Yarn or None")
+        if self.block_diffusion and (
+                set(self.layer_types) != {"full"} or self.attn_gate
+                or self.block_diffusion < 0):
+            raise ValueError(
+                "block_diffusion: the block length, a positive integer, "
+                "over \"full\" layers without an output gate (the mask is "
+                "the block-diffusion one: no other mixer kind, no window "
+                f"and no gate is defined under it), got "
+                f"{self.block_diffusion} with {self.layer_types}, "
+                f"attn_gate={self.attn_gate}")
         if self.head_chunk and self.vocab_size % self.head_chunk:
             raise ValueError(f"head_chunk ({self.head_chunk}) must divide "
                              f"vocab_size ({self.vocab_size})")
@@ -499,10 +538,11 @@ class HybridLM:
             of = of.reshape(b, t, hh * hd) * jax.nn.sigmoid(gate)
             return x + of.astype(x.dtype) @ p["w_out"], aux
 
-    def _qkv(self, p, hid, yarn=None):
+    def _qkv(self, p, hid, yarn=None, positions=None):
         """The attention mixers' heads from the layer's normed input:
         ``(q [B, T, heads, hd], k, v [B, T, kv heads, hd], the output
-        gate)``, ``q`` and ``k`` normed a head and rotated."""
+        gate)``, ``q`` and ``k`` normed a head and rotated (by
+        ``positions [T]`` where given)."""
         b, t, _ = hid.shape
         h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
         qg = (hid @ p["w_q"]).reshape(b, t, h, hd * (1 + self.attn_gate))
@@ -510,22 +550,32 @@ class HybridLM:
         k = (hid @ p["w_k"]).reshape(b, t, kv, hd)
         v = (hid @ p["w_v"]).reshape(b, t, kv, hd)
         q = _rotary(self._norm(q, p["q_norm"]),
-                    self.rope_theta, self.rotary_dim, yarn)
+                    self.rope_theta, self.rotary_dim, yarn, positions)
         k = _rotary(self._norm(k, p["k_norm"]),
-                    self.rope_theta, self.rotary_dim, yarn)
+                    self.rope_theta, self.rotary_dim, yarn, positions)
         return q, k, v, gate
 
     def _full_mixer(self, lp, x, window=None):
         """The attention mixer; ``window``: the window mixer, which sees
-        that many keys and turns its heads with the plain table."""
+        that many keys and turns its heads with the plain table. Under
+        ``block_diffusion`` the ``T`` rows are ``T / 2`` positions twice
+        (the noised copy, then the clean one) and the mask is the
+        block-diffusion one, under a scope of its own."""
         from apex_tpu.contrib.multihead_attn.flash_attention import (
             flash_attention, reference_attention)
         b, t, _ = x.shape
         h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
         yarn = None if window else self.rope_yarn
-        with jax.named_scope("window_attention" if window else "attention"):
+        mask, positions = {"causal": True, "window": window}, None
+        if self.block_diffusion:
+            mask = {"block_diffusion": (self.block_diffusion, t // 2)}
+            positions = jnp.tile(jnp.arange(t // 2), 2)
+        with jax.named_scope("diffusion_attention" if self.block_diffusion
+                             else "window_attention" if window
+                             else "attention"):
             p = lp["attn"]
-            q, k, v, gate = self._qkv(p, self._norm(x, lp["norm1"]), yarn)
+            q, k, v, gate = self._qkv(p, self._norm(x, lp["norm1"]), yarn,
+                                      positions)
             # each key/value head serves h // kv query heads: broadcast in
             # front of the kernel (its transpose sums the group's dK, dV)
             q = q.transpose(0, 2, 1, 3)
@@ -533,11 +583,17 @@ class HybridLM:
                     for a in (k, v))
             attend = flash_attention if self.attn_impl == "fast" \
                 else reference_attention
-            a = attend(q, k, v, causal=True, window=window,
+            a = attend(q, k, v, **mask,
                        scale=hd ** -0.5).transpose(0, 2, 1, 3)
             if self.attn_gate:
                 a = a * jax.nn.sigmoid(gate.astype(_F32)).astype(x.dtype)
-            return x + a.reshape(b, t, h * hd) @ p["w_o"]
+            out = x + a.reshape(b, t, h * hd) @ p["w_o"]
+            if not self.block_diffusion:
+                return out
+            # what the heads made for the first noised rows, before any
+            # router has read anything: a forward reading no routing moves
+            return out, {"diffusion_probe": a[:, :PROBE_ROWS].reshape(
+                b, -1, h * hd).astype(_F32)}
 
     def _index(self, p, hid):
         """The indexer of a "sparse" layer on the layer's normed input:
@@ -658,11 +714,13 @@ class HybridLM:
 
     def _block(self, kind: str, lp, x, ffn: str = "experts", bias=None):
         """One layer: ``(x, the expert layer's aux | None)``, and for a
-        "sparse" or a "kda" layer ``(x, (that, the mixer's aux))``.
+        "sparse" or a "kda" layer, and a "full" one under
+        ``block_diffusion``, ``(x, (that, the mixer's aux))``.
         ``bias``: the sigmoid router's selection bias of this layer."""
-        if kind in ("sparse", "kda"):
-            x, mixer_aux = (self._sparse_mixer if kind == "sparse"
-                            else self._kda_mixer)(lp, x)
+        if kind in ("sparse", "kda") or self.block_diffusion:
+            x, mixer_aux = {"sparse": self._sparse_mixer,
+                            "kda": self._kda_mixer,
+                            "full": self._full_mixer}[kind](lp, x)
             x, aux = self._ffn(lp, x, ffn, bias)
             return x, (aux, mixer_aux)
         x = {"linear": self._linear_mixer, "full": self._full_mixer,
@@ -704,7 +762,7 @@ class HybridLM:
             x = params["embed"][tokens]
         # a run of like layers is one scanned body over the run's stacked
         # parameters: three Gated DeltaNet layers compile once
-        auxes, indexers, kdas, first, row = [], [], [], 0, 0
+        auxes, indexers, kdas, probes, first, row = [], [], [], [], 0, 0
         saved = SAVED_NAMES
         if "sparse" in self.layer_types:
             from apex_tpu.ops import sparse_index
@@ -730,9 +788,10 @@ class HybridLM:
                     xs, row = (xs, router_bias[row:row + n]), row + n
             with jax.named_scope("layer_scan"):
                 x, aux = jax.lax.scan(block, x, xs)
-            if kind in ("sparse", "kda"):
+            if kind in ("sparse", "kda") or self.block_diffusion:
                 aux, mixer_aux = aux
-                (indexers if kind == "sparse" else kdas).append(mixer_aux)
+                {"sparse": indexers, "kda": kdas,
+                 "full": probes}[kind].append(mixer_aux)
             if aux is not None:
                 auxes.append(aux)
             first += n
@@ -760,6 +819,8 @@ class HybridLM:
         if kda_aux:
             counters["kda_chunk_decay_nats_max"] = jnp.max(
                 kda_aux["kda_chunk_decay_nats"])
+        if probes:      # layer 0's
+            counters["diffusion_probe"] = probes[0]["diffusion_probe"][0]
         return x, counters
 
     def apply(self, params: dict, tokens, router_bias=None):
@@ -777,8 +838,12 @@ class HybridLM:
         ``moe_live_tiles_max``, ``expert_load_max_over_mean``; the sigmoid
         router's ``expert_pairs``; the sparse layers' ``index_loss``,
         ``select_pairs``, ``select_live_tile_pct``; the "kda" layers'
-        ``kda_chunk_decay_nats_max``)."""
+        ``kda_chunk_decay_nats_max``). A model trained by block diffusion
+        (``block_diffusion`` > 0) takes the triple ``(tokens, masked, p)``
+        in ``tokens``' place: :meth:`diffusion_loss_with_counters`."""
         from apex_tpu.contrib.xentropy import linear_cross_entropy
+        if self.block_diffusion:
+            return self.diffusion_loss_with_counters(params, *tokens)
         x, c = self.hidden_states(params, tokens[:, :-1], router_bias)
         with jax.named_scope("head_loss"):
             losses = linear_cross_entropy(
@@ -789,6 +854,43 @@ class HybridLM:
                 "load_balance_loss")
             if "index_loss" in c:       # stays among the counters too
                 loss = loss + self.index_coef * c["index_loss"]
+        return loss, c
+
+    def diffusion_loss_with_counters(self, params: dict, tokens, masked, p):
+        """The block-diffusion loss of ``tokens [R, L]`` with the positions
+        ``masked [R, L]`` (bool) drawn at the probabilities ``p [R]``: the
+        layers see each sequence twice, ``[noised ; clean]`` (``2 L`` rows;
+        a masked position of the noised copy holds the mask token, id
+        ``vocab_size - 1``; both copies at positions ``0 .. L - 1``), the
+        head the noised copy alone, and a masked position predicts its own
+        token (no shift) with weight ``1 / p``: ``mean over R of (1 / L)
+        sum_masked xent / p``, plus ``aux_coef`` times the load-balancing
+        terms over all ``2 L`` rows. The counters are
+        :meth:`loss_with_counters`' and ``diffusion_masked_tokens`` (the
+        positions that carried loss), ``diffusion_weight_max`` (the
+        largest ``1 / p``) and ``diffusion_probe`` (``float32 [R,
+        PROBE_ROWS, heads x head_dim]``: what layer 0's heads made for the
+        first noised rows, before ``W_o``: a forward reading that no
+        router's choice has touched)."""
+        from apex_tpu.contrib.xentropy import linear_cross_entropy
+        if not self.block_diffusion:
+            raise ValueError("diffusion_loss_with_counters: the model has "
+                             "block_diffusion=0, its loss is next-token")
+        rows, length = tokens.shape
+        with jax.named_scope("embed"):
+            twice = jnp.concatenate([jnp.where(
+                masked, self.vocab_size - 1, tokens), tokens], axis=1)
+        x, c = self.hidden_states(params, twice)
+        with jax.named_scope("head_loss"):
+            losses = linear_cross_entropy(
+                x[:, :length].reshape(-1, self.hidden), self._head(params),
+                tokens.reshape(-1), chunk=self.head_chunk or self.vocab_size)
+            p = p.astype(_F32)
+            weight = (masked / p[:, None]).reshape(-1)
+            loss = jnp.sum(losses * weight) / (rows * length) \
+                + self.aux_coef * c.pop("load_balance_loss")
+            c["diffusion_masked_tokens"] = jnp.sum(masked)
+            c["diffusion_weight_max"] = jnp.max(1.0 / p)
         return loss, c
 
     def loss_with_router_state(self, params: dict, router_bias, tokens):

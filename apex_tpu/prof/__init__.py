@@ -119,6 +119,7 @@ SCOPES = ("embed", "attention", "mlp", "head_loss",     # models/transformer
           "linear_attention", "delta_rule",             # models/hybrid_lm
           "latent_attention", "short_conv", "window_attention",
           "sparse_attention", "sparse_index", "kda_attention",
+          "diffusion_attention",
           "moe_route", "moe_experts")                   # contrib/moe
 
 # Regions: names of *structure that encloses scopes*, opened with the same

@@ -71,6 +71,13 @@ def _flash(bwd, **kw):
         fwd(q, k, v).astype(F32) ** 2), argnums=(0, 1, 2))
 
 
+def _flash_bd(q, k, v):
+    from apex_tpu.contrib.multihead_attn import flash_attention
+    return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, block_diffusion=(4, q.shape[2] // 2)).astype(F32) ** 2),
+        argnums=(0, 1, 2))(q, k, v)
+
+
 def _flash_sel(q, k, v, select):
     from apex_tpu.contrib.multihead_attn import flash_attention
     return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
@@ -177,6 +184,11 @@ KERNELS = [
      _qkv(1, 32, 16384, 128) + [((1, 16384, 512), I32)],
      ("apex_flash_sel_fwd", "apex_flash_sel_bwd_dq",
       "apex_flash_sel_bwd_dkv")),
+    # SDAR's block-diffusion layer: 8,192 positions twice (the noised copy
+    # beside the clean one), 32 heads of 128, blocks of 4
+    ("flash_bd_fwd_bwd-B1H32S16384D128", lambda: _flash_bd,
+     _qkv(1, 32, 16384, 128),
+     ("apex_flash_bd_fwd", "apex_flash_bd_bwd_dq", "apex_flash_bd_bwd_dkv")),
     ("flash_fwd-B24H16S2048D128", lambda: _flash(False),
      _qkv(24, 16, 2048, 128),
      ("apex_flash_fwd",)),

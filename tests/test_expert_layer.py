@@ -101,14 +101,16 @@ def test_the_uncut_layer_is_the_dense_mixture_plus_the_shared_expert(router):
 @pytest.mark.parametrize("router", ROUTERS)
 @pytest.mark.parametrize("experts, chips, shared, top", [
     (E, 4, F, K), (E, 32, F, K), (64, 8, F, K), (64, 8, 0, K),
-    (64, 4, 0, 8)])
+    (64, 4, 0, 8), (128, 8, 0, 8)])
 def test_the_shares_add_up(experts, chips, shared, top, router):
     """The parts all shares give, the shared expert counted once, are the
     uncut layer (the eight shares of a 64-expert top-4 layer among them,
     with a shared expert and, the bias-balanced sigmoid layer of the
     short-convolution hybrids, with none; the four shares of a 64-expert
     top-8 layer with none, the softmax layer of the sliding-window
-    models); and a share computes its own experts' part, nothing that
+    models; the eight shares of a 128-expert top-8 layer with none, the
+    block-diffusion and the learned-key-set models'); and a share computes
+    its own experts' part, nothing that
     stands in for the others."""
     kind = dict(router=router, experts=experts, shared=shared, top=top)
     params, x = _params(2, **kind), _x(48, 3)
