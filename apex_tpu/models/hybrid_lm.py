@@ -26,11 +26,11 @@ short-convolution hybrids, with the layer pattern as data.
 RMSNorm (weight zero at init) or, with ``zero_centred_norm=False``, the
 plain one (``* w``, one at init); no matrix has a bias; the head is a
 matrix of its own or, with ``tied_head``, the embedding (its gradient is
-then the gather's scatter-add plus the chunked head's). Where
+then the gather's scatter-add plus the fused head's). Where
 :class:`~apex_tpu.models.TransformerLM` is one GPT-2 block repeated, this
 model's layers differ, so it is a class of its own and shares with the
-dense LM what lies under it: the flash-attention kernels, the chunked head
-(``linear_cross_entropy``), recomputation (``jax.checkpoint`` a block) and
+dense LM what lies under it: the flash-attention kernels, the fused head
+(``weighted_linear_cross_entropy``), recomputation (``jax.checkpoint`` a block) and
 the step builder (``apex_tpu.train_step.build_step``).
 
 The **Gated DeltaNet mixer** (``linear_k_heads`` key heads and
@@ -316,7 +316,12 @@ class HybridLM:
     zero_centred_norm: bool = True
     tied_head: bool = False     # the head is the embedding
     attn_impl: str = "fast"     # "fast": the flash kernels; "default": jnp
-    head_chunk: int = 0         # vocabulary columns a step of the head
+    head_chunk: int = 0         # accepted (it must divide the vocabulary)
+    #                             and unread: the head walks blocks of rows
+    #                             sized from the batch (contrib.xentropy's
+    #                             weighted_linear_cross_entropy); it stays
+    #                             while the benchmark's configurations and
+    #                             drivers pass it
     remat: bool = False         # recompute each block in the backward,
     #                             but for the flash forward's (o, lse)
 
@@ -841,17 +846,16 @@ class HybridLM:
         ``kda_chunk_decay_nats_max``). A model trained by block diffusion
         (``block_diffusion`` > 0) takes the triple ``(tokens, masked, p)``
         in ``tokens``' place: :meth:`diffusion_loss_with_counters`."""
-        from apex_tpu.contrib.xentropy import linear_cross_entropy
+        from apex_tpu.contrib.xentropy import weighted_linear_cross_entropy
         if self.block_diffusion:
             return self.diffusion_loss_with_counters(params, *tokens)
         x, c = self.hidden_states(params, tokens[:, :-1], router_bias)
         with jax.named_scope("head_loss"):
-            losses = linear_cross_entropy(
-                x.reshape(-1, self.hidden), self._head(params),
-                tokens[:, 1:].reshape(-1),
-                chunk=self.head_chunk or self.vocab_size)
-            loss = jnp.mean(losses) + self.aux_coef * c.pop(
-                "load_balance_loss")
+            targets = tokens[:, 1:].reshape(-1)
+            loss = weighted_linear_cross_entropy(
+                x.reshape(-1, self.hidden), self._head(params), targets,
+                jnp.full(targets.shape, 1.0 / targets.size, _F32)) \
+                + self.aux_coef * c.pop("load_balance_loss")
             if "index_loss" in c:       # stays among the counters too
                 loss = loss + self.index_coef * c["index_loss"]
         return loss, c
@@ -872,7 +876,7 @@ class HybridLM:
         PROBE_ROWS, heads x head_dim]``: what layer 0's heads made for the
         first noised rows, before ``W_o``: a forward reading that no
         router's choice has touched)."""
-        from apex_tpu.contrib.xentropy import linear_cross_entropy
+        from apex_tpu.contrib.xentropy import weighted_linear_cross_entropy
         if not self.block_diffusion:
             raise ValueError("diffusion_loss_with_counters: the model has "
                              "block_diffusion=0, its loss is next-token")
@@ -882,12 +886,11 @@ class HybridLM:
                 masked, self.vocab_size - 1, tokens), tokens], axis=1)
         x, c = self.hidden_states(params, twice)
         with jax.named_scope("head_loss"):
-            losses = linear_cross_entropy(
-                x[:, :length].reshape(-1, self.hidden), self._head(params),
-                tokens.reshape(-1), chunk=self.head_chunk or self.vocab_size)
             p = p.astype(_F32)
-            weight = (masked / p[:, None]).reshape(-1)
-            loss = jnp.sum(losses * weight) / (rows * length) \
+            weight = masked / p[:, None] / (rows * length)
+            loss = weighted_linear_cross_entropy(
+                x[:, :length].reshape(-1, self.hidden), self._head(params),
+                tokens.reshape(-1), weight.reshape(-1)) \
                 + self.aux_coef * c.pop("load_balance_loss")
             c["diffusion_masked_tokens"] = jnp.sum(masked)
             c["diffusion_weight_max"] = jnp.max(1.0 / p)

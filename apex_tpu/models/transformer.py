@@ -61,12 +61,14 @@ class TransformerLM:
     moe_aux_weight: float = 0.01   # Switch load-balance loss weight
     expert_axis: Optional[str] = None
     expert_axis_size: int = 0
-    # LM-head loss chunking: 0 computes full [B*T, V] logits through the
+    # The fused LM head: 0 computes full [B*T, V] logits through the
     # fused xentropy op; > 0 routes ``loss`` through
-    # ``contrib.xentropy.linear_cross_entropy`` scanning the (tied) head
-    # in vocab chunks of this size — peak memory O(N*chunk) instead of
+    # ``contrib.xentropy.weighted_linear_cross_entropy`` walking the
+    # (tied) head in blocks of rows — peak memory O(rows*V) instead of
     # the O(N*V) fp32 logits temp (4 GB at B=8, T=4k, V=32k — the r4
-    # long-context OOM), at one extra head-matmul pass in the backward.
+    # long-context OOM), the head's gradients made beside the loss. The
+    # value is a switch (and must divide the vocabulary): the rows of a
+    # block come from the shapes.
     head_chunk: int = 0
     # rematerialize each transformer block in the backward
     # (jax.checkpoint): activation memory drops from O(layers) block
@@ -243,20 +245,21 @@ class TransformerLM:
             }
         return out
 
-    def _token_losses(self, params, out, targets_flat):
-        """Per-token losses from apply()'s output — full logits through
-        the fused xentropy op, or (head_chunk > 0) final hidden states
-        through the chunked fused head+xentropy."""
-        from apex_tpu.contrib.xentropy import (SoftmaxCrossEntropyLoss,
-                                               linear_cross_entropy)
+    def _token_losses(self, params, out, targets_flat, weights):
+        """``sum(weights * per-token losses)`` from apply()'s output —
+        full logits through the fused xentropy op, or (head_chunk > 0)
+        final hidden states through the reduced fused head, which makes
+        the head's gradients beside the loss."""
+        from apex_tpu.contrib.xentropy import (
+            SoftmaxCrossEntropyLoss, weighted_linear_cross_entropy)
         with jax.named_scope("head_loss"):
             if self.head_chunk > 0:
-                return linear_cross_entropy(
+                return weighted_linear_cross_entropy(
                     out.reshape(-1, self.embed_dim), params["tok_emb"],
-                    targets_flat, chunk=self.head_chunk)
-            return SoftmaxCrossEntropyLoss.apply(
+                    targets_flat, weights)
+            return jnp.sum(weights * SoftmaxCrossEntropyLoss.apply(
                 out.reshape(-1, self.vocab_size), targets_flat,
-                padding_idx=None)  # no padding token in this LM
+                padding_idx=None))  # no padding token in this LM
 
     def loss(self, params: dict, tokens: jax.Array, *,
              is_training: bool = True,
@@ -277,9 +280,10 @@ class TransformerLM:
                              dropout_key=dropout_key, return_aux=moe,
                              return_hidden=hid)
             out, aux = out if moe else (out, None)
-            targets = tokens[:, 1:]
-            losses = self._token_losses(params, out, targets.reshape(-1))
-            loss = jnp.mean(losses)
+            targets = tokens[:, 1:].reshape(-1)
+            loss = self._token_losses(
+                params, out, targets,
+                jnp.full(targets.shape, 1.0 / targets.size, jnp.float32))
             if moe:  # Switch aux objective keeps the router balanced
                 loss = loss + self.moe_aux_weight * \
                     aux["moe_load_balance_loss"]
@@ -297,13 +301,13 @@ class TransformerLM:
             tokens[:, :1], self.seq_axis,
             [((i + 1) % n, i) for i in range(n)])
         targets = jnp.concatenate([tokens[:, 1:], nxt_first], axis=1)
-        losses = self._token_losses(
-            params, out, targets.reshape(-1)).reshape(b, t)
         # the global final position (last shard's last token) has no target
         is_last_shard = jax.lax.axis_index(self.seq_axis) == n - 1
-        mask = jnp.ones((b, t), losses.dtype).at[:, -1].set(
+        mask = jnp.ones((b, t), jnp.float32).at[:, -1].set(
             jnp.where(is_last_shard, 0.0, 1.0))
-        total = jax.lax.psum(jnp.sum(losses * mask), self.seq_axis)
+        total = jax.lax.psum(self._token_losses(
+            params, out, targets.reshape(-1), mask.reshape(-1)),
+            self.seq_axis)
         count = jax.lax.psum(jnp.sum(mask), self.seq_axis)
         loss = total / count
         if moe:

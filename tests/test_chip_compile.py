@@ -645,3 +645,33 @@ def test_one_chip_step_has_no_all_reduce(topo, for_chip):
     assert "all-reduce" not in text and "async-collective" not in text
     assert len(re.findall(r"%apex_mt_adam\S* = ", text)) == 1
     assert "apex_flash_fwd" in text
+
+
+# -- the reduced fused head: where a block's logits live ---------------------
+
+@pytest.mark.parametrize("n,d,v,rows,in_vmem", [
+    (16384, 2304, 20480, 1024, True),       # klin: 80 MiB a block
+    (16384, 2304, 24576, 1024, True),       # mellum2: 96 MiB, the edge
+    (8192, 2048, 50257, 2048, False),       # cgpt: 412 MB, HBM
+], ids=["klin", "mellum2", "cgpt"])
+def test_the_reduced_heads_logits_live_where_its_rows_were_chosen_for(
+        chip, for_chip, n, d, v, rows, in_vmem):
+    """``weighted_linear_cross_entropy``'s gradient at the cells' shapes:
+    a block is ``_block_rows(V)`` rows, its float32 logits carry ``S(1)``
+    (VMEM) where the rule chose 1,024 rows for that and not where the
+    vocabulary is too wide, and the float32 ``[V, D]`` accumulator of
+    ``dW`` is updated in place: no copy of that shape in the loop."""
+    from apex_tpu.contrib.xentropy import weighted_linear_cross_entropy
+
+    def grad(h, w, labels):
+        return jax.value_and_grad(
+            lambda h, w: weighted_linear_cross_entropy(
+                h, w, labels, jnp.full((n,), 1.0 / n, F32)), (0, 1))(h, w)
+    h, w, labels = (jax.ShapeDtypeStruct(s, t, sharding=chip) for s, t in
+                    (((n, d), BF16), ((v, d), BF16), ((n,), I32)))
+    text = jax.jit(grad).lower(h, w, labels).compile().as_text()
+    logits = re.findall(rf"= f32\[{rows},{v}\]\{{[^}}]*\}}", text)
+    assert logits, f"no [{rows}, {v}] float32 logits in the program"
+    assert any("S(1)" in l for l in logits) == in_vmem
+    assert not re.search(rf"= f32\[{v},{d}\]\{{[^}}]*\}} copy\(", text)
+    assert len(re.findall(r" convolution\(", text)) == 3

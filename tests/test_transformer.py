@@ -326,18 +326,32 @@ def test_remat_policy_validation():
                       remat_policy="dots_saveable")
 
 
-def test_head_chunk_loss_and_grads_match():
-    """head_chunk routes loss through the chunked fused head; values and
-    grads must match the materialized-logits path exactly (V=50 with
-    chunk 10 exercises multi-chunk label placement)."""
+def _per_row_loss(m, p, toks):
+    """The fused head's loss as it was before the reduced op: the mean of
+    the per-row op's losses over the final hidden states."""
+    from apex_tpu.contrib.xentropy import linear_cross_entropy
+    hidden = m.apply(p, toks[:, :-1], is_training=False, return_hidden=True)
+    return jnp.mean(linear_cross_entropy(
+        hidden.reshape(-1, m.embed_dim), p["tok_emb"],
+        toks[:, 1:].reshape(-1), chunk=m.head_chunk))
+
+
+@pytest.mark.parametrize("oracle", ["whole_logits", "per_row_op"])
+def test_head_chunk_loss_and_grads_match(oracle):
+    """head_chunk routes loss through the reduced fused head (blocks of
+    rows, the head's gradients made beside the loss); values and grads
+    must match the materialized-logits path and the per-row op's mean
+    (V=50 with chunk 10: multi-chunk label placement there)."""
     base = _model()
     chunked = _model(head_chunk=10)
     p = base.init(jax.random.key(0))
     toks = _tokens()
-    l0 = base.loss(p, toks, is_training=False)
+    want = {"whole_logits": lambda q: base.loss(q, toks, is_training=False),
+            "per_row_op": lambda q: _per_row_loss(chunked, q, toks)}[oracle]
+    l0 = want(p)
     l1 = chunked.loss(p, toks, is_training=False)
     np.testing.assert_allclose(float(l0), float(l1), rtol=1e-5)
-    g0 = jax.grad(lambda q: base.loss(q, toks, is_training=False))(p)
+    g0 = jax.grad(want)(p)
     g1 = jax.grad(lambda q: chunked.loss(q, toks, is_training=False))(p)
     for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -371,33 +385,62 @@ def test_head_chunk_must_divide_vocab():
         _model(head_chunk=7)
 
 
-def test_head_chunk_sequence_parallel_grads_match():
-    """Gradients of the chunked-head custom_vjp through shard_map +
+def _per_row_sp_loss(sp, p, toks):
+    """``TransformerLM.loss``'s sequence-parallel arm as it was before the
+    reduced op (inside shard_map): the per-row op's losses, the global last
+    position masked, the global mean by psum."""
+    from apex_tpu.contrib.xentropy import linear_cross_entropy
+    n, (b, t) = sp.seq_axis_size, toks.shape
+    hidden = sp.apply(p, toks, is_training=False, return_hidden=True)
+    nxt_first = jax.lax.ppermute(toks[:, :1], sp.seq_axis,
+                                 [((i + 1) % n, i) for i in range(n)])
+    targets = jnp.concatenate([toks[:, 1:], nxt_first], axis=1)
+    losses = linear_cross_entropy(
+        hidden.reshape(-1, sp.embed_dim), p["tok_emb"], targets.reshape(-1),
+        chunk=sp.head_chunk).reshape(b, t)
+    is_last_shard = jax.lax.axis_index(sp.seq_axis) == n - 1
+    mask = jnp.ones((b, t), losses.dtype).at[:, -1].set(
+        jnp.where(is_last_shard, 0.0, 1.0))
+    return jax.lax.psum(jnp.sum(losses * mask), sp.seq_axis) \
+        / jax.lax.psum(jnp.sum(mask), sp.seq_axis)
+
+
+@pytest.mark.parametrize("oracle", ["whole_logits", "per_row_op"])
+def test_head_chunk_sequence_parallel_grads_match(oracle):
+    """Gradients of the fused-head custom_vjp through shard_map +
     ppermute target shift must match the single-device materialized
     oracle — the long-context SP training configuration the fused head
-    exists for."""
+    exists for — and, with the loss, the same arm written with the
+    per-row op."""
     mesh = make_mesh({"seq": N}, devices=jax.devices()[:N])
     dense = _model()
     sp = _model(seq_axis="seq", seq_axis_size=N, head_chunk=10)
     p = dense.init(jax.random.key(0))
     toks = _tokens()
 
-    @jax.jit
-    @partial(shard_map, mesh=mesh, in_specs=(P(), P(None, "seq")),
-             out_specs=P(), check_vma=False)
-    def sp_loss(p, toks):
-        return sp.loss(p, toks, is_training=False)
+    def sharded(fn):
+        return jax.jit(partial(
+            shard_map, mesh=mesh, in_specs=(P(), P(None, "seq")),
+            out_specs=P(), check_vma=False)(fn))
 
-    def oracle(q):
+    sp_loss = sharded(lambda p, toks: sp.loss(p, toks, is_training=False))
+
+    def whole_logits(q):
         logits = dense.apply(q, toks)[:, :-1]
         logp = jax.nn.log_softmax(logits)
         return -jnp.mean(jnp.take_along_axis(logp, toks[:, 1:, None], -1))
 
+    per_row = sharded(partial(_per_row_sp_loss, sp))
+    oracle, tol = {
+        "whole_logits": (whole_logits, dict(rtol=5e-3, atol=1e-5)),
+        "per_row_op": (lambda q: per_row(q, toks),
+                       dict(rtol=1e-4, atol=1e-6))}[oracle]
+    np.testing.assert_allclose(float(sp_loss(p, toks)), float(oracle(p)),
+                               rtol=2e-4)
     g1 = jax.grad(oracle)(p)
     g2 = jax.grad(lambda q: sp_loss(q, toks))(p)
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=5e-3, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), **tol)
 
 
 # ---------------------------------------------------------------------------

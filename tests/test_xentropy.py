@@ -201,6 +201,127 @@ class TestLinearCrossEntropy:
                                    rtol=1e-5, atol=1e-5)
 
 
+class TestWeightedLinearCrossEntropy:
+    """The reduced fused head (``sum(row_weights * rows' losses)`` over
+    blocks of rows, its gradients made beside the loss) against the per-row
+    op under the same weights: value, ``dh`` and ``dW``."""
+
+    N, D, V = 24, 16, 40
+
+    def _data(self, dtype, weights, seed=0):
+        rs = np.random.RandomState(seed)
+        h = jnp.asarray(rs.randn(self.N, self.D), dtype)
+        w = jnp.asarray(rs.randn(self.V, self.D) * 0.1, dtype)
+        labels = jnp.asarray(rs.randint(0, self.V, self.N), jnp.int32)
+        labels = labels.at[3].set(7).at[20].set(7)
+        p = rs.uniform(0.2, 0.9, self.N).astype(np.float32)
+        rw = {"mask": rs.rand(self.N) < 0.5,            # zeros: no loss
+              "over_p": 1.0 / p}[weights]               # non-uniform
+        return h, w, labels, jnp.asarray(rw, jnp.float32)
+
+    # one block of rows (the op's own choice), several, and a size the
+    # rows do not divide by
+    @pytest.mark.parametrize("rows", [None, 8, 7])
+    @pytest.mark.parametrize("weights", ["mask", "over_p"])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("padding_idx", [None, 7])
+    @pytest.mark.parametrize("smoothing", [0.0, 0.1])
+    def test_value_and_gradients_are_the_per_row_ops(
+            self, smoothing, padding_idx, dtype, weights, rows):
+        from apex_tpu.contrib.xentropy import (
+            linear_cross_entropy, weighted_linear_cross_entropy)
+        h, w, labels, rw = self._data(jnp.dtype(dtype), weights)
+        kw = dict(smoothing=smoothing, padding_idx=padding_idx)
+
+        # a scaled loss: the cotangent that reaches the op is 48, not 1
+        def reduced(h, w):
+            return 48.0 * weighted_linear_cross_entropy(
+                h, w, labels, rw, _rows=rows, **kw)
+
+        def per_row(h, w):
+            return 48.0 * jnp.sum(rw * linear_cross_entropy(
+                h, w, labels, chunk=8, **kw))
+
+        got, (gh, gw) = jax.value_and_grad(reduced, (0, 1))(h, w)
+        want, (want_h, want_w) = jax.value_and_grad(per_row, (0, 1))(h, w)
+        assert got.dtype == jnp.float32 and got.shape == ()
+        assert gh.dtype == h.dtype and gw.dtype == w.dtype
+        # bf16: both round a float32 sum once, at the end; an ulp apart
+        tol = dict(rtol=1e-5, atol=1e-4) if dtype == "float32" \
+            else dict(rtol=2e-2, atol=2e-2)
+        np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(gh, np.float32),
+                                   np.asarray(want_h, np.float32), **tol)
+        np.testing.assert_allclose(np.asarray(gw, np.float32),
+                                   np.asarray(want_w, np.float32), **tol)
+        # rows at weight zero and padded rows: no gradient at all
+        dead = np.asarray(rw) == 0
+        if padding_idx is not None:
+            dead |= np.asarray(labels) == padding_idx
+        np.testing.assert_array_equal(np.asarray(gh, np.float32)[dead], 0.0)
+        # not differentiated: the same loss
+        np.testing.assert_allclose(float(reduced(h, w)), float(got),
+                                   rtol=1e-6)
+
+    @pytest.mark.parametrize("vocab, rows", [
+        (8192, 1024), (18992, 1024), (20480, 1024), (24576, 1024),
+        (24577, 2048), (50257, 2048), (151936, 2048)])
+    def test_the_blocks_rows_come_from_the_vocabulary(self, vocab, rows):
+        """1,024 rows where their float32 logits fit the share of VMEM
+        that XLA gives a loop's temporary (the hybrid cells' vocabularies),
+        2,048 above it (the dense cell's)."""
+        from apex_tpu.contrib.xentropy import linear_xentropy
+        assert linear_xentropy._block_rows(vocab) == rows
+        assert (4 * rows * vocab <= linear_xentropy.VMEM_LOGITS) \
+            == (rows == 1024)
+
+    def test_no_gradient_reaches_the_weights_and_shapes_are_checked(self):
+        from apex_tpu.contrib.xentropy import weighted_linear_cross_entropy
+        h, w, labels, rw = self._data(jnp.float32, "over_p")
+        g = jax.grad(lambda rw: weighted_linear_cross_entropy(
+            h, w, labels, rw))(rw)
+        np.testing.assert_array_equal(np.asarray(g), 0.0)
+        with pytest.raises(ValueError, match="row_weights"):
+            weighted_linear_cross_entropy(h, w, labels, rw[:-1])
+        with pytest.raises(ValueError, match="labels"):
+            weighted_linear_cross_entropy(h, w, labels[:-1], rw[:-1])
+
+    @staticmethod
+    def _vocabulary_wide_matmuls(jaxpr, v):
+        """``dot_general``s with a dimension of ``v`` in an operand or the
+        result, through every sub-jaxpr (a scan's body counts once: a
+        block)."""
+        n = 0
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general" and any(
+                    v in x.aval.shape for x in eqn.invars + eqn.outvars):
+                n += 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                n += TestWeightedLinearCrossEntropy._vocabulary_wide_matmuls(
+                    sub, v)
+        return n
+
+    @pytest.mark.parametrize("op, matmuls", [("reduced", 3), ("per_row", 4)])
+    def test_the_differentiated_step_holds_three_matmuls_a_block(
+            self, op, matmuls):
+        """The mechanism's counter: logits, ``dh`` and ``dW`` and no second
+        pass of logits, where the per-row op (one chunk: the whole
+        vocabulary) makes its logits again in the backward."""
+        from apex_tpu.contrib.xentropy import (
+            linear_cross_entropy, weighted_linear_cross_entropy)
+        h, w, labels, rw = self._data(jnp.float32, "over_p")
+        loss = {
+            "reduced": lambda h, w: weighted_linear_cross_entropy(
+                h, w, labels, rw, _rows=8),
+            "per_row": lambda h, w: jnp.sum(rw * linear_cross_entropy(
+                h, w, labels, chunk=self.V))}[op]
+        jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(h, w)
+        assert self._vocabulary_wide_matmuls(jaxpr.jaxpr, self.V) == matmuls
+        if op == "reduced":     # and alone it is one: the logits
+            assert self._vocabulary_wide_matmuls(
+                jax.make_jaxpr(loss)(h, w).jaxpr, self.V) == 1
+
+
 @pytest.mark.parametrize("seed", range(6))
 def test_fuzz_vs_torch_cross_entropy(seed):
     """Randomized fuzz against the REAL torch oracle: random N/V (odd,

@@ -16,7 +16,8 @@ Benchmarks:
              flash_attention.DEFAULT_FLASH_MIN_S)
   flash_verify / flash_blocks : anomaly recheck / block-size sweep
   mlp      : the reference's MLP microbenchmark, bf16 against fp32
-  linear_xent : the chunked fused LM head against materialized logits
+  linear_xent : the fused LM head, per-row (vocabulary chunks) and reduced
+             (row blocks), against materialized logits
 
 LayerNorm, xentropy, the BatchNorm moments and LAMB have no kernel to time:
 XLA's side won each on the chip and is the only one (docs/PERF.md
@@ -327,15 +328,18 @@ def bench_mlp(steps):
 
 
 def bench_linear_xent(steps):
-    """Fused chunked LM-head loss vs materialized logits + fused xent,
-    fwd+bwd at a long-context-feasible size (N=8192 tokens, D=1024,
-    V=32768 — the lm_bench S=4096 head shape at batch 2). The fused
-    path's pitch is the O(N*chunk) memory bound; this row answers
-    whether it also costs or saves TIME where both fit."""
+    """Fused LM-head loss vs materialized logits + fused xent, fwd+bwd
+    at a long-context-feasible size (N=8192 tokens, D=1024, V=32768 —
+    the lm_bench S=4096 head shape at batch 2): the per-row op chunked
+    over the vocabulary (four vocabulary-wide matmuls) and the reduced
+    op over blocks of rows (three), each against the materialized path.
+    The fused paths' pitch is the memory bound; these rows answer
+    whether they also cost or save TIME where both fit."""
     import jax
     import jax.numpy as jnp
     from apex_tpu.contrib.xentropy import (linear_cross_entropy,
-                                           softmax_cross_entropy_loss)
+                                           softmax_cross_entropy_loss,
+                                           weighted_linear_cross_entropy)
     n, d, v = 8192, 1024, 32768
     h = jax.random.normal(jax.random.key(0), (n, d), jnp.bfloat16)
     w = jax.random.normal(jax.random.key(1), (v, d), jnp.bfloat16) * 0.02
@@ -344,6 +348,11 @@ def bench_linear_xent(steps):
     def fused(h, w):
         return jax.grad(lambda h, w: jnp.mean(linear_cross_entropy(
             h, w, labels, chunk=8192)), argnums=(0, 1))(h, w)
+
+    def reduced(h, w):
+        rw = jnp.full((n,), 1.0 / n, jnp.float32)
+        return jax.grad(lambda h, w: weighted_linear_cross_entropy(
+            h, w, labels, rw), argnums=(0, 1))(h, w)
 
     def materialized(h, w):
         def loss(h, w):
@@ -355,11 +364,14 @@ def bench_linear_xent(steps):
         return jax.grad(loss, argnums=(0, 1))(h, w)
 
     tf = time_fn("linear_xent_fused", fused, h, w, steps=steps)
+    tr = time_fn("linear_xent_reduced", reduced, h, w, steps=steps)
     tm = time_fn("linear_xent_materialized", materialized, h, w,
                  steps=steps)
     # record() schema: "pallas" column = fused, "xla" = materialized
     record("linear_xent_fwd_bwd", f"n{n} d{d} v{v} chunk8192 bf16",
            tf, tm)
+    record("linear_xent_reduced_fwd_bwd", f"n{n} d{d} v{v} row-blocks bf16",
+           tr, tm)
 
 
 def bench_flash_crossover(steps):
